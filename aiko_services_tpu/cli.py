@@ -44,18 +44,18 @@ def pipeline(definition: str, name: str | None, transport: str | None,
     """Create and run a pipeline from a JSON definition (reference
     `aiko_pipeline create`, pipeline.py:1444-1528).
 
-    Elastic-fleet children honor two env knobs set by the replica
-    factory (serve/autoscale.py): AIKO_COMPILE_CACHE points JAX's
-    persistent compilation cache at the fleet's shared directory, and
-    AIKO_WARM_WEIGHTS names a descriptor file whose tensors are
-    fetched from a live sibling over the transfer plane instead of
-    re-running setup()."""
+    The persistent compile cache lives where JAX_COMPILATION_CACHE_DIR
+    says, else in the checkout's fixed `.jax_cache` (the replica
+    factory, serve/autoscale.py, passes its fleet directory to children
+    through that variable).  AIKO_WARM_WEIGHTS names a descriptor file
+    whose tensors are fetched from a live sibling over the transfer
+    plane instead of re-running setup()."""
     import json
     import os
 
     from .pipeline import create_pipeline
     from .runtime import Process, enable_compile_cache
-    enable_compile_cache()  # no-op unless AIKO_COMPILE_CACHE is set
+    enable_compile_cache()
     process = Process(transport_kind=transport)
     pipeline_instance = create_pipeline(process, definition, name=name)
     warm_weights = os.environ.get("AIKO_WARM_WEIGHTS")

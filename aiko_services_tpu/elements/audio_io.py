@@ -37,8 +37,8 @@ _DEVICE_TONE = None  # lazily-built module-level jit (stable identity)
 def synthesize_tone_on_device(frequency: float, seconds: float,
                               sample_rate: int = SAMPLE_RATE):
     """Tone synthesized directly in HBM as ONE device program (a single
-    dispatch -- eager op-by-op jnp would pay per-op dispatch latency,
-    which dominates on tunneled/remote devices)."""
+    dispatch -- eager op-by-op jnp would pay per-op dispatch
+    latency)."""
     global _DEVICE_TONE
     import functools
 

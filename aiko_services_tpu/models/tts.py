@@ -138,7 +138,7 @@ def _dft_matrices(n_fft: int):
     TPU-first: a 400x201 matmul rides the MXU while XLA's complex FFT
     at this size runs on the scalar/vector pipeline -- the Griffin-Lim
     loop is 2 transforms x 30 iterations deep, so the transform IS the
-    workload (bench note: tts section, BENCH_NOTES.md)."""
+    workload."""
     from ..ops.audio import dft_basis
     cos_m, sin_m = dft_basis(n_fft)
     return jnp.asarray(cos_m), jnp.asarray(sin_m)
@@ -225,8 +225,7 @@ def griffin_lim(magnitude, config: TTSConfig) -> jnp.ndarray:
     fully on-device, jit-compiled with the synthesis net.  The
     transforms run as real DFT matmuls (MXU) rather than complex FFTs,
     and the loop carries only the phase ANGLE (real), so no complex
-    dtype exists anywhere (speedup vs the jnp.fft formulation measured
-    in BENCH_NOTES.md, tts section)."""
+    dtype exists anywhere."""
     n_fft, hop = config.n_fft, config.hop
     magnitude = magnitude.transpose(0, 2, 1)            # (B, T, bins)
     frames = magnitude.shape[1]

@@ -81,13 +81,7 @@ def is_distributed() -> bool:
     local backend, after which jax.distributed.initialize refuses to run
     -- the `if not is_distributed(): initialize_distributed()` idiom has
     to stay safe."""
-    if _INITIALIZED:
-        return True
-    try:
-        from jax._src.distributed import global_state
-        return getattr(global_state, "client", None) is not None
-    except (ImportError, AttributeError):  # pragma: no cover - internals moved
-        return False
+    return _INITIALIZED or jax.distributed.is_initialized()
 
 
 def process_index() -> int:

@@ -80,10 +80,13 @@ def create_mesh(axes: dict | None = None, devices=None) -> Mesh:
         raise ValueError(
             f"Mesh axes {dict(canonical)} need {total} devices, "
             f"have {len(devices)}")
-    try:
-        grid = mesh_utils.create_device_mesh(shape, devices=devices)
-    except (ValueError, AssertionError):
+    if devices[0].platform == "cpu":
+        # virtual CPU devices (tests) have no topology to honor
         grid = np.asarray(devices).reshape(shape)
+    else:
+        # on an accelerator a grid the ICI-aware layout cannot build is
+        # an error, never a silent fall to an arbitrary device order
+        grid = mesh_utils.create_device_mesh(shape, devices=devices)
     return Mesh(grid, names)
 
 

@@ -86,6 +86,13 @@ class LifeCycleManager(Actor):
             use_interpreter=use_interpreter, env=env)
         return client_id
 
+    def client_state(self, client_id) -> str | None:
+        """"spawning" | "running" | "deleting", or None once the client
+        is gone (exited, killed at a lapsed lease, or deleted).  Safe
+        from any thread: one dict read."""
+        record = self.clients.get(client_id)
+        return None if record is None else record["state"]
+
     def _handshake_expired(self, client_id) -> None:
         record = self.clients.get(client_id)
         if record is not None and record["state"] == "spawning":

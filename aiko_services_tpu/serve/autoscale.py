@@ -20,8 +20,8 @@
 #             (Pipeline.export_weights / import_weights) AND the
 #             persistent compile cache (runtime/compile_cache.py) turns
 #             every fleet-known shape's XLA compile into a deserialize,
-#             so time-to-healthy is hand-off + deserialize, not the
-#             2-40 s-per-shape compile storm BENCH_NOTES documents
+#             so time-to-healthy is hand-off + deserialize, not a
+#             seconds-per-shape compile storm
 #   drain     scale-down re-pins the victim's streams and replays
 #             cursors through the gateway's zero-loss failover path
 #             (Gateway.drain_replica -> _migrate_streams): bit-identical
@@ -724,13 +724,21 @@ class ProcessReplicaFactory:
     `python -m aiko_services_tpu pipeline <definition> --name <name>`
     with an env OVERLAY (merged over os.environ by ProcessManager) that
     pins JAX_PLATFORMS, the persistent compile-cache directory
-    (AIKO_COMPILE_CACHE), and -- when a sibling exported weights -- an
+    (JAX_COMPILATION_CACHE_DIR, unless the parent's environment already
+    places the cache), and -- when a sibling exported weights -- an
     AIKO_WARM_WEIGHTS descriptor file the child imports over the
     transfer plane before serving.  The gateway attaches the replica
     when registrar discovery sees it (gateway.discover), which closes
     the autoscaler's time-to-healthy clock; retire() runs the lifecycle
     layer's graceful delete (terminate, deletion lease, SIGKILL
-    escalation)."""
+    escalation).
+
+    A chip belongs to ONE OS process: on a one-chip host the child
+    cannot open the device its parent (or a sibling) holds, and either
+    exits or blocks in backend init until the manager's handshake lease
+    kills it.  Both end as a reported spawn failure (`ready(None,
+    {"error": ...})`), never a wait for a replica that cannot arrive.
+    A multi-chip host needs a device pinned per child in `env`."""
 
     def __init__(self, lifecycle_manager, definition_path: str,
                  transport: str | None = None, env: dict | None = None,
@@ -748,18 +756,21 @@ class ProcessReplicaFactory:
         # gateway's event loop, which is where the autoscaler tick
         # calls spawn()
         thread = threading.Thread(
-            target=self._launch, args=(name, warm_source),
+            target=self._launch, args=(name, warm_source, ready),
             name=f"autoscale-launch-{name}", daemon=True)
         thread.start()
         return thread
 
-    def _launch(self, name: str, warm_source) -> None:
+    def _launch(self, name: str, warm_source, ready=None) -> None:
         import json
+        import os
         import sys
         import tempfile
+
+        from ..runtime.compile_cache import ENV_CACHE_DIR
         env = dict(self.env)
-        if self.compile_cache:
-            env["AIKO_COMPILE_CACHE"] = str(self.compile_cache)
+        if self.compile_cache and not os.environ.get(ENV_CACHE_DIR):
+            env[ENV_CACHE_DIR] = str(self.compile_cache)
         try:
             warm_exports = _resolve_exports(warm_source)
         except Exception:
@@ -780,10 +791,27 @@ class ProcessReplicaFactory:
                      self.definition_path, "--name", name]
         if self.transport:
             arguments += ["--transport", self.transport]
-        self._clients[name] = self.lifecycle_manager.create_client(
-            sys.executable, arguments, use_interpreter=False, env=env)
-        # no ready() here: the replica becomes healthy when registrar
-        # discovery attaches it (AutoScaler.note_replica_added)
+        client_id = self._clients[name] = (
+            self.lifecycle_manager.create_client(
+                sys.executable, arguments, use_interpreter=False, env=env))
+        # no ready(handle) here: the replica becomes healthy when
+        # registrar discovery attaches it
+        # (AutoScaler.note_replica_added).  What IS reported from here
+        # is a child that died first -- the manager drops a client
+        # whose process exits or whose handshake lease lapses
+        while self.lifecycle_manager.client_state(client_id) == "spawning":
+            time.sleep(0.2)
+        # pop, not check-then-delete: retire() pops the same entry from
+        # the gateway's thread, and whoever gets it owns the outcome
+        if (self.lifecycle_manager.client_state(client_id) is None
+                and self._clients.pop(name, None) is not None):
+            if ready is not None:
+                ready(None, {
+                    "name": name,
+                    "error": "replica process exited or missed its "
+                             "handshake before becoming healthy (on a "
+                             "one-chip host a second process cannot "
+                             "open the device)"})
 
     def retire(self, handle) -> None:
         name = getattr(handle, "name", handle)

@@ -6,8 +6,8 @@
 # XLA flop estimates per element), so every number in a tune report is
 # attributable to a typed graph node.
 #
-# Floor classifier (detector-roofline style -- BENCH_NOTES "Detector
-# roofline" measured the per-call dispatch floor this formalizes).
+# Floor classifier (detector-roofline style: the detect call's time
+# was flat in batch, a per-call dispatch floor this formalizes).
 # Exactly one label per element, checked in priority order:
 #
 #   admission-bound (gateway pseudo-node only, fleet-scope traces) the

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 __all__ = [
     "generate", "parse", "parse_list_to_dict", "parse_int", "parse_float",
-    "parse_number", "ParseError",
+    "parse_number", "ParseError", "CODEC",
 ]
 
 
@@ -192,17 +192,18 @@ def _parse_python(payload) -> tuple:
 
 
 # Native fast path: the C++ extension (native/sexpr_codec.cpp) parses
-# byte-per-char identically; built via `python -m
-# aiko_services_tpu.native.build`.  Payloads outside latin-1 (exotic
-# unicode atoms) take the Python path.
-try:
-    from ..native import sexpr_parse_native as _parse_native
-    from ..native import install_parse_error as _install_parse_error
-except ImportError:  # pragma: no cover
-    _parse_native = None
-else:
-    if _parse_native is not None:
-        _install_parse_error(ParseError)
+# byte-per-char identically.  It exists only where someone ran the
+# explicit build step `python -m aiko_services_tpu.native.build` (the
+# binary is not tracked by git); everywhere else the Python parser
+# runs.  CODEC names which one this process got, so entry points can
+# print it.  Payloads outside latin-1 (exotic unicode atoms) take the
+# Python path.
+from ..native import sexpr_parse_native as _parse_native
+from ..native import install_parse_error as _install_parse_error
+
+if _parse_native is not None:
+    _install_parse_error(ParseError)
+CODEC = "native" if _parse_native is not None else "python"
 
 
 def parse(payload) -> tuple:

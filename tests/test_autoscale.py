@@ -183,9 +183,41 @@ class TestCompileCache:
         assert after["hits"] > mid["hits"]       # warm: deserialized
         assert after["misses"] == mid["misses"]  # zero recompiles
 
-    def test_disabled_without_directory(self):
-        assert enable_compile_cache(None) is None
-        assert cache_stats()["dir"] is None
+    def test_default_directory_is_fixed_inside_the_checkout(
+            self, monkeypatch):
+        """No argument, no environment: the one in-checkout directory
+        (the path is part of the cache key -- a tempfile-, pid- or
+        time-derived name would never hit across runs)."""
+        from aiko_services_tpu.runtime.compile_cache import (
+            DEFAULT_CACHE_DIR)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        assert cache_stats()["dir"] is None          # off until enabled
+        assert enable_compile_cache() == DEFAULT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == DEFAULT_CACHE_DIR
+        assert cache_stats()["dir"] == DEFAULT_CACHE_DIR
+
+    def test_environment_placement_is_never_overridden(
+            self, tmp_path, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR decides: not an explicit argument,
+        not a replica factory's directory, not disable_compile_cache()
+        may point jax anywhere else."""
+        placed = str(tmp_path / "placed")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+        assert enable_compile_cache(str(tmp_path / "other")) == placed
+        assert jax.config.jax_compilation_cache_dir == placed
+        assert enable_compile_cache() == placed
+        # what a replica factory's _bring_up passes along
+        assert enable_compile_cache(str(tmp_path / "factory")) == placed
+        disable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == placed
+        assert cache_stats()["dir"] == placed
+        jax.jit(lambda x: x * 3.0 + 1.0)(
+            jnp.ones((7, 5))).block_until_ready()
+        assert os.listdir(placed)
+        assert not (tmp_path / "other").exists()
+        assert not (tmp_path / "factory").exists()
 
 
 # -- live weight hand-off ----------------------------------------------------
@@ -324,9 +356,25 @@ class TestScaleUp:
 
 
 class TestWarmStart:
+    @pytest.mark.parametrize("prewarmed", [False, True])
     def test_warm_spawn_zero_recompiles_and_identical_outputs(
-            self, tmp_path):
+            self, tmp_path, prewarmed):
         cache_dir = str(tmp_path / "compile_cache")
+        if prewarmed:
+            # the bench now keeps ONE fixed cache directory across
+            # runs, so the "cold" replica may find every shape already
+            # there.  The proof reads per-spawn hit/miss DELTAS, so a
+            # directory warmed by an earlier fleet must not break it
+            earlier = InProcessReplicaFactory(
+                lambda name: _definition(name, class_name="SlowAffine",
+                                         element="affine"),
+                warmup=_frame(0.0), compile_cache=cache_dir)
+            earlier_ready = queue.Queue()
+            earlier.spawn("earlier", ready=lambda handle, info:
+                          earlier_ready.put((handle, info)))
+            handle, info = earlier_ready.get(timeout=120)
+            assert handle is not None and info["cache_misses"] > 0
+            earlier.retire(handle)
         gateway_process = Process(transport_kind="loopback")
         gateway = Gateway(gateway_process,
                           policy="max_inflight=2;queue=256",
@@ -344,7 +392,10 @@ class TestWarmStart:
                           (handle, info)))
         handle0, info0 = cold_ready.get(timeout=120)
         assert handle0 is not None, info0
-        assert info0["cache_misses"] > 0  # the cold arm really compiled
+        if prewarmed:
+            assert info0["cache_misses"] == 0 and info0["cache_hits"] > 0
+        else:
+            assert info0["cache_misses"] > 0  # the cold arm compiled
         gateway.attach_replica(handle0.pipeline)
 
         # mutate replica0's params so only a REAL hand-off can match
@@ -573,7 +624,8 @@ class TestProcessManagerEnv:
         assert exits[0] == ("probe", 0)
         manager.terminate()
 
-    def test_process_factory_spawn_env_and_handoff_file(self, tmp_path):
+    def test_process_factory_spawn_env_and_handoff_file(
+            self, tmp_path, monkeypatch):
         """ProcessReplicaFactory glue, hermetically: the lifecycle
         manager is a recorder, so the test asserts exactly what a real
         spawn would inherit -- the compile-cache env overlay, the
@@ -591,6 +643,10 @@ class TestProcessManagerEnv:
             def delete_client(self, client_id):
                 self.deleted.append(client_id)
 
+            def client_state(self, client_id):
+                return "running"
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         recorder = _Recorder()
         factory = ProcessReplicaFactory(
             recorder, "/tmp/defn.json", transport="mqtt",
@@ -607,7 +663,7 @@ class TestProcessManagerEnv:
         assert "--name" in arguments and "gw-r1" in arguments
         assert "--transport" in arguments and "mqtt" in arguments
         assert env["JAX_PLATFORMS"] == "cpu"
-        assert env["AIKO_COMPILE_CACHE"] == str(tmp_path / "cache")
+        assert env["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path / "cache")
         with open(env["AIKO_WARM_WEIGHTS"]) as handoff:
             assert json.load(handoff) == exports
         os.unlink(env["AIKO_WARM_WEIGHTS"])
@@ -615,3 +671,33 @@ class TestProcessManagerEnv:
         assert recorder.deleted == [0]
         factory.retire("gw-r1")  # idempotent
         assert recorder.deleted == [0]
+
+    def test_process_factory_reports_a_child_that_dies_first(
+            self, tmp_path):
+        """A replica child that exits (or misses its handshake lease)
+        before becoming healthy is a REPORTED spawn failure -- what a
+        second OS process on a one-chip host amounts to, since it cannot
+        open the device its parent holds.  Here the child dies at once
+        (its definition does not exist); a real LifeCycleManager drops
+        the client and the factory hands the autoscaler's `ready` the
+        error instead of leaving the spawn pending."""
+        from aiko_services_tpu.runtime import LifeCycleManager
+        manager_process = Process(transport_kind="loopback")
+        manager = LifeCycleManager(manager_process, "lcm_replicas",
+                                   handshake_lease_time=20.0)
+        manager_process.run(in_thread=True)
+        try:
+            factory = ProcessReplicaFactory(
+                manager, str(tmp_path / "no_such_definition.json"),
+                env={"JAX_PLATFORMS": "cpu"})
+            reports = queue.Queue()
+            factory.spawn("gw-r9", ready=lambda handle, info:
+                          reports.put((handle, info)))
+            handle, info = reports.get(timeout=60)
+            assert handle is None
+            assert info["name"] == "gw-r9"
+            assert "before becoming healthy" in info["error"]
+            assert manager.clients == {}
+            factory.retire("gw-r9")  # nothing left to retire
+        finally:
+            manager_process.terminate()
