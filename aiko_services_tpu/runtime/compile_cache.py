@@ -77,8 +77,9 @@ def enable_compile_cache(directory: str | None = None) -> str:
     import jax
     from jax._src import compilation_cache
 
-    directory = os.path.abspath(
-        os.environ.get(ENV_CACHE_DIR) or directory or DEFAULT_CACHE_DIR)
+    # a directory placed from outside is taken verbatim, as jax reads it
+    directory = (os.environ.get(ENV_CACHE_DIR)
+                 or os.path.abspath(directory or DEFAULT_CACHE_DIR))
     with _LOCK:
         _install_listener()
         if _ENABLED_DIR == directory:

@@ -29,6 +29,10 @@ runs toy sizes on the platform jax finds, tags every result line
 `rehearsal platform=<platform>`, and ends with "ok": false: it checks the
 script, never the chip.  A phase that raises, times out, answers with a
 non-ok status or produces a non-finite value ends the run non-zero.
+
+The last line of stdout is one JSON object with exactly the keys "ok" and
+"device" ({"platform", "kind", "count"} as jax reports them); the line
+before it is the `[smoke] summary` that carries `"claim": null`.
 """
 
 from __future__ import annotations
@@ -834,8 +838,6 @@ def main(argv=None) -> int:
              "script, never the chip, and cannot end in ok=true")
     args = parser.parse_args(argv)
 
-
-
     device = jax.devices()[0]
     platform, count = device.platform, len(jax.devices())
     cache_dir = enable_compile_cache()
@@ -877,13 +879,15 @@ def main(argv=None) -> int:
     report.line(f"compile cache: dir={stats['dir']} hits={stats['hits']} "
                 f"misses={stats['misses']} requests={stats['requests']}")
     report.line(f"all phases ok in {time.perf_counter() - started:.1f} s")
+    ok = not args.rehearsal
+    report.line("summary " + json.dumps({
+        "rehearsal": args.rehearsal, "ok": ok, "claim": None}))
+    # the last line is the whole contract with the driver: exactly the
+    # keys "ok" and "device", nothing else (a rehearsal is never ok)
     print(json.dumps({
-        "ok": not args.rehearsal,
-        **({"rehearsal": f"platform={platform}"} if args.rehearsal
-           else {}),
+        "ok": ok,
         "device": {"platform": platform, "kind": device.device_kind,
-                   "count": count},
-        "claim": None}), flush=True)
+                   "count": count}}), flush=True)
     return 0
 
 

@@ -41,11 +41,13 @@ def test_rehearsal_passes_and_can_never_read_as_a_pass(
         for line in results)
     for phase in phases:
         assert any(f" {phase}: ok setup_s=" in line for line in results)
-    final = json.loads(lines[-1])
-    assert final["ok"] is False
-    assert final["rehearsal"] == "platform=cpu"
-    assert final["device"] == {"platform": "cpu", "kind": "cpu",
-                               "count": devices}
+    # the driver's contract for the last line: exactly these keys
+    assert json.loads(lines[-1]) == {
+        "ok": False,
+        "device": {"platform": "cpu", "kind": "cpu", "count": devices}}
+    summary = json.loads(lines[-2].split(" summary ", 1)[1])
+    assert summary["rehearsal"] is True and summary["ok"] is False
+    assert list(summary)[-1] == "claim" and summary["claim"] is None
     # the cache went where JAX_COMPILATION_CACHE_DIR put it, and
     # nowhere else: the script ran from an empty directory
     assert f"compile_cache_dir={tmp_path / 'cache'} " in results[1]
