@@ -60,6 +60,7 @@ import numpy as np
 from ..models import (
     init_paged_pool, paged_decode_step, paged_prefill,
     paged_prefill_chunk, paged_verify_step)
+from ..observe.trace import NO_SPANS
 from ..utils import get_logger
 from ..utils.padding import bucket_length
 from .blocks import TRASH_BLOCK, BlockManager
@@ -68,6 +69,8 @@ from .prefix import PrefixCache, PrefixPolicy, chain_hashes
 __all__ = ["DecodeEngine", "Completion", "StepReport"]
 
 _LOGGER = get_logger("decode_engine")
+# decode.tick_decoding's ladder: slots decoded in one tick, not seconds
+SLOT_BOUNDS = (0, 1, 2, 4, 8, 16, 32, 64, 128)
 
 
 @dataclass
@@ -147,7 +150,8 @@ class DecodeEngine:
                  max_context: int | None = None, eos_id: int | None = None,
                  prefill_chunk_size: int | None = None,
                  draft_params=None, draft_config=None, spec_k: int = 0,
-                 prefix_policy=None, registry=None):
+                 prefix_policy=None, registry=None, spans=None,
+                 node: str = "engine"):
         if decode_slots < 1:
             raise ValueError(f"decode_slots must be >= 1, "
                              f"got {decode_slots}")
@@ -185,6 +189,11 @@ class DecodeEngine:
         self.waiting: deque[_Request] = deque()
         self._admission_seq = 0
         self._registry = registry
+        # program spans (observe/trace.py): the owning pipeline's
+        # telemetry seam, which resolves a request id to its frame;
+        # `node` names this engine in `aiko:compile` instants
+        self._spans = spans if spans is not None else NO_SPANS
+        self._node = node
         # chunked prefill: coerced to a power-of-two block multiple so
         # the per-chunk executables stay logarithmic; a chunk covering
         # max_context degenerates to the monolithic path
@@ -275,6 +284,7 @@ class DecodeEngine:
         self.waiting.append(_Request(
             request_id=request_id, prompt=prompt, max_new=max_new,
             submitted_at=time.perf_counter()))
+        self._spans.record_engine_submit(request_id)
         self._update_gauges()
 
     def _ingest_kv_blocks(self, record: dict, needed: int,
@@ -578,6 +588,14 @@ class DecodeEngine:
             self._update_gauges()
         return cancelled
 
+    def first_token_at(self, request_id) -> float | None:
+        """perf_counter reading of a live request's first token (the
+        end of its prefill); None once it has left its slot."""
+        for slot in self.slots:
+            if slot is not None and slot.request.request_id == request_id:
+                return slot.request.first_token_at
+        return None
+
     def has_work(self) -> bool:
         return bool(self.waiting) or any(
             slot is not None for slot in self.slots)
@@ -592,6 +610,21 @@ class DecodeEngine:
         decode progress share the tick -- that interleaving is what
         stops a long prompt from convoying every co-scheduled slot."""
         report = StepReport()
+        started = time.perf_counter()
+        with self._spans.span("engine.step",
+                              waiting=len(self.waiting)) as tick:
+            decoding = self._tick(report)
+            tick.set(active=report.active, decoding=decoding,
+                     admitted=report.admitted)
+        if self._registry is not None:
+            self._registry.histogram("decode.tick_s").record(
+                time.perf_counter() - started)
+            self._registry.histogram(
+                "decode.tick_decoding", SLOT_BOUNDS).record(decoding)
+        return report
+
+    def _tick(self, report: StepReport) -> int:
+        """The body of one step; returns how many slots it decoded."""
         self._admit(report)
         ran_chunk = self._advance_prefills(report)
         active = [index for index, slot in enumerate(self.slots)
@@ -599,7 +632,7 @@ class DecodeEngine:
         if not active:
             self._update_gauges()
             report.active = 0
-            return report
+            return 0
         self._grow_or_preempt()
         active = [index for index, slot in enumerate(self.slots)
                   if slot is not None]
@@ -608,7 +641,7 @@ class DecodeEngine:
                     if not self.slots[index].prefilling]
         if not decoding:
             self._update_gauges()
-            return report
+            return 0
         if self.draft_params is not None:
             self._spec_round(decoding, report)
         else:
@@ -619,25 +652,29 @@ class DecodeEngine:
             self.counters["chunk_interleaves"] += 1
             self._bump("decode.chunk_interleaves", 1)
         self._update_gauges()
-        return report
+        return len(decoding)
 
     def _plain_step(self, decoding: list, report: StepReport) -> None:
         """One paged_decode_step over all slots; mid-prefill and free
         slots write to the trash block and their rows are ignored."""
-        write_blocks = np.zeros((self.slots_n,), np.int32)
-        write_offsets = np.zeros((self.slots_n,), np.int32)
-        for index in decoding:
-            position = int(self.positions[index])
-            block_index = position // self.blocks.block_size
-            write_blocks[index] = self.slots[index].blocks[block_index]
-            write_offsets[index] = position % self.blocks.block_size
-        before = _jit_cache_size()
-        self.pool, next_tokens = paged_decode_step(
-            self.params, self.config, self.pool, self.tables,
-            self.positions, self.last_tokens, write_blocks,
-            write_offsets)
-        self._note_compiles(_jit_cache_size() - before)
-        next_tokens = np.asarray(next_tokens)
+        with self._spans.span("engine.decode", decoding=len(decoding)):
+            write_blocks = np.zeros((self.slots_n,), np.int32)
+            write_offsets = np.zeros((self.slots_n,), np.int32)
+            for index in decoding:
+                position = int(self.positions[index])
+                block_index = position // self.blocks.block_size
+                write_blocks[index] = self.slots[index].blocks[
+                    block_index]
+                write_offsets[index] = position % self.blocks.block_size
+            before = _jit_cache_size()
+            self.pool, next_tokens = paged_decode_step(
+                self.params, self.config, self.pool, self.tables,
+                self.positions, self.last_tokens, write_blocks,
+                write_offsets)
+            self._note_compiles(_jit_cache_size() - before,
+                                "paged_decode_step")
+        with self._spans.span("engine.readback"):
+            next_tokens = np.asarray(next_tokens)
         for index in decoding:
             slot = self.slots[index]
             request = slot.request
@@ -734,13 +771,27 @@ class DecodeEngine:
                 # cache is skipping the quadratic prefix compute
                 self._tail_prefill(index, report)
                 continue
-            before = _jit_cache_size()
-            self.pool, first = paged_prefill(
-                self.params, self.config, self.pool, padded[None],
-                self.tables[index], np.int32(true_len))
-            self._note_compiles(_jit_cache_size() - before)
+            with self._prefill_span(slot, bucket):
+                before = _jit_cache_size()
+                self.pool, first = paged_prefill(
+                    self.params, self.config, self.pool, padded[None],
+                    self.tables[index], np.int32(true_len))
+                self._note_compiles(_jit_cache_size() - before,
+                                    "paged_prefill")
+                first = int(first)  # the readback waits for the prefill
             slot.prefill_pos = bucket
-            self._finish_prefill(index, report, int(first))
+            self._finish_prefill(index, report, first)
+
+    def _prefill_span(self, slot: "_Slot", bucket: int):
+        """The `engine.prefill` span around one prefill call and its
+        readback: `bucket` is the padded length the call runs at,
+        `queue_us` how long the request waited for its slot."""
+        request = slot.request
+        return self._spans.span(
+            "engine.prefill", request.request_id, bucket=bucket,
+            true_len=slot.true_len,
+            queue_us=round(((request.admitted_at or request.submitted_at)
+                            - request.submitted_at) * 1e6))
 
     def _tail_prefill(self, index: int, report: StepReport) -> None:
         """Prefill ONLY the uncached tail of a prefix-cache hit in one
@@ -765,13 +816,15 @@ class DecodeEngine:
                 write_blocks[offset] = slot.blocks[
                     position // block_size]
             write_offsets[offset] = position % block_size
-        before = _jit_cache_size()
-        self.pool, greedy = paged_prefill_chunk(
-            self.params, self.config, self.pool, chunk,
-            self.tables[index], np.int32(start), write_blocks,
-            write_offsets)
-        self._note_compiles(_jit_cache_size() - before)
-        first = int(np.asarray(greedy)[slot.true_len - 1 - start])
+        with self._prefill_span(slot, size):
+            before = _jit_cache_size()
+            self.pool, greedy = paged_prefill_chunk(
+                self.params, self.config, self.pool, chunk,
+                self.tables[index], np.int32(start), write_blocks,
+                write_offsets)
+            self._note_compiles(_jit_cache_size() - before,
+                                "paged_prefill_chunk")
+            first = int(np.asarray(greedy)[slot.true_len - 1 - start])
         self._finish_prefill(index, report, first)
 
     def _finish_prefill(self, index: int, report: StepReport,
@@ -811,7 +864,7 @@ class DecodeEngine:
             self.draft_params, self.draft_config, self.draft_pool,
             slot.padded[None], self.draft_tables[index],
             np.int32(slot.true_len))
-        self._note_compiles(_jit_cache_size() - before)
+        self._note_compiles(_jit_cache_size() - before, "draft_prefill")
         self.draft_positions[index] = slot.true_len
 
     def _advance_prefills(self, report: StepReport) -> bool:
@@ -865,22 +918,26 @@ class DecodeEngine:
                     draft_blocks[offset] = self.draft_tables[
                         index, block_index]
             write_offsets[offset] = position % block_size
-        before = _jit_cache_size()
-        self.pool, greedy = paged_prefill_chunk(
-            self.params, self.config, self.pool, chunk,
-            self.tables[index], np.int32(start), write_blocks,
-            write_offsets)
-        if feed_draft:
-            self.draft_pool, _ = paged_prefill_chunk(
-                self.draft_params, self.draft_config, self.draft_pool,
-                chunk, self.draft_tables[index], np.int32(start),
-                draft_blocks, write_offsets)
-        self._note_compiles(_jit_cache_size() - before)
-        self.counters["prefill_chunks"] += 1
-        self._bump("decode.prefill_chunks", 1)
-        slot.prefill_pos = start + take
+        with self._prefill_span(slot, size):
+            before = _jit_cache_size()
+            self.pool, greedy = paged_prefill_chunk(
+                self.params, self.config, self.pool, chunk,
+                self.tables[index], np.int32(start), write_blocks,
+                write_offsets)
+            if feed_draft:
+                self.draft_pool, _ = paged_prefill_chunk(
+                    self.draft_params, self.draft_config,
+                    self.draft_pool, chunk, self.draft_tables[index],
+                    np.int32(start), draft_blocks, write_offsets)
+            self._note_compiles(_jit_cache_size() - before,
+                                "paged_prefill_chunk")
+            self.counters["prefill_chunks"] += 1
+            self._bump("decode.prefill_chunks", 1)
+            slot.prefill_pos = start + take
+            if not slot.prefilling:
+                # the last chunk's readback waits for the whole prefill
+                first = int(np.asarray(greedy)[slot.true_len - 1 - start])
         if not slot.prefilling:
-            first = int(np.asarray(greedy)[slot.true_len - 1 - start])
             if feed_draft:
                 self.draft_positions[index] = slot.true_len
             self._finish_prefill(index, report, first,
@@ -917,61 +974,65 @@ class DecodeEngine:
                     ingest_blocks[index, j] = self.draft_tables[
                         index, position // block_size]
                     ingest_offsets[index, j] = position % block_size
-        draft_start = time.perf_counter()
-        before = _jit_cache_size()
-        self.draft_pool, draft_greedy = paged_verify_step(
-            self.draft_params, self.draft_config, self.draft_pool,
-            self.draft_tables, self.draft_positions, ingest,
-            ingest_blocks, ingest_offsets)
-        draft_greedy = np.asarray(draft_greedy)
-        proposals = np.zeros((self.slots_n, k), np.int32)
-        for index in decoding:
-            proposals[index, 0] = draft_greedy[
-                index, pending_len[index] - 1]
-            self.draft_positions[index] += pending_len[index]
-        # 2) k-1 single draft steps extend the proposal run, writing
-        # each proposal's K/V at its own position
-        current = proposals[:, 0:1].copy()
-        for run in range(1, k):
-            step_blocks = np.full((self.slots_n,), TRASH_BLOCK, np.int32)
-            step_offsets = np.zeros((self.slots_n,), np.int32)
-            for index in decoding:
-                position = int(self.draft_positions[index])
-                if position < self.max_context:
-                    step_blocks[index] = self.draft_tables[
-                        index, position // block_size]
-                    step_offsets[index] = position % block_size
-            self.draft_pool, current = paged_decode_step(
+        # draft proposals, their readbacks and the verify dispatch are
+        # `engine.decode`; `engine.readback` is the wait for the verify
+        with self._spans.span("engine.decode", decoding=len(decoding)):
+            draft_start = time.perf_counter()
+            before = _jit_cache_size()
+            self.draft_pool, draft_greedy = paged_verify_step(
                 self.draft_params, self.draft_config, self.draft_pool,
-                self.draft_tables, self.draft_positions, current,
-                step_blocks, step_offsets)
-            current = np.asarray(current)
+                self.draft_tables, self.draft_positions, ingest,
+                ingest_blocks, ingest_offsets)
+            draft_greedy = np.asarray(draft_greedy)
+            proposals = np.zeros((self.slots_n, k), np.int32)
             for index in decoding:
-                proposals[index, run] = current[index, 0]
-                self.draft_positions[index] += 1
-        self.spec_draft_s += time.perf_counter() - draft_start
-        # 3) target verification: [last_token, p_1..p_k] in one window
-        window = np.zeros((self.slots_n, k + 1), np.int32)
-        verify_blocks = np.full((self.slots_n, k + 1), TRASH_BLOCK,
-                                np.int32)
-        verify_offsets = np.zeros((self.slots_n, k + 1), np.int32)
-        for index in decoding:
-            slot = self.slots[index]
-            window[index, 0] = self.last_tokens[index, 0]
-            window[index, 1:] = proposals[index]
-            for j in range(k + 1):
-                position = int(self.positions[index]) + j
-                if position // block_size < len(slot.blocks):
-                    verify_blocks[index, j] = slot.blocks[
-                        position // block_size]
-                    verify_offsets[index, j] = position % block_size
-        verify_start = time.perf_counter()
-        self.pool, verified = paged_verify_step(
-            self.params, self.config, self.pool, self.tables,
-            self.positions, window, verify_blocks, verify_offsets)
-        verified = np.asarray(verified)
+                proposals[index, 0] = draft_greedy[
+                    index, pending_len[index] - 1]
+                self.draft_positions[index] += pending_len[index]
+            # 2) k-1 single draft steps extend the proposal run, writing
+            # each proposal's K/V at its own position
+            current = proposals[:, 0:1].copy()
+            for run in range(1, k):
+                step_blocks = np.full((self.slots_n,), TRASH_BLOCK, np.int32)
+                step_offsets = np.zeros((self.slots_n,), np.int32)
+                for index in decoding:
+                    position = int(self.draft_positions[index])
+                    if position < self.max_context:
+                        step_blocks[index] = self.draft_tables[
+                            index, position // block_size]
+                        step_offsets[index] = position % block_size
+                self.draft_pool, current = paged_decode_step(
+                    self.draft_params, self.draft_config, self.draft_pool,
+                    self.draft_tables, self.draft_positions, current,
+                    step_blocks, step_offsets)
+                current = np.asarray(current)
+                for index in decoding:
+                    proposals[index, run] = current[index, 0]
+                    self.draft_positions[index] += 1
+            self.spec_draft_s += time.perf_counter() - draft_start
+            # 3) target verification: [last_token, p_1..p_k] in one window
+            window = np.zeros((self.slots_n, k + 1), np.int32)
+            verify_blocks = np.full((self.slots_n, k + 1), TRASH_BLOCK,
+                                    np.int32)
+            verify_offsets = np.zeros((self.slots_n, k + 1), np.int32)
+            for index in decoding:
+                slot = self.slots[index]
+                window[index, 0] = self.last_tokens[index, 0]
+                window[index, 1:] = proposals[index]
+                for j in range(k + 1):
+                    position = int(self.positions[index]) + j
+                    if position // block_size < len(slot.blocks):
+                        verify_blocks[index, j] = slot.blocks[
+                            position // block_size]
+                        verify_offsets[index, j] = position % block_size
+            verify_start = time.perf_counter()
+            self.pool, verified = paged_verify_step(
+                self.params, self.config, self.pool, self.tables,
+                self.positions, window, verify_blocks, verify_offsets)
+        with self._spans.span("engine.readback"):
+            verified = np.asarray(verified)
         self.spec_verify_s += time.perf_counter() - verify_start
-        self._note_compiles(_jit_cache_size() - before)
+        self._note_compiles(_jit_cache_size() - before, "spec_round")
         # 4) greedy-exact acceptance: verified[j] is the target's
         # greedy token after window position j, so draft_j is accepted
         # iff it EQUALS verified[j-1]; the first mismatch wins a bonus
@@ -1282,10 +1343,11 @@ class DecodeEngine:
         assertion reads deltas of this across an admit/evict storm."""
         return self.counters["compiles"]
 
-    def _note_compiles(self, delta: int) -> None:
+    def _note_compiles(self, delta: int, what: str) -> None:
         if delta > 0:
             self.counters["compiles"] += delta
             self._bump("decode.compiles", delta)
+            self._spans.mark("compile", node=self._node, what=what)
 
     def _bump(self, name: str, amount: int) -> None:
         if self._registry is not None:

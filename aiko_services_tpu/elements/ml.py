@@ -8,6 +8,8 @@
 
 from __future__ import annotations
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,6 +22,7 @@ from ..models import (
     init_asr_params, init_detector_params, init_params, load_llama_params,
     load_pytree)
 from ..models import configs as model_configs
+from ..observe.trace import NO_SPANS
 from ..ops.device import as_device_array as _as_device_array
 from ..pipeline import (
     AsyncHostElement, ComputeElement, PipelineElement, StreamEvent)
@@ -423,6 +426,9 @@ class LMGenerate(ComputeElement):
         telemetry = getattr(self.pipeline, "telemetry", None)
         registry = (telemetry.registry if telemetry is not None
                     and telemetry.enabled else None)
+        # the seam the engine, the pump and the chunk publisher write
+        # program spans through (observe/trace.py)
+        self._spans = telemetry if registry is not None else NO_SPANS
         kv_blocks = self.get_parameter("kv_blocks")
         max_context = self.get_parameter("max_context")
         eos_id = self.get_parameter("eos_id")
@@ -450,7 +456,8 @@ class LMGenerate(ComputeElement):
             draft_params=draft_params, draft_config=draft_config,
             spec_k=spec_k,
             prefix_policy=prefix_policy,
-            registry=registry)
+            registry=registry, spans=self._spans,
+            node=self.definition.name)
         self._prefix_heads_shared = ""
         self._engine_frames = {}
         self._pump_posted = False
@@ -792,7 +799,8 @@ class LMGenerate(ComputeElement):
                 # otherwise overwrite its held prefix with later
                 # tokens.  A FALLBACK row re-prefills and re-emits
                 # from offset 0, so its buffer keeps the default start
-                entry["buffers"][row] = [min(resume, max_new), []]
+                entry["buffers"][row] = [min(resume, max_new), [],
+                                         time.perf_counter(), None]
             for rid, _offset, token in report.emitted:
                 self._buffer_streamed_token(rid, token)
             for completion in report.completions:
@@ -893,6 +901,7 @@ class LMGenerate(ComputeElement):
         the mailbox interleaves admissions with decode progress."""
         if not getattr(self, "_pump_posted", False):
             self._pump_posted = True
+            self._pump_posted_at = time.perf_counter()
             self.post_message("_engine_pump", [])
 
     def _engine_pump(self):
@@ -900,6 +909,16 @@ class LMGenerate(ComputeElement):
         engine = getattr(self, "_engine", None)
         if engine is None:
             return
+        spans = self._spans
+        if not spans.enabled:
+            self._pump(engine)
+            return
+        # the whole handler, and how long its message sat in the mailbox
+        with spans.span("engine.pump", waited_us=round(
+                (time.perf_counter() - self._pump_posted_at) * 1e6)):
+            self._pump(engine)
+
+    def _pump(self, engine):
         try:
             report = engine.step()
             for request_id, offset, token in report.emitted:
@@ -956,7 +975,17 @@ class LMGenerate(ComputeElement):
         if entry is None or not entry["stream_tokens"]:
             return
         row = request_id[2]
-        buffer = entry["buffers"].setdefault(row, [0, []])
+        buffer = entry["buffers"].get(row)
+        if buffer is None:
+            # [offset of the chunk's first token, tokens, since, first]:
+            # the first chunk counts from the request's first token (the
+            # end of its prefill, which may lie ticks back), later ones
+            # from the chunk before; `first` is what the first chunk
+            # waited, kept for every later chunk's mark
+            since = (self._engine.first_token_at(request_id)
+                     if self._spans.enabled else None)
+            buffer = entry["buffers"][row] = [
+                0, [], since or time.perf_counter(), None]
         buffer[1].append(int(token))
         if len(buffer[1]) >= entry["chunk"]:
             self._flush_stream_buffer(request_id[:2], entry, row)
@@ -969,14 +998,20 @@ class LMGenerate(ComputeElement):
         so offsets stay gapless).  Deliberately NOT the closed-batch
         `(tokens stream_id offset payload)` command: one command name,
         one schema."""
-        start, chunk = entry["buffers"].pop(row, (0, []))
+        start, chunk, since, first = entry["buffers"].pop(
+            row, (0, [], 0.0, None))
         if not chunk:
             return
         payload = ([self.tokenizer.decode(np.asarray(chunk, np.int32))]
                    if self.tokenizer is not None else [chunk])
         self.publish_out("token_chunk",
                          [key[0], key[1], row, start, payload])
-        entry["buffers"][row] = [start + len(chunk), []]
+        now = time.perf_counter()
+        if start == 0:
+            first = now - since
+        self._spans.record_chunk(key + (row,), start, len(chunk),
+                                 now - since, first)
+        entry["buffers"][row] = [start + len(chunk), [], now, first]
 
     def _finish_request(self, completion):
         import time

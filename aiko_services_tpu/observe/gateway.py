@@ -25,7 +25,9 @@ from __future__ import annotations
 
 from ..utils import get_logger, monotonic
 from .metrics import MetricsRegistry, SlidingWindow
-from .trace import Tracer, now_us, to_us, trace_metadata
+from .trace import (
+    NO_SPAN, Tracer, now_us, program_mark, program_span, to_us,
+    trace_metadata)
 
 __all__ = ["GatewayTelemetry"]
 
@@ -156,6 +158,17 @@ class GatewayTelemetry:
         if trace is not None:
             self.tracer.finish(trace, status=status)
 
+    def route_span(self, trace, replica_name: str,
+                   pool: str = "decode"):
+        """The scoped `aiko:gateway.route` span around the placement
+        that record_route times."""
+        if trace is None:
+            return NO_SPAN
+        return program_span(
+            "gateway.route", None, stream=trace.stream_id,
+            frame=trace.frame_id, trace_id=trace.trace_id,
+            replica=replica_name, pool=pool)
+
     def record_route(self, trace, start_s: float, replica_name: str,
                      pool: str = "decode") -> None:
         """The placement decision for one dispatched frame."""
@@ -170,9 +183,12 @@ class GatewayTelemetry:
         Returns the elapsed seconds for the queue-stage decomposition."""
         if trace is None:
             return 0.0
-        elapsed_us = now_us() - trace.start_us
+        elapsed_s = (now_us() - trace.start_us) / 1e6
         trace.span("admit:gateway", "gateway", trace.start_us)
-        return elapsed_us / 1e6
+        program_mark("gateway.admit", elapsed_s, None,
+                     stream=trace.stream_id, frame=trace.frame_id,
+                     trace_id=trace.trace_id)
+        return elapsed_s
 
     def record_shed_span(self, trace, reason: str) -> None:
         if trace is not None:

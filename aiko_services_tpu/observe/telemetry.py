@@ -15,7 +15,9 @@
 # compact summary into the pipeline's EC share for dashboards.
 #
 # Span names/categories and the time_queue_* vs time_* key split
-# follow THE taxonomy documented once in observe/trace.py.
+# follow THE taxonomy documented once in observe/trace.py.  The program
+# spans (`aiko:*`, the same table) go through span() / mark() and the
+# record_* hooks here, which write them on the JAX profiler's clock.
 
 from __future__ import annotations
 
@@ -23,9 +25,19 @@ import time
 
 from ..utils import get_logger, truthy
 from .metrics import MetricsRegistry, get_registry
-from .trace import Tracer, now_us, to_us, trace_metadata
+from .trace import (
+    NO_SPAN, Tracer, ingress_wait_s, now_us, program_mark, program_span,
+    to_us, trace_metadata)
 
 __all__ = ["PipelineTelemetry"]
+
+
+def _frame_args(trace) -> dict:
+    """What every program span of one frame carries."""
+    if trace is None:
+        return {}
+    return {"stream": trace.stream_id, "frame": trace.frame_id,
+            "trace_id": trace.trace_id}
 
 _LOGGER = get_logger("telemetry")
 
@@ -66,6 +78,10 @@ class PipelineTelemetry:
             "pipeline.chained_groups")
         self._element_hists: dict = {}
         self._queue_hists: dict = {}
+        if self.enabled:
+            # the loop this pipeline runs on names its own waits
+            # (aiko:loop.idle / aiko:sched.hold)
+            pipeline.process.event.trace_waits(program_span)
         if self._interval > 0:
             # with telemetry off only the cheap load heartbeat runs:
             # serving gateways age a replica's EC share (`stale_after`)
@@ -97,12 +113,20 @@ class PipelineTelemetry:
                     ) -> None:
         if not self.enabled:
             return
-        frame.trace = self.tracer.begin(stream.stream_id, frame.frame_id)
+        trace = frame.trace = self.tracer.begin(stream.stream_id,
+                                                frame.frame_id)
         if context is not None:
             # cross-process continuation: the gateway (or another
             # upstream hop) minted this trace -- keep its id, parent
             # our frame span under its span id
-            frame.trace.adopt(context)
+            trace.adopt(context)
+            waited_s = ingress_wait_s(context)
+            if waited_s is not None:
+                # the sender stamped its dispatch: what lies between is
+                # this pipeline's mailbox (the loop was busy)
+                trace.ingress_wait_s = waited_s
+                program_mark("ingress", waited_s, trace,
+                             **_frame_args(trace))
 
     def frame_end(self, stream, frame, dropped: bool = False,
                   error: bool = False) -> None:
@@ -146,6 +170,14 @@ class PipelineTelemetry:
             trace.events.append(
                 ("X", node, "element", to_us(start_s), elapsed_s * 1e6,
                  args))
+
+    def element_span(self, frame, node: str, path: str = "inline"):
+        """The scoped `aiko:element` span around one inline element
+        call (record_element keeps the ring's own record of it)."""
+        if not self.enabled:
+            return NO_SPAN
+        return program_span("element", None, node=node, path=path,
+                            **_frame_args(frame.trace))
 
     def record_pipeline_pass(self, frame, start_s: float) -> None:
         if not self.enabled:
@@ -386,8 +418,21 @@ class PipelineTelemetry:
 
     # -- micro-batch scheduler ---------------------------------------------
 
+    def group_span(self, node: str, frames: int):
+        """The scoped `aiko:sched.group` span around one coalesced
+        group, from the queue-wait close to the last frame resumed;
+        record_group adds `rows`, `target` and `path` once the group's
+        shape is known."""
+        if not self.enabled:
+            return NO_SPAN
+        return program_span("sched.group", None, node=node,
+                            frames=frames)
+
     def record_group(self, node: str, size: int, rows: int,
-                     fused: bool) -> None:
+                     fused: bool, held: int, span) -> None:
+        """One coalesced group dispatched: `size` frames holding
+        `held` rows, padded to `rows` (the shape the program runs);
+        `span` is the group's group_span()."""
         if not self.enabled:
             return
         (self._fused_groups if fused else self._chained_groups).inc()
@@ -395,6 +440,10 @@ class PipelineTelemetry:
             f"group_frames:{node}", OCCUPANCY_BOUNDS).record(size)
         self.registry.histogram(
             f"group_rows:{node}", OCCUPANCY_BOUNDS).record(rows)
+        self.registry.histogram(
+            f"group_held_rows:{node}", OCCUPANCY_BOUNDS).record(held)
+        span.set(rows=held, target=rows,
+                 path="fused" if fused else "chained")
 
     def record_compile(self, node: str, what: str) -> None:
         if not self.enabled:
@@ -402,6 +451,86 @@ class PipelineTelemetry:
         self.registry.counter(f"pipeline.compiles_{what}").inc()
         self.tracer.instant_global(f"compile:{node}", "compile",
                                    {"what": what})
+        program_mark("compile", node=node, what=what)
+
+    # -- program spans for components that hold no frame ------------------
+    # (the decode engine and the element that pumps it know requests by
+    # id; the frame, its stream and its trace id are looked up here)
+
+    def _request(self, request_id, args: dict):
+        """Add the identity of an engine request `(stream_id, frame_id,
+        row)` to a span's `args`; returns its frame's FrameTrace, or
+        None (no request, or its frame is gone)."""
+        if request_id is None:
+            return None
+        try:
+            stream_id, frame_id, row = request_id
+        except (TypeError, ValueError):
+            args["request"] = str(request_id)
+            return None
+        args.update(stream=stream_id, frame=frame_id, row=row)
+        stream = self.pipeline.streams.get(stream_id)
+        frame = stream.frames.get(frame_id) if stream is not None else None
+        trace = frame.trace if frame is not None else None
+        if trace is not None:
+            args["trace_id"] = trace.trace_id
+        return trace
+
+    def span(self, name: str, request_id=None, **args):
+        """A scoped program span `aiko:{name}`; with a `request_id` it
+        carries the request's identity and lands on its frame's trace
+        too."""
+        if not self.enabled:
+            return NO_SPAN
+        return program_span(name, self._request(request_id, args), **args)
+
+    def mark(self, name: str, waited_s: float | None = None,
+             request_id=None, **args) -> None:
+        """A closing mark `aiko:{name}` (see observe/trace.py)."""
+        if self.enabled:
+            program_mark(name, waited_s, self._request(request_id, args),
+                         **args)
+
+    def record_engine_submit(self, request_id) -> None:
+        """DecodeEngine.submit took a request: close replica ingress ->
+        submit (`aiko:engine.submit`), and record the whole wait before
+        the engine's own clock starts -- the sender's dispatch, where
+        known, to here -- as decode.ingress_wait_s.  What
+        decode.queue_wait_s measures begins at this instant."""
+        if not self.enabled:
+            return
+        identity: dict = {}
+        trace = self._request(request_id, identity)
+        if trace is None:
+            return
+        waited_s = (now_us() - trace.start_us) / 1e6
+        program_mark("engine.submit", waited_s, trace, **identity)
+        trace.submit_wait_s = waited_s + (trace.ingress_wait_s or 0.0)
+        self.registry.histogram("decode.ingress_wait_s").record(
+            trace.submit_wait_s)
+
+    def record_chunk(self, request_id, offset: int, tokens: int,
+                     waited_s: float, first_s: float | None = None
+                     ) -> None:
+        """One token chunk published: `waited_s` since the request's
+        first token (offset 0) or its previous chunk.  Every chunk also
+        says how its request began -- `first_us`, its first token ->
+        its first chunk (`first_s`; the publisher keeps it), and
+        `ingress_us`, dispatch -> DecodeEngine.submit -- because a
+        profile of a few seconds holds a dozen requests in flight and
+        perhaps none that began inside it."""
+        if not self.enabled:
+            return
+        args = {"offset": offset, "tokens": tokens}
+        trace = self._request(request_id, args)
+        if first_s is not None:
+            args["first_us"] = round(first_s * 1e6)
+        if trace is not None and trace.submit_wait_s is not None:
+            args["ingress_us"] = round(trace.submit_wait_s * 1e6)
+        program_mark("engine.chunk", waited_s, trace, **args)
+        if offset == 0:
+            self.registry.histogram("decode.first_chunk_s").record(
+                waited_s)
 
     def record_cohort_split(self, node: str, cohorts: int) -> None:
         if not self.enabled:

@@ -45,6 +45,62 @@
 #   compile    "compile:{node}"              (re)compilation instants
 #   park/fault instants                      park/resume, retries,
 #                                            deadline + breaker events
+#   program    "aiko:{layer}.{what}"         program spans (below); in
+#                                            the ring only where a frame
+#                                            exists and no older record
+#                                            already covers the interval
+#
+# PROGRAM SPANS -- the same work, named from inside, on the JAX
+# profiler's clock.  program_span / program_mark (the one function pair
+# PipelineTelemetry, GatewayTelemetry and the event loop go through)
+# write a TraceMe on the calling thread, so a `jax.profiler.start_trace`
+# session shows them over the device lines on one time axis; arguments
+# come back as the event's stats.  With no session a span costs one
+# inactive TraceMe (~0.6 us); without jax in the process, nothing.
+#
+#   a SCOPED span is a `with` block on one thread (work);
+#   a CLOSING MARK is written at the instant an interval that crossed
+#   threads or mailboxes CLOSES, carrying `waited_us` (a perf_counter
+#   difference): the interval is [start - waited_us, start] on the
+#   profiler's clock, with no second clock to align.
+#
+#   span (aiko: ...)   kind     written in / arguments
+#   ----------------   ------   ------------------------------------
+#   loop.idle          scoped   EventEngine.loop's wait, nothing due
+#                               soon, in slices of 50 ms (a span open
+#                               when a session starts is lost): loop
+#   sched.hold         scoped   the same wait when the nearest timer is
+#                               a micro-batch hold-down: loop, node
+#   sched.group        scoped   one coalesced group, queue-wait close to
+#                               the last frame resumed: node, frames,
+#                               rows (held), target (padded), path
+#   element            scoped   one inline element call: node, path
+#   gateway.route      scoped   one placement: stream, replica, pool
+#   gateway.admit      mark     frame submit -> first dispatch: stream
+#   ingress            mark     gateway dispatch -> replica ingress (the
+#                               mailbox wait): stream, frame
+#   engine.submit      mark     replica ingress -> DecodeEngine.submit
+#   engine.step        scoped   one engine tick: waiting, active,
+#                               decoding, admitted
+#   engine.prefill     scoped   one prefill call + its readback, inside
+#                               engine.step: bucket, true_len, queue_us
+#   engine.decode      scoped   table build + dispatch: decoding
+#   engine.readback    scoped   the readback that waits for the step
+#   engine.chunk       mark     first token (offset 0) or previous chunk
+#                               -> this token_chunk: offset, tokens;
+#                               and, so that ANY chunk of a request in a
+#                               short profile tells how it began,
+#                               first_us (its first token -> its first
+#                               chunk) and ingress_us (dispatch ->
+#                               DecodeEngine.submit), where known
+#   engine.pump        scoped   LMGenerate._engine_pump; waited_us = the
+#                               pump message's mailbox wait
+#   compile            instant  a fresh program / jit signature: node,
+#                               what
+#
+# Spans of one request also carry stream, frame (and row) and the
+# frame's trace_id; a span's parent is the span enclosing it on its
+# thread, across threads the trace_id.
 #
 # Naming scheme: "{kind}:{node}" -- tune/loader._node_of strips the
 # prefix (and the "[row]" suffix) to join spans to typed graph nodes.
@@ -52,7 +108,8 @@
 # path: `time_{node}` is element/device compute, `time_queue_{node}` is
 # scheduler wait (micro-batch fill, engine slot wait) -- never mixed.
 #
-# Cross-process propagation: a TRACE CONTEXT ({trace_id, span_id}) rides
+# Cross-process propagation: a TRACE CONTEXT ({trace_id, span_id}, and
+# `sent_unix_us` when it rides a dispatched frame) rides
 # frame data under TRACE_CONTEXT_KEY.  The serving gateway mints the
 # trace at admission (root-span owner); every downstream process pops
 # the context at stream ingress and CONTINUES the same trace -- its
@@ -71,13 +128,16 @@ import hashlib
 import itertools
 import json
 import os
+import sys
 import time
 from collections import deque
 
 __all__ = ["FrameTrace", "Tracer", "TRACE_CONTEXT_KEY",
+           "PROGRAM_PREFIX", "NO_SPAN", "NO_SPANS",
            "attach_trace_context", "chrome_trace_document",
            "clock_epoch_unix_us", "definition_fingerprint",
-           "make_trace_context", "pop_trace_context", "trace_context_of",
+           "make_trace_context", "pop_trace_context", "program_mark",
+           "program_span", "trace_context_of",
            "trace_metadata", "trace_metadata_of"]
 
 # trace-metadata schema version: bumped when the embedded layout
@@ -117,11 +177,30 @@ def clock_epoch_unix_us() -> float:
 TRACE_CONTEXT_KEY = "_trace_context"
 
 
-def make_trace_context(trace: "FrameTrace") -> dict:
+def make_trace_context(trace: "FrameTrace",
+                       dispatched: bool = False) -> dict:
     """The propagable identity of one frame trace: the (possibly
     already-propagated) trace id plus THIS process's frame span id as
-    the downstream parent."""
-    return {"trace_id": trace.trace_id, "span_id": trace.span_id}
+    the downstream parent.  `dispatched` stamps the sender's dispatch
+    time (Unix microseconds, the clock clock_epoch_unix_us aligns
+    processes on): the receiver's `aiko:ingress` mark measures its
+    mailbox wait from it."""
+    context = {"trace_id": trace.trace_id, "span_id": trace.span_id}
+    if dispatched:
+        context["sent_unix_us"] = round(time.time() * 1e6)
+    return context
+
+
+def ingress_wait_s(context: dict | None) -> float | None:
+    """Seconds since the sender dispatched the frame `context` rode
+    in on; None for a context with no dispatch time (a sender older
+    than the stamp, or a hop that does not dispatch)."""
+    try:
+        sent = float((context or {})["sent_unix_us"])
+    except (KeyError, TypeError, ValueError):
+        return None
+    # two hosts' clocks may disagree by more than the wait
+    return max(time.time() - sent / 1e6, 0.0)
 
 
 def trace_context_of(frame_data) -> dict | None:
@@ -149,6 +228,133 @@ def pop_trace_context(frame_data) -> dict | None:
     return context if isinstance(context, dict) else None
 
 
+# -- program spans on the profiler's clock ---------------------------------
+
+PROGRAM_PREFIX = "aiko:"
+_trace_me = None  # jax.profiler.TraceAnnotation, once jax is in the process
+
+
+def _annotation():
+    """jax's TraceMe class, or None while the process has not imported
+    jax (a control-plane process never pays for the import, and has no
+    profiler session to write into)."""
+    global _trace_me
+    if _trace_me is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _trace_me = TraceAnnotation
+    return _trace_me
+
+
+class _ProgramSpan:
+    """One scoped span: a TraceMe from __enter__ to __exit__ on the
+    calling thread, and an X event on `trace` (a FrameTrace) when the
+    span belongs to a frame.  set() adds arguments known only once the
+    work has run."""
+
+    __slots__ = ("name", "args", "trace", "_scope", "_start_us")
+
+    def __init__(self, name: str, trace, args: dict):
+        self.name = name
+        self.args = args
+        self.trace = trace
+        self._scope = None
+
+    def __enter__(self):
+        annotation = _annotation()
+        if annotation is not None:
+            # a TraceMe starts its interval when it is constructed
+            self._scope = annotation(self.name, **self.args)
+            self._scope.__enter__()
+        if self.trace is not None:
+            self._start_us = now_us()
+        return self
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+        if self._scope is not None:
+            self._scope.set_metadata(**args)
+
+    def __exit__(self, *exc_info):
+        if self._scope is not None:
+            self._scope.__exit__(*exc_info)
+            self._scope = None
+        if self.trace is not None:
+            self.trace.events.append(
+                ("X", self.name, "program", self._start_us,
+                 now_us() - self._start_us, self.args))
+        return False
+
+
+class _NoSpan:
+    """What a disabled seam hands back: a `with` block that does
+    nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def set(self, **args) -> None:
+        pass
+
+    def __exit__(self, *exc_info):
+        return False
+
+
+NO_SPAN = _NoSpan()
+
+
+def program_span(name: str, trace: "FrameTrace | None" = None, **args):
+    """A scoped span `aiko:{name}` around a `with` block of work."""
+    return _ProgramSpan(PROGRAM_PREFIX + name, trace, args)
+
+
+def program_mark(name: str, waited_s: float | None = None,
+                 trace: "FrameTrace | None" = None, **args) -> None:
+    """A closing mark `aiko:{name}`: the interval that ends NOW and
+    began `waited_s` ago (a perf_counter difference, carried as
+    `waited_us`).  Without `waited_s` it is an instant."""
+    name = PROGRAM_PREFIX + name
+    if waited_s is not None:
+        args["waited_us"] = round(waited_s * 1e6)
+    annotation = _annotation()
+    if annotation is not None:
+        with annotation(name, **args):
+            pass
+    if trace is not None:
+        end_us = now_us()
+        if waited_s is None:
+            trace.events.append(("i", name, "program", end_us, 0.0, args))
+        else:
+            trace.events.append(
+                ("X", name, "program", end_us - waited_s * 1e6,
+                 waited_s * 1e6, args))
+
+
+class _NoSpans:
+    """The seam of a component built without telemetry (a bare
+    DecodeEngine): every hook is a call that does nothing."""
+
+    __slots__ = ()
+    enabled = False
+
+    def span(self, name, request_id=None, **args):
+        return NO_SPAN
+
+    def mark(self, name, waited_s=None, request_id=None, **args) -> None:
+        pass
+
+    def record_engine_submit(self, request_id) -> None:
+        pass
+
+    def record_chunk(self, request_id, offset, tokens, waited_s,
+                     first_s=None) -> None:
+        pass
+
+
+NO_SPANS = _NoSpans()
+
+
 class FrameTrace:
     """Span accumulator for ONE frame: rides Frame.trace through the
     graph.  `marks` holds open interval starts (queue parks) keyed by
@@ -159,7 +365,8 @@ class FrameTrace:
 
     __slots__ = ("pid", "seq", "stream_id", "frame_id", "start_us",
                  "end_us", "status", "events", "marks",
-                 "origin_trace_id", "parent_span_id")
+                 "origin_trace_id", "parent_span_id", "ingress_wait_s",
+                 "submit_wait_s")
 
     def __init__(self, pid: int, seq: int, stream_id: str,
                  frame_id: int):
@@ -178,6 +385,12 @@ class FrameTrace:
         # upstream frame span
         self.origin_trace_id: str | None = None
         self.parent_span_id: str | None = None
+        # sender's dispatch -> this process's ingress (the mailbox
+        # wait), when the propagated context carried a dispatch time
+        self.ingress_wait_s: float | None = None
+        # the whole wait before a decode engine's own clock started:
+        # dispatch (where known, else ingress) -> DecodeEngine.submit
+        self.submit_wait_s: float | None = None
 
     @property
     def trace_id(self) -> str:

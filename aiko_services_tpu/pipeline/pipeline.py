@@ -791,8 +791,9 @@ class Pipeline(Actor):
                 time_start += time.perf_counter() - park_start
                 continue  # parked branch; siblings keep dispatching
             element_start = time.perf_counter()
-            stream_event, outputs = self._dispatch_element(
-                stream, frame, node_name, element, inputs)
+            with self.telemetry.element_span(frame, node_name):
+                stream_event, outputs = self._dispatch_element(
+                    stream, frame, node_name, element, inputs)
             self.telemetry.record_element(
                 frame, node_name, element_start,
                 time.perf_counter() - element_start, path="inline")
@@ -1231,6 +1232,8 @@ class Pipeline(Actor):
             self.post_message("_flush_micro_batch",
                               [node_name, None, gen])
 
+        # the loop names its wait for this timer aiko:sched.hold{node}
+        fire.hold_node = node_name
         self._micro_timers[node_name] = fire
         self.process.event.add_timer_handler(fire, wait_s)
 
@@ -1319,9 +1322,12 @@ class Pipeline(Actor):
                 if self.streams.get(entry[0].stream_id) is entry[0]
                 and entry[0].frames.get(entry[1].frame_id) is entry[1]]
             if group:
-                self._run_micro_group(element, group, micro)
+                with self.telemetry.group_span(node_name,
+                                               len(group)) as span:
+                    self._run_micro_group(element, group, micro, span)
 
-    def _run_micro_group(self, element, group: list, micro: int) -> None:
+    def _run_micro_group(self, element, group: list, micro: int,
+                         span) -> None:
         """One coalesced element call for `group` parked frames
         (possibly from SEVERAL streams): concat inputs on axis 0 --
         padded by default to the FULL micro_batch row count, so
@@ -1366,7 +1372,8 @@ class Pipeline(Actor):
         for _, parked_frame, _, _ in group:
             self.telemetry.record_queue_wait(parked_frame, node_name)
         self.telemetry.record_group(node_name, len(group), target,
-                                    fused=kernel_spec is not None)
+                                    fused=kernel_spec is not None,
+                                    held=total, span=span)
         per_frame = None
         element_start = time.perf_counter()
         # injected per-frame faults: a SINGLETON group consumes its
@@ -1682,7 +1689,11 @@ class Pipeline(Actor):
                            static_argnames=("target", "counts", "shared"))
         def fused(context, named, target, counts, shared):
             batch = _concat_pad(named, target)
-            outputs = kernel(context, **batch)
+            # the program stays `jit_fused`; the node's name rides its
+            # operations as metadata (op_name `jit(fused)/{node}/...`),
+            # which the compile cache's key leaves out
+            with jax.named_scope(node_name):
+                outputs = kernel(context, **batch)
             if not isinstance(outputs, dict):
                 raise TypeError(
                     f"{node_name}: group kernel must return a dict, "

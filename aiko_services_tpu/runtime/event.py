@@ -28,6 +28,10 @@ __all__ = ["EventEngine", "Mailbox"]
 
 _LOGGER = get_logger("event")
 _FLATOUT_MIN_INTERVAL = 0.001  # ~1 kHz cap (reference event.py:58-59)
+# a traced idle wait is written in slices this long: a span that is open
+# when a profiler session starts is lost to it, so a session that starts
+# in the middle of a long wait loses at most one slice of it
+_TRACED_WAIT_SLICE = 0.05
 
 
 class Mailbox:
@@ -74,6 +78,9 @@ class EventEngine:
         self._flatout_handlers: list = []
         self._terminated = False
         self._loop_thread: threading.Thread | None = None
+        # observe.trace.program_span once a telemetry seam asks for the
+        # loop's waits to be traced (trace_waits); None costs one check
+        self._wait_span = None
 
     # -- handler registration (thread-safe) --------------------------------
 
@@ -156,7 +163,10 @@ class EventEngine:
                 work = self._next_work_locked()
                 if work is None:
                     timeout = self._wait_timeout_locked()
-                    self._condition.wait(timeout)
+                    if self._wait_span is None:
+                        self._condition.wait(timeout)
+                    else:
+                        self._traced_wait_locked(timeout)
                     continue
             kind, payload = work
             now = monotonic()
@@ -216,6 +226,27 @@ class EventEngine:
             return None
         return max(0.0, self._timers[0][0] - monotonic())
 
+    def trace_waits(self, span_factory) -> None:
+        """Name this loop's waits in the profiler's trace: `span_factory`
+        is observe.trace.program_span, handed in by a telemetry seam so
+        that runtime/ imports neither observe/ nor jax."""
+        self._wait_span = span_factory
+
+    def _traced_wait_locked(self, timeout) -> None:
+        """The idle wait as a span: `sched.hold` when the nearest live
+        timer is a hold-down (its handler carries the node it holds as
+        `hold_node`), `loop.idle` otherwise.  At most one slice of it:
+        the loop comes round again and waits on."""
+        node = (getattr(self._timers[0][2].handler, "hold_node", None)
+                if self._timers else None)
+        span = (self._wait_span("loop.idle", loop=self.name)
+                if node is None else
+                self._wait_span("sched.hold", loop=self.name, node=node))
+        with span:
+            self._condition.wait(
+                _TRACED_WAIT_SLICE if timeout is None
+                else min(timeout, _TRACED_WAIT_SLICE))
+
     def _invoke(self, handler, *args) -> None:
         try:
             handler(*args)
@@ -243,8 +274,3 @@ class EventEngine:
 
     def on_loop_thread(self) -> bool:
         return threading.current_thread() is self._loop_thread
-
-    def mailbox_high_water(self) -> dict:
-        with self._condition:
-            return {name: mailbox.high_water
-                    for name, mailbox in self._mailboxes.items()}

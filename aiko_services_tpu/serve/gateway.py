@@ -1768,35 +1768,40 @@ class Gateway(Actor):
                     adopt_trace)
             data["restore"] = restore
             stream.restore_hint = None
-        route_start = time.perf_counter()
-        replica.outstanding += 1
-        replica.routed += 1
-        replica.note_load(time.monotonic(), self.policy)
-        self.telemetry.routed.inc()
-        self.telemetry.record_replica_routed(replica.name)
-        payload = entry[0] if data is None else data
         trace = stream.traces.get(frame_id)
+        with self.telemetry.route_span(trace, replica.name,
+                                       pool=replica.pool_role()):
+            route_start = time.perf_counter()
+            replica.outstanding += 1
+            replica.routed += 1
+            replica.note_load(time.monotonic(), self.policy)
+            self.telemetry.routed.inc()
+            self.telemetry.record_replica_routed(replica.name)
+            payload = entry[0] if data is None else data
+            if trace is not None:
+                if frame_id not in stream.dispatch_s:
+                    # FIRST dispatch closes the admit-wait span (submit
+                    # -> dispatch, parked wait included); re-dispatches
+                    # (disagg hop 2, failover replay) extend the same
+                    # trace without a second admission
+                    wait_s = self.telemetry.record_admit_wait(trace)
+                    self.telemetry.record_stage(stream.stream_id,
+                                                "queue", wait_s)
+                stream.dispatch_s[frame_id] = route_start
+                self.telemetry.record_route(trace, route_start,
+                                            replica.name,
+                                            pool=replica.pool_role())
+                self.telemetry.record_stage(
+                    stream.stream_id, "route",
+                    time.perf_counter() - route_start)
         if trace is not None:
-            if frame_id not in stream.dispatch_s:
-                # FIRST dispatch closes the admit-wait span (submit ->
-                # dispatch, parked wait included); re-dispatches (disagg
-                # hop 2, failover replay) extend the same trace without
-                # a second admission
-                wait_s = self.telemetry.record_admit_wait(trace)
-                self.telemetry.record_stage(stream.stream_id, "queue",
-                                            wait_s)
-            stream.dispatch_s[frame_id] = route_start
-            self.telemetry.record_route(trace, route_start,
-                                        replica.name,
-                                        pool=replica.pool_role())
-            self.telemetry.record_stage(
-                stream.stream_id, "route",
-                time.perf_counter() - route_start)
             # propagation: the trace context rides the frame data (a
             # COPY -- entry[0] stays pristine for replay byte-equality)
-            # so the replica continues the gateway's trace
-            payload = attach_trace_context(payload,
-                                           make_trace_context(trace))
+            # so the replica continues the gateway's trace; stamped with
+            # this dispatch, from which the replica measures how long
+            # the frame then waits in its mailbox (aiko:ingress)
+            payload = attach_trace_context(
+                payload, make_trace_context(trace, dispatched=True))
         if replica.pipeline is not None:
             replica.pipeline.post_message("process_frame", [
                 {"stream_id": stream.stream_id, "frame_id": frame_id},
