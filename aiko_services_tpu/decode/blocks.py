@@ -5,7 +5,7 @@
 # WHICH fixed-size block each slot's next token lands in.  Allocation
 # and free are O(1) list operations on the event loop -- the device
 # never sees fragmentation because the block table indirection
-# (paged_decode_step's gather) makes any block order equivalent.
+# (paged_decode_step's block walk) makes any block order equivalent.
 #
 # Block 0 is reserved as the TRASH block: inactive decode slots write
 # their masked garbage there, which is what keeps the engine step

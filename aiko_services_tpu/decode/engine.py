@@ -61,6 +61,7 @@ from ..models import (
     init_paged_pool, paged_decode_step, paged_prefill,
     paged_prefill_chunk, paged_verify_step)
 from ..observe.trace import NO_SPANS
+from ..parallel.attention import paged_live_blocks
 from ..utils import get_logger
 from ..utils.padding import bucket_length
 from .blocks import TRASH_BLOCK, BlockManager
@@ -248,7 +249,8 @@ class DecodeEngine:
                          "restore_replayed_tokens": 0,
                          "prefix_hits": 0, "prefix_partial_hits": 0,
                          "prefix_blocks_shared": 0,
-                         "prefix_evictions": 0}
+                         "prefix_evictions": 0,
+                         "live_blocks": 0, "table_blocks": 0}
         self._update_gauges()
 
     # -- submission --------------------------------------------------------
@@ -657,7 +659,8 @@ class DecodeEngine:
     def _plain_step(self, decoding: list, report: StepReport) -> None:
         """One paged_decode_step over all slots; mid-prefill and free
         slots write to the trash block and their rows are ignored."""
-        with self._spans.span("engine.decode", decoding=len(decoding)):
+        with self._spans.span("engine.decode", decoding=len(decoding),
+                              **self._walked(self.positions, 1)):
             write_blocks = np.zeros((self.slots_n,), np.int32)
             write_offsets = np.zeros((self.slots_n,), np.int32)
             for index in decoding:
@@ -782,16 +785,35 @@ class DecodeEngine:
             slot.prefill_pos = bucket
             self._finish_prefill(index, report, first)
 
-    def _prefill_span(self, slot: "_Slot", bucket: int):
+    def _prefill_span(self, slot: "_Slot", bucket: int, start=None):
         """The `engine.prefill` span around one prefill call and its
         readback: `bucket` is the padded length the call runs at,
-        `queue_us` how long the request waited for its slot."""
+        `queue_us` how long the request waited for its slot.  A chunk
+        call (`start` = its first position) walks the slot's table like
+        a decode step and carries `live_blocks`/`table_blocks` too."""
         request = slot.request
+        walked = ({} if start is None
+                  else self._walked(np.array([start]), bucket))
         return self._spans.span(
             "engine.prefill", request.request_id, bucket=bucket,
             true_len=slot.true_len,
             queue_us=round(((request.admitted_at or request.submitted_at)
-                            - request.submitted_at) * 1e6))
+                            - request.submitted_at) * 1e6), **walked)
+
+    def _walked(self, positions, window: int) -> dict:
+        """The span fields of one paged window call over the target
+        pool: `live_blocks`, the blocks the attention walks (every
+        slot's positions + window, an idle slot's one trash block), and
+        `table_blocks`, what the tables can name (what the table-wide
+        gather read).  Their running sums ride `stats()`."""
+        walked = {
+            "live_blocks": int(paged_live_blocks(
+                positions, window, self.blocks.block_size,
+                self.max_blocks).sum()),
+            "table_blocks": len(positions) * self.max_blocks}
+        for name, count in walked.items():
+            self.counters[name] += count
+        return walked
 
     def _tail_prefill(self, index: int, report: StepReport) -> None:
         """Prefill ONLY the uncached tail of a prefix-cache hit in one
@@ -816,7 +838,7 @@ class DecodeEngine:
                 write_blocks[offset] = slot.blocks[
                     position // block_size]
             write_offsets[offset] = position % block_size
-        with self._prefill_span(slot, size):
+        with self._prefill_span(slot, size, start):
             before = _jit_cache_size()
             self.pool, greedy = paged_prefill_chunk(
                 self.params, self.config, self.pool, chunk,
@@ -918,7 +940,7 @@ class DecodeEngine:
                     draft_blocks[offset] = self.draft_tables[
                         index, block_index]
             write_offsets[offset] = position % block_size
-        with self._prefill_span(slot, size):
+        with self._prefill_span(slot, size, start):
             before = _jit_cache_size()
             self.pool, greedy = paged_prefill_chunk(
                 self.params, self.config, self.pool, chunk,
@@ -976,7 +998,8 @@ class DecodeEngine:
                     ingest_offsets[index, j] = position % block_size
         # draft proposals, their readbacks and the verify dispatch are
         # `engine.decode`; `engine.readback` is the wait for the verify
-        with self._spans.span("engine.decode", decoding=len(decoding)):
+        with self._spans.span("engine.decode", decoding=len(decoding),
+                              **self._walked(self.positions, k + 1)):
             draft_start = time.perf_counter()
             before = _jit_cache_size()
             self.draft_pool, draft_greedy = paged_verify_step(

@@ -3,7 +3,7 @@
 # Million-user chat traffic is dominated by shared prefixes (system
 # prompts, few-shot templates, multi-turn history).  The paged pool
 # already makes block ORDER irrelevant -- the block-table indirection
-# (paged_decode_step's gather) means any request can point at any
+# (paged_decode_step's block walk) means any request can point at any
 # block -- so the only missing piece is an index from token content to
 # block id.  This module provides it, SGLang-RadixAttention style but
 # flattened to a hash CHAIN instead of a tree:

@@ -28,7 +28,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.attention import (
-    flash_attention, ring_attention, sp_decode_attention,
+    flash_attention, paged_attention, paged_attention_reference,
+    paged_attention_takes, ring_attention, sp_decode_attention,
     ulysses_attention)
 from .layers import (
     apply_rotary, dense, init_dense, init_norm, repeat_kv, rms_norm,
@@ -768,16 +769,28 @@ def generate_stream(params, config: TransformerConfig, prompt,
 # POOL of KV blocks plus per-slot block tables, so requests are
 # admitted and evicted mid-decode without ever changing an array shape
 # (the same zero-filler trick the micro-batch scheduler uses for group
-# arity).  Three invariants make it bit-compatible with generate():
+# arity).  Three invariants make it token-compatible with generate():
 #
 #   - block contents are written by the SAME forward()/_quantize_kv
 #     math as the contiguous cache (prefill literally reshapes a
-#     forward() cache into blocks);
-#   - the decode step's attention is the SAME masked einsum as
-#     _attention's cached branch, applied to the block-table gather --
-#     positions beyond a slot's cursor hold garbage (stale or trash)
-#     but are masked to exactly zero weight, like the zeros of a fresh
-#     contiguous cache;
+#     forward() cache into blocks), and a step writes its new K/V into
+#     the donated pool where it lies (one row per window position,
+#     _write_window) -- the pool rides the layer loop as carry and is
+#     never rebuilt;
+#   - the decode step's attention is the SAME mathematics and mask as
+#     _attention's cached branch -- bf16/f32 operands, float32 scores
+#     and accumulation, the softmax over exactly the positions <= the
+#     query's -- taken BLOCKWISE by the paged-attention kernel
+#     (parallel/attention.py), which walks each slot's live blocks in
+#     the pool in place.  Positions beyond a slot's cursor hold garbage
+#     (stale or trash) but get exactly zero weight.  Blockwise softmax
+#     rounds differently from one softmax over the whole row, so TOKENS
+#     equal generate()'s (tests/test_decode.py) and logits agree to
+#     tolerance (tests/test_paged_attention.py), not bitwise.  The
+#     table-wide gather + einsum (paged_attention_reference) stays as
+#     the kernel's oracle and serves what the kernel does not take:
+#     an int8 pool, a window too large for VMEM, and on the chip a
+#     head_dim off the 128 lanes (paged_attention_takes);
 #   - inactive slots compute on a reserved TRASH block (index 0, never
 #     allocated) so the step's shapes -- (slots, max_blocks) -- are
 #     compile-time constants across any admission/eviction sequence.
@@ -830,6 +843,24 @@ def paged_prefill(params, config: TransformerConfig, pool, prompt,
     return new_pool, first
 
 
+def _write_window(leaf, value, layer, write_blocks, write_offsets):
+    """Write the window's new K/V (or scales) into one pool leaf where
+    it lies.  leaf (L, num_blocks, H, block, d); value (S, H, W, d);
+    write_blocks/write_offsets (S, W).  One dynamic_update_slice per
+    window position, unrolled: on the chip a scatter, and a rolled loop
+    of updates too, make XLA re-lay the whole leaf out (two copies of it
+    a layer); these update in place in the layout the kernel reads.
+    Later positions win where inert rows share the trash block."""
+    slots, _, window, _ = value.shape
+    for s in range(slots):
+        for i in range(window):
+            # (H, d) -> (1, 1, H, 1, d) at [layer, block, :, offset, :]
+            leaf = jax.lax.dynamic_update_slice(
+                leaf, value[s, :, i][None, None, :, None, :],
+                (layer, write_blocks[s, i], 0, write_offsets[s, i], 0))
+    return leaf
+
+
 def _paged_window(params, config: TransformerConfig, pool, tables,
                   positions, tokens, write_blocks, write_offsets):
     """Shared paged-attention step over a per-slot TOKEN WINDOW -- the
@@ -840,14 +871,13 @@ def _paged_window(params, config: TransformerConfig, pool, tables,
     tokens (slots, W) are consumed left-to-right per slot: window
     position i sits at absolute position positions[slot] + i, its K/V
     lands at (write_blocks[slot, i], write_offsets[slot, i]) -- writes
-    happen for the WHOLE window before the attention gather, so later
+    happen for the WHOLE window before the attention reads, so later
     window positions attend to earlier ones causally, and rows the
     engine wants inert point their writes at the trash block.  Returns
     (pool, greedy (slots, W)) where greedy[s, i] is the greedy token
-    AFTER consuming window positions 0..i -- exactly what W successive
-    single-token decode steps would produce, which is the bit-identity
-    contract the chunked-prefill and speculative tests pin."""
-    block_size = pool["k"].shape[3]
+    AFTER consuming window positions 0..i -- the tokens W successive
+    single-token decode steps would produce, which is the identity the
+    chunked-prefill and speculative tests pin."""
     quantized = config.kv_dtype == "int8"
     h = _embed(params, config, tokens)
     slots, window = tokens.shape
@@ -856,22 +886,14 @@ def _paged_window(params, config: TransformerConfig, pool, tables,
                                 config.rope_theta)
     cos, sin = cos[:, None], sin[:, None]        # (S, 1, W, hd/2)
     hd = config.head_dim
-    repeats = config.n_heads // config.n_kv_heads
-
-    def gather(pool_layer):
-        # (num_blocks, H, bs, d)[tables] -> (S, MB, H, bs, d) -> the
-        # slot's contiguous cache view (S, H, MB*bs, d)
-        view = pool_layer[tables]
-        s, max_blocks, heads, _, depth = view.shape
-        return view.transpose(0, 2, 1, 3, 4).reshape(
-            s, heads, max_blocks * block_size, depth)
+    use_kernel = paged_attention_takes(config.n_heads, window, hd,
+                                       pool["k"].dtype)
 
     def layer_step(carry, xs):
-        h = carry
-        if quantized:
-            layer, pool_k, k_scale, pool_v, v_scale = xs
-        else:
-            layer, pool_k, pool_v = xs
+        # the pool rides the loop as CARRY and is written where it lies
+        # (indexed by layer): as scan xs -> ys every step rebuilt it whole
+        h, pool = carry
+        layer, index = xs
         x = rms_norm(layer["attn_norm"], h, config.norm_eps)
         q = dense(layer["wq"], x).reshape(
             slots, window, config.n_heads, hd).transpose(0, 2, 1, 3)
@@ -881,60 +903,29 @@ def _paged_window(params, config: TransformerConfig, pool, tables,
             slots, window, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
+        written = {"k": k, "v": v}
         if quantized:
-            k, k_scale_new = _quantize_kv(k)
-            v, v_scale_new = _quantize_kv(v)
-            k_scale = k_scale.at[write_blocks, :, write_offsets, :].set(
-                k_scale_new.transpose(0, 2, 1, 3))
-            v_scale = v_scale.at[write_blocks, :, write_offsets, :].set(
-                v_scale_new.transpose(0, 2, 1, 3))
-        # (S, H, W, d) -> (S, W, H, d): advanced indexing with the
-        # (S, W) block/offset pairs scatters every window position of
-        # every slot in one update
-        pool_k = pool_k.at[write_blocks, :, write_offsets, :].set(
-            k.transpose(0, 2, 1, 3))
-        pool_v = pool_v.at[write_blocks, :, write_offsets, :].set(
-            v.transpose(0, 2, 1, 3))
-        if quantized:
-            # dequantize into the einsum operand load, exactly as the
-            # contiguous int8 cache path does
-            k_eff = (gather(pool_k).astype(jnp.float32)
-                     * gather(k_scale)).astype(q.dtype)
-            v_eff = (gather(pool_v).astype(jnp.float32)
-                     * gather(v_scale)).astype(q.dtype)
-        else:
-            k_eff, v_eff = gather(pool_k), gather(pool_v)
-        k_full = repeat_kv(k_eff, repeats)
-        v_full = repeat_kv(v_eff, repeats)
-        scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_full,
-                            preferred_element_type=jnp.float32) * scale
-        k_pos = jnp.arange(k_full.shape[2])[None, None, None, :]
-        logits = jnp.where(k_pos <= q_pos[:, None, :, None], logits,
-                           -1e30)
-        weights = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhqk,bhkd->bhqd",
-                         weights.astype(v_full.dtype), v_full)
+            written["k"], written["k_scale"] = _quantize_kv(k)
+            written["v"], written["v_scale"] = _quantize_kv(v)
+        pool = {name: _write_window(pool[name], value, index,
+                                    write_blocks, write_offsets)
+                for name, value in written.items()}
+        attend = (paged_attention if use_kernel
+                  else paged_attention_reference)
+        # an int8 pool's scales (einsum path only) dequantize the
+        # gathered view, exactly as the contiguous int8 cache path does
+        scales = ((pool["k_scale"], pool["v_scale"]) if quantized else ())
+        out = attend(q, pool["k"], pool["v"], index, tables, positions,
+                     *scales)
         out = out.transpose(0, 2, 1, 3).reshape(slots, window, -1)
         h = h + dense(layer["wo"], out)
         mlp_out, _ = _mlp_block(
             config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps))
-        h = h + mlp_out
-        if quantized:
-            return h, (pool_k, k_scale, pool_v, v_scale)
-        return h, (pool_k, pool_v)
+        return (h + mlp_out, pool), None
 
-    if quantized:
-        xs = (params["layers"], pool["k"], pool["k_scale"], pool["v"],
-              pool["v_scale"])
-    else:
-        xs = (params["layers"], pool["k"], pool["v"])
-    h, updated = jax.lax.scan(layer_step, h, xs)
-    if quantized:
-        new_pool = {"k": updated[0], "k_scale": updated[1],
-                    "v": updated[2], "v_scale": updated[3]}
-    else:
-        new_pool = {"k": updated[0], "v": updated[1]}
+    (h, new_pool), _ = jax.lax.scan(
+        layer_step, (h, pool),
+        (params["layers"], jnp.arange(config.n_layers)))
     logits = _lm_head(params, config, h)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return new_pool, greedy

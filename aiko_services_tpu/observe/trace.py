@@ -84,7 +84,11 @@
 #                               decoding, admitted
 #   engine.prefill     scoped   one prefill call + its readback, inside
 #                               engine.step: bucket, true_len, queue_us
-#   engine.decode      scoped   table build + dispatch: decoding
+#                               (a chunk call: live_blocks, table_blocks)
+#   engine.decode      scoped   table build + dispatch: decoding,
+#                               live_blocks (blocks the paged attention
+#                               walks this step), table_blocks (what the
+#                               tables can name: slots x max_blocks)
 #   engine.readback    scoped   the readback that waits for the step
 #   engine.chunk       mark     first token (offset 0) or previous chunk
 #                               -> this token_chunk: offset, tokens;

@@ -32,8 +32,10 @@ from ..utils.padding import pad_axis_to
 from .mesh import create_mesh  # noqa: F401  (re-exported convenience)
 
 __all__ = [
-    "attention_reference", "flash_attention", "ring_attention",
-    "sp_decode_attention", "ulysses_attention",
+    "attention_reference", "flash_attention", "paged_attention",
+    "paged_attention_reference", "paged_attention_takes",
+    "paged_live_blocks", "ring_attention", "sp_decode_attention",
+    "ulysses_attention",
 ]
 
 _NEG_INF = -1e30
@@ -512,6 +514,271 @@ def _flash_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, block_q,
     dk = dk.reshape(batch, heads, padded_kv_len, head_dim)[:, :, :kv_len]
     dv = dv.reshape(batch, heads, padded_kv_len, head_dim)[:, :, :kv_len]
     return dq, dk, dv
+
+
+# -- Pallas paged attention (the continuous-batching decode step) -----------
+#
+# The decode engine keeps K/V in one pool of fixed-size blocks,
+# (layers, num_blocks, kv_heads, block, head_dim), named per slot by a
+# block table.  The kernel reads the pool WHERE IT LIES: for each slot it
+# walks only the blocks that hold live positions, fetching them from HBM
+# with asynchronous copies (several blocks a chunk, two chunks in flight),
+# and serves all `repeats` query heads of a KV group from one read of that
+# group's block.  The bytes moved follow the live positions, never the
+# table's capacity; no gathered view, no repeat_kv, no float32 copy of the
+# cache ever exists in HBM.
+
+_PAGED_CHUNK_POSITIONS = 512   # K/V positions fetched per chunk
+_PAGED_CHUNK_BLOCKS_MAX = 16   # copies in flight per chunk and leaf
+_PAGED_NARROW = 128            # positions multiplied of a short chunk
+_PAGED_MAX_ROW_BYTES = 8192    # heads x window x itemsize one slot brings
+
+
+def paged_attention_reference(q, pool_k, pool_v, layer, tables, positions,
+                              k_scale=None, v_scale=None):
+    """Plain-XLA paged attention, same signature as paged_attention:
+    gather every slot's WHOLE table from the pool's `layer` into a
+    contiguous view, repeat the KV heads and run the masked einsum.
+    Window row i of slot s sits at absolute position positions[s] + i
+    and sees k_pos <= that.  With k_scale/v_scale (the int8 pool's scale
+    leaves) the gathered view is dequantized into the einsum's operand
+    load.  The oracle the kernel's tests compare with, and the path of
+    the calls the kernel does not take (paged_attention_takes)."""
+    slots, heads, window, depth = q.shape
+    repeats = heads // pool_k.shape[2]
+
+    def view(leaf):
+        # (L, num_blocks, H, bs, d)[layer, tables] -> (S, MB, H, bs, d)
+        # -> the slot's contiguous cache view (S, H, MB*bs, d)
+        gathered = leaf[layer, tables]
+        s, max_blocks, kv_heads, block, d = gathered.shape
+        return gathered.transpose(0, 2, 1, 3, 4).reshape(
+            s, kv_heads, max_blocks * block, d)
+
+    k_eff, v_eff = view(pool_k), view(pool_v)
+    if k_scale is not None:
+        k_eff = (k_eff.astype(jnp.float32) * view(k_scale)).astype(q.dtype)
+        v_eff = (v_eff.astype(jnp.float32) * view(v_scale)).astype(q.dtype)
+    # each KV head serves `repeats` consecutive query heads
+    k_full = jnp.repeat(k_eff, repeats, axis=1)
+    v_full = jnp.repeat(v_eff, repeats, axis=1)
+    scale = 1.0 / jnp.sqrt(jnp.float32(depth))
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_full,
+                        preferred_element_type=jnp.float32) * scale
+    q_pos = positions[:, None] + jnp.arange(window)[None, :]
+    k_pos = jnp.arange(k_full.shape[2])[None, None, None, :]
+    logits = jnp.where(k_pos <= q_pos[:, None, :, None], logits, _NEG_INF)
+    weights = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v_full.dtype),
+                      v_full)
+
+
+def paged_attention_takes(heads: int, window: int, head_dim: int,
+                          pool_dtype) -> bool:
+    """Whether paged_attention serves a call, decided by what the call
+    is: a bf16 or float32 pool whose `heads x window` query rows fit one
+    slot's VMEM residency (4096 rows of bf16, 2048 of float32) -- window
+    1 always does.  An int8 pool (its scales ride a second leaf) and a
+    very large window keep the einsum, and so does, on the chip, a
+    head_dim that is not a multiple of the 128 lanes: Mosaic cannot
+    slice such a pool for the block copies (jax's own paged kernels
+    refuse it too)."""
+    dtype = jnp.dtype(pool_dtype)
+    return (dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+            and heads * window * dtype.itemsize <= _PAGED_MAX_ROW_BYTES
+            and (head_dim % 128 == 0 or _interpret()))
+
+
+def paged_live_blocks(positions, window: int, block: int,
+                      max_blocks: int):
+    """Blocks the kernel walks for each slot: those holding positions
+    0 .. positions + window - 1, at least one (an inactive slot reads
+    the trash block) and never more than the table names.  numpy or jax
+    integers in, the same out."""
+    blocks = (positions + window + block - 1) // block
+    return blocks.clip(1, max_blocks)
+
+
+def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
+                  q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, parity_ref, m_ref, l_ref, acc_ref, *,
+                  window: int, block: int, chunk_blocks: int,
+                  max_blocks: int, sm_scale: float):
+    """One slot per grid step.  The slot's live blocks arrive in chunks
+    of `chunk_blocks`; while chunk c is multiplied, chunk c + 1 (or the
+    next slot's first chunk) is already on its way into the other half
+    of k_buf/v_buf.  Softmax state (m, l, acc) lives in VMEM in float32
+    across the chunks."""
+    slot = pl.program_id(0)
+    slots = pl.num_programs(0)
+    kv_heads, rows = q_ref.shape[1], q_ref.shape[2]
+    chunk = chunk_blocks * block
+    narrow = (_PAGED_NARROW if chunk % _PAGED_NARROW == 0 else chunk)
+    layer = layer_ref[0]
+    position = positions_ref[slot]
+    chunks = (live_ref[slot] + chunk_blocks - 1) // chunk_blocks
+
+    def transfer(of_slot, of_chunk, half, start: bool):
+        # one copy per live block and leaf; blocks past the slot's last
+        # are neither started nor waited for
+        first = of_chunk * chunk_blocks
+        count = jnp.minimum(live_ref[of_slot] - first, chunk_blocks)
+
+        def one(j, carry):
+            page = tables_ref[of_slot * max_blocks + first + j]
+            offset = pl.multiple_of(j * block, block)
+            for leaf, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                               (v_hbm, v_buf))):
+                copy = pltpu.make_async_copy(
+                    hbm.at[layer, page],
+                    buf.at[half, :, pl.ds(offset, block), :],
+                    sems.at[leaf, half])
+                if start:
+                    copy.start()
+                else:
+                    copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, count, one, 0)
+
+    @pl.when(slot == 0)
+    def _first():
+        # dead columns are masked by position, but 0 x NaN is NaN: what
+        # no copy has written yet must at least be finite
+        v_buf[...] = jnp.zeros_like(v_buf)
+        parity_ref[0] = 0
+        transfer(0, 0, 0, start=True)
+
+    first_half = parity_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def attend(c, half, width: int):
+        # the chunk's first `width` positions: a matmul a head for K and
+        # one for V, the group's rows served from one read of its block
+        row_window = jax.lax.broadcasted_iota(
+            jnp.int32, (rows, width), 0) % window
+        column = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+        visible = c * chunk + column <= position + row_window
+        for head in range(kv_heads):
+            q = q_ref[0, head]                              # (rows, d)
+            k_blk = k_buf[half, head, :width]               # (width, d)
+            s = jax.lax.dot_general(
+                q, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(visible, s, _NEG_INF)
+            m_prev = m_ref[head, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[head] = l_ref[head] * alpha + jnp.broadcast_to(
+                jnp.sum(p, axis=-1, keepdims=True), l_ref.shape[1:])
+            v_blk = v_buf[half, head, :width]
+            acc_ref[head] = acc_ref[head] * alpha + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[head] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+
+    def chunk_step(c, carry):
+        half = (first_half + c) % 2
+        last = c + 1 == chunks
+        next_slot = jnp.where(last, slot + 1, slot)
+
+        @pl.when(next_slot < slots)
+        def _prefetch():
+            transfer(next_slot, jnp.where(last, 0, c + 1), 1 - half,
+                     start=True)
+
+        transfer(slot, c, half, start=False)
+        if narrow == chunk:
+            attend(c, half, chunk)
+        else:
+            # a chunk is multiplied whole (wide matmuls keep the MXU
+            # fed), but one that ends inside its first `narrow`
+            # positions -- an idle slot's trash block, a short tail --
+            # only that far
+            seen = position + window - c * chunk
+
+            @pl.when(seen <= narrow)
+            def _short():
+                attend(c, half, narrow)
+
+            @pl.when(seen > narrow)
+            def _whole():
+                attend(c, half, chunk)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, chunk_step, 0)
+    parity_ref[0] = (first_half + chunks) % 2
+    for head in range(kv_heads):
+        o_ref[0, head] = (acc_ref[head] / l_ref[head, :, :1]).astype(
+            o_ref.dtype)
+
+
+def paged_attention(q, pool_k, pool_v, layer, tables, positions):
+    """Paged attention over the pool in place.  q (slots, heads, W, d);
+    pool_k/pool_v the WHOLE pool leaves (layers, num_blocks, kv_heads,
+    block, d), left in HBM, of which `layer` (an int32 scalar, traced or
+    not) is read; tables (slots, max_blocks) int32; positions (slots,)
+    int32.  Same mathematics and mask as paged_attention_reference --
+    window row i of slot s attends to k_pos <= positions[s] + i, scores
+    and accumulation in float32, operands in the pool's dtype -- with
+    the softmax taken blockwise, so outputs agree to rounding, not
+    bitwise.  Mosaic on the chip, interpreted on CPU (_interpret)."""
+    slots, heads, window, depth = q.shape
+    _, _, kv_heads, block, _ = pool_k.shape
+    max_blocks = tables.shape[1]
+    repeats = heads // kv_heads
+    rows = repeats * window
+    # the group's repeats x W query rows are one matmul operand; padded
+    # with zero rows to the dtype's sublane tile (sliced off below)
+    grouped = _pad_seq(q.reshape(slots, kv_heads, rows, depth),
+                       8 * 4 // jnp.dtype(q.dtype).itemsize)
+    padded_rows = grouped.shape[2]
+    chunk_blocks = max(1, min(_PAGED_CHUNK_BLOCKS_MAX,
+                              _PAGED_CHUNK_POSITIONS // block, max_blocks))
+    chunk = chunk_blocks * block
+    positions = positions.astype(jnp.int32)
+    live = paged_live_blocks(positions, window, block, max_blocks)
+
+    kernel = functools.partial(
+        _paged_kernel, window=window, block=block,
+        chunk_blocks=chunk_blocks, max_blocks=max_blocks,
+        sm_scale=1.0 / math.sqrt(depth))
+    q_spec = pl.BlockSpec((1, kv_heads, padded_rows, depth),
+                          lambda s, *_: (s, 0, 0, 0),
+                          memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(slots,),
+            in_specs=[q_spec,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, kv_heads, chunk, depth), pool_k.dtype),
+                pltpu.VMEM((2, kv_heads, chunk, depth), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),               # buffer parity
+                pltpu.VMEM((kv_heads, padded_rows, _STAT_LANES),
+                           jnp.float32),                   # m
+                pltpu.VMEM((kv_heads, padded_rows, _STAT_LANES),
+                           jnp.float32),                   # l
+                pltpu.VMEM((kv_heads, padded_rows, depth),
+                           jnp.float32),                   # acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct(grouped.shape, q.dtype),
+        # the buffer parity and the copy in flight cross grid steps
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      tables.reshape(-1).astype(jnp.int32), positions, live,
+      grouped, pool_k, pool_v)
+    return out[:, :, :rows].reshape(slots, heads, window, depth)
 
 
 # -- Ring attention (sequence parallel) -------------------------------------

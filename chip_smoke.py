@@ -11,12 +11,15 @@ random weights from fixed seeds), and checks what comes out:
 
   train     make_train_step on the 8-layer llama32_1b cut, three steps
   kernels   flash_attention against an f32 reference at the probe
-            lengths, forward and backward, and the Mosaic custom call in
-            the compiled train step and prefill
+            lengths, forward and backward, paged_attention against its
+            einsum oracle, and the Mosaic custom call in the compiled
+            train step and prefill
   pipeline  the headline 3-stage graph (speech -> LM, vision ->
             detections) through create_pipeline / create_stream
   serve     Registrar + replica pipeline + Gateway + DecodeEngine:
-            two waves of eight ragged-prompt streams
+            two waves of eight ragged-prompt streams, on a 4-layer cut
+            of Mistral-7B's widths (head_dim 128: the paged-attention
+            kernel serves the decode step, which is checked)
   link      three facts about host<->device (findings, not speeds)
 
 With four or more devices the same process drives a four-device mesh
@@ -54,12 +57,13 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec
 
 from aiko_services_tpu.models import (
-    forward, init_params, make_train_step, param_specs)
+    forward, init_params, make_train_step, paged_decode_step, param_specs)
 from aiko_services_tpu.models.configs import LLAMA32_1B, LM_TOY
 from aiko_services_tpu.parallel import (
     create_mesh, filter_specs, shard_pytree)
 from aiko_services_tpu.parallel.attention import (
-    attention_reference, flash_attention)
+    attention_reference, flash_attention, paged_attention,
+    paged_attention_reference, paged_attention_takes)
 from aiko_services_tpu.pipeline import create_pipeline
 from aiko_services_tpu.runtime import (
     Process, Registrar, cache_stats, enable_compile_cache)
@@ -103,6 +107,13 @@ class Sizes:
             self.max_new = 32
             self.serve = {"decode_slots": 8, "kv_block_size": 32,
                           "max_context": 2048}
+            # llama32_1b's heads are 64 wide, which Mosaic cannot slice
+            # a paged pool by: the served model is Mistral-7B-v0.1's
+            # widths (head_dim 128) at 4 of its 32 layers
+            self.serve_lm = {
+                "vocab_size": 32000, "d_model": 4096, "n_layers": 4,
+                "n_heads": 32, "n_kv_heads": 8, "d_ff": 14336,
+                "max_seq_len": 4096, "dtype": "bfloat16"}
             self.prompt_lengths = (32, 128)
             self.train_config = replace(LLAMA32_1B, n_layers=8)
             self.train_batch, self.train_seq = 4, 1024
@@ -131,6 +142,7 @@ class Sizes:
             self.max_new = 8
             self.serve = {"decode_slots": 8, "kv_block_size": 8,
                           "max_context": 128}
+            self.serve_lm = self.lm
             self.prompt_lengths = (8, 32)
             self.train_config = replace(LM_TOY, n_layers=2)
             self.train_batch, self.train_seq = 2, 64
@@ -426,7 +438,7 @@ def _serve_definition(sizes: Sizes) -> dict:
             {"name": "lm",
              "input": [{"name": "tokens", "type": "any"}],
              "output": [{"name": "generated", "type": "any"}],
-             "parameters": dict(sizes.lm, **sizes.serve,
+             "parameters": dict(sizes.serve_lm, **sizes.serve,
                                 continuous=True, stream_tokens=True,
                                 max_new_tokens=sizes.max_new),
              "deploy": _local("LMGenerate")},
@@ -496,10 +508,27 @@ def phase_serve(sizes: Sizes, report: Report, platform: str) -> None:
         _require(stats["compiles"] == compiles_before,
                  f"second wave compiled: {compiles_before} -> "
                  f"{stats['compiles']}")
-        pool = element._engine.pool
+        engine = element._engine
+        pool = engine.pool
         _require(all(_on_platform(leaf, platform)
                      for leaf in pool.values()),
                  "the paged KV pool is not on the device")
+        # the step the waves ran, compiled again from the engine's own
+        # arguments (a cache hit): the paged-attention kernel is in it,
+        # and it holds no second pool while it runs
+        idle = np.zeros((engine.slots_n,), np.int32)
+        step = paged_decode_step.lower(
+            engine.params, engine.config, pool, engine.tables,
+            engine.positions, engine.last_tokens, idle, idle).compile()
+        mosaic = _has_mosaic_call(step)
+        temp_bytes = step.memory_analysis().temp_size_in_bytes
+        pool_bytes = sum(leaf.nbytes for leaf in pool.values())
+        _require(mosaic == (platform == "tpu"),
+                 f"decode step Mosaic custom call present={mosaic} on "
+                 f"{platform}")
+        _require(platform != "tpu" or temp_bytes < pool_bytes,
+                 f"decode step needs {temp_bytes} temporary bytes, the "
+                 f"pool is {pool_bytes}: the step copies the pool")
         report.phase("serve", setup_s, steady_s, completed=16,
                      tokens_each=sizes.max_new,
                      admitted=stats["admitted"],
@@ -507,7 +536,11 @@ def phase_serve(sizes: Sizes, report: Report, platform: str) -> None:
                      compiles=stats["compiles"],
                      compiles_second_wave=(stats["compiles"]
                                            - compiles_before),
-                     kv_blocks=stats["blocks"])
+                     kv_blocks=stats["blocks"],
+                     live_blocks=stats["live_blocks"],
+                     table_blocks=stats["table_blocks"],
+                     decode_mosaic_custom_call=mosaic,
+                     decode_temp_bytes=temp_bytes, pool_bytes=pool_bytes)
     finally:
         _stop(processes, threads)
 
@@ -613,6 +646,36 @@ def phase_kernels(sizes: Sizes, report: Report, platform: str) -> None:
                  f"flash backward {name} off the reference by "
                  f"{error:.3g} of full scale")
 
+    # the paged-attention kernel of the served decode step against its
+    # einsum oracle: 8 slots on a pool of 32-position blocks, cursors on
+    # both sides of a block, of the short chunk and of a chunk, one
+    # slot idle on the trash block
+    positions = jnp.asarray([0, 31, 32, 127, 128, 511, 512, 2000],
+                            jnp.int32)
+    slots, max_blocks, kv_heads, repeats = positions.shape[0], 64, 8, 4
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    pool_shape = (2, slots * max_blocks + 1, kv_heads, 32, 128)
+    pool_k, pool_v = (jax.random.normal(key, pool_shape, dtype)
+                      for key in keys[:2])
+    tables = 1 + jnp.arange(slots * max_blocks, dtype=jnp.int32).reshape(
+        slots, max_blocks).at[0].set(0)
+    paged_error = 0.0
+    for window in (1, 5):
+        q = jax.random.normal(
+            keys[2], (slots, kv_heads * repeats, window, 128), dtype)
+        _require(paged_attention_takes(q.shape[1], window, 128, dtype),
+                 f"paged_attention refuses window {window}")
+        got, want = (np.asarray(attend(q, pool_k, pool_v, jnp.int32(1),
+                                       tables, positions), np.float32)
+                     for attend in (paged_attention,
+                                    paged_attention_reference))
+        _require(np.all(np.isfinite(got)),
+                 f"paged_attention W={window}: non-finite output")
+        paged_error = max(paged_error, float(np.abs(got - want).max()))
+        _require(paged_error <= FLASH_ATOL,
+                 f"paged_attention W={window}: off the einsum by "
+                 f"{paged_error:.3g}")
+
     # the prefill the LM elements run: forward() with no cache
     config = sizes.train_config
     params = init_params(config, jax.random.PRNGKey(0))
@@ -633,6 +696,7 @@ def phase_kernels(sizes: Sizes, report: Report, platform: str) -> None:
         lengths=list(PROBE_LENGTHS), dtype=KERNEL_DTYPE,
         max_abs_err=max(worst.values()), tol=FLASH_ATOL,
         grad_err=grad_error, grad_tol=FLASH_GRAD_TOL,
+        paged_max_abs_err=paged_error,
         prefill_mosaic_custom_call=mosaic)
 
 

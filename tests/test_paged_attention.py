@@ -1,0 +1,252 @@
+# The paged-attention kernel (parallel/attention.py paged_attention) against
+# its einsum oracle (paged_attention_reference) on random pools, interpreted
+# on the CPU: ragged lengths around every block and chunk edge, an idle slot
+# on the trash block, garbage past every cursor, GQA ratios, window sizes,
+# float32 and bfloat16.  One parametrised test, each case counted.
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from aiko_services_tpu.decode.blocks import TRASH_BLOCK
+from aiko_services_tpu.models.transformer import (
+    TransformerConfig, init_paged_pool, init_params, paged_decode_step)
+from aiko_services_tpu.parallel import attention
+from aiko_services_tpu.parallel.attention import (
+    paged_attention, paged_attention_reference, paged_attention_takes,
+    paged_live_blocks)
+
+BLOCK = 16
+MAX_BLOCKS = 40                 # table capacity 640: three chunks of 256
+CAPACITY = BLOCK * MAX_BLOCKS
+CHUNK = 16 * BLOCK              # the kernel's chunk at this block size
+DEPTH = 32
+LAYERS = 2
+# what a cursor can be: the first position, both sides of a block edge,
+# both sides of the short chunk's end (128) and of a chunk edge, the
+# table's last position
+RAGGED = (0, BLOCK - 2, BLOCK - 1, BLOCK, 127, 128, CHUNK - 1, CHUNK,
+          CAPACITY - 1)
+GARBAGE = 1e4                   # finite, like stale K/V; would swamp any sum
+TOLERANCE = {"float32": 1e-5, "bfloat16": 2e-2}
+
+
+def _case(kv_heads, repeats, window, dtype, seed):
+    """A pool whose every position past a slot's cursor + window holds
+    GARBAGE, tables of scattered blocks, and one idle slot: cursor 0,
+    every table entry the trash block."""
+    rng = np.random.default_rng(seed)
+    # the last position the window writes must exist in the table
+    positions = np.array(
+        [min(p, CAPACITY - window) for p in RAGGED] + [0], np.int32)
+    slots = len(positions)
+    num_blocks = slots * MAX_BLOCKS + 1
+    shape = (LAYERS, num_blocks, kv_heads, BLOCK, DEPTH)
+    pool_k = rng.normal(size=shape).astype(np.float32)
+    pool_v = rng.normal(size=shape).astype(np.float32)
+    tables = rng.permutation(np.arange(1, num_blocks))[
+        :slots * MAX_BLOCKS].reshape(slots, MAX_BLOCKS).astype(np.int32)
+    tables[-1] = TRASH_BLOCK
+    for slot in range(slots - 1):
+        live = int(positions[slot]) + window
+        for index, block in enumerate(tables[slot]):
+            dead_from = min(max(live - index * BLOCK, 0), BLOCK)
+            pool_k[:, block, :, dead_from:] = GARBAGE
+            pool_v[:, block, :, dead_from:] = -GARBAGE
+    # the trash block: one live position, garbage after it
+    pool_k[:, TRASH_BLOCK, :, window:] = GARBAGE
+    pool_v[:, TRASH_BLOCK, :, window:] = -GARBAGE
+    q = rng.normal(size=(slots, kv_heads * repeats, window, DEPTH))
+    return (jnp.asarray(q, dtype), jnp.asarray(pool_k, dtype),
+            jnp.asarray(pool_v, dtype), jnp.asarray(tables),
+            jnp.asarray(positions))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("window", [1, 4, 16])
+@pytest.mark.parametrize("kv_heads,repeats", [(2, 1), (2, 4), (1, 8)])
+def test_kernel_matches_einsum_oracle(kv_heads, repeats, window, dtype):
+    q, pool_k, pool_v, tables, positions = _case(
+        kv_heads, repeats, window, jnp.dtype(dtype),
+        seed=kv_heads * 100 + repeats * 10 + window)
+    assert paged_attention_takes(kv_heads * repeats, window, DEPTH,
+                                 pool_k.dtype)
+    assert CHUNK == BLOCK * min(attention._PAGED_CHUNK_BLOCKS_MAX,
+                                attention._PAGED_CHUNK_POSITIONS // BLOCK)
+    assert attention._PAGED_NARROW < CHUNK     # both widths are run
+    layer = jnp.int32(1)
+    out = paged_attention(q, pool_k, pool_v, layer, tables, positions)
+    oracle = paged_attention_reference(q, pool_k, pool_v, layer, tables,
+                                       positions)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    out = np.asarray(out, np.float32)
+    # garbage past a cursor that reached the output would be ~1e4
+    assert np.isfinite(out).all() and np.abs(out).max() < 10.0
+    np.testing.assert_allclose(out, np.asarray(oracle, np.float32),
+                               atol=TOLERANCE[dtype], rtol=0)
+
+
+def test_kernel_reads_the_layer_it_is_given():
+    q, pool_k, pool_v, tables, positions = _case(2, 2, 1, jnp.float32, 5)
+    outs = [np.asarray(paged_attention(q, pool_k, pool_v, jnp.int32(layer),
+                                       tables, positions))
+            for layer in range(LAYERS)]
+    assert np.abs(outs[0] - outs[1]).max() > 1e-2
+    for layer, out in enumerate(outs):
+        np.testing.assert_allclose(
+            out, np.asarray(paged_attention_reference(
+                q, pool_k, pool_v, layer, tables, positions)), atol=1e-5)
+
+
+def test_live_blocks_follow_the_cursor_not_the_table():
+    positions = np.array([0, BLOCK - 1, BLOCK, CAPACITY - 1, CAPACITY + 40])
+    np.testing.assert_array_equal(
+        paged_live_blocks(positions, 1, BLOCK, MAX_BLOCKS),
+        [1, 1, 2, MAX_BLOCKS, MAX_BLOCKS])
+    np.testing.assert_array_equal(
+        paged_live_blocks(positions, BLOCK + 1, BLOCK, MAX_BLOCKS),
+        [2, 2, 3, MAX_BLOCKS, MAX_BLOCKS])
+
+
+@pytest.mark.parametrize("heads,window,dtype,takes", [
+    (32, 1, "bfloat16", True), (32, 1, "float32", True),
+    (32, 5, "bfloat16", True), (32, 128, "bfloat16", True),
+    (32, 256, "bfloat16", False), (32, 64, "float32", True),
+    (32, 128, "float32", False), (32, 1, "int8", False)])
+def test_what_takes_the_kernel_is_decided_by_shape_and_dtype(
+        heads, window, dtype, takes):
+    assert paged_attention_takes(heads, window, 128, dtype) is takes
+
+
+def test_decode_step_updates_the_pool_in_place_and_only_where_told():
+    """The served step: the donated pool comes back with exactly the
+    rows at (layer, write_block, write_offset) changed, in every leaf."""
+    config = TransformerConfig(vocab_size=64, n_layers=2, n_heads=4,
+                               n_kv_heads=2, d_model=32, d_ff=64,
+                               max_seq_len=4 * BLOCK, dtype="float32")
+    params = init_params(config, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(3)
+    slots, max_blocks = 3, 4
+    pool = init_paged_pool(config, slots * max_blocks + 1, BLOCK)
+    pool = {name: jnp.asarray(rng.normal(size=leaf.shape), leaf.dtype)
+            for name, leaf in pool.items()}
+    before = {name: np.asarray(leaf) for name, leaf in pool.items()}
+    tables = 1 + np.arange(slots * max_blocks, dtype=np.int32).reshape(
+        slots, max_blocks)
+    positions = np.array([0, BLOCK + 1, 4 * BLOCK - 1], np.int32)
+    write_blocks = tables[np.arange(slots), positions // BLOCK]
+    write_offsets = positions % BLOCK
+    tokens = np.array([[1], [2], [3]], np.int32)
+    pool, greedy = paged_decode_step(params, config, pool, tables,
+                                     positions, tokens, write_blocks,
+                                     write_offsets)
+    assert greedy.shape == (slots, 1)
+    for name, leaf in pool.items():
+        changed = np.argwhere(
+            (np.asarray(leaf) != before[name]).any(axis=(2, 4)))
+        expected = sorted(
+            (layer, int(block), int(offset))
+            for layer in range(config.n_layers)
+            for block, offset in zip(write_blocks, write_offsets))
+        assert sorted(map(tuple, changed)) == expected, name
+
+
+# -- compiled for the chip, here, without the chip ---------------------------
+#
+# The TPU's compiler is installed and compiles for a chip that is described,
+# not attached: what Mosaic or XLA would refuse or re-lay out on the v5e shows
+# at no chip time.  The topology is described inside a fixture (never while a
+# module is imported), and these tests stay in this one file.
+
+SERVED = dict(slots=16, kv_heads=8, repeats=4, block=32, max_blocks=128,
+              depth=128, layers=16, blocks=1024)     # benchmark/configs
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as error:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {error}")
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.fixture
+def for_the_chip(one_chip, monkeypatch):
+    """Shapes placed on the described chip; kernels lowered through
+    Mosaic, not the interpreter; nothing written to the compile cache
+    (an entry compiled for a described chip cannot be read back)."""
+    monkeypatch.setattr(attention, "_interpret", lambda: False)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, jnp.dtype(dtype), sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", cache)
+
+
+@pytest.mark.parametrize("window,dtype", [
+    (1, "bfloat16"), (5, "bfloat16"), (128, "bfloat16"), (1, "float32"),
+    (64, "float32")])
+def test_kernel_compiles_for_the_v5e_at_the_served_shape(
+        for_the_chip, window, dtype):
+    s = SERVED
+    pool = for_the_chip((s["layers"], s["blocks"], s["kv_heads"],
+                         s["block"], s["depth"]), dtype)
+    slots = s["slots"] if window < 64 else 1        # a prefill chunk
+    compiled = jax.jit(paged_attention).lower(
+        for_the_chip((slots, s["kv_heads"] * s["repeats"], window,
+                      s["depth"]), dtype),
+        pool, pool, for_the_chip((), "int32"),
+        for_the_chip((slots, s["max_blocks"]), "int32"),
+        for_the_chip((slots,), "int32")).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the pool is read where it lies: nothing of its size is allocated
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_served_decode_step_moves_no_pool_leaf_on_the_v5e(for_the_chip):
+    s = SERVED
+    config = TransformerConfig(
+        vocab_size=32000, d_model=4096, n_layers=s["layers"], n_heads=32,
+        n_kv_heads=s["kv_heads"], d_ff=14336, max_seq_len=4096,
+        dtype="bfloat16")
+    place = lambda tree: jax.tree_util.tree_map(       # noqa: E731
+        lambda leaf: for_the_chip(leaf.shape, leaf.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: init_params(config, jax.random.PRNGKey(0))))
+    pool = place(jax.eval_shape(
+        lambda: init_paged_pool(config, s["blocks"], s["block"])))
+    int32 = lambda *shape: for_the_chip(shape, "int32")  # noqa: E731
+    slots = s["slots"]
+    compiled = paged_decode_step.lower(
+        params, config, pool, int32(slots, s["max_blocks"]), int32(slots),
+        int32(slots, 1), int32(slots), int32(slots)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    leaf = pool["k"]
+    leaf_bytes = int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+    memory = compiled.memory_analysis()
+    # the donated pool is updated where it lies: no second pool, no
+    # gathered view, no copy of a leaf (a scatter made two a layer)
+    assert memory.alias_size_in_bytes == 2 * leaf_bytes
+    assert memory.temp_size_in_bytes < leaf_bytes // 16
+    leaf_type = "bf16[" + ",".join(map(str, leaf.shape)) + "]"
+    moved = [line for line in text.splitlines()
+             if f"= {leaf_type}" in line and " copy(" in line]
+    assert not moved, moved[:2]
+
+
+def test_on_the_chip_a_head_dim_off_the_lanes_keeps_the_einsum(
+        monkeypatch):
+    assert paged_attention_takes(32, 1, 64, "bfloat16")      # interpreted
+    monkeypatch.setattr(attention, "_interpret", lambda: False)
+    assert not paged_attention_takes(32, 1, 64, "bfloat16")
+    assert paged_attention_takes(32, 1, 128, "bfloat16")
+    assert paged_attention_takes(32, 1, 256, "float32")

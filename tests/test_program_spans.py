@@ -519,7 +519,7 @@ SERVED_SPANS = {
     "engine.submit": REQUEST | {"waited_us"},
     "engine.step": {"waiting", "active", "decoding", "admitted"},
     "engine.prefill": REQUEST | {"bucket", "true_len", "queue_us"},
-    "engine.decode": {"decoding"},
+    "engine.decode": {"decoding", "live_blocks", "table_blocks"},
     "engine.readback": set(),
     "engine.chunk": REQUEST | {"offset", "tokens", "waited_us", "first_us",
                                "ingress_us"},
