@@ -61,7 +61,7 @@ from ..models import (
     init_paged_pool, paged_decode_step, paged_prefill,
     paged_prefill_chunk, paged_verify_step)
 from ..observe.trace import NO_SPANS
-from ..parallel.attention import paged_live_blocks
+from ..parallel.attention import flash_attention_takes, paged_live_blocks
 from ..utils import get_logger
 from ..utils.padding import bucket_length
 from .blocks import TRASH_BLOCK, BlockManager
@@ -250,7 +250,8 @@ class DecodeEngine:
                          "prefix_hits": 0, "prefix_partial_hits": 0,
                          "prefix_blocks_shared": 0,
                          "prefix_evictions": 0,
-                         "live_blocks": 0, "table_blocks": 0}
+                         "live_blocks": 0, "table_blocks": 0,
+                         "prefill_flash": 0, "prefill_einsum": 0}
         self._update_gauges()
 
     # -- submission --------------------------------------------------------
@@ -788,17 +789,27 @@ class DecodeEngine:
     def _prefill_span(self, slot: "_Slot", bucket: int, start=None):
         """The `engine.prefill` span around one prefill call and its
         readback: `bucket` is the padded length the call runs at,
-        `queue_us` how long the request waited for its slot.  A chunk
-        call (`start` = its first position) walks the slot's table like
-        a decode step and carries `live_blocks`/`table_blocks` too."""
+        `queue_us` how long the request waited for its slot.  A whole
+        prefill says which `attention` its bucket takes (flash, the
+        blockwise kernel over the fresh K/V, or einsum: the model step's
+        own choice, by the same predicate); a chunk call (`start` = its
+        first position) walks the slot's table like a decode step and
+        carries `live_blocks`/`table_blocks` instead.  The running
+        counts of both ride `stats()`."""
         request = slot.request
-        walked = ({} if start is None
-                  else self._walked(np.array([start]), bucket))
+        if start is None:
+            attention = ("flash" if flash_attention_takes(
+                1, self.config.n_heads, bucket, self.config.jnp_dtype,
+                self.pool["k"].dtype) else "einsum")
+            self.counters["prefill_" + attention] += 1
+            fields = {"attention": attention}
+        else:
+            fields = self._walked(np.array([start]), bucket)
         return self._spans.span(
             "engine.prefill", request.request_id, bucket=bucket,
             true_len=slot.true_len,
             queue_us=round(((request.admitted_at or request.submitted_at)
-                            - request.submitted_at) * 1e6), **walked)
+                            - request.submitted_at) * 1e6), **fields)
 
     def _walked(self, positions, window: int) -> dict:
         """The span fields of one paged window call over the target

@@ -28,9 +28,9 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.attention import (
-    flash_attention, paged_attention, paged_attention_reference,
-    paged_attention_takes, ring_attention, sp_decode_attention,
-    ulysses_attention)
+    flash_attention, flash_attention_takes, paged_attention,
+    paged_attention_reference, paged_attention_takes, ring_attention,
+    sp_decode_attention, ulysses_attention)
 from .layers import (
     apply_rotary, dense, init_dense, init_norm, repeat_kv, rms_norm,
     rotary_embedding)
@@ -320,8 +320,10 @@ def _attention(config: TransformerConfig, layer, h, cos, sin,
     """Returns (output, new_k, new_v, new_k_scale, new_v_scale) -- the
     scale entries are None unless the cache is int8-quantized.  Without
     a cache: flash-attention causal prefill.  With a cache: write new
-    K/V at `pos` (quantizing when the cache is int8), masked attention
-    over the whole cache buffer."""
+    K/V at `pos` (quantizing when the cache is int8), then masked
+    attention over the whole cache buffer -- or, for a prefill from the
+    static position 0 that flash_attention_takes, the same causal
+    attention over the fresh K/V alone, blockwise."""
     batch, length, _ = h.shape
     hd = config.head_dim
     q = dense(layer["wq"], h).reshape(
@@ -344,8 +346,7 @@ def _attention(config: TransformerConfig, layer, h, cos, sin,
             out = sp_prefill(q, repeat_kv(k, repeats),
                              repeat_kv(v, repeats))
         else:
-            out = flash_attention(q, repeat_kv(k, repeats),
-                                  repeat_kv(v, repeats), causal=True)
+            out = flash_attention(q, k, v, causal=True)
     else:
         quantized = cache_k.dtype == jnp.int8
         if quantized:
@@ -377,6 +378,16 @@ def _attention(config: TransformerConfig, layer, h, cos, sin,
             # cache shard (GQA heads expand inside the shard), partials
             # merge with a pmax/psum online-softmax
             out = sp_decode_attention(q, cache_k, cache_v, pos)
+        elif (length > 1 and isinstance(pos, (int, np.integer)) and pos == 0
+              and flash_attention_takes(batch, config.n_heads, length,
+                                        k.dtype, cache_k.dtype)):
+            # cached PREFILL: the cache holds nothing before position 0,
+            # and columns past `length` were masked anyway, so the causal
+            # attention over the fresh K/V is the masked one over the
+            # cache buffer -- taken blockwise, grouped K/V as they are,
+            # no (length x max_len) scores in HBM.  Blockwise softmax
+            # rounds differently: logits agree to tolerance, not bitwise
+            out = flash_attention(q, k, v, causal=True)
         else:
             if quantized:
                 # dequantize into the einsum operand load (int8 codes x

@@ -83,8 +83,10 @@
 #   engine.step        scoped   one engine tick: waiting, active,
 #                               decoding, admitted
 #   engine.prefill     scoped   one prefill call + its readback, inside
-#                               engine.step: bucket, true_len, queue_us
-#                               (a chunk call: live_blocks, table_blocks)
+#                               engine.step: bucket, true_len, queue_us,
+#                               attention (flash | einsum: what the
+#                               bucket's attention takes; a chunk call
+#                               instead: live_blocks, table_blocks)
 #   engine.decode      scoped   table build + dispatch: decoding,
 #                               live_blocks (blocks the paged attention
 #                               walks this step), table_blocks (what the

@@ -32,8 +32,8 @@ from ..utils.padding import pad_axis_to
 from .mesh import create_mesh  # noqa: F401  (re-exported convenience)
 
 __all__ = [
-    "attention_reference", "flash_attention", "paged_attention",
-    "paged_attention_reference", "paged_attention_takes",
+    "attention_reference", "flash_attention", "flash_attention_takes",
+    "paged_attention", "paged_attention_reference", "paged_attention_takes",
     "paged_live_blocks", "ring_attention", "sp_decode_attention",
     "ulysses_attention",
 ]
@@ -78,15 +78,48 @@ def attention_reference(q, k, v, causal: bool = False, sm_scale=None,
 
 _STAT_LANES = 128  # min f32 lane width for the m/l scratch tiles
 
+# Tiles, chosen from the call's shape (flash_attention).  On the v5e at
+# (1, 32 q / 8 kv heads, 4096, 128) bf16 causal the forward kernel took
+# 5.81 ms with 128 x 128 tiles, 2.20 with 512 x 512, 1.39 with 512 x 1024
+# and 1.36 with 1024 x 1024 (1.50 with 512 x 2048: more of a wide tile
+# lies above the diagonal): what a K step costs beside its scores -- the
+# rescaling of acc, m and l, a grid step's 0.35 us -- is spread over
+# `block_k` columns.  A group's query rows (repeats x block_q x head_dim)
+# are held to what four heads of 128 lanes bring, so q, the output and the
+# float32 accumulator stay inside the VMEM asked for whatever the grouping.
+_FLASH_BLOCK = 1024
+_FLASH_GROUP_ELEMENTS = 4 * _FLASH_BLOCK * 128
+_FLASH_VMEM_BYTES = 48 << 20
+# the backward kernels hold four float32 score-sized tiles a step
+_FLASH_BACKWARD_BLOCK = 512
+# The masked einsum's float32 scores (batch x heads x L x L) beyond which
+# a cached prefill attends through the kernel.  Up to here XLA keeps the
+# scores in the v5e's 128 MiB of VMEM and one fused softmax beats a
+# blockwise kernel whose tiles are few and small; past it they cross HBM
+# four times.  Measured on the v5e, 32 q / 8 kv heads, bf16, einsum
+# against kernel in us a layer: batch 1 -- L 512 (32 MiB) 53 / 73, L 1024
+# (128 MiB) 409 / 158, L 4096 15478 / 1313; batch 8 -- L 256 (64 MiB)
+# 91 / 257, L 512 818 / 480; batch 32 -- L 128 (64 MiB) 151 / 453, L 256
+# (256 MiB) 1063 / 1059; head_dim 64 alike (L 512 44 / 72, L 1024 406 /
+# 156).
+_FLASH_MIN_SCORE_BYTES = 64 << 20
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                  acc_ref, *,
-                  causal: bool, sm_scale: float, kv_len: int, q_offset: int):
-    """One (batch*head, q_block, k_block) grid step of the online-softmax
-    recurrence.  K/V stream through VMEM one block per step (HBM->VMEM via
-    the grid pipeline -- whole-sequence K/V never resides on chip), with
-    m/l/acc scratch persisting across the sequential k dimension."""
-    block_q = q_ref.shape[1]
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
+                  causal: bool, sm_scale: float, kv_len: int, q_offset: int,
+                  with_lse: bool):
+    """One (batch*kv_head, q_block, k_block) grid step of the
+    online-softmax recurrence.  K/V stream through VMEM one block per
+    step (HBM->VMEM via the grid pipeline -- whole-sequence K/V never
+    resides on chip) and serve all `repeats` query heads of their group
+    from that one read; m/l/acc scratch persists across the sequential k
+    dimension.  The dots take q, k, v (and p, cast to v's dtype) as they
+    come -- bf16 in, bf16 on the MXU -- and accumulate in float32."""
+    if with_lse:
+        lse_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        m_ref, l_ref, acc_ref = rest
+    repeats, block_q = q_ref.shape[1], q_ref.shape[2]
     block_k = k_ref.shape[1]
     # program ids must be read OUTSIDE pl.when bodies (interpret-mode
     # lowering of program_id inside cond is unsupported)
@@ -94,52 +127,63 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     ki = pl.program_id(2)
     num_kb = pl.num_programs(2)
     q_base = qi * block_q + q_offset
-    q_pos = (q_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0))
-    k_pos = (ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1))
+    k_base = ki * block_k
 
     @pl.when(ki == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    needed = ki * block_k < kv_len
+    needed = k_base < kv_len
+    # a tile wholly on the visible side of the mask is not masked at all
+    inside = k_base + block_k <= kv_len
     if causal:  # skip blocks entirely above the causal diagonal
-        needed = jnp.logical_and(
-            needed, ki * block_k <= q_base + block_q - 1)
+        needed = jnp.logical_and(needed, k_base <= q_base + block_q - 1)
+        inside = jnp.logical_and(inside, k_base + block_k - 1 <= q_base)
 
-    @pl.when(needed)
-    def _update():
-        q = q_ref[0].astype(jnp.float32) * sm_scale    # (block_q, d)
-        k_blk = k_ref[0].astype(jnp.float32)           # (block_k, d)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (block_q, block_k)
-        mask = k_pos < kv_len
-        if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos)
-        s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    def update(masked: bool):
+        k_blk = k_ref[0]                               # (block_k, d)
+        v_blk = v_ref[0]
+        if masked:
+            k_pos = k_base + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            mask = k_pos < kv_len
+            if causal:
+                q_pos = q_base + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                mask = jnp.logical_and(mask, k_pos <= q_pos)
+        for head in range(repeats):
+            s = jax.lax.dot_general(
+                q_ref[0, head], k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            if masked:
+                s = jnp.where(mask, s, _NEG_INF)
+            m_prev = m_ref[head, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[head] = l_ref[head] * alpha + jnp.broadcast_to(
+                jnp.sum(p, axis=-1, keepdims=True), l_ref.shape[1:])
+            acc_ref[head] = acc_ref[head] * alpha + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[head] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+
+    pl.when(jnp.logical_and(needed, inside))(
+        functools.partial(update, False))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(inside)))(
+        functools.partial(update, True))
 
     @pl.when(ki == num_kb - 1)
     def _finish():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(
-            l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
-        # per-row logsumexp: the only forward residual the backward
-        # kernels need beyond q/k/v/o
-        lse_ref[0] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
+        o_ref[0] = (acc_ref[...] / jnp.maximum(
+            l_ref[:, :, :1], 1e-30)).astype(o_ref.dtype)
+        if with_lse:
+            # per-row logsumexp: the only forward residual the backward
+            # kernels need beyond q/k/v/o
+            lse_ref[0] = m_ref[...] + jnp.log(
+                jnp.maximum(l_ref[...], 1e-30))
 
 
 def _pad_seq(x, block: int):
@@ -148,13 +192,48 @@ def _pad_seq(x, block: int):
     return pad_axis_to(x, 2, padded)
 
 
+def _flash_block(length: int, largest: int) -> int:
+    """The tile for a sequence axis of `length`: the axis itself while it
+    is shorter than a 128-row tile (Mosaic on the v5e takes (37, 37),
+    (8, 8) and (1, 128) tiles as they come, forward and backward;
+    chip_smoke.py's kernels phase keeps checking), else the fewest tiles
+    of at most `largest` rows, evened out to multiples of 128 so the
+    padding stays under one 128-row tile a tile (1100 -> two of 640,
+    not two of 1024)."""
+    if length <= 128:
+        return max(length, 1)
+    tiles = -(-length // largest)
+    return -(-length // (tiles * 128)) * 128
+
+
+def flash_attention_takes(batch: int, heads: int, length: int, dtype,
+                          cache_dtype) -> bool:
+    """Whether a cached forward of `length` tokens from position 0
+    attends over its own fresh K/V through flash_attention (models/
+    transformer.py _attention), decided by what the call is: bf16 or
+    float32 K/V written to a cache of their own dtype (an int8 cache is
+    attended over as QUANTISED, which the fresh tensors are not), and
+    float32 scores too many for the einsum to keep on the chip
+    (_FLASH_MIN_SCORE_BYTES: at 32 heads a lone prompt from 1024 tokens,
+    32 rows from 256)."""
+    dtype = jnp.dtype(dtype)
+    return (dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+            and jnp.dtype(cache_dtype) == dtype
+            and batch * heads * length * length * 4 > _FLASH_MIN_SCORE_BYTES)
+
+
 def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: int | None = None, block_k: int | None = None,
                     q_offset: int = 0):
-    """Blockwise attention, (B, H, L, D) in and out.
+    """Blockwise attention: q (B, H, L, D), k/v (B, Hkv, Lk, D) with H a
+    multiple of Hkv -- KV head g serves query heads g*H/Hkv onward, as
+    repeat_kv lays them out, from one read of its tiles; no repeated K/V
+    exists in HBM.  Output (B, H, L, D).
 
     q_offset shifts the causal mask for callers whose q shard starts at a
     nonzero global position (ring attention resumes, KV-cached decode).
+    block_q/block_k default to tiles sized for the chip from the
+    lengths, head_dim and the grouping (_flash_block).
 
     Differentiable end-to-end in Pallas: the forward kernel saves the
     per-row logsumexp, and the backward pass runs two blockwise kernels
@@ -162,14 +241,21 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
     O(L x block), never O(L^2).
     """
     batch, heads, q_len, head_dim = q.shape
-    kv_len = k.shape[2]
+    kv_heads, kv_len = k.shape[1], k.shape[2]
+    if heads % kv_heads:
+        raise ValueError(
+            f"flash_attention: {heads} query heads are not a multiple of "
+            f"{kv_heads} KV heads")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)
-    # sequences shorter than a block get one block of their own length.
-    # Mosaic on the v5e takes these as they come -- (37, 37), (8, 8) and
-    # (1, 128) tiles all compiled and matched the reference, forward and
-    # backward (chip_smoke.py's kernels phase keeps checking) -- so no
-    # rounding up to the (8, 128) tiling is done here
+    if block_q is None:
+        group = (heads // kv_heads) * head_dim
+        block_q = _flash_block(q_len, max(
+            128, min(_FLASH_BLOCK, _FLASH_GROUP_ELEMENTS // group // 128
+                     * 128)))
+    if block_k is None:
+        block_k = _flash_block(kv_len, _FLASH_BLOCK)
+    # an explicit block longer than its axis is one block of the axis
     block_q = min(block_q, max(q_len, 1))
     block_k = min(block_k, max(kv_len, 1))
 
@@ -177,14 +263,15 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
         return _flash(q, k, v, bool(causal), float(sm_scale), int(block_q),
                       int(block_k), int(q_offset))
 
-    spec = _ambient_mesh_spec(batch, heads)
+    spec = _ambient_mesh_spec(batch, kv_heads)
     if spec is None:
         return attend(q, k, v)
     # a Mosaic kernel cannot be partitioned automatically (on the chip
     # the lowering raises "wrap the call in a shard_map"; the CPU
     # interpreter never noticed).  Attention is independent across batch
-    # rows and heads, so under an ambient mesh every shard runs the
-    # kernel on its own rows and heads
+    # rows and KV groups, so under an ambient mesh every shard runs the
+    # kernel on its own rows and groups (a group's query heads are
+    # contiguous, so a split of the KV heads splits the query heads alike)
     return jax.shard_map(attend, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
 
@@ -210,9 +297,9 @@ def _ambient_mesh_spec(batch: int, heads: int):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, sm_scale, block_q, block_k, q_offset):
-    out, _ = _flash_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                         q_offset)
-    return out
+    # forward only (inference): no logsumexp is written
+    return _flash_impl(q, k, v, causal, sm_scale, block_q, block_k,
+                       q_offset, with_lse=False)
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, q_offset):
@@ -224,8 +311,23 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, q_offset):
 def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, residuals,
                cotangent):
     q, k, v, out, lse = residuals
-    return _flash_bwd_impl(q, k, v, out, lse, cotangent, causal, sm_scale,
-                           block_q, block_k, q_offset)
+    batch, kv_heads = k.shape[:2]
+    repeats = q.shape[1] // kv_heads
+    if repeats > 1:
+        # the backward kernels take one K/V head a query head: a group's
+        # K/V is repeated for them and its query heads' dk/dv summed
+        k, v = (jnp.repeat(x, repeats, axis=1) for x in (k, v))
+    dq, dk, dv = _flash_bwd_impl(
+        q, k, v, out, lse, cotangent, causal, sm_scale,
+        min(block_q, _FLASH_BACKWARD_BLOCK),
+        min(block_k, _FLASH_BACKWARD_BLOCK), q_offset)
+    if repeats > 1:
+        dk, dv = (
+            grad.astype(jnp.float32).reshape(
+                batch, kv_heads, repeats, *grad.shape[2:]).sum(
+                    axis=2).astype(grad.dtype)
+            for grad in (dk, dv))
+    return dq, dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -233,68 +335,75 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "sm_scale", "block_q", "block_k", "q_offset"))
-def _flash_impl(q, k, v, causal, sm_scale, block_q, block_k, q_offset):
+    static_argnames=("causal", "sm_scale", "block_q", "block_k", "q_offset",
+                     "with_lse"))
+def _flash_impl(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
+                with_lse: bool = True):
+    """The forward kernel's call: (out, lse) or, with_lse=False, out
+    alone -- the (B, H, L) logsumexp is the backward's residual (and the
+    ring's merge weight); a forward that nothing differentiates writes
+    none."""
     batch, heads, q_len, head_dim = q.shape
-    kv_len = k.shape[2]
+    kv_heads, kv_len = k.shape[1], k.shape[2]
+    repeats = heads // kv_heads
+    groups = batch * kv_heads
 
-    q_padded = _pad_seq(q, block_q).reshape(
-        batch * heads, -1, head_dim)
-    k_padded = _pad_seq(k, block_k).reshape(
-        batch * heads, -1, head_dim)
-    v_padded = _pad_seq(v, block_k).reshape(
-        batch * heads, -1, head_dim)
-    padded_q_len = q_padded.shape[1]
+    # (B, H, L, d) -> (B*Hkv, repeats, L, d): a group's query heads
+    # share the leading index of its K/V head
+    q_padded = _pad_seq(q, block_q).reshape(groups, repeats, -1, head_dim)
+    k_padded = _pad_seq(k, block_k).reshape(groups, -1, head_dim)
+    v_padded = _pad_seq(v, block_k).reshape(groups, -1, head_dim)
+    padded_q_len = q_padded.shape[2]
     # k blocks stream through the grid's sequential minor dimension, so
-    # VMEM holds one (block_q, d) q tile + one (block_k, d) k/v tile each
-    # step regardless of sequence length
-    grid = (batch * heads, padded_q_len // block_q,
-            k_padded.shape[1] // block_k)
+    # VMEM holds one group's (repeats, block_q, d) q tile + one
+    # (block_k, d) k/v tile each step regardless of sequence length
+    grid = (groups, padded_q_len // block_q, k_padded.shape[1] // block_k)
+    offset = int(q_offset) + (kv_len - q_len if causal else 0)
 
+    def kv_index(g, qi, ki):
+        if causal:
+            # a step above the diagonal is skipped: naming the last block
+            # that was needed again, it fetches nothing either
+            ki = jnp.minimum(
+                ki, (qi * block_q + offset + block_q - 1) // block_k)
+        return (g, ki, 0)
+
+    q_spec = pl.BlockSpec((1, repeats, block_q, head_dim),
+                          lambda g, qi, ki: (g, 0, qi, 0),
+                          memory_space=pltpu.VMEM)
+    kv_spec = pl.BlockSpec((1, block_k, head_dim), kv_index,
+                           memory_space=pltpu.VMEM)
+    stat_spec = pl.BlockSpec((1, repeats, block_q, _STAT_LANES),
+                             lambda g, qi, ki: (g, 0, qi, 0),
+                             memory_space=pltpu.VMEM)
+    stat_shape = (groups, repeats, padded_q_len, _STAT_LANES)
     kernel = functools.partial(
-        _flash_kernel,
-        causal=causal, sm_scale=float(sm_scale), kv_len=kv_len,
-        q_offset=int(q_offset) + (kv_len - q_len if causal else 0))
-    out, lse = pl.pallas_call(
+        _flash_kernel, causal=causal, sm_scale=float(sm_scale),
+        kv_len=kv_len, q_offset=offset, with_lse=with_lse)
+    results = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, head_dim),
-                         lambda bh, qi, ki: (bh, qi, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, head_dim),
-                         lambda bh, qi, ki: (bh, ki, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, head_dim),
-                         lambda bh, qi, ki: (bh, ki, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (1, block_q, head_dim), lambda bh, qi, ki: (bh, qi, 0),
-                memory_space=pltpu.VMEM),
-            pl.BlockSpec(
-                (1, block_q, _STAT_LANES), lambda bh, qi, ki: (bh, qi, 0),
-                memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(
-                (batch * heads, padded_q_len, head_dim), q.dtype),
-            jax.ShapeDtypeStruct(
-                (batch * heads, padded_q_len, _STAT_LANES), jnp.float32),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, stat_spec][:1 + with_lse],
+        out_shape=[jax.ShapeDtypeStruct(q_padded.shape, q.dtype),
+                   jax.ShapeDtypeStruct(stat_shape, jnp.float32)
+                   ][:1 + with_lse],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),   # m
-            pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),   # l
-            pltpu.VMEM((block_q, head_dim), jnp.float32),      # acc
+            pltpu.VMEM((repeats, block_q, _STAT_LANES), jnp.float32),  # m
+            pltpu.VMEM((repeats, block_q, _STAT_LANES), jnp.float32),  # l
+            pltpu.VMEM((repeats, block_q, head_dim), jnp.float32),   # acc
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FLASH_VMEM_BYTES),
         interpret=_interpret(),
     )(q_padded, k_padded, v_padded)
-    out = out.reshape(batch, heads, padded_q_len, head_dim)[:, :, :q_len]
-    lse = lse.reshape(batch, heads, padded_q_len, _STAT_LANES)[:, :, :q_len,
-                                                               0]
+    out = results[0].reshape(batch, heads, padded_q_len,
+                             head_dim)[:, :, :q_len]
+    if not with_lse:
+        return out
+    lse = results[1].reshape(batch, heads, padded_q_len,
+                             _STAT_LANES)[:, :, :q_len, 0]
     return out, lse
 
 

@@ -11,9 +11,10 @@ random weights from fixed seeds), and checks what comes out:
 
   train     make_train_step on the 8-layer llama32_1b cut, three steps
   kernels   flash_attention against an f32 reference at the probe
-            lengths, forward and backward, paged_attention against its
-            einsum oracle, and the Mosaic custom call in the compiled
-            train step and prefill
+            lengths and in the served prefill's form (K/V read grouped,
+            head_dim 128, the long-L tiles), forward and backward,
+            paged_attention against its einsum oracle, and the Mosaic
+            custom call in the compiled train step and prefill
   pipeline  the headline 3-stage graph (speech -> LM, vision ->
             detections) through create_pipeline / create_stream
   serve     Registrar + replica pipeline + Gateway + DecodeEngine:
@@ -128,6 +129,7 @@ class Sizes:
                 "max_seq_len": config.max_seq_len, "dtype": "bfloat16"}
             self.long_tokens = 8192
             self.chain_size, self.chain_steps = 4096, 256
+            self.grouped_probe_length = 2500
         else:
             self.audio_seconds = 1.0
             self.asr = {"d_model": 128, "enc_layers": 1, "dec_layers": 1,
@@ -149,6 +151,7 @@ class Sizes:
             self.long_lm = self.lm
             self.long_tokens = 256
             self.chain_size, self.chain_steps = 256, 16
+            self.grouped_probe_length = 300
         self.max_tokens = 16
         self.rows = 2
 
@@ -590,10 +593,55 @@ def phase_train(sizes: Sizes, report: Report, platform: str):
 
 
 def _reference_attention(q, k, v, causal: bool):
+    """float32 at "highest" precision; grouped K/V repeated as repeat_kv
+    lays them out."""
+    repeats = q.shape[1] // k.shape[1]
     with jax.default_matmul_precision("highest"):
         return attention_reference(
-            q.astype(jnp.float32), k.astype(jnp.float32),
-            v.astype(jnp.float32), causal=causal)
+            q.astype(jnp.float32),
+            jnp.repeat(k.astype(jnp.float32), repeats, axis=1),
+            jnp.repeat(v.astype(jnp.float32), repeats, axis=1),
+            causal=causal)
+
+
+def _flash_probe(q, k, v, causal: bool, label: str) -> float:
+    """Forward against the reference; the largest absolute error."""
+    expected = np.asarray(_reference_attention(q, k, v, causal))
+    got = np.asarray(flash_attention(q, k, v, causal=causal), np.float32)
+    _require(np.all(np.isfinite(got)), f"{label}: non-finite output")
+    error = float(np.abs(got - expected).max())
+    excess = np.abs(got - expected) - FLASH_RTOL * np.abs(expected)
+    _require(float(excess.max()) <= FLASH_ATOL,
+             f"{label}: off the reference by {error:.3g}")
+    return error
+
+
+def _flash_grad_probe(q, k, v, cotangent, label: str) -> float:
+    """Causal dq/dk/dv against the reference's; the largest error as a
+    share of each gradient's full scale."""
+    def scalar(attend, q, k, v):
+        return jnp.sum(attend(q, k, v).astype(jnp.float32)
+                       * cotangent.astype(jnp.float32))
+
+    grads = jax.grad(partial(scalar, partial(flash_attention,
+                                             causal=True)),
+                     argnums=(0, 1, 2))(q, k, v)
+    expected = jax.grad(partial(scalar, partial(_reference_attention,
+                                                causal=True)),
+                        argnums=(0, 1, 2))(q, k, v)
+    worst = 0.0
+    for name, got, want in zip(("dq", "dk", "dv"), grads, expected):
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want, np.float32)
+        _require(got.shape == want.shape and np.all(np.isfinite(got)),
+                 f"{label} {name}: non-finite or misshapen gradient")
+        error = float(np.abs(got - want).max()
+                      / max(np.abs(want).max(), 1e-6))
+        worst = max(worst, error)
+        _require(error <= FLASH_GRAD_TOL,
+                 f"{label} {name} off the reference by {error:.3g} of "
+                 f"full scale")
+    return worst
 
 
 def phase_kernels(sizes: Sizes, report: Report, platform: str) -> None:
@@ -605,46 +653,30 @@ def phase_kernels(sizes: Sizes, report: Report, platform: str) -> None:
         q, k, v = (jax.random.normal(key, (2, 4, length, 64), dtype)
                    for key in keys)
         for causal in (False, True):
-            out = flash_attention(q, k, v, causal=causal)
-            expected = np.asarray(_reference_attention(q, k, v, causal))
-            got = np.asarray(out, np.float32)
-            _require(np.all(np.isfinite(got)),
-                     f"flash_attention L={length} causal={causal}: "
-                     f"non-finite output")
-            excess = np.abs(got - expected) - FLASH_RTOL * np.abs(expected)
-            worst[(length, causal)] = float(np.abs(got - expected).max())
-            _require(float(excess.max()) <= FLASH_ATOL,
-                     f"flash_attention L={length} causal={causal}: off "
-                     f"the reference by {worst[(length, causal)]:.3g}")
+            worst[(length, causal)] = _flash_probe(
+                q, k, v, causal,
+                f"flash_attention L={length} causal={causal}")
 
     # backward kernels, at the one probe length that is neither short
     # nor a multiple of the block
-    length = 250
     keys = jax.random.split(jax.random.PRNGKey(7), 4)
-    q, k, v, cotangent = (jax.random.normal(key, (2, 4, length, 64), dtype)
+    q, k, v, cotangent = (jax.random.normal(key, (2, 4, 250, 64), dtype)
                           for key in keys)
+    grad_error = _flash_grad_probe(q, k, v, cotangent, "flash backward")
 
-    def scalar(attend, q, k, v):
-        return jnp.sum(attend(q, k, v).astype(jnp.float32)
-                       * cotangent.astype(jnp.float32))
-
-    grads = jax.grad(partial(scalar, partial(flash_attention,
-                                             causal=True)),
-                     argnums=(0, 1, 2))(q, k, v)
-    expected = jax.grad(partial(scalar, partial(_reference_attention,
-                                                causal=True)),
-                        argnums=(0, 1, 2))(q, k, v)
-    grad_error = 0.0
-    for name, got, want in zip(("dq", "dk", "dv"), grads, expected):
-        got = np.asarray(got, np.float32)
-        want = np.asarray(want, np.float32)
-        _require(np.all(np.isfinite(got)), f"{name}: non-finite gradient")
-        error = float(np.abs(got - want).max()
-                      / max(np.abs(want).max(), 1e-6))
-        grad_error = max(grad_error, error)
-        _require(error <= FLASH_GRAD_TOL,
-                 f"flash backward {name} off the reference by "
-                 f"{error:.3g} of full scale")
+    # the served prefill's form: four query heads a KV head read
+    # grouped, head_dim 128, long enough for several of the long-L
+    # tiles and a padded tail; forward and backward
+    length = sizes.grouped_probe_length
+    keys = jax.random.split(jax.random.PRNGKey(13), 4)
+    q, cotangent = (jax.random.normal(key, (1, 8, length, 128), dtype)
+                    for key in keys[:2])
+    k, v = (jax.random.normal(key, (1, 2, length, 128), dtype)
+            for key in keys[2:])
+    grouped_error = _flash_probe(q, k, v, True,
+                                 f"grouped flash_attention L={length}")
+    grad_error = max(grad_error, _flash_grad_probe(
+        q, k, v, cotangent, f"grouped flash backward L={length}"))
 
     # the paged-attention kernel of the served decode step against its
     # einsum oracle: 8 slots on a pool of 32-position blocks, cursors on
@@ -695,6 +727,7 @@ def phase_kernels(sizes: Sizes, report: Report, platform: str) -> None:
         "kernels", setup_s, steady_s, head_dim=64,
         lengths=list(PROBE_LENGTHS), dtype=KERNEL_DTYPE,
         max_abs_err=max(worst.values()), tol=FLASH_ATOL,
+        grouped_length=length, grouped_max_abs_err=grouped_error,
         grad_err=grad_error, grad_tol=FLASH_GRAD_TOL,
         paged_max_abs_err=paged_error,
         prefill_mosaic_custom_call=mosaic)

@@ -125,6 +125,37 @@ def test_engine_matches_generate_bitwise(tiny_model):
     assert stats["free_blocks"] == engine.blocks.capacity  # all returned
 
 
+def test_engine_counts_the_attention_each_whole_prefill_takes(
+        tiny_model, monkeypatch):
+    """stats() counts whole prefills by what their bucket's attention
+    takes, by the model step's own predicate (flash_attention_takes).
+    Where it is the flash kernel -- steered here by the threshold, on a
+    config of its own so that no einsum program compiled earlier is
+    reused -- the engine's tokens still equal the closed batch's."""
+    from aiko_services_tpu.parallel import attention
+    params, config = tiny_model
+    prompts = [np.arange(1, n, dtype=np.int32) for n in (6, 10, 4)]
+    engine = DecodeEngine(params, config, decode_slots=3, kv_block_size=8)
+    for index, prompt in enumerate(prompts):
+        engine.submit(index, prompt, 4)
+    drain(engine)
+    stats = engine.stats()
+    assert (stats["prefill_einsum"], stats["prefill_flash"]) == (3, 0)
+
+    monkeypatch.setattr(attention, "_FLASH_MIN_SCORE_BYTES", 0)
+    grouped = TransformerConfig(**{**TINY, "n_heads": 4, "n_kv_heads": 2})
+    params = init_params(grouped, jax.random.PRNGKey(1))
+    engine = DecodeEngine(params, grouped, decode_slots=3, kv_block_size=8)
+    for index, prompt in enumerate(prompts):
+        engine.submit(index, prompt, 4)
+    done = drain(engine)
+    stats = engine.stats()
+    assert (stats["prefill_einsum"], stats["prefill_flash"]) == (0, 3)
+    for index, prompt in enumerate(prompts):
+        np.testing.assert_array_equal(
+            done[index].tokens, reference(params, grouped, prompt, 4))
+
+
 def test_engine_eos_frees_slot_early(tiny_model):
     """A sequence hitting eos_id completes before max_new; its tokens
     are EOS-padded to the fixed width and its slot frees immediately."""

@@ -13,7 +13,8 @@ import pytest
 
 from aiko_services_tpu.decode.blocks import TRASH_BLOCK
 from aiko_services_tpu.models.transformer import (
-    TransformerConfig, init_paged_pool, init_params, paged_decode_step)
+    TransformerConfig, init_paged_pool, init_params, paged_decode_step,
+    paged_prefill)
 from aiko_services_tpu.parallel import attention
 from aiko_services_tpu.parallel.attention import (
     paged_attention, paged_attention_reference, paged_attention_takes,
@@ -211,7 +212,9 @@ def test_kernel_compiles_for_the_v5e_at_the_served_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-def test_served_decode_step_moves_no_pool_leaf_on_the_v5e(for_the_chip):
+def _served_model(for_the_chip):
+    """(config, params, pool, int32): the served model's shapes, placed
+    on the described chip."""
     s = SERVED
     config = TransformerConfig(
         vocab_size=32000, d_model=4096, n_layers=s["layers"], n_heads=32,
@@ -223,7 +226,25 @@ def test_served_decode_step_moves_no_pool_leaf_on_the_v5e(for_the_chip):
         lambda: init_params(config, jax.random.PRNGKey(0))))
     pool = place(jax.eval_shape(
         lambda: init_paged_pool(config, s["blocks"], s["block"])))
-    int32 = lambda *shape: for_the_chip(shape, "int32")  # noqa: E731
+    return config, params, pool, lambda *shape: for_the_chip(shape, "int32")
+
+
+def test_served_prefill_keeps_no_scores_in_hbm_on_the_v5e(for_the_chip):
+    """The 4096 bucket of lm.longprompt: the einsum's float32 scores
+    (2.1 GB a layer) made 4.87 GB of temporaries; through the flash
+    kernel the compiler reached 0.79 GB (the float32 logits of 4096
+    positions, MLP intermediates)."""
+    config, params, pool, int32 = _served_model(for_the_chip)
+    compiled = paged_prefill.lower(
+        params, config, pool, int32(1, 4096), int32(SERVED["max_blocks"]),
+        int32()).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_served_decode_step_moves_no_pool_leaf_on_the_v5e(for_the_chip):
+    s = SERVED
+    config, params, pool, int32 = _served_model(for_the_chip)
     slots = s["slots"]
     compiled = paged_decode_step.lower(
         params, config, pool, int32(slots, s["max_blocks"]), int32(slots),

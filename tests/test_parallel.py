@@ -71,6 +71,121 @@ class TestFlashAttention:
         np.testing.assert_allclose(actual, expected, atol=2e-3, rtol=2e-3)
 
 
+def _grouped_qkv(repeats, seq, dtype, kv_heads=2, dim=16, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(keys[0], (2, kv_heads * repeats, seq, dim), dtype)
+    k, v = (jax.random.normal(key, (2, kv_heads, seq, dim), dtype)
+            for key in keys[1:])
+    return q, k, v
+
+
+def _grouped_reference(q, k, v, causal=True):
+    """The float32 oracle over K/V repeated as repeat_kv lays them out:
+    KV head g serves query heads g * repeats onward."""
+    repeats = q.shape[1] // k.shape[1]
+    return attention_reference(
+        q.astype(jnp.float32),
+        jnp.repeat(k.astype(jnp.float32), repeats, axis=1),
+        jnp.repeat(v.astype(jnp.float32), repeats, axis=1), causal=causal)
+
+
+class TestFlashGrouped:
+    """The forward kernel reads K/V grouped -- a KV head's tile serves
+    its `repeats` query heads, no repeated K/V exists -- and multiplies
+    in the input dtype.  Interpreted on the CPU: short L, small tiles."""
+    # bf16: operands and p rounded to 8 bits before the MXU, as in the
+    # einsum this replaces; float32 keeps float32 dots
+    TOLERANCE = {"float32": 2e-5, "bfloat16": 2e-2}
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("seq", [64, 50])       # on and off a tile
+    @pytest.mark.parametrize("repeats", [1, 4])
+    def test_kernel_matches_reference(self, repeats, seq, dtype):
+        q, k, v = _grouped_qkv(repeats, seq, jnp.dtype(dtype),
+                               seed=repeats + seq)
+        expected = np.asarray(_grouped_reference(q, k, v))
+        for tiles in ({"block_q": 16, "block_k": 32}, {}):
+            actual = flash_attention(q, k, v, causal=True, **tiles)
+            assert actual.shape == q.shape and actual.dtype == q.dtype
+            np.testing.assert_allclose(
+                np.asarray(actual, np.float32), expected,
+                atol=self.TOLERANCE[dtype], rtol=0, err_msg=str(tiles))
+
+    def test_operands_reach_the_dots_in_their_own_dtype(self):
+        q, k, v = _grouped_qkv(4, 32, jnp.bfloat16)
+        closed = jax.make_jaxpr(
+            lambda q, k, v: flash_attention(q, k, v, causal=True))(q, k, v)
+
+        def dots(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "dot_general":
+                    yield eqn
+                for value in eqn.params.values():
+                    # a pallas_call's body, a pl.when's branches
+                    for inner in (value if isinstance(value, tuple)
+                                  else (value,)):
+                        inner = getattr(inner, "jaxpr", inner)
+                        if hasattr(inner, "eqns"):
+                            yield from dots(inner)
+
+        found = list(dots(closed.jaxpr))
+        assert len(found) >= 2 * 4          # QK^T and PV, a query head
+        for eqn in found:
+            # bf16 x bf16 on the MXU, accumulated in float32: no operand
+            # is widened on its way in
+            assert [var.aval.dtype for var in eqn.invars] == [
+                jnp.bfloat16, jnp.bfloat16], eqn
+            assert eqn.outvars[0].aval.dtype == jnp.float32, eqn
+
+    @pytest.mark.parametrize("length,largest,block", [
+        (16, 1024, 16), (37, 1024, 37), (128, 1024, 128), (250, 1024, 256),
+        (1024, 1024, 1024), (1100, 1024, 640), (4096, 1024, 1024),
+        (3000, 1024, 1024), (4096, 512, 512)])
+    def test_tiles_follow_the_length(self, length, largest, block):
+        from aiko_services_tpu.parallel.attention import _flash_block
+        assert _flash_block(length, largest) == block
+
+    @pytest.mark.parametrize("batch,heads,length,dtype,cache,takes", [
+        (1, 32, 4096, "bfloat16", "bfloat16", True),    # lm.longprompt
+        (1, 32, 1024, "bfloat16", "bfloat16", True),
+        (1, 32, 512, "bfloat16", "bfloat16", False),    # scores fit VMEM
+        (32, 32, 16, "bfloat16", "bfloat16", False),    # the graph's LM
+        (32, 32, 256, "bfloat16", "bfloat16", True),
+        (1, 32, 4096, "float32", "float32", True),
+        (1, 32, 4096, "bfloat16", "int8", False),       # quantised cache
+        (1, 32, 4096, "int8", "int8", False),
+        (1, 32, 4096, "float32", "bfloat16", False)])
+    def test_what_takes_the_kernel_is_decided_by_shape_and_dtype(
+            self, batch, heads, length, dtype, cache, takes):
+        from aiko_services_tpu.parallel.attention import (
+            flash_attention_takes)
+        assert flash_attention_takes(batch, heads, length, dtype,
+                                     cache) is takes
+
+    @pytest.mark.parametrize("repeats", [1, 4])
+    def test_grouped_grad_parity(self, repeats):
+        q, k, v = _grouped_qkv(repeats, 50, jnp.float32, seed=5)
+
+        def loss(attend):
+            return lambda q, k, v: jnp.sum(attend(q, k, v) ** 2)
+
+        got = jax.grad(loss(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=16, block_k=32)),
+            argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(_grouped_reference), argnums=(0, 1, 2))(q, k, v)
+        for actual, expected, name in zip(got, want, ("dq", "dk", "dv")):
+            assert actual.shape == expected.shape, name
+            np.testing.assert_allclose(
+                np.asarray(actual), np.asarray(expected),
+                atol=5e-3, rtol=5e-3, err_msg=name)
+
+    def test_heads_must_group_evenly(self):
+        q, _, _ = _grouped_qkv(3, 16, jnp.float32)         # 6 query heads
+        _, k, v = _grouped_qkv(1, 16, jnp.float32, kv_heads=4)
+        with pytest.raises(ValueError, match="not a multiple"):
+            flash_attention(q, k, v, causal=True)
+
+
 class TestSequenceParallel:
     @pytest.mark.parametrize("causal", [False, True])
     def test_ring_attention(self, causal):

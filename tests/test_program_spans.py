@@ -518,7 +518,8 @@ SERVED_SPANS = {
     "ingress": {"stream", "frame", "trace_id", "waited_us"},
     "engine.submit": REQUEST | {"waited_us"},
     "engine.step": {"waiting", "active", "decoding", "admitted"},
-    "engine.prefill": REQUEST | {"bucket", "true_len", "queue_us"},
+    "engine.prefill": REQUEST | {"bucket", "true_len", "queue_us",
+                                 "attention"},
     "engine.decode": {"decoding", "live_blocks", "table_blocks"},
     "engine.readback": set(),
     "engine.chunk": REQUEST | {"offset", "tokens", "waited_us", "first_us",
@@ -571,6 +572,9 @@ class TestServedSpans:
         assert sorted(args["true_len"] for args in prefill.values()) == [
             5, 6, 7]
         assert {args["bucket"] for args in prefill.values()} == {8}
+        # a bucket of 8 is far under what the flash kernel takes
+        assert {args["attention"] for args in prefill.values()} == {
+            "einsum"}
 
     def test_ingress_mark_reaches_back_to_the_gateways_dispatch(
             self, served_run):

@@ -2,6 +2,8 @@
 # (the KV-cache path must reproduce the flash prefill path), generation
 # determinism, sharded train step on the virtual 8-device mesh.
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from aiko_services_tpu.models import (
     TransformerConfig, cache_specs, count_params, forward, generate,
     init_cache, init_params, make_train_step, param_specs)
-from aiko_services_tpu.parallel import create_mesh, shard_pytree
+from aiko_services_tpu.models.transformer import (
+    init_paged_pool, paged_prefill)
+from aiko_services_tpu.parallel import (
+    attention, create_mesh, shard_pytree)
 
 CONFIG = TransformerConfig(
     vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -300,7 +305,6 @@ class TestMoECapacityDispatch:
     def test_capacity_matches_dense_oracle_when_unconstrained(self):
         """With capacity >= L no token is ever dropped, so capacity
         dispatch must agree exactly with the masked-dense oracle."""
-        import dataclasses
         cap = self._config(moe_capacity_factor=8.0)  # C = L
         dense = dataclasses.replace(cap, moe_capacity_factor=0.0)
         params = init_params(cap, jax.random.PRNGKey(0))
@@ -339,7 +343,6 @@ class TestMoECapacityDispatch:
         """The compiled FLOP count of the capacity forward must be far
         below masked-dense (which pays E x the FFN): per-device FLOPs
         follow E_local x C, i.e. ~capacity_factor x one dense FFN."""
-        import dataclasses
         cap = self._config(n_experts=8, d_ff=128,
                            moe_capacity_factor=1.0)
         dense = dataclasses.replace(cap, moe_capacity_factor=0.0)
@@ -376,7 +379,6 @@ class TestMoECapacityDispatch:
         """L < E routes through the per-token weight-gather path; it
         must agree with the masked-dense oracle (no capacity drops at
         L=1/L=2)."""
-        import dataclasses
         cap = self._config(n_experts=8)
         dense = dataclasses.replace(cap, moe_capacity_factor=0.0)
         params = init_params(cap, jax.random.PRNGKey(0))
@@ -1060,3 +1062,117 @@ class TestWeightOnlyInt8:
         assert ((values >= 0) & (values < config.vocab_size)).all()
         agreement = float(np.mean(values == np.asarray(weights_only)))
         assert agreement >= 0.5, f"token agreement {agreement:.2f}"
+
+
+class TestCachedPrefillThroughTheKernel:
+    """A multi-token cached forward from the static position 0 attends
+    over its own fresh K/V through flash_attention where the call's
+    shape takes it (parallel/attention.py flash_attention_takes), and
+    through the masked einsum over the cache buffer otherwise.  At test
+    sizes the scores are far under what takes the kernel, so the tests
+    steer by the module's threshold; the jits compiled per `config` get
+    a second config that differs only in `max_seq_len`, which no
+    computation here reads, so each arm has a program of its own."""
+    # float32: blockwise against whole-row softmax; bf16: besides, the
+    # two arms round h differently at every layer
+    LOGITS_TOLERANCE = {"float32": 2e-5, "bfloat16": 5e-2}
+
+    @staticmethod
+    def _prefill(config, params, tokens, cache=None, pos=0):
+        if cache is None:
+            cache = init_cache(config, tokens.shape[0], max_len=48)
+        return forward(params, config, tokens, cache=cache, pos=pos)
+
+    @staticmethod
+    def _tokens(shape, seed=3):
+        return jax.random.randint(jax.random.PRNGKey(seed), shape, 0,
+                                  CONFIG.vocab_size).astype(jnp.int32)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_logits_to_tolerance_and_cache_written_alike(
+            self, dtype, monkeypatch):
+        config = dataclasses.replace(CONFIG, dtype=dtype)
+        params = init_params(config, jax.random.PRNGKey(0))
+        tokens = self._tokens((2, 40))
+        want, want_cache = self._prefill(config, params, tokens)
+        monkeypatch.setattr(attention, "_FLASH_MIN_SCORE_BYTES", 0)
+        assert "pallas_call" in str(jax.make_jaxpr(
+            lambda tokens: self._prefill(config, params, tokens))(tokens))
+        got, got_cache = self._prefill(config, params, tokens)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want),
+            atol=self.LOGITS_TOLERANCE[dtype], rtol=0)
+        for name in want_cache:
+            leaf = np.asarray(got_cache[name], np.float32)
+            other = np.asarray(want_cache[name], np.float32)
+            # K/V are written before the attention reads: the first
+            # layer's are the same bits, a later layer's follow its
+            # input, which the attention below it rounded differently
+            np.testing.assert_array_equal(leaf[0], other[0], err_msg=name)
+            np.testing.assert_allclose(
+                leaf, other, atol=self.LOGITS_TOLERANCE[dtype], rtol=0,
+                err_msg=name)
+            assert not leaf[:, :, :, 40:].any(), name
+
+    @pytest.mark.parametrize("case", ["int8_cache", "traced_pos",
+                                      "one_token", "later_position"])
+    def test_what_the_kernel_does_not_take_keeps_its_bits(
+            self, case, monkeypatch):
+        config = (dataclasses.replace(CONFIG, kv_dtype="int8")
+                  if case == "int8_cache" else CONFIG)
+        params = _params()
+        tokens = self._tokens((2, 1 if case == "one_token" else 24))
+        _, begun = self._prefill(config, params, tokens[:, :8])
+
+        def run():
+            if case == "traced_pos":
+                return jax.jit(lambda pos: self._prefill(
+                    config, params, tokens, pos=pos))(jnp.int32(0))
+            if case == "later_position":
+                return self._prefill(config, params, tokens[:, 8:],
+                                     cache=begun, pos=8)
+            return self._prefill(config, params, tokens)
+
+        want, want_cache = run()
+        monkeypatch.setattr(attention, "_FLASH_MIN_SCORE_BYTES", 0)
+        got, got_cache = run()
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        for name in want_cache:
+            np.testing.assert_array_equal(np.asarray(got_cache[name]),
+                                          np.asarray(want_cache[name]))
+
+    def test_generate_greedy_tokens_unchanged(self, monkeypatch):
+        params = _params()
+        prompt = self._tokens((2, 24), seed=5)
+        want, _ = generate(params, CONFIG, prompt, max_new_tokens=8)
+        monkeypatch.setattr(attention, "_FLASH_MIN_SCORE_BYTES", 0)
+        got, _ = generate(
+            params, dataclasses.replace(CONFIG, max_seq_len=65), prompt,
+            max_new_tokens=8)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_paged_prefill_first_token_and_blocks_unchanged(
+            self, monkeypatch):
+        params = _params()
+        block, true_len = 8, 29
+        prompt = np.zeros((1, 32), np.int32)
+        prompt[0, :true_len] = np.asarray(self._tokens((true_len,), seed=7))
+        table_row = np.array([5, 2, 7, 1, 0, 0], np.int32)
+
+        def run(config):
+            pool = init_paged_pool(config, 9, block)
+            return paged_prefill(params, config, pool, prompt, table_row,
+                                 np.int32(true_len))
+
+        want_pool, want_first = run(CONFIG)
+        monkeypatch.setattr(attention, "_FLASH_MIN_SCORE_BYTES", 0)
+        got_pool, got_first = run(
+            dataclasses.replace(CONFIG, max_seq_len=65))
+        assert int(got_first) == int(want_first)
+        for name in want_pool:
+            leaf, other = (np.asarray(got_pool[name]),
+                           np.asarray(want_pool[name]))
+            np.testing.assert_array_equal(leaf[0], other[0], err_msg=name)
+            np.testing.assert_allclose(leaf, other, atol=2e-5, rtol=0)
+            # only the four named blocks were written
+            assert not leaf[:, [0, 3, 4, 6, 8]].any(), name
