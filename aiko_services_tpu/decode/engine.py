@@ -58,10 +58,10 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..models import (
-    init_paged_pool, paged_decode_step, paged_prefill,
-    paged_prefill_chunk, paged_verify_step)
+    cache_attention_kind, init_paged_pool, paged_decode_step,
+    paged_prefill, paged_prefill_chunk, paged_verify_step)
 from ..observe.trace import NO_SPANS
-from ..parallel.attention import flash_attention_takes, paged_live_blocks
+from ..parallel.attention import paged_live_blocks
 from ..utils import get_logger
 from ..utils.padding import bucket_length
 from .blocks import TRASH_BLOCK, BlockManager
@@ -791,16 +791,15 @@ class DecodeEngine:
         readback: `bucket` is the padded length the call runs at,
         `queue_us` how long the request waited for its slot.  A whole
         prefill says which `attention` its bucket takes (flash, the
-        blockwise kernel over the fresh K/V, or einsum: the model step's
-        own choice, by the same predicate); a chunk call (`start` = its
-        first position) walks the slot's table like a decode step and
+        blockwise kernel over the fresh K/V, or einsum), asking the
+        function the model step itself decides by; a chunk call (`start`
+        = its first position) walks the slot's table like a decode step and
         carries `live_blocks`/`table_blocks` instead.  The running
         counts of both ride `stats()`."""
         request = slot.request
         if start is None:
-            attention = ("flash" if flash_attention_takes(
-                1, self.config.n_heads, bucket, self.config.jnp_dtype,
-                self.pool["k"].dtype) else "einsum")
+            attention = cache_attention_kind(self.config, self.pool, 1,
+                                             bucket)
             self.counters["prefill_" + attention] += 1
             fields = {"attention": attention}
         else:

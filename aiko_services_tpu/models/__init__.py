@@ -3,8 +3,8 @@ from .transformer import (                                    # noqa: F401
     cache_specs, decode_step, generate, generate_stream, make_train_step,
     count_params, quantize_weights_int8, quantized_param_specs,
     init_paged_pool, paged_prefill, paged_decode_step,
-    paged_prefill_chunk, paged_verify_step, REMAT_POLICIES,
-    resolve_remat_policy)
+    paged_prefill_chunk, paged_verify_step, cache_attention_kind,
+    REMAT_POLICIES, resolve_remat_policy)
 from .tokenizer import BPETokenizer, train_bpe                # noqa: F401
 from .weights import (                                        # noqa: F401
     read_safetensors, write_safetensors, SafetensorsFile, save_pytree,

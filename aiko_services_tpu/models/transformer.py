@@ -6,9 +6,11 @@
 # TPU-first design:
 #   - params are a plain pytree; layers are STACKED on a leading axis and
 #     executed with lax.scan (one compiled layer body, not n_layers copies);
-#   - attention runs the Pallas flash kernel for prefill and a masked-cache
-#     einsum for incremental decode; KV cache is a preallocated jax.Array
-#     updated in place via dynamic_update_slice (donated across steps);
+#   - ONE decoder layer (_decoder_layer) over three KV stores: none
+#     (training, scoring: the Pallas flash kernel), a preallocated
+#     contiguous cache updated in place via dynamic_update_slice and
+#     donated across steps (generate(); flash prefill, masked einsum
+#     decode), and the paged pool (the decode engine; the paged kernel);
 #   - param_specs() gives megatron-style TP over the "model" mesh axis +
 #     FSDP over "fsdp"; activation constraints shard batch on "data" and
 #     sequence on "seq";
@@ -41,7 +43,7 @@ __all__ = [
     "generate_stream", "make_train_step", "count_params",
     "quantize_weights_int8", "quantized_param_specs",
     "init_paged_pool", "paged_prefill", "paged_decode_step",
-    "paged_prefill_chunk", "paged_verify_step",
+    "paged_prefill_chunk", "paged_verify_step", "cache_attention_kind",
     "REMAT_POLICIES", "resolve_remat_policy",
 ]
 
@@ -82,12 +84,6 @@ class TransformerConfig:
     moe_capacity_factor: float = 1.25
     # weight of the Switch load-balancing aux loss in make_train_step
     moe_aux_weight: float = 0.01
-    # short sequences (L < E, i.e. incremental decode): gather only the
-    # selected expert's weights per token -- optimal when experts are
-    # replicated (single chip / no EP).  Set False when expert weights
-    # shard on the "expert" axis, where the dispatch einsum keeps weights
-    # stationary and moves (tiny) tokens instead.
-    moe_decode_gather: bool = True
     # "int8": KV cache stores 8-bit codes + a per-(head, position) f32
     # scale -- halves cache HBM (doubling feasible decode batch at fixed
     # memory) and halves the cache-read bandwidth that bounds decode.
@@ -314,106 +310,137 @@ def _quantize_kv(x):
 
 # -- forward ----------------------------------------------------------------
 
-def _attention(config: TransformerConfig, layer, h, cos, sin,
-               cache_k=None, cache_v=None, pos=None,
-               cache_k_scale=None, cache_v_scale=None):
-    """Returns (output, new_k, new_v, new_k_scale, new_v_scale) -- the
-    scale entries are None unless the cache is int8-quantized.  Without
-    a cache: flash-attention causal prefill.  With a cache: write new
-    K/V at `pos` (quantizing when the cache is int8), then masked
-    attention over the whole cache buffer -- or, for a prefill from the
-    static position 0 that flash_attention_takes, the same causal
-    attention over the fresh K/V alone, blockwise."""
-    batch, length, _ = h.shape
+def _project_qkv(config: TransformerConfig, layer, x, cos, sin):
+    """x (B, L, d_model) -> rotated q (B, H, L, hd), rotated k and plain v
+    (B, Hkv, L, hd)."""
+    batch, length, _ = x.shape
     hd = config.head_dim
-    q = dense(layer["wq"], h).reshape(
+    q = dense(layer["wq"], x).reshape(
         batch, length, config.n_heads, hd).transpose(0, 2, 1, 3)
-    k = dense(layer["wk"], h).reshape(
+    k = dense(layer["wk"], x).reshape(
         batch, length, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
-    v = dense(layer["wv"], h).reshape(
+    v = dense(layer["wv"], x).reshape(
         batch, length, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
-    q = apply_rotary(q, cos, sin)
-    k = apply_rotary(k, cos, sin)
+    return apply_rotary(q, cos, sin), apply_rotary(k, cos, sin), v
+
+
+def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend):
+    """THE decoder layer, on every path: attention norm, projections and
+    rotary, `attend`, wo and residual, MLP norm, FFN, residual.  Only
+    `attend` differs, by where the K/V live: attend(q, k, v) stores the
+    new K/V and returns (attention output (B, H, L, hd), the store's new
+    leaves) -- _attend_fresh, _attend_cache, _attend_pool.  Returns
+    (h, the FFN's aux loss, the store's new leaves)."""
+    batch, length, _ = h.shape
+    q, k, v = _project_qkv(
+        config, layer, rms_norm(layer["attn_norm"], h, config.norm_eps),
+        cos, sin)
+    out, leaves = attend(q, k, v)
+    h = h + dense(layer["wo"],
+                  out.transpose(0, 2, 1, 3).reshape(batch, length, -1))
+    mlp_out, aux = _mlp_block(
+        config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps))
+    return h + mlp_out, aux, leaves
+
+
+def _sp_prefill(config: TransformerConfig, q, k, v):
     repeats = config.n_heads // config.n_kv_heads
+    k, v = repeat_kv(k, repeats), repeat_kv(v, repeats)
+    if config.sp_mechanism == "ulysses":
+        return ulysses_attention(q, k, v, mesh=None, causal=True)
+    return ring_attention(q, k, v, causal=True)
 
-    def sp_prefill(q, k, v):
-        if config.sp_mechanism == "ulysses":
-            return ulysses_attention(q, k, v, mesh=None, causal=True)
-        return ring_attention(q, k, v, causal=True)
 
-    if cache_k is None:
-        if config.sequence_parallel:
-            out = sp_prefill(q, repeat_kv(k, repeats),
-                             repeat_kv(v, repeats))
-        else:
-            out = flash_attention(q, k, v, causal=True)
-    else:
-        quantized = cache_k.dtype == jnp.int8
-        if quantized:
-            k, k_scale = _quantize_kv(k)
-            v, v_scale = _quantize_kv(v)
-            cache_k_scale = jax.lax.dynamic_update_slice(
-                cache_k_scale, k_scale, (0, 0, pos, 0))
-            cache_v_scale = jax.lax.dynamic_update_slice(
-                cache_v_scale, v_scale, (0, 0, pos, 0))
-        cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, 0, pos, 0))
-        cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, 0, pos, 0))
-        if config.sequence_parallel and length > 1:
-            # cached PREFILL: sequence-parallel attention over the fresh
-            # K/V only -- valid solely at pos == 0 (the generate/prefill
-            # contract); multi-token cached decode at pos > 0 would need
-            # the earlier cache shards too.  Best-effort guard: a traced
-            # pos cannot be checked at trace time, so the contract is
-            # enforceable only for concrete ints
-            if isinstance(pos, (int, np.integer)) and pos != 0:
-                raise ValueError(
-                    "sequence-parallel cached prefill requires pos == 0 "
-                    f"(got pos={pos}); multi-token cached decode at "
-                    "pos > 0 is not supported on this path")
-            out = sp_prefill(q, repeat_kv(k, repeats),
-                             repeat_kv(v, repeats))
-        elif config.sequence_parallel:
-            # long-context decode: cache length sharded over the mesh
-            # "seq" axis; per-device attention touches only the local
-            # cache shard (GQA heads expand inside the shard), partials
-            # merge with a pmax/psum online-softmax
-            out = sp_decode_attention(q, cache_k, cache_v, pos)
-        elif (length > 1 and isinstance(pos, (int, np.integer)) and pos == 0
-              and flash_attention_takes(batch, config.n_heads, length,
-                                        k.dtype, cache_k.dtype)):
-            # cached PREFILL: the cache holds nothing before position 0,
-            # and columns past `length` were masked anyway, so the causal
-            # attention over the fresh K/V is the masked one over the
-            # cache buffer -- taken blockwise, grouped K/V as they are,
-            # no (length x max_len) scores in HBM.  Blockwise softmax
-            # rounds differently: logits agree to tolerance, not bitwise
-            out = flash_attention(q, k, v, causal=True)
-        else:
-            if quantized:
-                # dequantize into the einsum operand load (int8 codes x
-                # per-position scale); the cache READ stays 8-bit, which
-                # is the bandwidth that bounds decode
-                k_eff = (cache_k.astype(jnp.float32)
-                         * cache_k_scale).astype(q.dtype)
-                v_eff = (cache_v.astype(jnp.float32)
-                         * cache_v_scale).astype(q.dtype)
-            else:
-                k_eff, v_eff = cache_k, cache_v
-            k_full = repeat_kv(k_eff, repeats)
-            v_full = repeat_kv(v_eff, repeats)
-            scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-            logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_full,
-                                preferred_element_type=jnp.float32) * scale
-            max_len = cache_k.shape[2]
-            q_pos = pos + jnp.arange(length)[:, None]
-            k_pos = jnp.arange(max_len)[None, :]
-            logits = jnp.where(k_pos <= q_pos, logits, -1e30)
-            weights = jax.nn.softmax(logits, axis=-1)
-            out = jnp.einsum("bhqk,bhkd->bhqd",
-                             weights.astype(v_full.dtype), v_full)
-    out = out.transpose(0, 2, 1, 3).reshape(batch, length, -1)
-    return (dense(layer["wo"], out), cache_k, cache_v,
-            cache_k_scale, cache_v_scale)
+def _attend_fresh(config: TransformerConfig, q, k, v):
+    """No KV store (training, scoring): causal attention over the fresh
+    K/V, blockwise."""
+    if config.sequence_parallel:
+        return _sp_prefill(config, q, k, v), None
+    return flash_attention(q, k, v, causal=True), None
+
+
+def _kv_to_write(store: dict, k, v) -> dict:
+    """What one layer writes to `store` (a cache's or a pool's leaves)
+    for fresh k, v: themselves, or their int8 codes and scales where the
+    store is int8."""
+    written = {"k": k, "v": v}
+    if store["k"].dtype == jnp.int8:
+        written["k"], written["k_scale"] = _quantize_kv(k)
+        written["v"], written["v_scale"] = _quantize_kv(v)
+    return written
+
+
+def cache_attention_kind(config: TransformerConfig, store: dict, batch: int,
+                         length: int, pos=0) -> str:
+    """"flash" or "einsum": what a forward of (batch, length) tokens at
+    `pos` attends through when its K/V go to a contiguous cache of
+    `store`'s dtype (a cache, or the pool paged_prefill scatters its
+    cache into: their leaves are alike).  _attend_cache decides by this,
+    and the engine names its prefill spans by it."""
+    dtype = store["k"].dtype
+    if (length > 1 and isinstance(pos, (int, np.integer)) and pos == 0
+            and flash_attention_takes(batch, config.n_heads, length, dtype,
+                                      dtype)):
+        return "flash"
+    return "einsum"
+
+
+def _attend_cache(config: TransformerConfig, cache: dict, pos, q, k, v):
+    """Contiguous cache (init_cache; `cache` is one layer's leaves):
+    write the new K/V at `pos`, then masked attention over the whole
+    buffer -- or, for a prefill from the static position 0 that
+    flash_attention_takes, the same causal attention over the fresh K/V
+    alone, blockwise."""
+    batch, _, length, hd = q.shape
+    cache = {name: jax.lax.dynamic_update_slice(cache[name], value,
+                                                (0, 0, pos, 0))
+             for name, value in _kv_to_write(cache, k, v).items()}
+    if config.sequence_parallel and length > 1:
+        # cached PREFILL: sequence-parallel attention over the fresh
+        # K/V only -- valid solely at pos == 0 (the generate/prefill
+        # contract); multi-token cached decode at pos > 0 would need
+        # the earlier cache shards too.  Best-effort guard: a traced
+        # pos cannot be checked at trace time, so the contract is
+        # enforceable only for concrete ints
+        if isinstance(pos, (int, np.integer)) and pos != 0:
+            raise ValueError(
+                "sequence-parallel cached prefill requires pos == 0 "
+                f"(got pos={pos}); multi-token cached decode at "
+                "pos > 0 is not supported on this path")
+        return _sp_prefill(config, q, k, v), cache
+    if config.sequence_parallel:
+        # long-context decode: cache length sharded over the mesh
+        # "seq" axis; per-device attention touches only the local
+        # cache shard (GQA heads expand inside the shard), partials
+        # merge with a pmax/psum online-softmax
+        return sp_decode_attention(q, cache["k"], cache["v"], pos), cache
+    if cache_attention_kind(config, cache, batch, length, pos) == "flash":
+        # the cache holds nothing before position 0, and columns past
+        # `length` were masked anyway, so the causal attention over the
+        # fresh K/V is the masked one over the cache buffer -- taken
+        # blockwise, grouped K/V as they are, no (length x max_len)
+        # scores in HBM.  Blockwise softmax rounds differently: logits
+        # agree to tolerance, not bitwise
+        return flash_attention(q, k, v, causal=True), cache
+    k_eff, v_eff = cache["k"], cache["v"]
+    if "k_scale" in cache:
+        # dequantize into the einsum operand load (int8 codes x
+        # per-position scale); the cache READ stays 8-bit, which is the
+        # bandwidth that bounds decode
+        k_eff = (k_eff.astype(jnp.float32) * cache["k_scale"]).astype(q.dtype)
+        v_eff = (v_eff.astype(jnp.float32) * cache["v_scale"]).astype(q.dtype)
+    repeats = config.n_heads // config.n_kv_heads
+    k_full = repeat_kv(k_eff, repeats)
+    v_full = repeat_kv(v_eff, repeats)
+    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_full,
+                        preferred_element_type=jnp.float32) * scale
+    q_pos = pos + jnp.arange(length)[:, None]
+    k_pos = jnp.arange(k_eff.shape[2])[None, :]
+    logits = jnp.where(k_pos <= q_pos, logits, -1e30)
+    weights = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd",
+                      weights.astype(v_full.dtype), v_full), cache
 
 
 def _router(config: TransformerConfig, layer, x):
@@ -467,7 +494,7 @@ def _switch_moe(config: TransformerConfig, layer, x):
     dropped (standard Switch behavior; the residual connection carries
     them unchanged).  For short sequences (L < E, incremental decode)
     the capacity floor of one slot per expert would cost E x the FFN, so
-    the path switches to per-token weight gather (moe_decode_gather).
+    the path switches to per-token weight gather (_switch_moe_gather).
 
     With expert weights and the (B, E, C, ...) buffers sharded on the
     "expert" mesh axis, each device computes only its local experts:
@@ -481,7 +508,7 @@ def _switch_moe(config: TransformerConfig, layer, x):
         return _switch_moe_dense(config, layer, x)
     batch, length, d_model = x.shape
     experts = config.n_experts
-    if length < experts and config.moe_decode_gather:
+    if length < experts:
         # capacity would floor at 1 slot x E experts (E x the FLOPs);
         # gather the chosen expert's weights per token instead
         return _switch_moe_gather(config, layer, x)
@@ -518,8 +545,8 @@ def _switch_moe_gather(config: TransformerConfig, layer, x):
     (incremental decode, L < E): read only the selected expert's weight
     rows -- per-token FLOPs and HBM reads equal ONE dense FFN, vs the
     capacity path's E floor-of-one slots.  Optimal when expert weights
-    are replicated (single chip); under EP sharding prefer the dispatch
-    einsums (moe_decode_gather=False) so weights stay stationary."""
+    are replicated (single chip); under EP sharding the dispatch einsums
+    would keep the weights stationary (no caller shards them yet)."""
     best, _, weight, aux = _router(config, layer, x)
     wg = jnp.take(layer["w_gate"]["w"], best, axis=0)      # (B, L, D, F)
     wu = jnp.take(layer["w_up"]["w"], best, axis=0)
@@ -551,8 +578,8 @@ def _embed(params: dict, config: TransformerConfig, tokens):
 
 
 def _mlp_block(config: TransformerConfig, layer, mlp_in):
-    """One layer's FFN (dense SwiGLU or switch MoE), shared by
-    forward() and the paged decode path.  Returns (output, aux)."""
+    """One layer's FFN (dense SwiGLU or switch MoE).  Returns
+    (output, aux)."""
     if config.n_experts > 0:
         return _switch_moe(config, layer, mlp_in)
     return dense(
@@ -618,30 +645,13 @@ def forward(params: dict, config: TransformerConfig, tokens,
     def layer_step(carry, xs):
         h, aux_sum = carry
         layer, layer_cache = xs
-        attn_out, new_k, new_v, new_k_scale, new_v_scale = _attention(
-            config, layer, rms_norm(layer["attn_norm"], h, config.norm_eps),
-            cos, sin,
-            cache_k=None if layer_cache is None else layer_cache["k"],
-            cache_v=None if layer_cache is None else layer_cache["v"],
-            cache_k_scale=(None if layer_cache is None
-                           else layer_cache.get("k_scale")),
-            cache_v_scale=(None if layer_cache is None
-                           else layer_cache.get("v_scale")),
-            pos=pos)
-        h = h + attn_out
-        mlp_out, aux = _mlp_block(
-            config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps))
+        h, aux, new_cache = _decoder_layer(
+            config, layer, h, cos, sin,
+            partial(_attend_fresh, config) if layer_cache is None
+            else partial(_attend_cache, config, layer_cache, pos))
         aux_sum = aux_sum + aux
-        h = h + mlp_out
         if activation_specs:
             h = jax.lax.with_sharding_constraint(h, act_spec)
-        if new_k is None:
-            new_cache = None
-        elif new_k_scale is not None:
-            new_cache = {"k": new_k, "k_scale": new_k_scale,
-                         "v": new_v, "v_scale": new_v_scale}
-        else:
-            new_cache = {"k": new_k, "v": new_v}
         return (h, aux_sum), new_cache
 
     aux0 = jnp.zeros((), jnp.float32)
@@ -774,34 +784,27 @@ def generate_stream(params, config: TransformerConfig, prompt,
 
 # -- paged KV: the continuous-batching decode substrate ----------------------
 #
-# The fori_loop generate() above is a CLOSED batch: every sequence in
-# the jit must finish before any new request touches the chip.  The
-# decode/ subsystem replaces the per-request cache with one fixed-size
-# POOL of KV blocks plus per-slot block tables, so requests are
-# admitted and evicted mid-decode without ever changing an array shape
-# (the same zero-filler trick the micro-batch scheduler uses for group
-# arity).  Three invariants make it token-compatible with generate():
+# generate() above is a CLOSED batch: every sequence in the jit must
+# finish before a new request touches the chip.  The decode/ subsystem
+# keeps one fixed-size POOL of KV blocks plus per-slot block tables, so
+# requests are admitted and evicted mid-decode without an array shape
+# changing.  The layer is _decoder_layer either way; the pool is its
+# third KV store (_attend_pool).  What keeps it token-compatible with
+# generate():
 #
-#   - block contents are written by the SAME forward()/_quantize_kv
-#     math as the contiguous cache (prefill literally reshapes a
-#     forward() cache into blocks), and a step writes its new K/V into
-#     the donated pool where it lies (one row per window position,
-#     _write_window) -- the pool rides the layer loop as carry and is
-#     never rebuilt;
-#   - the decode step's attention is the SAME mathematics and mask as
-#     _attention's cached branch -- bf16/f32 operands, float32 scores
-#     and accumulation, the softmax over exactly the positions <= the
-#     query's -- taken BLOCKWISE by the paged-attention kernel
-#     (parallel/attention.py), which walks each slot's live blocks in
-#     the pool in place.  Positions beyond a slot's cursor hold garbage
-#     (stale or trash) but get exactly zero weight.  Blockwise softmax
-#     rounds differently from one softmax over the whole row, so TOKENS
-#     equal generate()'s (tests/test_decode.py) and logits agree to
-#     tolerance (tests/test_paged_attention.py), not bitwise.  The
-#     table-wide gather + einsum (paged_attention_reference) stays as
-#     the kernel's oracle and serves what the kernel does not take:
-#     an int8 pool, a window too large for VMEM, and on the chip a
-#     head_dim off the 128 lanes (paged_attention_takes);
+#   - blocks hold what the contiguous cache would (_kv_to_write; a whole
+#     prefill reshapes a forward() cache into blocks), and a step writes
+#     its K/V into the donated pool where it lies (_write_window);
+#   - the step's attention has _attend_cache's mathematics and mask --
+#     bf16/f32 operands, float32 scores and accumulation, a softmax over
+#     exactly the positions <= the query's -- taken BLOCKWISE by the
+#     paged-attention kernel (parallel/attention.py).  Positions beyond
+#     a slot's cursor hold garbage (stale or trash) but get exactly zero
+#     weight.  Blockwise softmax rounds differently from one softmax
+#     over the whole row, so TOKENS equal generate()'s
+#     (tests/test_decode.py) and logits agree to tolerance
+#     (tests/test_paged_attention.py, tests/test_transformer.py), not
+#     bitwise;
 #   - inactive slots compute on a reserved TRASH block (index 0, never
 #     allocated) so the step's shapes -- (slots, max_blocks) -- are
 #     compile-time constants across any admission/eviction sequence.
@@ -872,67 +875,61 @@ def _write_window(leaf, value, layer, write_blocks, write_offsets):
     return leaf
 
 
+def _attend_pool(config: TransformerConfig, pool: dict, layer, tables,
+                 positions, write_blocks, write_offsets, q, k, v):
+    """Paged pool (init_paged_pool; `pool` is the whole pool, `layer`
+    this layer's index into it): write the WHOLE window's K/V where it
+    lies, then attend through each slot's block table -- so later
+    window positions attend to earlier ones causally.  The kernel walks
+    the live blocks in place where paged_attention_takes; its oracle,
+    the table-wide gather + einsum, serves the rest (an int8 pool,
+    whose scales dequantize the gathered view as the contiguous int8
+    cache's do; a window too large for VMEM; on the chip a head_dim off
+    the 128 lanes)."""
+    pool = {name: _write_window(pool[name], value, layer, write_blocks,
+                                write_offsets)
+            for name, value in _kv_to_write(pool, k, v).items()}
+    attend = (paged_attention if paged_attention_takes(
+        config.n_heads, q.shape[2], config.head_dim, pool["k"].dtype)
+        else paged_attention_reference)
+    scales = ((pool["k_scale"], pool["v_scale"]) if "k_scale" in pool
+              else ())
+    return attend(q, pool["k"], pool["v"], layer, tables, positions,
+                  *scales), pool
+
+
 def _paged_window(params, config: TransformerConfig, pool, tables,
                   positions, tokens, write_blocks, write_offsets):
-    """Shared paged-attention step over a per-slot TOKEN WINDOW -- the
-    one traced implementation behind paged_decode_step (window 1),
+    """The decoder over a per-slot TOKEN WINDOW and the paged pool --
+    the one traced implementation behind paged_decode_step (window 1),
     paged_verify_step (speculative verification, window k+1), and
     paged_prefill_chunk (chunked prefill, window = chunk bucket).
 
     tokens (slots, W) are consumed left-to-right per slot: window
     position i sits at absolute position positions[slot] + i, its K/V
-    lands at (write_blocks[slot, i], write_offsets[slot, i]) -- writes
-    happen for the WHOLE window before the attention reads, so later
-    window positions attend to earlier ones causally, and rows the
-    engine wants inert point their writes at the trash block.  Returns
-    (pool, greedy (slots, W)) where greedy[s, i] is the greedy token
-    AFTER consuming window positions 0..i -- the tokens W successive
-    single-token decode steps would produce, which is the identity the
-    chunked-prefill and speculative tests pin."""
-    quantized = config.kv_dtype == "int8"
+    lands at (write_blocks[slot, i], write_offsets[slot, i]), and rows
+    the engine wants inert point their writes at the trash block.
+    Returns (pool, greedy (slots, W)) where greedy[s, i] is the greedy
+    token AFTER consuming window positions 0..i -- the tokens W
+    successive single-token decode steps would produce, which is the
+    identity the chunked-prefill and speculative tests pin."""
     h = _embed(params, config, tokens)
-    slots, window = tokens.shape
-    q_pos = positions[:, None] + jnp.arange(window)[None, :]  # (S, W)
+    q_pos = positions[:, None] + jnp.arange(tokens.shape[1])[None, :]
     cos, sin = rotary_embedding(q_pos, config.head_dim,
                                 config.rope_theta)
     cos, sin = cos[:, None], sin[:, None]        # (S, 1, W, hd/2)
-    hd = config.head_dim
-    use_kernel = paged_attention_takes(config.n_heads, window, hd,
-                                       pool["k"].dtype)
 
     def layer_step(carry, xs):
         # the pool rides the loop as CARRY and is written where it lies
-        # (indexed by layer): as scan xs -> ys every step rebuilt it whole
+        # (indexed by layer): as scan xs -> ys every step rebuilt it
+        # whole.  The FFN's aux loss is dropped: nothing here trains
         h, pool = carry
         layer, index = xs
-        x = rms_norm(layer["attn_norm"], h, config.norm_eps)
-        q = dense(layer["wq"], x).reshape(
-            slots, window, config.n_heads, hd).transpose(0, 2, 1, 3)
-        k = dense(layer["wk"], x).reshape(
-            slots, window, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
-        v = dense(layer["wv"], x).reshape(
-            slots, window, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
-        written = {"k": k, "v": v}
-        if quantized:
-            written["k"], written["k_scale"] = _quantize_kv(k)
-            written["v"], written["v_scale"] = _quantize_kv(v)
-        pool = {name: _write_window(pool[name], value, index,
-                                    write_blocks, write_offsets)
-                for name, value in written.items()}
-        attend = (paged_attention if use_kernel
-                  else paged_attention_reference)
-        # an int8 pool's scales (einsum path only) dequantize the
-        # gathered view, exactly as the contiguous int8 cache path does
-        scales = ((pool["k_scale"], pool["v_scale"]) if quantized else ())
-        out = attend(q, pool["k"], pool["v"], index, tables, positions,
-                     *scales)
-        out = out.transpose(0, 2, 1, 3).reshape(slots, window, -1)
-        h = h + dense(layer["wo"], out)
-        mlp_out, _ = _mlp_block(
-            config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps))
-        return (h + mlp_out, pool), None
+        h, _, pool = _decoder_layer(
+            config, layer, h, cos, sin,
+            partial(_attend_pool, config, pool, index, tables, positions,
+                    write_blocks, write_offsets))
+        return (h, pool), None
 
     (h, new_pool), _ = jax.lax.scan(
         layer_step, (h, pool),

@@ -210,7 +210,7 @@ def flash_attention_takes(batch: int, heads: int, length: int, dtype,
                           cache_dtype) -> bool:
     """Whether a cached forward of `length` tokens from position 0
     attends over its own fresh K/V through flash_attention (models/
-    transformer.py _attention), decided by what the call is: bf16 or
+    transformer.py _attend_cache), decided by what the call is: bf16 or
     float32 K/V written to a cache of their own dtype (an int8 cache is
     attended over as QUANTISED, which the fresh tensors are not), and
     float32 scores too many for the einsum to keep on the chip

@@ -128,11 +128,14 @@ def test_engine_matches_generate_bitwise(tiny_model):
 def test_engine_counts_the_attention_each_whole_prefill_takes(
         tiny_model, monkeypatch):
     """stats() counts whole prefills by what their bucket's attention
-    takes, by the model step's own predicate (flash_attention_takes).
-    Where it is the flash kernel -- steered here by the threshold, on a
-    config of its own so that no einsum program compiled earlier is
-    reused -- the engine's tokens still equal the closed batch's."""
+    takes, asking the model (cache_attention_kind, by which the step
+    itself decides): the engine holds no predicate of its own.  Where it
+    is the flash kernel -- steered here by the threshold, on a config of
+    its own so that no einsum program compiled earlier is reused -- the
+    engine's tokens still equal the closed batch's."""
+    from aiko_services_tpu.decode import engine as engine_module
     from aiko_services_tpu.parallel import attention
+    assert "flash_attention_takes" not in vars(engine_module)
     params, config = tiny_model
     prompts = [np.arange(1, n, dtype=np.int32) for n in (6, 10, 4)]
     engine = DecodeEngine(params, config, decode_slots=3, kv_block_size=8)

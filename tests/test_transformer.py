@@ -12,10 +12,10 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from aiko_services_tpu.models import (
-    TransformerConfig, cache_specs, count_params, forward, generate,
-    init_cache, init_params, make_train_step, param_specs)
+    TransformerConfig, cache_specs, count_params, decode_step, forward,
+    generate, init_cache, init_params, make_train_step, param_specs)
 from aiko_services_tpu.models.transformer import (
-    init_paged_pool, paged_prefill)
+    init_paged_pool, paged_decode_step, paged_prefill)
 from aiko_services_tpu.parallel import (
     attention, create_mesh, shard_pytree)
 
@@ -1176,3 +1176,86 @@ class TestCachedPrefillThroughTheKernel:
             np.testing.assert_allclose(leaf, other, atol=2e-5, rtol=0)
             # only the four named blocks were written
             assert not leaf[:, [0, 3, 4, 6, 8]].any(), name
+
+
+# case -> (config fields, what the contiguous cache's logits may differ
+# by from the cache-less forward's).  float32 throughout.  dense and
+# experts: blockwise softmax (the flash kernel) against the whole-row
+# einsum.  int8: besides, every cached K/V is rounded to 8 bits once,
+# which the cache-less forward never does.  The experts drop no token
+# (capacity = the sequence), or what is dropped would follow the length
+# a path happens to see; prefills dispatch by capacity, single tokens
+# gather the chosen expert's weights.
+STORE_CASES = {
+    "dense": ({}, 2e-5),
+    "int8_kv": ({"kv_dtype": "int8"}, 2e-2),
+    "experts": ({"n_experts": 4, "moe_capacity_factor": 4.0}, 2e-5),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STORE_CASES))
+def test_a_layer_is_the_same_layer_over_every_store(case):
+    """One decoder layer, three KV stores: the same tokens through (a)
+    the cache-less forward, (b) a prefill at position 0 and decode_steps
+    over a contiguous cache, (c) paged_prefill and paged_decode_steps
+    over a pool.  (b)'s logits agree with (a)'s to the case's tolerance
+    at every position.  The paged steps hand out greedy tokens, no
+    logits: each is the contiguous cache's own argmax, as
+    tests/test_decode.py holds the engine to generate(), and the pool
+    ends up holding what the cache holds."""
+    fields, tolerance = STORE_CASES[case]
+    config = dataclasses.replace(CONFIG, **fields)
+    params = init_params(config, jax.random.PRNGKey(0))
+    rows, prompt_len, length, block = 2, 8, 14, 4
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (rows, length), 0,
+                                config.vocab_size).astype(jnp.int32)
+
+    fresh = np.asarray(forward(params, config, tokens))
+
+    cache = init_cache(config, rows, max_len=16)
+    logits, cache = forward(params, config, tokens[:, :prompt_len],
+                            cache=cache, pos=0)
+    cached = [np.asarray(logits)]
+    for position in range(prompt_len, length):
+        _, logits, cache = decode_step(
+            params, config, cache, tokens[:, position:position + 1],
+            jnp.int32(position))
+        cached.append(np.asarray(logits))
+    cached = np.concatenate(cached, axis=1)
+    np.testing.assert_allclose(cached, fresh, atol=tolerance, rtol=0)
+
+    max_blocks = 16 // block
+    tables = 1 + np.arange(rows * max_blocks, dtype=np.int32).reshape(
+        rows, max_blocks)                    # block 0 is the trash block
+    pool = init_paged_pool(config, 1 + rows * max_blocks, block)
+    paged = np.zeros((rows, length), np.int32)
+    for row in range(rows):
+        # one executable: true_len is traced, so every position of the
+        # prompt can be asked for its greedy token
+        for true_len in range(1, prompt_len + 1):
+            pool, first = paged_prefill(
+                params, config, pool, tokens[row:row + 1, :prompt_len],
+                tables[row], np.int32(true_len))
+            paged[row, true_len - 1] = int(first)
+    for position in range(prompt_len, length):
+        pool, greedy = paged_decode_step(
+            params, config, pool, tables,
+            np.full((rows,), position, np.int32),
+            tokens[:, position:position + 1],
+            tables[:, position // block],
+            np.full((rows,), position % block, np.int32))
+        paged[:, position] = np.asarray(greedy)[:, 0]
+    np.testing.assert_array_equal(paged, cached.argmax(axis=-1))
+
+    for name, leaf in cache.items():
+        # (layers, rows, H, 16, d) against the pool's blocks through the
+        # tables: the same K/V to rounding (matmuls of other row counts,
+        # blockwise attention below a later layer), where an int8 code
+        # may differ by one step
+        held = np.asarray(pool[name], np.float32)[:, tables]
+        held = held.transpose(0, 1, 3, 2, 4, 5).reshape(leaf.shape)
+        np.testing.assert_allclose(
+            held[:, :, :, :length],
+            np.asarray(leaf, np.float32)[:, :, :, :length],
+            atol=1 if leaf.dtype == jnp.int8 else 2e-5, rtol=0,
+            err_msg=name)
