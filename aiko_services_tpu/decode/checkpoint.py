@@ -508,13 +508,23 @@ class DecodeCheckpointer:
                 entry = self._state[key] = {
                     "request": request, "gen": 0, "pos": 0,
                     "tick": self.ticks, "seq": -1}
-            lag_tokens = len(request.generated) - entry["gen"]
+            # tokens the engine has dispatched for, the one in flight
+            # included: what a crash now would have to decode again
+            produced = int(engine.positions[index]) - slot.true_len + 1
+            lag_tokens = produced - entry["gen"]
             lag_ticks = self.ticks - entry["tick"]
             if lag_tokens <= 0:
                 continue
             if (lag_ticks < self.policy.checkpoint_every
                     and lag_tokens < self.policy.max_checkpoint_lag):
                 continue
+            # a snapshot is positions beside generated tokens: read the
+            # decode step in flight first, which advanced the one and
+            # not yet the other (at checkpoint_every=1 the engine is
+            # then as synchronous as its checkpoints ask)
+            engine.settle()
+            if engine.slots[index] is not slot:
+                continue  # that step completed it
             try:
                 shipped += self._snapshot(index, slot, entry,
                                           lag_ticks)

@@ -45,6 +45,23 @@
 #     per-slot block rows, so speculation never touches the target
 #     pool's allocation/preemption logic.
 #
+# THE PLAIN DECODE STEP RUNS ONE AHEAD OF ITS READBACK.  Everything a
+# step needs but its tokens the host knows when it dispatches: a slot's
+# next position, its write block and offset, and whether the token in
+# flight is its last by count.  So a tick dispatches step n + 1 from
+# step n's tokens as the device array they already are, and only then
+# reads step n back and does its bookkeeping (settle): the device
+# finds the next step queued when one ends.  Positions advance at the
+# dispatch; generated, emitted_upto and decode_steps at the settle.
+# What needs the tokens on the host settles first: a host-made token
+# (a prefill's first, a restored request's last), an admission into a
+# slot the step in flight completes, a checkpoint, a cancel.  An EOS is
+# seen one step late: the step after it has run for that slot, its
+# token is dropped (overrun_tokens) and its row landed in a block the
+# slot owned when the step was dispatched; a slot that changes hands
+# while its token is in flight loses that token the same way, since the
+# settle goes by the slot's `seq`.  Emitted tokens are unchanged.
+#
 # Everything here runs on the event loop (host bookkeeping is a few
 # numpy writes per step); the device work is the fused step calls.
 
@@ -54,7 +71,9 @@ import time
 
 from collections import deque
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..models import (
@@ -104,6 +123,12 @@ class StepReport:
     emitted: list = field(default_factory=list)
     admitted: int = 0
     active: int = 0
+
+
+class _InFlight(NamedTuple):
+    """A decode step dispatched and not yet read."""
+    tokens: object   # its next_tokens (slots, 1), on the device
+    rows: dict       # {slot index: slot seq} of the rows that count
 
 
 class _Slot:
@@ -188,6 +213,13 @@ class DecodeEngine:
         self.last_tokens = np.zeros((self.slots_n, 1), np.int32)
         self.slots: list[_Slot | None] = [None] * self.slots_n
         self.waiting: deque[_Request] = deque()
+        # the decode step dispatched and not yet read.  While there is
+        # none, `last_tokens` is the next step's token input; while
+        # there is one, its tokens are, and `last_tokens` lags a step
+        self._inflight: _InFlight | None = None
+        # what a settle outside step() surfaced; the next step() carries
+        # it out
+        self._carry = StepReport()
         self._admission_seq = 0
         self._registry = registry
         # program spans (observe/trace.py): the owning pipeline's
@@ -251,7 +283,9 @@ class DecodeEngine:
                          "prefix_blocks_shared": 0,
                          "prefix_evictions": 0,
                          "live_blocks": 0, "table_blocks": 0,
-                         "prefill_flash": 0, "prefill_einsum": 0}
+                         "prefill_flash": 0, "prefill_einsum": 0,
+                         "decode_steps": 0, "steps_ahead": 0,
+                         "overrun_tokens": 0}
         self._update_gauges()
 
     # -- submission --------------------------------------------------------
@@ -355,6 +389,7 @@ class DecodeEngine:
         Returns a StepReport carrying the first token's emission (and
         the completion, when max_new == 1)."""
         report = StepReport()
+        self.settle(report)
         prompt = np.asarray(handoff["prompt"], np.int32).reshape(-1)
         max_new = int(handoff["max_new"])
         true_len = int(handoff.get("true_len", prompt.size))
@@ -452,6 +487,7 @@ class DecodeEngine:
         BACK to a plain submit() -- the existing replay re-prefill --
         with decode.restore_fallbacks counting the degradation."""
         report = StepReport()
+        self.settle(report)
         if record is not None:
             prompt = np.asarray(record.get("prompt", ()),
                                 np.int32).reshape(-1)
@@ -572,7 +608,10 @@ class DecodeEngine:
     def cancel(self, predicate) -> int:
         """Drop every request whose request_id satisfies `predicate`
         (waiting or mid-decode; a cancelled slot frees immediately).
-        Returns the number cancelled."""
+        Returns the number cancelled.  A step in flight is read first,
+        so a request it completes is complete, not cancelled; what it
+        surfaced leaves with the next step()'s report."""
+        self.settle()
         cancelled = 0
         kept = deque()
         for request in self.waiting:
@@ -600,8 +639,12 @@ class DecodeEngine:
         return None
 
     def has_work(self) -> bool:
-        return bool(self.waiting) or any(
-            slot is not None for slot in self.slots)
+        """True while a request waits or holds a slot, a step is
+        unread, or a settle's tokens await a step() to carry them out:
+        a drain loop and the element's pump run until all are out."""
+        return (bool(self.waiting) or self._inflight is not None
+                or bool(self._carry.emitted or self._carry.completions)
+                or any(slot is not None for slot in self.slots))
 
     # -- the engine step ---------------------------------------------------
 
@@ -612,7 +655,7 @@ class DecodeEngine:
         step over the decoding slots.  Chunked prefill progress and
         decode progress share the tick -- that interleaving is what
         stops a long prompt from convoying every co-scheduled slot."""
-        report = StepReport()
+        report, self._carry = self._carry, StepReport()
         started = time.perf_counter()
         with self._spans.span("engine.step",
                               waiting=len(self.waiting)) as tick:
@@ -628,21 +671,24 @@ class DecodeEngine:
 
     def _tick(self, report: StepReport) -> int:
         """The body of one step; returns how many slots it decoded."""
+        if self.waiting and any(self._ends_in_flight(index)
+                                for index in range(self.slots_n)):
+            # the step in flight completes a slot by count: read it, so
+            # the waiting request takes that slot in this tick
+            self.settle(report)
         self._admit(report)
         ran_chunk = self._advance_prefills(report)
-        active = [index for index, slot in enumerate(self.slots)
-                  if slot is not None]
-        if not active:
-            self._update_gauges()
-            report.active = 0
-            return 0
-        self._grow_or_preempt()
+        self._grow_or_preempt(report)
         active = [index for index, slot in enumerate(self.slots)
                   if slot is not None]
         report.active = len(active)
+        # a slot whose token in flight is its last by count has no next
+        # step: it holds its slot until the settle completes it
         decoding = [index for index in active
-                    if not self.slots[index].prefilling]
+                    if not self.slots[index].prefilling
+                    and not self._ends_in_flight(index)]
         if not decoding:
+            self.settle(report)  # nothing to dispatch ahead of it
             self._update_gauges()
             return 0
         if self.draft_params is not None:
@@ -659,8 +705,15 @@ class DecodeEngine:
 
     def _plain_step(self, decoding: list, report: StepReport) -> None:
         """One paged_decode_step over all slots; mid-prefill and free
-        slots write to the trash block and their rows are ignored."""
+        slots write to the trash block and their rows are ignored.
+        Dispatched BEFORE the step in flight is read: its tokens go in
+        as the device array they are (a slot decodes here only if it
+        decoded there: a host-made token settles first), so the device
+        finds this step queued when that one ends, and that one's
+        readback and bookkeeping overlap this one."""
+        ahead = self._inflight is not None
         with self._spans.span("engine.decode", decoding=len(decoding),
+                              ahead=int(ahead),
                               **self._walked(self.positions, 1)):
             write_blocks = np.zeros((self.slots_n,), np.int32)
             write_offsets = np.zeros((self.slots_n,), np.int32)
@@ -670,20 +723,57 @@ class DecodeEngine:
                 write_blocks[index] = self.slots[index].blocks[
                     block_index]
                 write_offsets[index] = position % self.blocks.block_size
+            # always a device array, so the step keeps one signature;
+            # and copies of what the host writes on while the step
+            # runs: a jitted call may read a numpy argument in place
+            tokens = (self._inflight.tokens if ahead
+                      else jnp.asarray(self.last_tokens.copy()))
             before = _jit_cache_size()
             self.pool, next_tokens = paged_decode_step(
-                self.params, self.config, self.pool, self.tables,
-                self.positions, self.last_tokens, write_blocks,
+                self.params, self.config, self.pool, self.tables.copy(),
+                self.positions.copy(), tokens, write_blocks,
                 write_offsets)
             self._note_compiles(_jit_cache_size() - before,
                                 "paged_decode_step")
+        rows = {index: self.slots[index].seq for index in decoding}
+        self.positions[decoding] += 1
+        self.counters["decode_steps"] += 1
+        self.counters["steps_ahead"] += ahead
+        self.settle(report)
+        self._inflight = _InFlight(next_tokens, rows)
+
+    def _ends_in_flight(self, index: int) -> bool:
+        """Slot `index`, as it is held now, has a token in the step
+        dispatched and not yet read, and it is its last by count."""
+        slot = self.slots[index]
+        if (self._inflight is None or slot is None
+                or self._inflight.rows.get(index) != slot.seq):
+            return False
+        request = slot.request
+        return len(request.generated) + 1 >= request.max_new
+
+    def settle(self, report: StepReport | None = None) -> None:
+        """Read the step in flight, if there is one, and do its
+        bookkeeping: tokens generated and surfaced, finished requests
+        completed.  Into `report`, or outside a step() into the carry
+        the next step() takes out.  By the slot's identity: a row whose
+        slot was released since the dispatch is dropped and counted (an
+        EOS the step before brought; what else releases a slot, a
+        cancel or a preemption, settles first)."""
+        if self._inflight is None:
+            return
+        (next_tokens, rows), self._inflight = self._inflight, None
+        if report is None:
+            report = self._carry
         with self._spans.span("engine.readback"):
             next_tokens = np.asarray(next_tokens)
-        for index in decoding:
+        for index, seq in rows.items():
             slot = self.slots[index]
+            if slot is None or slot.seq != seq:
+                self.counters["overrun_tokens"] += 1
+                continue
             request = slot.request
             token = int(next_tokens[index, 0])
-            self.positions[index] += 1
             self.last_tokens[index, 0] = token
             request.generated.append(token)
             request.decode_steps += 1
@@ -874,6 +964,10 @@ class DecodeEngine:
             self._register_slot_prefix(slot)
         if request.first_token_at is None:
             request.first_token_at = time.perf_counter()
+        # a token the host makes goes into `last_tokens`, which is the
+        # next step's input only once the step in flight is read (it
+        # ran before the prefill whose token this is: nothing to wait)
+        self.settle(report)
         request.generated.append(first)
         self.positions[index] = slot.true_len
         self.last_tokens[index, 0] = first
@@ -1118,10 +1212,15 @@ class DecodeEngine:
 
     # -- block growth / preemption ----------------------------------------
 
-    def _grow_or_preempt(self) -> None:
+    def _grow_or_preempt(self, report: StepReport) -> None:
         """Ensure every active slot owns the block its next write
         position lands in; on exhaustion preempt the youngest slot so
-        the oldest always progresses (no livelock)."""
+        the oldest always progresses (no livelock).  A slot whose token
+        in flight is its last by count does not grow, and nobody is
+        preempted on a pool the step in flight may be about to relieve:
+        it is read first, so victims are chosen on today's state.  (A
+        slot whose token in flight turns out its EOS may have taken a
+        free block for the step after; the settle returns it.)"""
         order = sorted(
             (index for index, slot in enumerate(self.slots)
              if slot is not None),
@@ -1133,6 +1232,8 @@ class DecodeEngine:
                 continue  # preempted below while growing an older slot
             if slot.prefilling:
                 continue  # prompt blocks were fully granted at admission
+            if self._ends_in_flight(index):
+                continue  # no step follows the one in flight
             # speculative rounds write a k+1 window per step, so growth
             # covers the whole window -- but never past what the
             # request can still EMIT (a near-complete slot must not
@@ -1153,6 +1254,13 @@ class DecodeEngine:
                 if granted is not None:
                     slot.blocks.extend(granted)
                     self.tables[index, len(slot.blocks) - 1] = granted[0]
+                    continue
+                if self._inflight is not None:
+                    # what the step in flight completes frees blocks,
+                    # and may be this slot: read it before a victim pays
+                    self.settle(report)
+                    if self.slots[index] is not slot:
+                        break
                     continue
                 victim = max(
                     (other for other in range(self.slots_n)

@@ -88,10 +88,24 @@
 #                               bucket's attention takes; a chunk call
 #                               instead: live_blocks, table_blocks)
 #   engine.decode      scoped   table build + dispatch: decoding,
+#                               ahead (1: dispatched while the step
+#                               before was unread, from its tokens on
+#                               the device; 0: from the host's, after an
+#                               admission or an empty engine),
 #                               live_blocks (blocks the paged attention
 #                               walks this step), table_blocks (what the
 #                               tables can name: slots x max_blocks)
-#   engine.readback    scoped   the readback that waits for the step
+#   engine.readback    scoped   the settle's readback of the step in
+#                               flight: in a tick after that tick's
+#                               engine.decode where it ran ahead, or
+#                               after its last engine.prefill; outside
+#                               engine.step where a cancel, an adoption,
+#                               a restore or a checkpoint settles.
+#                               Running counts in engine_stats():
+#                               decode_steps, steps_ahead (of them, with
+#                               ahead=1), overrun_tokens (read and
+#                               dropped: the slot was released after the
+#                               dispatch, by an EOS seen a step late)
 #   engine.chunk       mark     first token (offset 0) or previous chunk
 #                               -> this token_chunk: offset, tokens;
 #                               and, so that ANY chunk of a request in a

@@ -396,6 +396,314 @@ def test_engine_int8_kv_matches_quantized_generate():
             done[index].tokens, reference(params, config, prompt, 6))
 
 
+# -- one step ahead of the readback --------------------------------------------
+
+class _Order:
+    """The engine's spans seam, recording (name, fields) in the order
+    the spans open: which of dispatch and readback came first."""
+
+    enabled = True
+
+    class _Span:
+        def __init__(self, fields):
+            self.fields = fields
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set(self, **fields):
+            self.fields.update(fields)
+
+    def __init__(self):
+        self.events = []
+
+    def span(self, name, request_id=None, **fields):
+        self.events.append((name, fields))
+        return self._Span(fields)
+
+    def mark(self, name, waited_s=None, request_id=None, **fields):
+        pass
+
+    def record_engine_submit(self, request_id):
+        pass
+
+    def named(self, *names):
+        return [event for event in self.events if event[0] in names]
+
+    def unread(self) -> bool:
+        """A step was dispatched and has not been read back."""
+        return (len(self.named("engine.decode"))
+                > len(self.named("engine.readback")))
+
+
+def _serve(engine, arrivals, synchronous=False, each_tick=None):
+    """Drive `engine` over `arrivals` ({tick: [(id, prompt, max_new)]})
+    until idle.  `synchronous` reads every step back in its own tick:
+    the engine as it was before it ran ahead, the twin whose decisions
+    (admissions, preemptions) the run-ahead engine has to repeat.
+    Returns ({id: Completion}, {id: [(offset, token)]})."""
+    done, emitted = {}, {}
+    tick = 0
+    while engine.has_work() or any(at >= tick for at in arrivals):
+        for request_id, prompt, max_new in arrivals.get(tick, ()):
+            engine.submit(request_id, prompt, max_new)
+        report = engine.step()
+        if synchronous:
+            engine.settle(report)
+        for request_id, offset, token in report.emitted:
+            emitted.setdefault(request_id, []).append((offset, token))
+        for completion in report.completions:
+            assert completion.request_id not in done
+            done[completion.request_id] = completion
+        if each_tick is not None:
+            each_tick(tick)
+        tick += 1
+        assert tick < 2000, "engine failed to drain (deadlock?)"
+    return done, emitted
+
+
+def _expected(params, config, prompt, max_new, eos=None):
+    """The closed batch's tokens, cut and padded at `eos` as a
+    Completion's are."""
+    tokens = np.array(reference(params, config, prompt, max_new))
+    if eos is not None and eos in tokens:
+        tokens[int(np.argmax(tokens == eos)) + 1:] = eos
+    return tokens
+
+
+def _assert_served(params, config, engine, requests, done, emitted,
+                   eos=None):
+    for request_id, prompt, max_new in requests:
+        expected = _expected(params, config, prompt, max_new, eos)
+        np.testing.assert_array_equal(done[request_id].tokens, expected)
+        count = done[request_id].stats["tokens"]
+        assert emitted[request_id] == [
+            (offset, int(expected[offset])) for offset in range(count)]
+    stats = engine.stats()
+    assert (stats["free_blocks"] + stats.get("prefix_cached_blocks", 0)
+            == engine.blocks.capacity)
+    assert stats["active_slots"] == 0
+
+
+def _twins(params, config, arrivals, **options):
+    """The same traffic through a run-ahead engine and its synchronous
+    twin; returns (engine, twin, done, emitted), the twin's results
+    already held equal."""
+    engine = DecodeEngine(params, config, **options)
+    twin = DecodeEngine(params, config, **options)
+    done, emitted = _serve(engine, arrivals)
+    twin_done, twin_emitted = _serve(twin, arrivals, synchronous=True)
+    assert twin.counters["steps_ahead"] == 0
+    assert emitted == twin_emitted
+    assert sorted(done, key=str) == sorted(twin_done, key=str)
+    for name in ("admitted", "completed", "preempted",
+                 "deferred_admissions"):
+        assert engine.counters[name] == twin.counters[name], name
+    return engine, twin, done, emitted
+
+
+def _run_ahead_storm(params, config, _monkeypatch):
+    """(a) Admissions and evictions over more requests than slots."""
+    rng = np.random.default_rng(11)
+    arrivals, requests = {}, []
+    for index in range(14):
+        request = (index, rng.integers(1, 64, size=int(
+            rng.integers(1, 21))).astype(np.int32),
+            int(rng.integers(1, 10)))
+        requests.append(request)
+        arrivals.setdefault(int(rng.integers(0, 24)), []).append(request)
+    engine, _twin, done, emitted = _twins(
+        params, config, arrivals, decode_slots=3, kv_block_size=8)
+    _assert_served(params, config, engine, requests, done, emitted)
+    stats = engine.stats()
+    assert stats["preempted"] == 0 and stats["overrun_tokens"] == 0
+    assert 0 < stats["steps_ahead"] < stats["decode_steps"]
+
+
+def _run_ahead_eos(params, config, _monkeypatch):
+    """(b) An EOS arrives with the next step already dispatched for its
+    slot: tokens cut where the synchronous engine cuts them, the overrun
+    dropped and counted, nobody preempted for it, the slot taken by the
+    waiting request."""
+    prompts = [np.arange(1, 6, dtype=np.int32),
+               np.arange(20, 29, dtype=np.int32)]
+    tokens = reference(params, config, prompts[0], 12)
+    cut = next(k for k in range(1, 11) if tokens[k] not in tokens[:k])
+    eos = int(tokens[cut])
+    requests = [(index, prompt, 12)
+                for index, prompt in enumerate(prompts)]
+    # a pool of four blocks beside the trash block: the prompts take
+    # one and two, so one is free when the overrun step may want it
+    engine, _twin, done, emitted = _twins(
+        params, config, {0: requests}, decode_slots=1, kv_block_size=8,
+        kv_blocks=5, eos_id=eos)
+    _assert_served(params, config, engine, requests, done, emitted,
+                   eos=eos)
+    assert done[0].stats["tokens"] == cut + 1
+    # every request that an EOS ended before its count ran one step over
+    overruns = sum(
+        1 for _id, prompt, max_new in requests
+        if eos in reference(params, config, prompt, max_new)[:-1])
+    assert overruns >= 1
+    assert engine.counters["overrun_tokens"] == overruns
+    assert engine.counters["preempted"] == 0
+    assert engine.counters["admitted"] == 2
+
+
+def _run_ahead_preemption(params, config, _monkeypatch):
+    """(c) The pool runs out with a step in flight: it is read before a
+    victim is chosen, so the victims are the synchronous engine's."""
+    requests = [(0, np.arange(1, 5, dtype=np.int32), 12),
+                (1, np.arange(11, 15, dtype=np.int32), 12)]
+    engine, _twin, done, emitted = _twins(
+        params, config, {0: requests}, decode_slots=2, kv_block_size=4,
+        kv_blocks=6)
+    assert engine.counters["preempted"] >= 1
+    assert done[1].stats["preemptions"] >= 1
+    assert engine.counters["steps_ahead"] > 0
+    _assert_served(params, config, engine, requests, done, emitted)
+
+
+def _run_ahead_cancel(params, config, _monkeypatch):
+    """(d) A cancel while the victim's token is in flight."""
+    order = _Order()
+    engine = DecodeEngine(params, config, decode_slots=2, kv_block_size=8,
+                          spans=order)
+    requests = [(index, np.arange(1, 7, dtype=np.int32) + 3 * index, 10)
+                for index in range(3)]
+    cancelled = []
+
+    def cancel_the_first(tick):
+        if tick == 3:
+            assert order.unread()
+            cancelled.append(engine.cancel(lambda rid: rid == 0))
+            assert not order.unread()
+
+    done, emitted = _serve(engine, {0: requests},
+                           each_tick=cancel_the_first)
+    assert cancelled == [1] and engine.counters["cancelled"] == 1
+    assert sorted(done) == [1, 2]
+    # the victim's token in flight was read by the cancel: what it had
+    # surfaced by then is the closed batch's, and nothing after it
+    assert emitted[0] == [
+        (offset, int(token)) for offset, token in enumerate(
+            reference(params, config, requests[0][1], 10)[:5])]
+    _assert_served(params, config, engine, requests[1:], done, emitted)
+
+
+def _run_ahead_unread_state(params, config, _monkeypatch):
+    """(e) With a step unread: has_work() holds, stats() reads nothing
+    back, a prefix export and a checkpoint see positions = prompt +
+    generated - 1, and the last tokens still come out."""
+    from aiko_services_tpu.decode import (
+        CheckpointKeeper, CheckpointPolicy, DecodeCheckpointer)
+    order = _Order()
+    engine = DecodeEngine(params, config, decode_slots=2, kv_block_size=8,
+                          prefix_policy="prefix_cache=on", spans=order)
+    keeper = CheckpointKeeper("run-ahead")
+    snapshots, store = [], keeper.store
+
+    def spy(snapshot):
+        snapshots.append(snapshot)
+        store(snapshot)
+
+    keeper.store = spy
+    checkpointer = DecodeCheckpointer(
+        engine, CheckpointPolicy.parse(
+            "checkpoint_every=3;max_checkpoint_lag=32;keeper=run-ahead"),
+        keeper=keeper)
+    prompt = np.arange(1, 18, dtype=np.int32)  # two whole blocks
+    request = (0, prompt, 12)
+    unread_ticks = []
+
+    def look(tick):
+        if order.unread():
+            unread_ticks.append(tick)
+            assert engine.has_work()
+            reads = len(order.named("engine.readback"))
+            stats = engine.stats()
+            assert stats["active_slots"] == 1
+            exported = engine.export_prefix_snapshot(prompt)
+            assert exported["position"] == exported["true_len"] == 16
+            assert exported["prompt"] == [int(t) for t in prompt[:16]]
+            assert len(order.named("engine.readback")) == reads
+        checkpointer.tick()
+        slot = engine.slots[0]
+        if slot is not None and snapshots and not order.unread():
+            assert int(engine.positions[0]) == (
+                slot.true_len + len(slot.request.generated) - 1)
+
+    done, emitted = _serve(engine, {0: [request]}, each_tick=look)
+    assert len(unread_ticks) >= 6
+    assert len(snapshots) >= 3
+    for snapshot in snapshots:
+        assert snapshot["position"] == (
+            snapshot["true_len"] + len(snapshot["generated"]) - 1)
+        assert snapshot["blocks_total"] == engine.blocks.blocks_for(
+            snapshot["position"])
+    assert keeper.flush()
+    keeper.stop()
+    _assert_served(params, config, engine, [request], done, emitted)
+
+
+def _run_ahead_order(params, config, monkeypatch):
+    """(f) The order itself, for a lone request: step n + 1 is
+    dispatched, from step n's tokens as the device array they are,
+    before step n is read."""
+    from aiko_services_tpu.decode import engine as engine_module
+    order = _Order()
+    calls = []
+    step = engine_module.paged_decode_step
+
+    def recording_step(params, config, pool, tables, positions, tokens,
+                       *rest):
+        result = step(params, config, pool, tables, positions, tokens,
+                      *rest)
+        calls.append((tokens, result[1], np.array(positions)))
+        return result
+
+    recording_step._cache_size = step._cache_size
+    monkeypatch.setattr(engine_module, "paged_decode_step",
+                        recording_step)
+    engine = DecodeEngine(params, config, decode_slots=2, kv_block_size=8,
+                          spans=order)
+    request = (0, np.arange(1, 6, dtype=np.int32), 9)
+    done, emitted = _serve(engine, {0: [request]})
+    _assert_served(params, config, engine, [request], done, emitted)
+    stats = engine.stats()
+    assert stats["decode_steps"] == len(calls) == 8
+    assert stats["steps_ahead"] == stats["decode_steps"] - 1
+    assert stats["overrun_tokens"] == 0
+    names = [name for name, _ in order.named("engine.decode",
+                                             "engine.readback")]
+    assert names == (["engine.decode"]
+                     + ["engine.decode", "engine.readback"] * 7
+                     + ["engine.readback"])
+    assert [fields["ahead"] for _, fields in order.named(
+        "engine.decode")] == [0] + [1] * 7
+    for index, (tokens, _out, positions) in enumerate(calls):
+        assert isinstance(tokens, jax.Array)
+        assert positions[0] == 5 + index  # advanced at the dispatch
+        if index:
+            assert tokens is calls[index - 1][1]
+
+
+RUN_AHEAD_CASES = {
+    "storm": _run_ahead_storm, "eos": _run_ahead_eos,
+    "preemption": _run_ahead_preemption, "cancel": _run_ahead_cancel,
+    "unread_state": _run_ahead_unread_state, "order": _run_ahead_order}
+
+
+@pytest.mark.parametrize("case", sorted(RUN_AHEAD_CASES))
+def test_run_ahead(tiny_model, monkeypatch, case):
+    """The plain decode step runs one ahead of its readback, and every
+    request's tokens stay the closed batch's to the bit."""
+    RUN_AHEAD_CASES[case](*tiny_model, monkeypatch)
+
+
 # -- chunked prefill (paged_prefill_chunk) ----------------------------------
 
 

@@ -520,7 +520,7 @@ SERVED_SPANS = {
     "engine.step": {"waiting", "active", "decoding", "admitted"},
     "engine.prefill": REQUEST | {"bucket", "true_len", "queue_us",
                                  "attention"},
-    "engine.decode": {"decoding", "live_blocks", "table_blocks"},
+    "engine.decode": {"decoding", "ahead", "live_blocks", "table_blocks"},
     "engine.readback": set(),
     "engine.chunk": REQUEST | {"offset", "tokens", "waited_us", "first_us",
                                "ingress_us"},
@@ -546,16 +546,36 @@ class TestServedSpans:
             inner = [event[0] for event in recorded.inside(step)]
             assert set(inner) <= {"engine.prefill", "engine.decode",
                                   "engine.readback", "compile"}, inner
-            if step[4]["decoding"]:
-                assert inner.count("engine.decode") == 1
-                assert inner.count("engine.readback") == 1
+            # a tick that decodes dispatches one step, and no tick reads
+            # more than one: the step before, or none after an admission
+            # (the prefill's first token settled what was in flight)
+            assert inner.count("engine.decode") == bool(
+                step[4]["decoding"])
+            assert inner.count("engine.readback") <= 1
             assert inner.count("engine.prefill") == step[4]["admitted"]
             kinds |= set(inner)
         assert {"engine.prefill", "engine.decode",
                 "engine.readback"} <= kinds
+        # every step dispatched is read once
+        assert len(recorded.named("engine.readback")) == len(
+            recorded.named("engine.decode"))
         for pump in recorded.named("engine.pump"):
             assert [event[0] for event in recorded.inside(pump)
                     ].count("engine.step") == 1
+
+    def test_the_engines_running_counts_agree_with_its_spans(
+            self, served_run):
+        """`engine_stats()` counts the steps dispatched, those of them
+        dispatched ahead of the readback before (`ahead=1` on their
+        span), and the tokens read and dropped."""
+        recorded, run = served_run
+        stats = run["replica"].elements["lm"].engine_stats()
+        decodes = recorded.named("engine.decode")
+        assert stats["decode_steps"] == len(decodes)
+        assert stats["steps_ahead"] == sum(
+            event[4]["ahead"] for event in decodes)
+        assert 0 < stats["steps_ahead"] < stats["decode_steps"]
+        assert stats["overrun_tokens"] == 0
 
     def test_spans_of_one_request_share_its_trace_id(self, served_run):
         recorded, run = served_run

@@ -624,6 +624,20 @@ class TestGatewayWarmFailover:
         process_a.add_message_handler(
             lambda t, p: _collect_chunks(chunks_a, p),
             f"{replica_a.elements['lm'].topic_path}/out")
+        # a paced engine: replica A's pump stops after 12 ticks, so the
+        # crash is mid-stream by construction.  Left to run, the tiny
+        # model can finish its 24 tokens between two polls of wait_for;
+        # the completion then drops the keeper's snapshot, and the wait
+        # for a kept one never ends
+        lm_a = replica_a.elements["lm"]
+        pump, ticks = lm_a._pump, []
+
+        def paced_pump(engine):
+            if len(ticks) < 12:
+                ticks.append(engine)
+                pump(engine)
+
+        lm_a._pump = paced_pump
         process_a.run(in_thread=True)
         replica_a.create_stream("s", grace_time=300,
                                 queue_response=queue.Queue())
