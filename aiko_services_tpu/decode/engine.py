@@ -129,6 +129,9 @@ class _InFlight(NamedTuple):
     """A decode step dispatched and not yet read."""
     tokens: object   # its next_tokens (slots, 1), on the device
     rows: dict       # {slot index: slot seq} of the rows that count
+    # what a routed-expert model's step counted, int32 (2,) on the
+    # device: distinct held experts read, token-expert pairs computed
+    experts: object = None
 
 
 class _Slot:
@@ -285,8 +288,39 @@ class DecodeEngine:
                          "live_blocks": 0, "table_blocks": 0,
                          "prefill_flash": 0, "prefill_einsum": 0,
                          "decode_steps": 0, "steps_ahead": 0,
-                         "overrun_tokens": 0}
+                         "overrun_tokens": 0,
+                         "experts_read": 0, "expert_pairs": 0,
+                         "latent_positions": 0}
+        # `experts_read`/`expert_pairs` of the newest decode step read
+        # back, as the next `engine.decode` span carries them; empty
+        # for a model without routed experts
+        self._experts_seen: dict = {}
         self._update_gauges()
+
+    def warm(self, buckets=()) -> None:
+        """Compile the decode step and the whole prefill of each of
+        `buckets` (prompt lengths; each is rounded up to its bucket) on
+        the idle engine, before any request: every table names the
+        trash block, so nothing a request will read is written.  A
+        model whose programs take longer to compile than a stream's
+        lease at the gateway (60 s) otherwise loses its first requests
+        to the compile."""
+        if self.has_work():
+            raise RuntimeError("warm() is for an engine with no request")
+        before = _jit_cache_size()
+        for bucket in sorted({self._bucket(int(length))
+                              for length in buckets}):
+            self.pool, _ = paged_prefill(
+                self.params, self.config, self.pool,
+                np.zeros((1, bucket), np.int32), self.tables[0],
+                np.int32(1))
+        idle = np.zeros((self.slots_n,), np.int32)
+        self.pool, tokens, *_ = paged_decode_step(
+            self.params, self.config, self.pool, self.tables.copy(),
+            self.positions.copy(), jnp.asarray(self.last_tokens.copy()),
+            idle, idle.copy())
+        np.asarray(tokens)          # wait for the last of them
+        self._note_compiles(_jit_cache_size() - before, "warm")
 
     # -- submission --------------------------------------------------------
 
@@ -713,8 +747,9 @@ class DecodeEngine:
         readback and bookkeeping overlap this one."""
         ahead = self._inflight is not None
         with self._spans.span("engine.decode", decoding=len(decoding),
-                              ahead=int(ahead),
-                              **self._walked(self.positions, 1)):
+                              ahead=int(ahead), **self._experts_seen,
+                              **self._walked(self.positions, 1,
+                                             decoding)):
             write_blocks = np.zeros((self.slots_n,), np.int32)
             write_offsets = np.zeros((self.slots_n,), np.int32)
             for index in decoding:
@@ -729,7 +764,8 @@ class DecodeEngine:
             tokens = (self._inflight.tokens if ahead
                       else jnp.asarray(self.last_tokens.copy()))
             before = _jit_cache_size()
-            self.pool, next_tokens = paged_decode_step(
+            # a model with routed experts hands its counts out third
+            self.pool, next_tokens, *experts = paged_decode_step(
                 self.params, self.config, self.pool, self.tables.copy(),
                 self.positions.copy(), tokens, write_blocks,
                 write_offsets)
@@ -740,7 +776,7 @@ class DecodeEngine:
         self.counters["decode_steps"] += 1
         self.counters["steps_ahead"] += ahead
         self.settle(report)
-        self._inflight = _InFlight(next_tokens, rows)
+        self._inflight = _InFlight(next_tokens, rows, *experts)
 
     def _ends_in_flight(self, index: int) -> bool:
         """Slot `index`, as it is held now, has a token in the step
@@ -762,11 +798,19 @@ class DecodeEngine:
         cancel or a preemption, settles first)."""
         if self._inflight is None:
             return
-        (next_tokens, rows), self._inflight = self._inflight, None
+        (next_tokens, rows, experts), self._inflight = self._inflight, None
         if report is None:
             report = self._carry
         with self._spans.span("engine.readback"):
             next_tokens = np.asarray(next_tokens)
+            if experts is not None:
+                # the step's own counts, read with its tokens; the next
+                # `engine.decode` span to open carries them
+                read, pairs = (int(count) for count in np.asarray(experts))
+                self._experts_seen = {"experts_read": read,
+                                      "expert_pairs": pairs}
+                self.counters["experts_read"] += read
+                self.counters["expert_pairs"] += pairs
         for index, seq in rows.items():
             slot = self.slots[index]
             if slot is None or slot.seq != seq:
@@ -900,17 +944,23 @@ class DecodeEngine:
             queue_us=round(((request.admitted_at or request.submitted_at)
                             - request.submitted_at) * 1e6), **fields)
 
-    def _walked(self, positions, window: int) -> dict:
+    def _walked(self, positions, window: int, decoding=None) -> dict:
         """The span fields of one paged window call over the target
         pool: `live_blocks`, the blocks the attention walks (every
         slot's positions + window, an idle slot's one trash block), and
         `table_blocks`, what the tables can name (what the table-wide
-        gather read).  Their running sums ride `stats()`."""
+        gather read); over a latent pool a decode step (`decoding`: its
+        slots) also `latent_positions`, the live rows those slots'
+        attention reads, this step's own among them.  Their running
+        sums ride `stats()`."""
         walked = {
             "live_blocks": int(paged_live_blocks(
                 positions, window, self.blocks.block_size,
                 self.max_blocks).sum()),
             "table_blocks": len(positions) * self.max_blocks}
+        if decoding is not None and "kv" in self.pool:
+            walked["latent_positions"] = int(
+                positions[decoding].sum()) + len(decoding) * window
         for name, count in walked.items():
             self.counters[name] += count
         return walked

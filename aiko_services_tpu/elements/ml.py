@@ -60,6 +60,18 @@ def _transformer_config(element) -> TransformerConfig:
     # "int8" halves KV-cache HBM and read bandwidth (serving batch
     # headroom); numerics pinned in tests/test_transformer.py
     kv_dtype = str(element.get_parameter("kv_dtype", "") or "")
+    published = element.get_parameter("model")
+    if published:
+        # a published config.json's keys, whole (benchmark/configs/
+        # deepseek_v2_*.json): norm_eps, rope_theta and the YaRN keys
+        # reach the model from the file
+        if published.get("model_type") != "deepseek_v2":
+            raise ValueError(
+                f"model: model_type {published.get('model_type')!r} has "
+                f"no reader (models/configs.py has deepseek_v2's)")
+        return model_configs.deepseek_v2_config(
+            published, element.get_parameter("max_seq_len"),
+            element.get_parameter("dtype"))
     preset = element.get_parameter("preset")
     if preset:
         config = _LM_PRESETS[str(preset)]
@@ -240,6 +252,13 @@ class LMGenerate(ComputeElement):
             self.config = _transformer_config(self)
             _default_lm_state_spec(self, self.config)
             self.tokenizer = _tokenizer_for(self)
+            # warm_buckets: prompt lengths whose prefill programs, and
+            # the decode step, the engine compiles here and now (with
+            # the weights made), not under its first requests
+            buckets = self.get_parameter("warm_buckets")
+            if buckets and self.engine_managed(None):
+                self._ensure_ready()
+                self._ensure_engine().warm(buckets)
 
     def setup(self):
         return _load_transformer_params(self, self.config)

@@ -18,7 +18,7 @@ from .transformer import TransformerConfig
 __all__ = [
     "LLAMA3_8B", "LLAMA32_1B", "LM_TOY",
     "WHISPER_TINY", "WHISPER_SMALL",
-    "YOLOV8N_SHAPE", "DETECTOR_TOY",
+    "YOLOV8N_SHAPE", "DETECTOR_TOY", "deepseek_v2_config",
     "transformer_flops_per_token", "asr_flops_per_example",
     "tts_flops_per_example",
     "detector_flops_per_image",
@@ -36,6 +36,70 @@ LLAMA32_1B = TransformerConfig(
     vocab_size=128256, d_model=2048, n_layers=16, n_heads=32,
     n_kv_heads=8, d_ff=8192, max_seq_len=8192, rope_theta=500000.0,
     dtype="bfloat16")
+
+def deepseek_v2_config(published: dict, max_seq_len: int | None = None,
+                       dtype: str | None = None) -> TransformerConfig:
+    """TransformerConfig from DeepSeek-V2's published config.json keys
+    (huggingface.co/deepseek-ai/DeepSeek-V2), every one under its own
+    name.  Two keys beside the published ones describe one process's
+    share of an expert-parallel deployment: `router_experts`, the
+    router's width where `n_routed_experts` is what is held, and
+    `experts_held`, [lo, hi) of the router's numbering."""
+    unsupported = {
+        "model_type": "deepseek_v2", "hidden_act": "silu",
+        "scoring_func": "softmax", "topk_method": "group_limited_greedy",
+        "norm_topk_prob": False, "moe_layer_freq": 1,
+        "attention_bias": False}
+    for key, value in unsupported.items():
+        if published.get(key, value) != value:
+            raise ValueError(f"deepseek_v2: {key}={published[key]!r} is "
+                             f"not implemented (only {value!r})")
+    router = int(published.get("router_experts",
+                               published["n_routed_experts"]))
+    held = tuple(int(edge) for edge in
+                 published.get("experts_held", (0, router)))
+    if held[1] - held[0] != int(published["n_routed_experts"]):
+        raise ValueError(
+            f"deepseek_v2: experts_held {held} is not the "
+            f"{published['n_routed_experts']} experts n_routed_experts "
+            f"says are held")
+    scaling = published.get("rope_scaling") or {}
+    if scaling and scaling.get("type") != "yarn":
+        raise ValueError(f"deepseek_v2: rope_scaling type "
+                         f"{scaling.get('type')!r} is not implemented")
+    return TransformerConfig(
+        vocab_size=int(published["vocab_size"]),
+        d_model=int(published["hidden_size"]),
+        n_layers=int(published["num_hidden_layers"]),
+        n_heads=int(published["num_attention_heads"]),
+        n_kv_heads=int(published["num_attention_heads"]),
+        d_ff=int(published["intermediate_size"]),
+        max_seq_len=int(max_seq_len
+                        or published["max_position_embeddings"]),
+        rope_theta=float(published["rope_theta"]),
+        norm_eps=float(published["rms_norm_eps"]),
+        dtype=str(dtype or published.get("torch_dtype", "bfloat16")),
+        q_lora_rank=int(published["q_lora_rank"]),
+        kv_lora_rank=int(published["kv_lora_rank"]),
+        qk_nope_head_dim=int(published["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(published["qk_rope_head_dim"]),
+        v_head_dim=int(published["v_head_dim"]),
+        rope_factor=float(scaling.get("factor", 1.0)),
+        rope_original_max=int(scaling.get(
+            "original_max_position_embeddings", 4096)),
+        rope_beta_fast=float(scaling.get("beta_fast", 32.0)),
+        rope_beta_slow=float(scaling.get("beta_slow", 1.0)),
+        rope_mscale=float(scaling.get("mscale", 1.0)),
+        rope_mscale_all_dim=float(scaling.get("mscale_all_dim", 0.0)),
+        top_k=int(published["num_experts_per_tok"]),
+        n_routed_experts=router, experts_held=held,
+        n_shared_experts=int(published["n_shared_experts"]),
+        moe_d_ff=int(published["moe_intermediate_size"]),
+        n_groups=int(published["n_group"]),
+        topk_groups=int(published["topk_group"]),
+        routed_scaling=float(published["routed_scaling_factor"]),
+        first_dense_layers=int(published["first_k_dense_replace"]))
+
 
 # small config for hermetic tests / CPU runs
 LM_TOY = TransformerConfig(

@@ -14,12 +14,15 @@
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
     "dense", "rms_norm", "layer_norm", "rotary_embedding", "apply_rotary",
+    "yarn_frequencies", "yarn_mscale",
     "swiglu", "init_dense", "init_norm", "repeat_kv", "conv2d", "init_conv",
 ]
 
@@ -73,12 +76,46 @@ def layer_norm(params: dict, x, eps: float = 1e-5):
     return out
 
 
-def rotary_embedding(positions, head_dim: int, theta: float = 10000.0):
-    """positions (..., L) int -> cos/sin tables (..., L, head_dim//2)."""
-    frequencies = 1.0 / (theta ** (
-        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rotary_embedding(positions, head_dim: int, theta: float = 10000.0,
+                     frequencies=None):
+    """positions (..., L) int -> cos/sin tables (..., L, head_dim//2).
+    `frequencies` (head_dim//2,) replaces theta's own (yarn_frequencies)."""
+    if frequencies is None:
+        frequencies = 1.0 / (theta ** (
+            jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     angles = positions[..., None].astype(jnp.float32) * frequencies
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1 for a
+    context stretched by `factor` > 1, else 1."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, factor: float,
+                     original_max: int, beta_fast: float,
+                     beta_slow: float) -> np.ndarray:
+    """YaRN's head_dim//2 rotary frequencies.  A dimension that turns more
+    than beta_fast times within the original context keeps theta's
+    frequency, one that turns fewer than beta_slow times is interpolated
+    (divided by `factor`), and a linear ramp over the dimensions between
+    blends the two."""
+    plain = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64)
+                             / head_dim))
+
+    def dimension_turning(rotations: float) -> float:
+        return (head_dim * math.log(original_max
+                                    / (rotations * 2.0 * math.pi))
+                / (2.0 * math.log(theta)))
+
+    low = max(math.floor(dimension_turning(beta_fast)), 0)
+    high = min(math.ceil(dimension_turning(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
 
 
 def apply_rotary(x, cos, sin):

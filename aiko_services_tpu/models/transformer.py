@@ -30,12 +30,13 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.attention import (
-    flash_attention, flash_attention_takes, paged_attention,
-    paged_attention_reference, paged_attention_takes, ring_attention,
-    sp_decode_attention, ulysses_attention)
+    attention_reference, flash_attention, flash_attention_takes,
+    paged_attention, paged_attention_reference, paged_attention_takes,
+    ring_attention, sp_decode_attention, ulysses_attention)
+from ..parallel.experts import expert_ffn
 from .layers import (
     apply_rotary, dense, init_dense, init_norm, repeat_kv, rms_norm,
-    rotary_embedding)
+    rotary_embedding, swiglu, yarn_frequencies, yarn_mscale)
 
 __all__ = [
     "TransformerConfig", "init_params", "param_specs", "forward",
@@ -91,6 +92,44 @@ class TransformerConfig:
     # (one rounding per token ever); reads dequantize into the attention
     # einsum, which XLA fuses into the operand load.
     kv_dtype: str = ""
+    # -- multi-head latent attention (DeepSeek-V2): kv_lora_rank > 0 ----
+    # Queries come through a low-rank bottleneck (q_lora_rank), keys and
+    # values through ONE latent of kv_lora_rank a position plus one
+    # rotary key of qk_rope_head_dim shared by every head; the KV store
+    # holds that row and nothing else.  n_kv_heads and head_dim are not
+    # read.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN: rope_factor > 1 blends every rotary frequency between its own
+    # and its own / factor (layers.yarn_frequencies), multiplies cos/sin
+    # by mscale's ratio and the softmax scale by mscale_all_dim's square
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # -- routed experts with shared experts (DeepSeek-V2): top_k > 0 ----
+    # The router scores n_routed_experts in n_groups groups, keeps the
+    # best topk_groups groups by their largest score and the top_k
+    # experts among them, weighted routed_scaling x softmax score, not
+    # renormalised; nothing is dropped.  This process computes the
+    # experts in experts_held = (lo, hi) of the router's numbering (all
+    # when empty: the other shares of an expert-parallel deployment hold
+    # the rest) plus n_shared_experts always-on experts.  The first
+    # first_dense_layers layers keep the dense FFN of d_ff.
+    top_k: int = 0
+    n_routed_experts: int = 0
+    experts_held: tuple = ()
+    n_shared_experts: int = 0
+    moe_d_ff: int = 0
+    n_groups: int = 1
+    topk_groups: int = 1
+    routed_scaling: float = 1.0
+    first_dense_layers: int = 0
 
     def __post_init__(self):
         if self.sp_mechanism not in ("ring", "ulysses"):
@@ -107,9 +146,46 @@ class TransformerConfig:
                 "sequence-parallel decode path (sp_decode_attention "
                 "reads the raw cache shards)")
 
+        if self.kv_lora_rank and (self.kv_dtype or self.sequence_parallel):
+            raise ValueError(
+                "latent attention keeps its cache in the compute dtype "
+                "and on one device (no kv_dtype, no sequence_parallel)")
+        if self.top_k and self.n_experts:
+            raise ValueError("top_k (routed experts) and n_experts (the "
+                             "top-1 switch FFN) are two FFNs: set one")
+        if self.top_k and self.n_routed_experts % self.n_groups:
+            raise ValueError(
+                f"{self.n_routed_experts} routed experts do not divide "
+                f"into {self.n_groups} groups")
+
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def rotary_dim(self) -> int:
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """Values a position a layer in a latent store: the latent and
+        the shared rotary key, padded to the 128 lanes Mosaic slices a
+        pool by (512 + 64 -> 640)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def attention_scale(self) -> float:
+        """The softmax scale: head_dim^-0.5, under YaRN times mscale^2
+        of mscale_all_dim."""
+        depth = (self.qk_nope_head_dim + self.qk_rope_head_dim
+                 if self.kv_lora_rank else self.head_dim)
+        m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return depth ** -0.5 * m * m
+
+    @property
+    def held(self) -> tuple:
+        """[lo, hi) of the router's experts computed here."""
+        return tuple(self.experts_held) or (0, self.n_routed_experts)
 
     @property
     def jnp_dtype(self):
@@ -118,18 +194,76 @@ class TransformerConfig:
 
 # -- parameters -------------------------------------------------------------
 
-def _init_layer(key, config: TransformerConfig) -> dict:
-    keys = jax.random.split(key, 8)
+def _init_latent_attention(keys, config: TransformerConfig) -> dict:
+    """MLA's five projections and two inner norms.  wq_b's columns are a
+    head's [nope ; rope] queries, wkv_a's the latent then the shared
+    rotary key, wkv_b's a head's [nope keys ; values]."""
+    d, heads, dtype = config.d_model, config.n_heads, config.jnp_dtype
+    nope, rope = config.qk_nope_head_dim, config.qk_rope_head_dim
+    return {
+        "wq_a": init_dense(keys[0], d, config.q_lora_rank, dtype),
+        "q_norm": init_norm(config.q_lora_rank, dtype),
+        "wq_b": init_dense(keys[1], config.q_lora_rank,
+                           heads * (nope + rope), dtype),
+        "wkv_a": init_dense(keys[2], d, config.kv_lora_rank + rope, dtype),
+        "kv_norm": init_norm(config.kv_lora_rank, dtype),
+        "wkv_b": init_dense(keys[3], config.kv_lora_rank,
+                            heads * (nope + config.v_head_dim), dtype),
+        "wo": init_dense(keys[4], heads * config.v_head_dim, d, dtype),
+    }
+
+
+def _init_routed_ffn(keys, config: TransformerConfig) -> dict:
+    """The experts held, the router over all of them and the shared
+    experts (one SwiGLU of n_shared x moe_d_ff).  Expert e of the
+    router's numbering is drawn from fold_in(its leaf's key, e), one
+    expert at a time: the same numbers whichever share holds it, and no
+    float32 copy of a stacked leaf (5 GB at DeepSeek-V2's widths)."""
+    d, ff, dtype = config.d_model, config.moe_d_ff, config.jnp_dtype
+
+    def held(key, rows, cols):
+        return {"w": jnp.stack([
+            init_dense(jax.random.fold_in(key, expert), rows, cols,
+                       dtype)["w"] for expert in range(*config.held)])}
+
+    shared = config.n_shared_experts * ff
+    return {
+        "w_gate": held(keys[0], d, ff), "w_up": held(keys[1], d, ff),
+        "w_down": held(keys[2], ff, d),
+        "router": init_dense(keys[3], d, config.n_routed_experts, dtype),
+        "shared_gate": init_dense(keys[4], d, shared, dtype),
+        "shared_up": init_dense(keys[5], d, shared, dtype),
+        "shared_down": init_dense(keys[6], shared, d, dtype),
+    }
+
+
+def _init_layer(key, config: TransformerConfig,
+                routed: bool = False) -> dict:
+    """One layer's weights; `routed` gives it the routed-expert FFN."""
     d, hd, ff = config.d_model, config.head_dim, config.d_ff
     dtype = config.jnp_dtype
-    layer = {
-        "attn_norm": init_norm(d, dtype),
-        "wq": init_dense(keys[0], d, config.n_heads * hd, dtype),
-        "wk": init_dense(keys[1], d, config.n_kv_heads * hd, dtype),
-        "wv": init_dense(keys[2], d, config.n_kv_heads * hd, dtype),
-        "wo": init_dense(keys[3], config.n_heads * hd, d, dtype),
-        "mlp_norm": init_norm(d, dtype),
-    }
+    if config.kv_lora_rank or config.top_k:
+        # five attention keys, then the FFN's: 3 dense or 7 routed
+        keys = jax.random.split(key, 12)
+        ffn_keys = keys[5:]
+    else:
+        keys = jax.random.split(key, 8)
+        ffn_keys = keys[4:]
+    if config.kv_lora_rank:
+        layer = _init_latent_attention(keys, config)
+    else:
+        layer = {
+            "wq": init_dense(keys[0], d, config.n_heads * hd, dtype),
+            "wk": init_dense(keys[1], d, config.n_kv_heads * hd, dtype),
+            "wv": init_dense(keys[2], d, config.n_kv_heads * hd, dtype),
+            "wo": init_dense(keys[3], config.n_heads * hd, d, dtype),
+        }
+    layer["attn_norm"] = init_norm(d, dtype)
+    layer["mlp_norm"] = init_norm(d, dtype)
+    if routed:
+        layer.update(_init_routed_ffn(ffn_keys, config))
+        return layer
+    gate_key, up_key, down_key, *more = ffn_keys
     if config.n_experts > 0:
         experts = config.n_experts
 
@@ -138,29 +272,60 @@ def _init_layer(key, config: TransformerConfig) -> dict:
                 key, (experts, rows, cols), jnp.float32)
                 / jnp.sqrt(jnp.float32(rows))).astype(dtype)}
 
-        layer["router"] = init_dense(keys[7], d, experts, dtype)
-        layer["w_gate"] = expert_weights(keys[4], d, ff)
-        layer["w_up"] = expert_weights(keys[5], d, ff)
-        layer["w_down"] = expert_weights(keys[6], ff, d)
+        layer["router"] = init_dense(more[0], d, experts, dtype)
+        layer["w_gate"] = expert_weights(gate_key, d, ff)
+        layer["w_up"] = expert_weights(up_key, d, ff)
+        layer["w_down"] = expert_weights(down_key, ff, d)
     else:
-        layer["w_gate"] = init_dense(keys[4], d, ff, dtype)
-        layer["w_up"] = init_dense(keys[5], d, ff, dtype)
-        layer["w_down"] = init_dense(keys[6], ff, d, dtype)
+        layer["w_gate"] = init_dense(gate_key, d, ff, dtype)
+        layer["w_up"] = init_dense(up_key, d, ff, dtype)
+        layer["w_down"] = init_dense(down_key, ff, d, dtype)
     return layer
 
 
+def _stack_layers(layers: list) -> dict:
+    """Per-layer weight dicts -> one dict of leaves stacked on a leading
+    axis, a leaf at a time, letting each layer's copy go as its stack is
+    made: stacked all at once a model is held twice (four expert layers
+    of DeepSeek-V2's widths are 9 GB)."""
+    flat = [jax.tree_util.tree_flatten(layer) for layer in layers]
+    layers.clear()
+    treedef = flat[0][1]
+    leaves = [layer_leaves for layer_leaves, _ in flat]
+    stacked = []
+    for index in range(treedef.num_leaves):
+        parts = [layer_leaves[index] for layer_leaves in leaves]
+        for layer_leaves in leaves:
+            layer_leaves[index] = None
+        stacked.append(jnp.stack(parts))
+        del parts
+    return jax.tree_util.tree_unflatten(treedef, stacked)
+
+
+def _leading_dense(config: TransformerConfig) -> int:
+    """Layers that run before the stack of routed-expert layers."""
+    return config.first_dense_layers if config.top_k else 0
+
+
 def init_params(config: TransformerConfig, key) -> dict:
+    """Seeded weights.  "layers" is the stack the scan runs; a model
+    with routed experts whose first layers are dense has those apart,
+    as "dense_layers" (their FFN leaves have other shapes)."""
     embed_key, *layer_keys = jax.random.split(key, config.n_layers + 1)
-    layers = [_init_layer(k, config) for k in layer_keys]
-    stacked = jax.tree_util.tree_map(
-        lambda *leaves: jnp.stack(leaves), *layers)
-    return {
+    lead = _leading_dense(config)
+    params = {
         "embed": {"w": (jax.random.normal(
             embed_key, (config.vocab_size, config.d_model), jnp.float32)
             * 0.02).astype(config.jnp_dtype)},
-        "layers": stacked,
+        "layers": _stack_layers([
+            _init_layer(k, config, routed=config.top_k > 0)
+            for k in layer_keys[lead:]]),
         "norm_out": init_norm(config.d_model, config.jnp_dtype),
     }
+    if lead:
+        params["dense_layers"] = _stack_layers(
+            [_init_layer(k, config) for k in layer_keys[:lead]])
+    return params
 
 
 def param_specs(config: TransformerConfig,
@@ -170,28 +335,46 @@ def param_specs(config: TransformerConfig,
     (Scaling-book recipe: shard the big matmuls, replicate the norms.)
     lm_head=True adds the untied-output-head spec (checkpoint-loaded
     Llama-3-8B+ params carry one)."""
+    column, row = P(None, "fsdp", "model"), P(None, "model", "fsdp")
     layer = {
         "attn_norm": {"scale": P(None, None)},
-        "wq": {"w": P(None, "fsdp", "model")},
-        "wk": {"w": P(None, "fsdp", "model")},
-        "wv": {"w": P(None, "fsdp", "model")},
-        "wo": {"w": P(None, "model", "fsdp")},
+        "wo": {"w": row},
         "mlp_norm": {"scale": P(None, None)},
     }
-    if config.n_experts > 0:
-        layer["router"] = {"w": P(None, None, None)}
-        layer["w_gate"] = {"w": P(None, "expert", "fsdp", "model")}
-        layer["w_up"] = {"w": P(None, "expert", "fsdp", "model")}
-        layer["w_down"] = {"w": P(None, "expert", "model", "fsdp")}
+    if config.kv_lora_rank:
+        # the low-rank projections in are replicated across "model" (one
+        # latent serves every head); those out of them split by head
+        layer.update({
+            "wq_a": {"w": P(None, "fsdp", None)},
+            "q_norm": {"scale": P(None, None)},
+            "wq_b": {"w": P(None, None, "model")},
+            "wkv_a": {"w": P(None, "fsdp", None)},
+            "kv_norm": {"scale": P(None, None)},
+            "wkv_b": {"w": P(None, None, "model")}})
     else:
-        layer["w_gate"] = {"w": P(None, "fsdp", "model")}
-        layer["w_up"] = {"w": P(None, "fsdp", "model")}
-        layer["w_down"] = {"w": P(None, "model", "fsdp")}
+        layer.update({"wq": {"w": column}, "wk": {"w": column},
+                      "wv": {"w": column}})
+    dense_ffn = {"w_gate": {"w": column}, "w_up": {"w": column},
+                 "w_down": {"w": row}}
+    expert_ffn_specs = {
+        "router": {"w": P(None, None, None)},
+        "w_gate": {"w": P(None, "expert", "fsdp", "model")},
+        "w_up": {"w": P(None, "expert", "fsdp", "model")},
+        "w_down": {"w": P(None, "expert", "model", "fsdp")}}
     specs = {
         "embed": {"w": P(None, "fsdp")},
-        "layers": layer,
         "norm_out": {"scale": P(None)},
     }
+    if config.top_k:
+        specs["layers"] = dict(
+            layer, **expert_ffn_specs, shared_gate={"w": column},
+            shared_up={"w": column}, shared_down={"w": row})
+        if _leading_dense(config):
+            specs["dense_layers"] = dict(layer, **dense_ffn)
+    elif config.n_experts > 0:
+        specs["layers"] = dict(layer, **expert_ffn_specs)
+    else:
+        specs["layers"] = dict(layer, **dense_ffn)
     if lm_head:
         specs["lm_head"] = {"w": P(None, "fsdp")}
     return specs
@@ -217,6 +400,11 @@ def quantize_weights_int8(params: dict,
     ~2x decode throughput at fixed batch.  Norms and biases stay f32;
     MoE expert FFNs stay unquantized (their dispatch einsums bypass
     dense()).  NOT for training -- optax rejects int8 leaves loudly."""
+    if config.kv_lora_rank or config.top_k:
+        raise ValueError("weight-only int8 covers the grouped-query "
+                         "dense and switch layers, not latent attention "
+                         "or routed experts")
+
     def quant(entry: dict, axis: int) -> dict:
         w = entry["w"].astype(jnp.float32)
         scale = jnp.maximum(
@@ -271,6 +459,11 @@ def quantized_param_specs(config: TransformerConfig,
 def init_cache(config: TransformerConfig, batch: int,
                max_len: int | None = None) -> dict:
     max_len = max_len or config.max_seq_len
+    if config.kv_lora_rank:
+        # one leaf: a position's latent and shared rotary key, which is
+        # key and value of every head (_project_latent)
+        return {"kv": jnp.zeros((config.n_layers, batch, 1, max_len,
+                                 config.latent_row), config.jnp_dtype)}
     shape = (config.n_layers, batch, config.n_kv_heads, max_len,
              config.head_dim)
     if config.kv_dtype == "int8":
@@ -324,23 +517,82 @@ def _project_qkv(config: TransformerConfig, layer, x, cos, sin):
     return apply_rotary(q, cos, sin), apply_rotary(k, cos, sin), v
 
 
+def _project_latent(config: TransformerConfig, layer, x, cos, sin):
+    """MLA's projections.  x (B, L, d_model) -> q (B, H, L, nope + rope),
+    its rotary slice rotated, and the one row a position leaves behind,
+    (B, 1, L, latent_row): [RMSNorm(c_kv) ; RoPE(k_r) ; zeros to the
+    lanes] -- every head's key and, through wkv_b, its value.  No v."""
+    batch, length, _ = x.shape
+    nope, rank = config.qk_nope_head_dim, config.kv_lora_rank
+    rope = config.qk_rope_head_dim
+    c_q = rms_norm(layer["q_norm"], dense(layer["wq_a"], x),
+                   config.norm_eps)
+    q = dense(layer["wq_b"], c_q).reshape(
+        batch, length, config.n_heads, nope + rope).transpose(0, 2, 1, 3)
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rotary(q[..., nope:], cos, sin)], axis=-1)
+    down = dense(layer["wkv_a"], x)[:, None]           # (B, 1, L, rank+rope)
+    latent = jnp.concatenate(
+        [rms_norm(layer["kv_norm"], down[..., :rank], config.norm_eps),
+         apply_rotary(down[..., rank:], cos, sin),
+         jnp.zeros((batch, 1, length, config.latent_row - rank - rope),
+                   x.dtype)], axis=-1)
+    return q, latent, None
+
+
+def _latent_up(config: TransformerConfig, layer) -> tuple:
+    """wkv_b as the keys' (rank, H, nope) and the values' (rank, H, v)
+    up-projections."""
+    w = layer["wkv_b"]["w"].reshape(
+        config.kv_lora_rank, config.n_heads,
+        config.qk_nope_head_dim + config.v_head_dim)
+    return w[..., :config.qk_nope_head_dim], w[..., config.qk_nope_head_dim:]
+
+
+def _latent_expand(config: TransformerConfig, layer, latent):
+    """Decompress latent rows (B, 1, L, latent_row) to every head's
+    k (B, H, L, nope + rope) and v (B, H, L, v)."""
+    batch, _, length, _ = latent.shape
+    rank, nope = config.kv_lora_rank, config.qk_nope_head_dim
+    kv = dense(layer["wkv_b"], latent[:, 0, :, :rank]).reshape(
+        batch, length, config.n_heads, nope + config.v_head_dim
+    ).transpose(0, 2, 1, 3)
+    k_rope = jnp.broadcast_to(
+        latent[..., rank:rank + config.qk_rope_head_dim],
+        (batch, config.n_heads, length, config.qk_rope_head_dim))
+    return (jnp.concatenate([kv[..., :nope], k_rope], axis=-1),
+            kv[..., nope:])
+
+
+def _latent_flash(config: TransformerConfig, q, k, v):
+    """Causal blockwise attention of decompressed MLA heads: q.k over
+    nope + rope (zero-padded to the lanes, which adds nothing to a
+    score), v of its own width, YaRN's softmax scale."""
+    pad = [(0, 0)] * 3 + [(0, -q.shape[-1] % 128)]
+    return flash_attention(jnp.pad(q, pad), jnp.pad(k, pad), v,
+                           causal=True, sm_scale=config.attention_scale)
+
+
 def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend):
     """THE decoder layer, on every path: attention norm, projections and
     rotary, `attend`, wo and residual, MLP norm, FFN, residual.  Only
-    `attend` differs, by where the K/V live: attend(q, k, v) stores the
-    new K/V and returns (attention output (B, H, L, hd), the store's new
-    leaves) -- _attend_fresh, _attend_cache, _attend_pool.  Returns
-    (h, the FFN's aux loss, the store's new leaves)."""
+    `attend` differs, by where the K/V live: attend(layer, q, k, v)
+    stores the new K/V and returns (attention output (B, H, L, hd), the
+    store's new leaves) -- _attend_fresh, _attend_cache, _attend_pool.
+    Under latent attention k is the latent row and v None; the stores
+    keep the row, and attend decompressed (fresh, cache) or absorbed
+    (pool).  Returns (h, the FFN's stats, the store's new leaves)."""
     batch, length, _ = h.shape
-    q, k, v = _project_qkv(
+    project = _project_latent if config.kv_lora_rank else _project_qkv
+    q, k, v = project(
         config, layer, rms_norm(layer["attn_norm"], h, config.norm_eps),
         cos, sin)
-    out, leaves = attend(q, k, v)
+    out, leaves = attend(layer, q, k, v)
     h = h + dense(layer["wo"],
                   out.transpose(0, 2, 1, 3).reshape(batch, length, -1))
-    mlp_out, aux = _mlp_block(
+    mlp_out, stats = _mlp_block(
         config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps))
-    return h + mlp_out, aux, leaves
+    return h + mlp_out, stats, leaves
 
 
 def _sp_prefill(config: TransformerConfig, q, k, v):
@@ -351,9 +603,12 @@ def _sp_prefill(config: TransformerConfig, q, k, v):
     return ring_attention(q, k, v, causal=True)
 
 
-def _attend_fresh(config: TransformerConfig, q, k, v):
+def _attend_fresh(config: TransformerConfig, layer, q, k, v):
     """No KV store (training, scoring): causal attention over the fresh
     K/V, blockwise."""
+    if config.kv_lora_rank:
+        return _latent_flash(config, q,
+                             *_latent_expand(config, layer, k)), None
     if config.sequence_parallel:
         return _sp_prefill(config, q, k, v), None
     return flash_attention(q, k, v, causal=True), None
@@ -370,6 +625,12 @@ def _kv_to_write(store: dict, k, v) -> dict:
     return written
 
 
+def _store_leaf(store: dict):
+    """The leaf that says a store's shape and dtype: a latent store's
+    one leaf, else the keys'."""
+    return store["kv"] if "kv" in store else store["k"]
+
+
 def cache_attention_kind(config: TransformerConfig, store: dict, batch: int,
                          length: int, pos=0) -> str:
     """"flash" or "einsum": what a forward of (batch, length) tokens at
@@ -377,7 +638,7 @@ def cache_attention_kind(config: TransformerConfig, store: dict, batch: int,
     `store`'s dtype (a cache, or the pool paged_prefill scatters its
     cache into: their leaves are alike).  _attend_cache decides by this,
     and the engine names its prefill spans by it."""
-    dtype = store["k"].dtype
+    dtype = _store_leaf(store).dtype
     if (length > 1 and isinstance(pos, (int, np.integer)) and pos == 0
             and flash_attention_takes(batch, config.n_heads, length, dtype,
                                       dtype)):
@@ -385,12 +646,32 @@ def cache_attention_kind(config: TransformerConfig, store: dict, batch: int,
     return "einsum"
 
 
-def _attend_cache(config: TransformerConfig, cache: dict, pos, q, k, v):
+def _attend_cache_latent(config: TransformerConfig, cache: dict, pos,
+                         layer, q, latent):
+    """_attend_cache for latent attention, decompressed: the fresh rows
+    alone through the flash kernel where a prefill from position 0 takes
+    it, else the whole buffer's rows made heads again and masked."""
+    batch, _, length, _ = q.shape
+    cache = {"kv": jax.lax.dynamic_update_slice(cache["kv"], latent,
+                                                (0, 0, pos, 0))}
+    if cache_attention_kind(config, cache, batch, length, pos) == "flash":
+        return _latent_flash(
+            config, q, *_latent_expand(config, layer, latent)), cache
+    k, v = _latent_expand(config, layer, cache["kv"])
+    return attention_reference(
+        q, k, v, causal=True, sm_scale=config.attention_scale,
+        q_offset=pos - (k.shape[2] - length)), cache
+
+
+def _attend_cache(config: TransformerConfig, cache: dict, pos, layer,
+                  q, k, v):
     """Contiguous cache (init_cache; `cache` is one layer's leaves):
     write the new K/V at `pos`, then masked attention over the whole
     buffer -- or, for a prefill from the static position 0 that
     flash_attention_takes, the same causal attention over the fresh K/V
     alone, blockwise."""
+    if config.kv_lora_rank:
+        return _attend_cache_latent(config, cache, pos, layer, q, k)
     batch, _, length, hd = q.shape
     cache = {name: jax.lax.dynamic_update_slice(cache[name], value,
                                                 (0, 0, pos, 0))
@@ -577,15 +858,101 @@ def _embed(params: dict, config: TransformerConfig, tokens):
     return h
 
 
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def _scan_layers(config: TransformerConfig, step, carry, stack, extra):
+    """jax.lax.scan of step(carry, (layer, extra[i])) over a stack of
+    layers.  The routed experts' weights do not ride the scan: a
+    kernel's operand that the loop slices out of the stack is copied
+    whole every iteration (1.9 GB a layer at DeepSeek-V2's widths), so
+    such a layer carries the whole stacked leaves and its index among
+    them as layer["experts"], and the kernel reads the layer where it
+    lies (parallel/experts.py)."""
+    if not (config.top_k and "router" in stack):
+        return jax.lax.scan(step, carry, (stack, extra))
+    held = {name: stack[name] for name in _EXPERT_LEAVES}
+    rest = {name: leaf for name, leaf in stack.items()
+            if name not in _EXPERT_LEAVES}
+
+    def with_experts(carry, xs):
+        layer, index, extra_i = xs
+        return step(carry, (dict(layer, experts=(held, index)), extra_i))
+
+    return jax.lax.scan(
+        with_experts, carry,
+        (rest, jnp.arange(stack["router"]["w"].shape[0]), extra))
+
+
+def _route(config: TransformerConfig, router: dict, x):
+    """DeepSeek-V2's group-limited greedy router.  x (T, d) -> (weights
+    (T, k) float32, ids (T, k) int32 in the router's numbering): softmax
+    scores in float32 over all n_routed_experts, a group's score its
+    largest, the best topk_groups groups kept, the top_k scores among
+    their experts chosen (ties to the lower index, jax.lax.top_k's
+    order), weighted routed_scaling x score and not renormalised."""
+    tokens = x.shape[0]
+    scores = jax.nn.softmax(jnp.einsum(
+        "td,de->te", x.astype(jnp.float32),
+        router["w"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    per_group = config.n_routed_experts // config.n_groups
+    _, kept = jax.lax.top_k(
+        scores.reshape(tokens, config.n_groups, per_group).max(axis=-1),
+        config.topk_groups)
+    group_mask = jnp.zeros((tokens, config.n_groups), bool).at[
+        jnp.arange(tokens)[:, None], kept].set(True)
+    weights, ids = jax.lax.top_k(
+        jnp.where(jnp.repeat(group_mask, per_group, axis=1), scores, 0.0),
+        config.top_k)
+    return weights * config.routed_scaling, ids
+
+
+def _routed_moe(config: TransformerConfig, layer, x):
+    """Shared(x) + sum over a token's chosen experts of g_i E_i(x), of
+    which this process adds the experts it holds (config.held) and
+    leaves the rest to the shares that hold them; a token none of whose
+    experts is held gets the shared experts only.  No token is dropped:
+    every token-expert pair held is computed (parallel/experts.py).
+    Returns (output, stats) with stats[1:] = distinct held experts hit
+    and pairs computed."""
+    batch, length, d_model = x.shape
+    tokens = x.reshape(batch * length, d_model)
+    weights, ids = _route(config, layer["router"], tokens)
+    low, high = config.held
+    held = (ids >= low) & (ids < high)
+    stacked, index = layer["experts"]        # _scan_layers
+    routed, experts_read, pairs = expert_ffn(
+        tokens, *(stacked[name]["w"] for name in _EXPERT_LEAVES),
+        jnp.where(held, ids - low, high - low),
+        jnp.where(held, weights, 0.0), layer=index)
+    shared = swiglu(layer["shared_gate"], layer["shared_up"],
+                    layer["shared_down"], x)
+    stats = jnp.stack([jnp.float32(0.0), experts_read.astype(jnp.float32),
+                       pairs.astype(jnp.float32)])
+    return shared + routed.reshape(x.shape).astype(x.dtype), stats
+
+
+# what an FFN reports beside its output, summed over layers: the switch
+# FFN's load-balancing loss, and of the routed experts the distinct
+# experts read and the token-expert pairs computed
+_FFN_STATS = 3
+
+
 def _mlp_block(config: TransformerConfig, layer, mlp_in):
-    """One layer's FFN (dense SwiGLU or switch MoE).  Returns
-    (output, aux)."""
+    """One layer's FFN (dense SwiGLU, switch MoE, or routed + shared
+    experts where the layer has a router).  Returns (output, stats
+    float32 (_FFN_STATS,))."""
+    if config.top_k and "router" in layer:
+        return _routed_moe(config, layer, mlp_in)
+    stats = jnp.zeros((_FFN_STATS,), jnp.float32)
     if config.n_experts > 0:
-        return _switch_moe(config, layer, mlp_in)
+        out, aux = _switch_moe(config, layer, mlp_in)
+        return out, stats.at[0].set(aux)
     return dense(
         layer["w_down"],
         jax.nn.silu(dense(layer["w_gate"], mlp_in))
-        * dense(layer["w_up"], mlp_in)), jnp.zeros((), jnp.float32)
+        * dense(layer["w_up"], mlp_in)), stats
 
 
 def _lm_head(params: dict, config: TransformerConfig, h):
@@ -602,6 +969,33 @@ def _lm_head(params: dict, config: TransformerConfig, h):
         # streams 8-bit codes, the (V,) scale applies to the result
         logits = logits * head["w_scale"][:, 0]
     return logits
+
+
+def _rotary_tables(config: TransformerConfig, positions):
+    """cos/sin of `positions` over the rotary width: the heads' own, or
+    under latent attention the rotary slice's; YaRN's frequencies and
+    multiplier where the context is stretched."""
+    if config.rope_factor <= 1.0:
+        return rotary_embedding(positions, config.rotary_dim,
+                                config.rope_theta)
+    cos, sin = rotary_embedding(
+        positions, config.rotary_dim, frequencies=yarn_frequencies(
+            config.rotary_dim, config.rope_theta, config.rope_factor,
+            config.rope_original_max, config.rope_beta_fast,
+            config.rope_beta_slow))
+    multiplier = (yarn_mscale(config.rope_factor, config.rope_mscale)
+                  / yarn_mscale(config.rope_factor,
+                                config.rope_mscale_all_dim))
+    return cos * multiplier, sin * multiplier
+
+
+def _layer_stacks(params: dict, config: TransformerConfig) -> list:
+    """[(stacked layers, index of the first, how many)] in the order they
+    run: the leading dense layers of a routed-expert model, then the
+    stack every model has."""
+    lead = _leading_dense(config)
+    stacks = [(params["dense_layers"], 0, lead)] if lead else []
+    return stacks + [(params["layers"], lead, config.n_layers - lead)]
 
 
 def forward(params: dict, config: TransformerConfig, tokens,
@@ -638,25 +1032,25 @@ def forward(params: dict, config: TransformerConfig, tokens,
     if activation_specs:
         h = jax.lax.with_sharding_constraint(h, act_spec)
     positions = pos + jnp.arange(tokens.shape[1])
-    cos, sin = rotary_embedding(positions, config.head_dim,
-                                config.rope_theta)
+    cos, sin = _rotary_tables(config, positions)
     cos, sin = cos[None, None], sin[None, None]  # (1, 1, L, hd/2)
 
     def layer_step(carry, xs):
-        h, aux_sum = carry
+        h, stats_sum = carry
         layer, layer_cache = xs
-        h, aux, new_cache = _decoder_layer(
+        h, stats, new_cache = _decoder_layer(
             config, layer, h, cos, sin,
             partial(_attend_fresh, config) if layer_cache is None
             else partial(_attend_cache, config, layer_cache, pos))
-        aux_sum = aux_sum + aux
+        stats_sum = stats_sum + stats
         if activation_specs:
             h = jax.lax.with_sharding_constraint(h, act_spec)
-        return (h, aux_sum), new_cache
+        return (h, stats_sum), new_cache
 
-    aux0 = jnp.zeros((), jnp.float32)
+    carry = (h, jnp.zeros((_FFN_STATS,), jnp.float32))
+    stacks = _layer_stacks(params, config)
     if cache is None:
-        body = lambda carry, layer: layer_step(carry, (layer, None))  # noqa: E731
+        body = layer_step
         policy = resolve_remat_policy(remat_policy)
         if policy is not None:
             # remat over the scanned layer body: the standard trade --
@@ -665,15 +1059,25 @@ def forward(params: dict, config: TransformerConfig, tokens,
             # documented setting under scan (the scan boundary already
             # blocks the CSE that prevent_cse guards against).
             body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-        (h, aux_sum), _ = jax.lax.scan(body, (h, aux0), params["layers"])
+        for stack, _, _ in stacks:
+            carry, _ = _scan_layers(config, body, carry, stack, None)
         new_cache = None
     else:
-        (h, aux_sum), new_cache = jax.lax.scan(
-            layer_step, (h, aux0), (params["layers"], cache))
+        written = []
+        for stack, first, count in stacks:
+            carry, part = _scan_layers(
+                config, layer_step, carry, stack,
+                cache if len(stacks) == 1 else jax.tree_util.tree_map(
+                    lambda leaf: leaf[first:first + count], cache))
+            written.append(part)
+        new_cache = written[0] if len(written) == 1 else \
+            jax.tree_util.tree_map(
+                lambda *parts: jnp.concatenate(parts), *written)
+    h, stats_sum = carry
     logits = _lm_head(params, config, h)
     if new_cache is None:
         if return_aux:
-            return logits, aux_sum / max(config.n_layers, 1)
+            return logits, stats_sum[0] / max(config.n_layers, 1)
         return logits
     return logits, new_cache
 
@@ -816,6 +1220,11 @@ def init_paged_pool(config: TransformerConfig, num_blocks: int,
     block tables.  Block 0 is the engine's reserved trash block
     (inactive-slot writes land there).  Same leaf names/dtypes as
     init_cache, so the int8 KV path carries over unchanged."""
+    if config.kv_lora_rank:
+        # the latent pool: one leaf, one row a position a layer
+        return {"kv": jnp.zeros(
+            (config.n_layers, num_blocks, 1, block_size,
+             config.latent_row), config.jnp_dtype)}
     shape = (config.n_layers, num_blocks, config.n_kv_heads, block_size,
              config.head_dim)
     if config.kv_dtype == "int8":
@@ -840,7 +1249,7 @@ def paged_prefill(params, config: TransformerConfig, pool, prompt,
     TRUE prompt length -- causal masking makes logits at true_len-1
     independent of the right-padding.  One executable per bucket; the
     decode loop never recompiles (paged_decode_step below)."""
-    block_size = pool["k"].shape[3]
+    block_size = _store_leaf(pool).shape[3]
     local = init_cache(config, 1, max_len=prompt.shape[1])
     logits, local = forward(params, config, prompt, cache=local, pos=0)
     first = jnp.argmax(logits[0, true_len - 1]).astype(jnp.int32)
@@ -875,9 +1284,40 @@ def _write_window(leaf, value, layer, write_blocks, write_offsets):
     return leaf
 
 
-def _attend_pool(config: TransformerConfig, pool: dict, layer, tables,
-                 positions, write_blocks, write_offsets, q, k, v):
-    """Paged pool (init_paged_pool; `pool` is the whole pool, `layer`
+def _attend_pool_latent(config: TransformerConfig, pool: dict, index,
+                        tables, positions, write_blocks, write_offsets,
+                        layer, q, latent):
+    """_attend_pool for latent attention, absorbed: the keys' up-
+    projection goes into the query (q~_h = W_k,h^T q_nope_h), the
+    scores are q~_h . c_kv + q_rope_h . k_r against the rows as they
+    lie, the weighted rows come back (S, H, W, rank) and the values'
+    up-projection is applied to them.  128 query heads over ONE key
+    head whose first `rank` values are also the value: no head's key or
+    value is ever made, and a row is read once."""
+    pool = {"kv": _write_window(pool["kv"], latent, index, write_blocks,
+                                write_offsets)}
+    nope, rank = config.qk_nope_head_dim, config.kv_lora_rank
+    keys_up, values_up = _latent_up(config, layer)
+    absorbed = jnp.einsum("shwn,chn->shwc", q[..., :nope], keys_up,
+                          preferred_element_type=jnp.float32
+                          ).astype(q.dtype)
+    pad = config.latent_row - rank - config.qk_rope_head_dim
+    query = jnp.concatenate(
+        [absorbed, q[..., nope:],
+         jnp.zeros(q.shape[:3] + (pad,), q.dtype)], axis=-1)
+    attend = (paged_attention if paged_attention_takes(
+        config.n_heads, q.shape[2], config.latent_row, pool["kv"].dtype,
+        value_dim=rank) else paged_attention_reference)
+    weighted = attend(query, pool["kv"], None, index, tables, positions,
+                      sm_scale=config.attention_scale, value_dim=rank)
+    return jnp.einsum("shwc,chv->shwv", weighted, values_up,
+                      preferred_element_type=jnp.float32
+                      ).astype(q.dtype), pool
+
+
+def _attend_pool(config: TransformerConfig, pool: dict, index, tables,
+                 positions, write_blocks, write_offsets, layer, q, k, v):
+    """Paged pool (init_paged_pool; `pool` is the whole pool, `index`
     this layer's index into it): write the WHOLE window's K/V where it
     lies, then attend through each slot's block table -- so later
     window positions attend to earlier ones causally.  The kernel walks
@@ -886,7 +1326,10 @@ def _attend_pool(config: TransformerConfig, pool: dict, layer, tables,
     whose scales dequantize the gathered view as the contiguous int8
     cache's do; a window too large for VMEM; on the chip a head_dim off
     the 128 lanes)."""
-    pool = {name: _write_window(pool[name], value, layer, write_blocks,
+    if config.kv_lora_rank:
+        return _attend_pool_latent(config, pool, index, tables, positions,
+                                   write_blocks, write_offsets, layer, q, k)
+    pool = {name: _write_window(pool[name], value, index, write_blocks,
                                 write_offsets)
             for name, value in _kv_to_write(pool, k, v).items()}
     attend = (paged_attention if paged_attention_takes(
@@ -894,7 +1337,7 @@ def _attend_pool(config: TransformerConfig, pool: dict, layer, tables,
         else paged_attention_reference)
     scales = ((pool["k_scale"], pool["v_scale"]) if "k_scale" in pool
               else ())
-    return attend(q, pool["k"], pool["v"], layer, tables, positions,
+    return attend(q, pool["k"], pool["v"], index, tables, positions,
                   *scales), pool
 
 
@@ -909,34 +1352,38 @@ def _paged_window(params, config: TransformerConfig, pool, tables,
     position i sits at absolute position positions[slot] + i, its K/V
     lands at (write_blocks[slot, i], write_offsets[slot, i]), and rows
     the engine wants inert point their writes at the trash block.
-    Returns (pool, greedy (slots, W)) where greedy[s, i] is the greedy
-    token AFTER consuming window positions 0..i -- the tokens W
+    Returns (pool, greedy (slots, W), counts) where greedy[s, i] is the
+    greedy token AFTER consuming window positions 0..i -- the tokens W
     successive single-token decode steps would produce, which is the
-    identity the chunked-prefill and speculative tests pin."""
+    identity the chunked-prefill and speculative tests pin -- and counts
+    int32 (2,) what the routed experts did, summed over layers: distinct
+    held experts read, token-expert pairs computed (zeros without)."""
     h = _embed(params, config, tokens)
     q_pos = positions[:, None] + jnp.arange(tokens.shape[1])[None, :]
-    cos, sin = rotary_embedding(q_pos, config.head_dim,
-                                config.rope_theta)
+    cos, sin = _rotary_tables(config, q_pos)
     cos, sin = cos[:, None], sin[:, None]        # (S, 1, W, hd/2)
 
     def layer_step(carry, xs):
         # the pool rides the loop as CARRY and is written where it lies
         # (indexed by layer): as scan xs -> ys every step rebuilt it
-        # whole.  The FFN's aux loss is dropped: nothing here trains
-        h, pool = carry
+        # whole.  Nothing here trains: of the FFN's stats only the
+        # experts' counts go on
+        h, pool, stats_sum = carry
         layer, index = xs
-        h, _, pool = _decoder_layer(
+        h, stats, pool = _decoder_layer(
             config, layer, h, cos, sin,
             partial(_attend_pool, config, pool, index, tables, positions,
                     write_blocks, write_offsets))
-        return (h, pool), None
+        return (h, pool, stats_sum + stats), None
 
-    (h, new_pool), _ = jax.lax.scan(
-        layer_step, (h, pool),
-        (params["layers"], jnp.arange(config.n_layers)))
+    carry = (h, pool, jnp.zeros((_FFN_STATS,), jnp.float32))
+    for stack, first, count in _layer_stacks(params, config):
+        carry, _ = _scan_layers(config, layer_step, carry, stack,
+                                first + jnp.arange(count))
+    h, new_pool, stats_sum = carry
     logits = _lm_head(params, config, h)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return new_pool, greedy
+    return new_pool, greedy, stats_sum[1:].astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(2,))
@@ -956,10 +1403,14 @@ def paged_decode_step(params, config: TransformerConfig, pool, tables,
     Per-slot positions (unlike forward's scalar `pos`) are the whole
     point: slot 3 can be 400 tokens into its completion while slot 0 is
     on its first -- the rotary phase and causal mask resolve per row.
-    The window-1 instantiation of _paged_window."""
-    return _paged_window(params, config, pool, tables, positions,
-                         tokens, write_blocks[:, None],
-                         write_offsets[:, None])
+    The window-1 instantiation of _paged_window.  A model with routed
+    experts returns a third value, int32 (2,): the distinct held experts
+    the step read and the token-expert pairs it computed, over its
+    layers."""
+    pool, greedy, counts = _paged_window(
+        params, config, pool, tables, positions, tokens,
+        write_blocks[:, None], write_offsets[:, None])
+    return (pool, greedy, counts) if config.top_k else (pool, greedy)
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(2,))
@@ -977,7 +1428,7 @@ def paged_verify_step(params, config: TransformerConfig, pool, tables,
     write_blocks/write_offsets (slots, W); overflow/inactive window
     positions point at the trash block.  One executable per W."""
     return _paged_window(params, config, pool, tables, positions,
-                         tokens, write_blocks, write_offsets)
+                         tokens, write_blocks, write_offsets)[:2]
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(2,))
@@ -997,7 +1448,7 @@ def paged_prefill_chunk(params, config: TransformerConfig, pool, tokens,
     prompt end is the request's first generated token, bit-identical
     to monolithic paged_prefill's.  One executable per power-of-two
     chunk bucket."""
-    pool, greedy = _paged_window(
+    pool, greedy, _ = _paged_window(
         params, config, pool, table_row[None],
         jnp.reshape(start, (1,)), tokens, write_blocks[None],
         write_offsets[None])

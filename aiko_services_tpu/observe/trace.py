@@ -94,7 +94,19 @@
 #                               admission or an empty engine),
 #                               live_blocks (blocks the paged attention
 #                               walks this step), table_blocks (what the
-#                               tables can name: slots x max_blocks)
+#                               tables can name: slots x max_blocks);
+#                               over a latent pool latent_positions (the
+#                               live rows this step's slots attend over,
+#                               its own among them); with routed experts
+#                               experts_read (distinct held experts hit,
+#                               summed over the expert layers) and
+#                               expert_pairs (token-expert pairs computed
+#                               here), both of the NEWEST STEP READ BACK
+#                               when the span opens -- the device counts
+#                               them, so they come with that step's
+#                               tokens, two dispatches late under the
+#                               run-ahead; absent until one has.  Running
+#                               sums of all three in engine_stats()
 #   engine.readback    scoped   the settle's readback of the step in
 #                               flight: in a tick after that tick's
 #                               engine.decode where it ran ahead, or

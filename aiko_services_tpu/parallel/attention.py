@@ -225,10 +225,13 @@ def flash_attention_takes(batch: int, heads: int, length: int, dtype,
 def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
                     block_q: int | None = None, block_k: int | None = None,
                     q_offset: int = 0):
-    """Blockwise attention: q (B, H, L, D), k/v (B, Hkv, Lk, D) with H a
-    multiple of Hkv -- KV head g serves query heads g*H/Hkv onward, as
-    repeat_kv lays them out, from one read of its tiles; no repeated K/V
-    exists in HBM.  Output (B, H, L, D).
+    """Blockwise attention: q (B, H, L, D), k (B, Hkv, Lk, D), v (B, Hkv,
+    Lk, Dv) with H a multiple of Hkv -- KV head g serves query heads
+    g*H/Hkv onward, as repeat_kv lays them out, from one read of its
+    tiles; no repeated K/V exists in HBM.  Output (B, H, L, Dv).  Dv is
+    D everywhere but under latent attention, whose heads score over 192
+    and carry values of 128; that forward is named `mla_flash_attention`
+    in the device trace, and its backward is the plain one's.
 
     q_offset shifts the causal mask for callers whose q shard starts at a
     nonzero global position (ring attention resumes, KV-cached decode).
@@ -317,10 +320,18 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, residuals,
         # the backward kernels take one K/V head a query head: a group's
         # K/V is repeated for them and its query heads' dk/dv summed
         k, v = (jnp.repeat(x, repeats, axis=1) for x in (k, v))
-    dq, dk, dv = _flash_bwd_impl(
-        q, k, v, out, lse, cotangent, causal, sm_scale,
-        min(block_q, _FLASH_BACKWARD_BLOCK),
-        min(block_k, _FLASH_BACKWARD_BLOCK), q_offset)
+    if v.shape[-1] != q.shape[-1]:
+        # the backward kernels hold one head_dim: a value width of its
+        # own differentiates through the plain-XLA oracle
+        _, vjp = jax.vjp(functools.partial(
+            attention_reference, causal=causal, sm_scale=sm_scale,
+            q_offset=q_offset), q, k, v)
+        dq, dk, dv = vjp(cotangent)
+    else:
+        dq, dk, dv = _flash_bwd_impl(
+            q, k, v, out, lse, cotangent, causal, sm_scale,
+            min(block_q, _FLASH_BACKWARD_BLOCK),
+            min(block_k, _FLASH_BACKWARD_BLOCK), q_offset)
     if repeats > 1:
         dk, dv = (
             grad.astype(jnp.float32).reshape(
@@ -345,6 +356,7 @@ def _flash_impl(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
     none."""
     batch, heads, q_len, head_dim = q.shape
     kv_heads, kv_len = k.shape[1], k.shape[2]
+    value_dim = v.shape[3]
     repeats = heads // kv_heads
     groups = batch * kv_heads
 
@@ -352,7 +364,7 @@ def _flash_impl(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
     # share the leading index of its K/V head
     q_padded = _pad_seq(q, block_q).reshape(groups, repeats, -1, head_dim)
     k_padded = _pad_seq(k, block_k).reshape(groups, -1, head_dim)
-    v_padded = _pad_seq(v, block_k).reshape(groups, -1, head_dim)
+    v_padded = _pad_seq(v, block_k).reshape(groups, -1, value_dim)
     padded_q_len = q_padded.shape[2]
     # k blocks stream through the grid's sequential minor dimension, so
     # VMEM holds one group's (repeats, block_q, d) q tile + one
@@ -373,6 +385,13 @@ def _flash_impl(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
                           memory_space=pltpu.VMEM)
     kv_spec = pl.BlockSpec((1, block_k, head_dim), kv_index,
                            memory_space=pltpu.VMEM)
+    # the values and the output have the values' width: head_dim but
+    # under latent attention (192 to score, 128 to carry)
+    v_spec = pl.BlockSpec((1, block_k, value_dim), kv_index,
+                          memory_space=pltpu.VMEM)
+    o_spec = pl.BlockSpec((1, repeats, block_q, value_dim),
+                          lambda g, qi, ki: (g, 0, qi, 0),
+                          memory_space=pltpu.VMEM)
     stat_spec = pl.BlockSpec((1, repeats, block_q, _STAT_LANES),
                              lambda g, qi, ki: (g, 0, qi, 0),
                              memory_space=pltpu.VMEM)
@@ -383,23 +402,27 @@ def _flash_impl(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
     results = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, stat_spec][:1 + with_lse],
-        out_shape=[jax.ShapeDtypeStruct(q_padded.shape, q.dtype),
+        in_specs=[q_spec, kv_spec, v_spec],
+        out_specs=[o_spec, stat_spec][:1 + with_lse],
+        out_shape=[jax.ShapeDtypeStruct(
+            q_padded.shape[:3] + (value_dim,), q.dtype),
                    jax.ShapeDtypeStruct(stat_shape, jnp.float32)
                    ][:1 + with_lse],
         scratch_shapes=[
             pltpu.VMEM((repeats, block_q, _STAT_LANES), jnp.float32),  # m
             pltpu.VMEM((repeats, block_q, _STAT_LANES), jnp.float32),  # l
-            pltpu.VMEM((repeats, block_q, head_dim), jnp.float32),   # acc
+            pltpu.VMEM((repeats, block_q, value_dim), jnp.float32),  # acc
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_FLASH_VMEM_BYTES),
+        # only the forward with a value width of its own is named: the
+        # plain one stays what it compiled to
+        name="mla_flash_attention" if value_dim != head_dim else None,
         interpret=_interpret(),
     )(q_padded, k_padded, v_padded)
     out = results[0].reshape(batch, heads, padded_q_len,
-                             head_dim)[:, :, :q_len]
+                             value_dim)[:, :, :q_len]
     if not with_lse:
         return out
     lse = results[1].reshape(batch, heads, padded_q_len,
@@ -641,18 +664,22 @@ _PAGED_CHUNK_POSITIONS = 512   # K/V positions fetched per chunk
 _PAGED_CHUNK_BLOCKS_MAX = 16   # copies in flight per chunk and leaf
 _PAGED_NARROW = 128            # positions multiplied of a short chunk
 _PAGED_MAX_ROW_BYTES = 8192    # heads x window x itemsize one slot brings
+_PAGED_LATENT_ROW_BYTES = 2048  # the same for a latent pool's wider rows
 
 
 def paged_attention_reference(q, pool_k, pool_v, layer, tables, positions,
-                              k_scale=None, v_scale=None):
+                              k_scale=None, v_scale=None, sm_scale=None,
+                              value_dim=None):
     """Plain-XLA paged attention, same signature as paged_attention:
     gather every slot's WHOLE table from the pool's `layer` into a
     contiguous view, repeat the KV heads and run the masked einsum.
     Window row i of slot s sits at absolute position positions[s] + i
     and sees k_pos <= that.  With k_scale/v_scale (the int8 pool's scale
     leaves) the gathered view is dequantized into the einsum's operand
-    load.  The oracle the kernel's tests compare with, and the path of
-    the calls the kernel does not take (paged_attention_takes)."""
+    load.  With pool_v None the pool is a latent one: a row is the key,
+    and its first `value_dim` values the value.  The oracle the kernel's
+    tests compare with, and the path of the calls the kernel does not
+    take (paged_attention_takes)."""
     slots, heads, window, depth = q.shape
     repeats = heads // pool_k.shape[2]
 
@@ -664,14 +691,16 @@ def paged_attention_reference(q, pool_k, pool_v, layer, tables, positions,
         return gathered.transpose(0, 2, 1, 3, 4).reshape(
             s, kv_heads, max_blocks * block, d)
 
-    k_eff, v_eff = view(pool_k), view(pool_v)
+    k_eff = view(pool_k)
+    v_eff = k_eff[..., :value_dim] if pool_v is None else view(pool_v)
     if k_scale is not None:
         k_eff = (k_eff.astype(jnp.float32) * view(k_scale)).astype(q.dtype)
         v_eff = (v_eff.astype(jnp.float32) * view(v_scale)).astype(q.dtype)
     # each KV head serves `repeats` consecutive query heads
     k_full = jnp.repeat(k_eff, repeats, axis=1)
     v_full = jnp.repeat(v_eff, repeats, axis=1)
-    scale = 1.0 / jnp.sqrt(jnp.float32(depth))
+    scale = (1.0 / jnp.sqrt(jnp.float32(depth)) if sm_scale is None
+             else sm_scale)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_full,
                         preferred_element_type=jnp.float32) * scale
     q_pos = positions[:, None] + jnp.arange(window)[None, :]
@@ -683,7 +712,7 @@ def paged_attention_reference(q, pool_k, pool_v, layer, tables, positions,
 
 
 def paged_attention_takes(heads: int, window: int, head_dim: int,
-                          pool_dtype) -> bool:
+                          pool_dtype, value_dim: int | None = None) -> bool:
     """Whether paged_attention serves a call, decided by what the call
     is: a bf16 or float32 pool whose `heads x window` query rows fit one
     slot's VMEM residency (4096 rows of bf16, 2048 of float32) -- window
@@ -691,11 +720,17 @@ def paged_attention_takes(heads: int, window: int, head_dim: int,
     very large window keep the einsum, and so does, on the chip, a
     head_dim that is not a multiple of the 128 lanes: Mosaic cannot
     slice such a pool for the block copies (jax's own paged kernels
-    refuse it too)."""
+    refuse it too).  A latent pool (`value_dim` given: head_dim is its
+    row, 640 wide at DeepSeek-V2's sizes, against one key head) has a
+    quarter of the rows' room: 1024 of bf16, a window of 8 at 128 heads."""
     dtype = jnp.dtype(pool_dtype)
+    room = (_PAGED_MAX_ROW_BYTES if value_dim is None
+            else _PAGED_LATENT_ROW_BYTES)
     return (dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
-            and heads * window * dtype.itemsize <= _PAGED_MAX_ROW_BYTES
-            and (head_dim % 128 == 0 or _interpret()))
+            and heads * window * dtype.itemsize <= room
+            and (head_dim % 128 == 0 or _interpret())
+            and (value_dim is None or value_dim % 128 == 0
+                 or _interpret()))
 
 
 def paged_live_blocks(positions, window: int, block: int,
@@ -709,15 +744,24 @@ def paged_live_blocks(positions, window: int, block: int,
 
 
 def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
-                  q_ref, k_hbm, v_hbm, o_ref,
-                  k_buf, v_buf, sems, parity_ref, m_ref, l_ref, acc_ref, *,
+                  q_ref, *rest,
                   window: int, block: int, chunk_blocks: int,
-                  max_blocks: int, sm_scale: float):
+                  max_blocks: int, sm_scale: float,
+                  value_dim: int | None = None):
     """One slot per grid step.  The slot's live blocks arrive in chunks
     of `chunk_blocks`; while chunk c is multiplied, chunk c + 1 (or the
     next slot's first chunk) is already on its way into the other half
     of k_buf/v_buf.  Softmax state (m, l, acc) lives in VMEM in float32
-    across the chunks."""
+    across the chunks.  With `value_dim` the pool is a latent one: there
+    is no V leaf and no V buffer, a row is fetched once and its first
+    `value_dim` values are the value."""
+    if value_dim is None:
+        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, parity_ref, m_ref, l_ref,
+         acc_ref) = rest
+        leaves = ((k_hbm, k_buf), (v_hbm, v_buf))
+    else:
+        k_hbm, o_ref, k_buf, sems, parity_ref, m_ref, l_ref, acc_ref = rest
+        leaves, v_buf = ((k_hbm, k_buf),), k_buf
     slot = pl.program_id(0)
     slots = pl.num_programs(0)
     kv_heads, rows = q_ref.shape[1], q_ref.shape[2]
@@ -736,8 +780,7 @@ def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
         def one(j, carry):
             page = tables_ref[of_slot * max_blocks + first + j]
             offset = pl.multiple_of(j * block, block)
-            for leaf, (hbm, buf) in enumerate(((k_hbm, k_buf),
-                                               (v_hbm, v_buf))):
+            for leaf, (hbm, buf) in enumerate(leaves):
                 copy = pltpu.make_async_copy(
                     hbm.at[layer, page],
                     buf.at[half, :, pl.ds(offset, block), :],
@@ -783,7 +826,8 @@ def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
             alpha = jnp.exp(m_prev - m_new)
             l_ref[head] = l_ref[head] * alpha + jnp.broadcast_to(
                 jnp.sum(p, axis=-1, keepdims=True), l_ref.shape[1:])
-            v_blk = v_buf[half, head, :width]
+            v_blk = (v_buf[half, head, :width] if value_dim is None
+                     else k_blk[:, :value_dim])
             acc_ref[head] = acc_ref[head] * alpha + jax.lax.dot_general(
                 p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -825,7 +869,8 @@ def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
             o_ref.dtype)
 
 
-def paged_attention(q, pool_k, pool_v, layer, tables, positions):
+def paged_attention(q, pool_k, pool_v, layer, tables, positions,
+                    sm_scale=None, value_dim=None):
     """Paged attention over the pool in place.  q (slots, heads, W, d);
     pool_k/pool_v the WHOLE pool leaves (layers, num_blocks, kv_heads,
     block, d), left in HBM, of which `layer` (an int32 scalar, traced or
@@ -834,7 +879,13 @@ def paged_attention(q, pool_k, pool_v, layer, tables, positions):
     window row i of slot s attends to k_pos <= positions[s] + i, scores
     and accumulation in float32, operands in the pool's dtype -- with
     the softmax taken blockwise, so outputs agree to rounding, not
-    bitwise.  Mosaic on the chip, interpreted on CPU (_interpret)."""
+    bitwise.  Mosaic on the chip, interpreted on CPU (_interpret).
+
+    pool_v None is a latent pool (latent attention absorbed: many query
+    heads over ONE key head): a row of pool_k is the key and its first
+    `value_dim` values the value, so the output is (slots, heads, W,
+    value_dim) and a live row is read once.  That kernel is named
+    `mla_paged_attention` in the device trace."""
     slots, heads, window, depth = q.shape
     _, _, kv_heads, block, _ = pool_k.shape
     max_blocks = tables.shape[1]
@@ -851,11 +902,18 @@ def paged_attention(q, pool_k, pool_v, layer, tables, positions):
     positions = positions.astype(jnp.int32)
     live = paged_live_blocks(positions, window, block, max_blocks)
 
+    pools = (pool_k,) if pool_v is None else (pool_k, pool_v)
+    out_depth = depth if pool_v is not None else int(value_dim)
     kernel = functools.partial(
         _paged_kernel, window=window, block=block,
         chunk_blocks=chunk_blocks, max_blocks=max_blocks,
-        sm_scale=1.0 / math.sqrt(depth))
+        sm_scale=(1.0 / math.sqrt(depth) if sm_scale is None
+                  else float(sm_scale)),
+        value_dim=None if pool_v is not None else out_depth)
     q_spec = pl.BlockSpec((1, kv_heads, padded_rows, depth),
+                          lambda s, *_: (s, 0, 0, 0),
+                          memory_space=pltpu.VMEM)
+    o_spec = pl.BlockSpec((1, kv_heads, padded_rows, out_depth),
                           lambda s, *_: (s, 0, 0, 0),
                           memory_space=pltpu.VMEM)
     out = pl.pallas_call(
@@ -863,31 +921,32 @@ def paged_attention(q, pool_k, pool_v, layer, tables, positions):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(slots,),
-            in_specs=[q_spec,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=q_spec,
+            in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)
+                                 for _ in pools],
+            out_specs=o_spec,
             scratch_shapes=[
-                pltpu.VMEM((2, kv_heads, chunk, depth), pool_k.dtype),
-                pltpu.VMEM((2, kv_heads, chunk, depth), pool_v.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((2, kv_heads, chunk, depth), pool.dtype)
+                for pool in pools] + [
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
                 pltpu.SMEM((1,), jnp.int32),               # buffer parity
                 pltpu.VMEM((kv_heads, padded_rows, _STAT_LANES),
                            jnp.float32),                   # m
                 pltpu.VMEM((kv_heads, padded_rows, _STAT_LANES),
                            jnp.float32),                   # l
-                pltpu.VMEM((kv_heads, padded_rows, depth),
+                pltpu.VMEM((kv_heads, padded_rows, out_depth),
                            jnp.float32),                   # acc
             ]),
-        out_shape=jax.ShapeDtypeStruct(grouped.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            grouped.shape[:3] + (out_depth,), q.dtype),
         # the buffer parity and the copy in flight cross grid steps
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
+        name=None if pool_v is not None else "mla_paged_attention",
         interpret=_interpret(),
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       tables.reshape(-1).astype(jnp.int32), positions, live,
-      grouped, pool_k, pool_v)
-    return out[:, :, :rows].reshape(slots, heads, window, depth)
+      grouped, *pools)
+    return out[:, :, :rows].reshape(slots, heads, window, out_depth)
 
 
 # -- Ring attention (sequence parallel) -------------------------------------

@@ -90,6 +90,35 @@ def test_kernel_matches_einsum_oracle(kv_heads, repeats, window, dtype):
                                atol=TOLERANCE[dtype], rtol=0)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("window", [1, 4])
+@pytest.mark.parametrize("heads", [4, 16])
+def test_latent_kernel_matches_einsum_oracle(heads, window, dtype):
+    """A latent pool: no V leaf, one key head for every query head, a
+    row's first 24 of 32 values the value, a softmax scale of its own.
+    Same ragged cursors, garbage and idle slot as the K/V case."""
+    q, pool, _, tables, positions = _case(
+        1, heads, window, jnp.dtype(dtype), seed=heads * 10 + window)
+    value_dim, scale = 24, 0.31
+    assert paged_attention_takes(heads, window, DEPTH, pool.dtype,
+                                 value_dim=value_dim)
+    layer = jnp.int32(1)
+    out = paged_attention(q, pool, None, layer, tables, positions,
+                          sm_scale=scale, value_dim=value_dim)
+    oracle = paged_attention_reference(
+        q, pool, None, layer, tables, positions, sm_scale=scale,
+        value_dim=value_dim)
+    assert out.shape == q.shape[:3] + (value_dim,) and out.dtype == q.dtype
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all() and np.abs(out).max() < 10.0
+    np.testing.assert_allclose(out, np.asarray(oracle, np.float32),
+                               atol=TOLERANCE[dtype], rtol=0)
+    # the scale is read: the default 1/sqrt(depth) gives other numbers
+    other = paged_attention(q, pool, None, layer, tables, positions,
+                            value_dim=value_dim)
+    assert np.abs(np.asarray(other, np.float32) - out).max() > 1e-3
+
+
 def test_kernel_reads_the_layer_it_is_given():
     q, pool_k, pool_v, tables, positions = _case(2, 2, 1, jnp.float32, 5)
     outs = [np.asarray(paged_attention(q, pool_k, pool_v, jnp.int32(layer),
@@ -271,3 +300,127 @@ def test_on_the_chip_a_head_dim_off_the_lanes_keeps_the_einsum(
     assert not paged_attention_takes(32, 1, 64, "bfloat16")
     assert paged_attention_takes(32, 1, 128, "bfloat16")
     assert paged_attention_takes(32, 1, 256, "float32")
+
+
+# -- DeepSeek-V2's share at its served shapes (benchmark/configs/
+# deepseek_v2_ep4_l5.json): the three new kernels and the two programs
+# the engine runs, compiled for the v5e --------------------------------
+
+DSV2 = dict(slots=16, heads=128, block=32, max_blocks=256, blocks=4096,
+            layers=5, row=640, rank=512, d=5120, f=1536, held=40, k=6)
+
+
+@pytest.mark.parametrize("window", (1, 8))
+def test_latent_kernel_compiles_for_the_v5e_at_the_served_shape(
+        for_the_chip, window):
+    s = DSV2
+    assert paged_attention_takes(s["heads"], window, s["row"], "bfloat16",
+                                 value_dim=s["rank"])
+    assert not paged_attention_takes(s["heads"], 16, s["row"], "bfloat16",
+                                     value_dim=s["rank"])
+    pool = for_the_chip((s["layers"], s["blocks"], 1, s["block"],
+                         s["row"]), "bfloat16")
+    compiled = jax.jit(lambda q, pool, layer, tables, positions:
+                       paged_attention(q, pool, None, layer, tables,
+                                       positions, sm_scale=0.1,
+                                       value_dim=s["rank"])).lower(
+        for_the_chip((s["slots"], s["heads"], window, s["row"]),
+                     "bfloat16"),
+        pool, for_the_chip((), "int32"),
+        for_the_chip((s["slots"], s["max_blocks"]), "int32"),
+        for_the_chip((s["slots"],), "int32")).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert "mla_paged_attention" in compiled.as_text()
+    # the one leaf is read where it lies
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("tokens,temporary", [(16, 1 << 24), (8192, 3 << 29)])
+def test_expert_kernel_compiles_for_the_v5e_at_the_served_shape(
+        for_the_chip, monkeypatch, tokens, temporary):
+    """A decode step's 16 tokens and a prefill's 8192: the row buffer
+    holds every pair (8192 x 6 rows + a tile an expert: 1.2 GB of rows in
+    and out at the prefill), no weight is copied."""
+    from aiko_services_tpu.parallel import experts
+    monkeypatch.setattr(experts, "_interpret", lambda: False)
+    s = DSV2
+    weights = [for_the_chip((4, s["held"]) + shape, "bfloat16") for shape in
+               ((s["d"], s["f"]), (s["d"], s["f"]), (s["f"], s["d"]))]
+    compiled = jax.jit(experts.expert_ffn).lower(
+        for_the_chip((tokens, s["d"]), "bfloat16"), *weights,
+        for_the_chip((tokens, s["k"]), "int32"),
+        for_the_chip((tokens, s["k"]), "float32"),
+        for_the_chip((), "int32")).compile()
+    assert "moe_expert_ffn" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < temporary
+
+
+def test_latent_prefill_kernel_compiles_for_the_v5e(for_the_chip):
+    """128 heads score over 192 (padded to 256 lanes) and carry 128, at
+    the 8192 bucket: no L x L scores (34 GB in float32) anywhere."""
+    compiled = jax.jit(lambda q, k, v: attention.flash_attention(
+        q, k, v, causal=True, sm_scale=0.1)).lower(
+        for_the_chip((1, 128, 8192, 256), "bfloat16"),
+        for_the_chip((1, 128, 8192, 256), "bfloat16"),
+        for_the_chip((1, 128, 8192, 128), "bfloat16")).compile()
+    assert "mla_flash_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def _served_share(for_the_chip, monkeypatch):
+    import json
+    import pathlib
+    from aiko_services_tpu.models.configs import deepseek_v2_config
+    from aiko_services_tpu.parallel import experts
+    monkeypatch.setattr(experts, "_interpret", lambda: False)
+    published = json.loads((pathlib.Path(__file__).parent.parent
+                            / "benchmark/configs/deepseek_v2_ep4_l5.json"
+                            ).read_text())
+    config = deepseek_v2_config(published,
+                                published["serve"]["max_context"])
+    place = lambda tree: jax.tree_util.tree_map(       # noqa: E731
+        lambda leaf: for_the_chip(leaf.shape, leaf.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: init_params(config, jax.random.PRNGKey(0))))
+    pool = place(jax.eval_shape(lambda: init_paged_pool(
+        config, DSV2["blocks"], DSV2["block"])))
+    return config, params, pool, lambda *shape: for_the_chip(shape, "int32")
+
+
+def test_served_share_decode_step_copies_no_pool_and_no_expert(
+        for_the_chip, monkeypatch):
+    """DeepSeek-V2's share, 16 slots: the latent pool (one leaf, 640
+    values a position a layer) is updated where it lies, and the stacked
+    expert weights are read where they lie -- sliced by the layer scan
+    for the kernel they were copied, 1.9 GB a layer."""
+    s = DSV2
+    config, params, pool, int32 = _served_share(for_the_chip, monkeypatch)
+    assert set(pool) == {"kv"} and pool["kv"].shape[-1] == s["row"] <= 640
+    slots = s["slots"]
+    compiled = paged_decode_step.lower(
+        params, config, pool, int32(slots, s["max_blocks"]), int32(slots),
+        int32(slots, 1), int32(slots), int32(slots)).compile()
+    text = compiled.as_text()
+    assert "mla_paged_attention" in text and "moe_expert_ffn" in text
+    leaf = pool["kv"]
+    leaf_bytes = int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == leaf_bytes
+    assert memory.temp_size_in_bytes < 1 << 27
+    # weights 10.07 GB + pool 0.84 GB: what the chip holds
+    assert 10.8e9 < memory.argument_size_in_bytes < 11.0e9
+
+
+def test_served_share_prefill_fits_the_chip_at_the_8192_bucket(
+        for_the_chip, monkeypatch):
+    config, params, pool, int32 = _served_share(for_the_chip, monkeypatch)
+    compiled = paged_prefill.lower(
+        params, config, pool, int32(1, 8192), int32(DSV2["max_blocks"]),
+        int32()).compile()
+    text = compiled.as_text()
+    assert "mla_flash_attention" in text and "moe_expert_ffn" in text
+    memory = compiled.memory_analysis()
+    # 2.06 GB when written: q, k, v of 128 heads, the experts' row buffer
+    assert memory.temp_size_in_bytes < 5 << 29
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.0e9)

@@ -1190,6 +1190,17 @@ STORE_CASES = {
     "dense": ({}, 2e-5),
     "int8_kv": ({"kv_dtype": "int8"}, 2e-2),
     "experts": ({"n_experts": 4, "moe_capacity_factor": 4.0}, 2e-5),
+    # DeepSeek-V2's layer: latent attention (decompressed over the cache,
+    # absorbed over the pool), routed + shared experts with nothing
+    # dropped, a leading dense layer apart from the scanned stack
+    "latent_routed": ({
+        "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 8,
+        "qk_rope_head_dim": 4, "v_head_dim": 8, "rope_factor": 40.0,
+        "rope_original_max": 8, "rope_mscale": 0.707,
+        "rope_mscale_all_dim": 0.707, "top_k": 2, "n_routed_experts": 8,
+        "experts_held": (2, 6), "n_shared_experts": 1, "moe_d_ff": 16,
+        "n_groups": 4, "topk_groups": 2, "routed_scaling": 4.0,
+        "first_dense_layers": 1}, 2e-5),
 }
 
 
@@ -1238,7 +1249,8 @@ def test_a_layer_is_the_same_layer_over_every_store(case):
                 tables[row], np.int32(true_len))
             paged[row, true_len - 1] = int(first)
     for position in range(prompt_len, length):
-        pool, greedy = paged_decode_step(
+        # (routed experts hand their counts out third)
+        pool, greedy, *_ = paged_decode_step(
             params, config, pool, tables,
             np.full((rows,), position, np.int32),
             tokens[:, position:position + 1],

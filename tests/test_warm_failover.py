@@ -41,6 +41,12 @@ ELEMENTS = "aiko_services_tpu.elements"
 
 TINY = dict(vocab_size=64, n_layers=2, n_heads=2, n_kv_heads=2,
             d_model=32, d_ff=64, max_seq_len=64, dtype="float32")
+# DeepSeek-V2's layer on TINY: the pool is one leaf of latent rows
+LATENT = dict(q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=8,
+              qk_rope_head_dim=4, v_head_dim=8, top_k=2,
+              n_routed_experts=4, n_shared_experts=1, moe_d_ff=16,
+              n_groups=2, topk_groups=1, routed_scaling=2.0,
+              first_dense_layers=1)
 
 
 @pytest.fixture(autouse=True)
@@ -228,12 +234,15 @@ class TestCheckpointer:
 
 
 class TestRestore:
-    @pytest.mark.parametrize("kv_dtype", ("", "int8"))
-    def test_bit_identical_f32_and_int8(self, kv_dtype):
+    @pytest.mark.parametrize("fields", (
+        {"kv_dtype": ""}, {"kv_dtype": "int8"}, LATENT),
+        ids=("f32", "int8", "latent"))
+    def test_bit_identical_f32_and_int8(self, fields):
         """The tentpole invariant: a mid-decode crash restored from
         the keeper finishes BIT-IDENTICAL to an uncrashed run, for
-        both the f32 and int8 (codes + scales) pool layouts."""
-        config = TransformerConfig(**{**TINY, "kv_dtype": kv_dtype})
+        the f32, the int8 (codes + scales) and the latent (one leaf of
+        latent rows) pool layouts."""
+        config = TransformerConfig(**{**TINY, **fields})
         params = init_params(config, jax.random.PRNGKey(0))
         rng = np.random.default_rng(5)
         prompt = rng.integers(1, 64, size=11).astype(np.int32)
