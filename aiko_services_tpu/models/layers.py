@@ -8,7 +8,10 @@
 # framework machinery.
 #
 # Conventions: weights stored (in_features, out_features) so forward is
-# x @ w; attention heads live in the last-but-one axis (B, H, L, D);
+# x @ w (dense), except the projections into attention heads that a decode
+# step would otherwise re-lay out every layer, stored (out_features,
+# in_features) and contracted by head (dense_heads); attention heads live
+# in the last-but-one axis (B, H, L, D);
 # everything computes in the dtype of the incoming activations with f32
 # accumulation for matmuls and reductions.
 
@@ -24,6 +27,7 @@ __all__ = [
     "dense", "rms_norm", "layer_norm", "rotary_embedding", "apply_rotary",
     "yarn_frequencies", "yarn_mscale",
     "swiglu", "init_dense", "init_norm", "repeat_kv", "conv2d", "init_conv",
+    "dense_heads", "init_dense_t",
 ]
 
 
@@ -34,7 +38,18 @@ def init_dense(key, in_features: int, out_features: int,
                                     jnp.float32) * scale).astype(dtype)}
 
 
-def dense(params: dict, x):
+def init_dense_t(key, in_features: int, out_features: int,
+                 dtype=jnp.float32) -> dict:
+    """init_dense's numbers, bit for bit, held (out_features,
+    in_features): the orientation checkpoints publish, which dense_heads
+    contracts split by head."""
+    return {"w": init_dense(key, in_features, out_features, dtype)["w"].T}
+
+
+def _dense(equation: str, params: dict, x, scale_shape: tuple):
+    """einsum(equation, x, w) with float32 accumulation, then an int8
+    weight's per-output-channel scale, reshaped to `scale_shape` to
+    broadcast over the product, and the bias."""
     w = params["w"]
     if w.dtype == jnp.int8:
         # weight-only int8 (transformer.quantize_weights_int8): weights
@@ -43,15 +58,35 @@ def dense(params: dict, x):
         # in AFTER the f32 accumulation (scales factor out of the
         # contraction), so the matmul itself never sees a dequantized
         # copy in memory
-        out = jnp.einsum("...i,io->...o", x, w.astype(x.dtype),
+        out = jnp.einsum(equation, x, w.astype(x.dtype),
                          preferred_element_type=jnp.float32)
-        out = out * params["w_scale"].astype(jnp.float32)
+        out = out * params["w_scale"].reshape(scale_shape).astype(
+            jnp.float32)
     else:
-        out = jnp.einsum("...i,io->...o", x, w,
+        out = jnp.einsum(equation, x, w,
                          preferred_element_type=jnp.float32)
     if "b" in params:
         out = out + params["b"]
     return out.astype(x.dtype)
+
+
+def dense(params: dict, x):
+    """x @ w over a weight held (in, out); int8 scales (1, out)."""
+    return _dense("...i,io->...o", params, x, (-1,))
+
+
+def dense_heads(params: dict, x):
+    """x (B, L, in) through a projection into attention heads whose
+    weight, stored (heads * depth, in), is handed over split by head,
+    (heads, depth, in), and contracted on its last axis as it lies ->
+    (B, heads, L, depth); int8 scales (heads, depth, 1).  A layer scan's
+    slice of a stacked (layers, in, heads * depth) leaf, whose product is
+    split into heads and rotated, is copied transposed before its matmul
+    every layer of every decode step; this way the matmul reads the slice
+    once, in place (tests/test_paged_attention.py holds the compiled
+    step to that)."""
+    heads, depth, _ = params["w"].shape
+    return _dense("bli,hdi->bhld", params, x, (heads, 1, depth))
 
 
 def init_norm(features: int, dtype=jnp.float32) -> dict:

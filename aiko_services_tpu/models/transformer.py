@@ -35,8 +35,9 @@ from ..parallel.attention import (
     ring_attention, sp_decode_attention, ulysses_attention)
 from ..parallel.experts import expert_ffn
 from .layers import (
-    apply_rotary, dense, init_dense, init_norm, repeat_kv, rms_norm,
-    rotary_embedding, swiglu, yarn_frequencies, yarn_mscale)
+    apply_rotary, dense, dense_heads, init_dense, init_dense_t, init_norm,
+    repeat_kv, rms_norm, rotary_embedding, swiglu, yarn_frequencies,
+    yarn_mscale)
 
 __all__ = [
     "TransformerConfig", "init_params", "param_specs", "forward",
@@ -195,20 +196,28 @@ class TransformerConfig:
 # -- parameters -------------------------------------------------------------
 
 def _init_latent_attention(keys, config: TransformerConfig) -> dict:
-    """MLA's five projections and two inner norms.  wq_b's columns are a
-    head's [nope ; rope] queries, wkv_a's the latent then the shared
-    rotary key, wkv_b's a head's [nope keys ; values]."""
+    """MLA's projections and two inner norms.  wkv_a's columns are the
+    latent then the shared rotary key.  The published wq_b, (q_rank, a
+    head's [nope ; rope] queries), and wkv_b, (rank, a head's [nope keys ;
+    values]), are drawn whole, as published, and held as the operands the
+    decode step's matmuls read where they lie: wq_b (nope + rope, H,
+    q_rank), the keys' up-projection wk_b (H, nope, rank) and the values'
+    wv_b (H, rank, v), the two the absorbed step contracts."""
     d, heads, dtype = config.d_model, config.n_heads, config.jnp_dtype
     nope, rope = config.qk_nope_head_dim, config.qk_rope_head_dim
+    rank, q_rank = config.kv_lora_rank, config.q_lora_rank
+    wq_b = init_dense(keys[1], q_rank, heads * (nope + rope),
+                      dtype)["w"].reshape(q_rank, heads, -1)
+    wkv_b = init_dense(keys[3], rank, heads * (nope + config.v_head_dim),
+                       dtype)["w"].reshape(rank, heads, -1)
     return {
-        "wq_a": init_dense(keys[0], d, config.q_lora_rank, dtype),
-        "q_norm": init_norm(config.q_lora_rank, dtype),
-        "wq_b": init_dense(keys[1], config.q_lora_rank,
-                           heads * (nope + rope), dtype),
-        "wkv_a": init_dense(keys[2], d, config.kv_lora_rank + rope, dtype),
-        "kv_norm": init_norm(config.kv_lora_rank, dtype),
-        "wkv_b": init_dense(keys[3], config.kv_lora_rank,
-                            heads * (nope + config.v_head_dim), dtype),
+        "wq_a": init_dense(keys[0], d, q_rank, dtype),
+        "q_norm": init_norm(q_rank, dtype),
+        "wq_b": {"w": wq_b.transpose(2, 1, 0)},
+        "wkv_a": init_dense(keys[2], d, rank + rope, dtype),
+        "kv_norm": init_norm(rank, dtype),
+        "wk_b": {"w": wkv_b[..., :nope].transpose(1, 2, 0)},
+        "wv_b": {"w": wkv_b[..., nope:].transpose(1, 0, 2)},
         "wo": init_dense(keys[4], heads * config.v_head_dim, d, dtype),
     }
 
@@ -253,8 +262,9 @@ def _init_layer(key, config: TransformerConfig,
         layer = _init_latent_attention(keys, config)
     else:
         layer = {
-            "wq": init_dense(keys[0], d, config.n_heads * hd, dtype),
-            "wk": init_dense(keys[1], d, config.n_kv_heads * hd, dtype),
+            # (out, in): the decode step reads them in place (_by_head)
+            "wq": init_dense_t(keys[0], d, config.n_heads * hd, dtype),
+            "wk": init_dense_t(keys[1], d, config.n_kv_heads * hd, dtype),
             "wv": init_dense(keys[2], d, config.n_kv_heads * hd, dtype),
             "wo": init_dense(keys[3], config.n_heads * hd, d, dtype),
         }
@@ -336,6 +346,8 @@ def param_specs(config: TransformerConfig,
     lm_head=True adds the untied-output-head spec (checkpoint-loaded
     Llama-3-8B+ params carry one)."""
     column, row = P(None, "fsdp", "model"), P(None, "model", "fsdp")
+    # a column-parallel weight held (out, in): the same logical axes
+    column_t = P(None, "model", "fsdp")
     layer = {
         "attn_norm": {"scale": P(None, None)},
         "wo": {"w": row},
@@ -347,12 +359,13 @@ def param_specs(config: TransformerConfig,
         layer.update({
             "wq_a": {"w": P(None, "fsdp", None)},
             "q_norm": {"scale": P(None, None)},
-            "wq_b": {"w": P(None, None, "model")},
+            "wq_b": {"w": P(None, None, "model", None)},
             "wkv_a": {"w": P(None, "fsdp", None)},
             "kv_norm": {"scale": P(None, None)},
-            "wkv_b": {"w": P(None, None, "model")}})
+            "wk_b": {"w": P(None, "model", None, None)},
+            "wv_b": {"w": P(None, "model", None, None)}})
     else:
-        layer.update({"wq": {"w": column}, "wk": {"w": column},
+        layer.update({"wq": {"w": column_t}, "wk": {"w": column_t},
                       "wv": {"w": column}})
     dense_ffn = {"w_gate": {"w": column}, "w_up": {"w": column},
                  "w_down": {"w": row}}
@@ -386,7 +399,18 @@ def count_params(params) -> int:
 
 # -- weight-only int8 (serving decode) ---------------------------------------
 
-_DENSE_QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# the dense weights that quantize, each with the axis it is contracted over,
+# which its per-output-channel scale collapses: the last of wq and wk, held
+# (out, in), else the one before
+_DENSE_QUANT_AXES = {"wq": -1, "wk": -1, "wv": -2, "wo": -2,
+                     "w_gate": -2, "w_up": -2, "w_down": -2}
+
+
+def _quantized_axes(config: TransformerConfig) -> dict:
+    """_DENSE_QUANT_AXES of the leaves `config` has as dense weights: a
+    switch model's expert FFN stays unquantized."""
+    return {key: axis for key, axis in _DENSE_QUANT_AXES.items()
+            if config.n_experts == 0 or not key.startswith("w_")}
 
 
 def quantize_weights_int8(params: dict,
@@ -415,11 +439,9 @@ def quantize_weights_int8(params: dict,
             out["b"] = entry["b"]
         return out
 
-    dense_keys = (_DENSE_QUANT_KEYS[:4] if config.n_experts > 0
-                  else _DENSE_QUANT_KEYS)
     layers = dict(params["layers"])
-    for key in dense_keys:
-        layers[key] = quant(layers[key], axis=-2)
+    for key, axis in _quantized_axes(config).items():
+        layers[key] = quant(layers[key], axis=axis)
     quantized = dict(params)
     quantized["layers"] = layers
     quantized["embed"] = quant(params["embed"], axis=-1)
@@ -432,20 +454,18 @@ def quantized_param_specs(config: TransformerConfig,
                           lm_head: bool = False) -> dict:
     """param_specs + a spec per w_scale plane: same layout as its
     weight with the quantization axis (collapsed to 1 by keepdims)
-    unsharded -- -2 for dense per-output-channel scales, -1 for the
-    embed/lm_head per-row scales."""
+    unsharded -- the contracted axis for dense per-output-channel scales,
+    -1 for the embed/lm_head per-row scales."""
     def scale_spec(spec: P, axis: int) -> P:
         entries = list(tuple(spec))
         entries[axis] = None
         return P(*entries)
 
     specs = param_specs(config, lm_head=lm_head)
-    dense_keys = (_DENSE_QUANT_KEYS[:4] if config.n_experts > 0
-                  else _DENSE_QUANT_KEYS)
     layer = dict(specs["layers"])
-    for key in dense_keys:
+    for key, axis in _quantized_axes(config).items():
         layer[key] = dict(layer[key])
-        layer[key]["w_scale"] = scale_spec(layer[key]["w"], -2)
+        layer[key]["w_scale"] = scale_spec(layer[key]["w"], axis)
     specs["layers"] = layer
     for name in ("embed", "lm_head"):
         if name in specs:
@@ -507,28 +527,25 @@ def _project_qkv(config: TransformerConfig, layer, x, cos, sin):
     """x (B, L, d_model) -> rotated q (B, H, L, hd), rotated k and plain v
     (B, Hkv, L, hd)."""
     batch, length, _ = x.shape
-    hd = config.head_dim
-    q = dense(layer["wq"], x).reshape(
-        batch, length, config.n_heads, hd).transpose(0, 2, 1, 3)
-    k = dense(layer["wk"], x).reshape(
-        batch, length, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
     v = dense(layer["wv"], x).reshape(
-        batch, length, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
-    return apply_rotary(q, cos, sin), apply_rotary(k, cos, sin), v
+        batch, length, config.n_kv_heads, config.head_dim
+    ).transpose(0, 2, 1, 3)
+    return (apply_rotary(dense_heads(layer["wq"], x), cos, sin),
+            apply_rotary(dense_heads(layer["wk"], x), cos, sin), v)
 
 
 def _project_latent(config: TransformerConfig, layer, x, cos, sin):
     """MLA's projections.  x (B, L, d_model) -> q (B, H, L, nope + rope),
     its rotary slice rotated, and the one row a position leaves behind,
     (B, 1, L, latent_row): [RMSNorm(c_kv) ; RoPE(k_r) ; zeros to the
-    lanes] -- every head's key and, through wkv_b, its value.  No v."""
+    lanes] -- every head's key and, through wv_b, its value.  No v."""
     batch, length, _ = x.shape
     nope, rank = config.qk_nope_head_dim, config.kv_lora_rank
     rope = config.qk_rope_head_dim
     c_q = rms_norm(layer["q_norm"], dense(layer["wq_a"], x),
                    config.norm_eps)
-    q = dense(layer["wq_b"], c_q).reshape(
-        batch, length, config.n_heads, nope + rope).transpose(0, 2, 1, 3)
+    q = jnp.einsum("blr,dhr->bhld", c_q, layer["wq_b"]["w"],
+                   preferred_element_type=jnp.float32).astype(x.dtype)
     q = jnp.concatenate(
         [q[..., :nope], apply_rotary(q[..., nope:], cos, sin)], axis=-1)
     down = dense(layer["wkv_a"], x)[:, None]           # (B, 1, L, rank+rope)
@@ -540,28 +557,21 @@ def _project_latent(config: TransformerConfig, layer, x, cos, sin):
     return q, latent, None
 
 
-def _latent_up(config: TransformerConfig, layer) -> tuple:
-    """wkv_b as the keys' (rank, H, nope) and the values' (rank, H, v)
-    up-projections."""
-    w = layer["wkv_b"]["w"].reshape(
-        config.kv_lora_rank, config.n_heads,
-        config.qk_nope_head_dim + config.v_head_dim)
-    return w[..., :config.qk_nope_head_dim], w[..., config.qk_nope_head_dim:]
-
-
 def _latent_expand(config: TransformerConfig, layer, latent):
     """Decompress latent rows (B, 1, L, latent_row) to every head's
     k (B, H, L, nope + rope) and v (B, H, L, v)."""
     batch, _, length, _ = latent.shape
-    rank, nope = config.kv_lora_rank, config.qk_nope_head_dim
-    kv = dense(layer["wkv_b"], latent[:, 0, :, :rank]).reshape(
-        batch, length, config.n_heads, nope + config.v_head_dim
-    ).transpose(0, 2, 1, 3)
+    rank = config.kv_lora_rank
+    c_kv = latent[:, 0, :, :rank]
+    k_nope = jnp.einsum("blc,hnc->bhln", c_kv, layer["wk_b"]["w"],
+                        preferred_element_type=jnp.float32
+                        ).astype(latent.dtype)
+    v = jnp.einsum("blc,hcv->bhlv", c_kv, layer["wv_b"]["w"],
+                   preferred_element_type=jnp.float32).astype(latent.dtype)
     k_rope = jnp.broadcast_to(
         latent[..., rank:rank + config.qk_rope_head_dim],
         (batch, config.n_heads, length, config.qk_rope_head_dim))
-    return (jnp.concatenate([kv[..., :nope], k_rope], axis=-1),
-            kv[..., nope:])
+    return jnp.concatenate([k_nope, k_rope], axis=-1), v
 
 
 def _latent_flash(config: TransformerConfig, q, k, v):
@@ -861,14 +871,34 @@ def _embed(params: dict, config: TransformerConfig, tokens):
 _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
+def _by_head(config: TransformerConfig, stack: dict) -> dict:
+    """A stack of layers with wq and wk (and their int8 scales) as
+    (layers, heads, depth, in), what dense_heads contracts: the bytes of
+    the stored (layers, heads * depth, in), reshaped once, outside the
+    layer loop.  A reshape between the loop's slice of the stack and the
+    matmul makes XLA stage the slice whole through fast memory before
+    multiplying from there (45 + 12 us a layer for wq at Mistral-7B's
+    widths, where the matmul that reads the stack itself takes 45)."""
+    if "wq" not in stack:
+        return stack
+    stack = dict(stack)
+    for name, heads in (("wq", config.n_heads), ("wk", config.n_kv_heads)):
+        stack[name] = {
+            key: leaf.reshape(leaf.shape[0], heads, -1, leaf.shape[-1])
+            for key, leaf in stack[name].items()}
+    return stack
+
+
 def _scan_layers(config: TransformerConfig, step, carry, stack, extra):
     """jax.lax.scan of step(carry, (layer, extra[i])) over a stack of
-    layers.  The routed experts' weights do not ride the scan: a
+    layers, wq and wk split by head (_by_head).  The routed experts'
+    weights do not ride the scan: a
     kernel's operand that the loop slices out of the stack is copied
     whole every iteration (1.9 GB a layer at DeepSeek-V2's widths), so
     such a layer carries the whole stacked leaves and its index among
     them as layer["experts"], and the kernel reads the layer where it
     lies (parallel/experts.py)."""
+    stack = _by_head(config, stack)
     if not (config.top_k and "router" in stack):
         return jax.lax.scan(step, carry, (stack, extra))
     held = {name: stack[name] for name in _EXPERT_LEAVES}
@@ -1297,8 +1327,8 @@ def _attend_pool_latent(config: TransformerConfig, pool: dict, index,
     pool = {"kv": _write_window(pool["kv"], latent, index, write_blocks,
                                 write_offsets)}
     nope, rank = config.qk_nope_head_dim, config.kv_lora_rank
-    keys_up, values_up = _latent_up(config, layer)
-    absorbed = jnp.einsum("shwn,chn->shwc", q[..., :nope], keys_up,
+    absorbed = jnp.einsum("shwn,hnc->shwc", q[..., :nope],
+                          layer["wk_b"]["w"],
                           preferred_element_type=jnp.float32
                           ).astype(q.dtype)
     pad = config.latent_row - rank - config.qk_rope_head_dim
@@ -1310,7 +1340,7 @@ def _attend_pool_latent(config: TransformerConfig, pool: dict, index,
         value_dim=rank) else paged_attention_reference)
     weighted = attend(query, pool["kv"], None, index, tables, positions,
                       sm_scale=config.attention_scale, value_dim=rank)
-    return jnp.einsum("shwc,chv->shwv", weighted, values_up,
+    return jnp.einsum("shwc,hcv->shwv", weighted, layer["wv_b"]["w"],
                       preferred_element_type=jnp.float32
                       ).astype(q.dtype), pool
 

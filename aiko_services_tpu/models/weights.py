@@ -178,14 +178,15 @@ def open_checkpoint(paths):
 def llama_name_map(layer: int) -> dict:
     """HF tensor name -> (pytree path under layers, transpose?) for one
     decoder layer.  HF nn.Linear stores (out, in); this framework stores
-    (in, out) so matmuls read x @ w (layers.py:10-12)."""
+    (in, out) so matmuls read x @ w (layers.py:10-12), except wq and wk,
+    which it too holds (out, in) (layers.dense_heads)."""
     prefix = f"model.layers.{layer}."
     return {
         prefix + "input_layernorm.weight": (("attn_norm", "scale"), False),
         prefix + "post_attention_layernorm.weight": (
             ("mlp_norm", "scale"), False),
-        prefix + "self_attn.q_proj.weight": (("wq", "w"), True),
-        prefix + "self_attn.k_proj.weight": (("wk", "w"), True),
+        prefix + "self_attn.q_proj.weight": (("wq", "w"), False),
+        prefix + "self_attn.k_proj.weight": (("wk", "w"), False),
         prefix + "self_attn.v_proj.weight": (("wv", "w"), True),
         prefix + "self_attn.o_proj.weight": (("wo", "w"), True),
         prefix + "mlp.gate_proj.weight": (("w_gate", "w"), True),

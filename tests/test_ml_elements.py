@@ -595,7 +595,7 @@ def test_meshed_lm_defaults_to_megatron_param_sharding():
     element = pipeline.elements["lm"]
     wq = element.state["layers"]["wq"]["w"]
     assert not wq.sharding.is_fully_replicated
-    assert wq.sharding.spec == P(None, "fsdp", "model"), wq.sharding.spec
+    assert wq.sharding.spec == P(None, "model", "fsdp"), wq.sharding.spec
     process.terminate()
 
 
@@ -640,7 +640,7 @@ def test_restored_meshed_lm_keeps_megatron_sharding(tmp_path):
     restored.restore_checkpoint(checkpointer, step=1)
     element = restored.elements["lm"]
     wq = element.state["layers"]["wq"]["w"]
-    assert wq.sharding.spec == P(None, "fsdp", "model"), wq.sharding.spec
+    assert wq.sharding.spec == P(None, "model", "fsdp"), wq.sharding.spec
     # and the restored element still serves frames
     rq = queue.Queue()
     restored_stream = (restored.streams.get("s")

@@ -4,7 +4,9 @@
 # on the trash block, garbage past every cursor, GQA ratios, window sizes,
 # float32 and bfloat16.  One parametrised test, each case counted.
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -424,3 +426,87 @@ def test_served_share_prefill_fits_the_chip_at_the_8192_bucket(
     assert memory.temp_size_in_bytes < 5 << 29
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.0e9)
+
+
+# -- the decode step multiplies its weights where they lie -------------------
+#
+# A layer scan's slice of a stacked (layers, in, out) projection whose
+# product is then split into heads and rotated was copied transposed before
+# its matmul, every layer of every step: Mistral's wq and wk (35.9 + 9.1 us
+# a layer on the chip), DeepSeek-V2's wq_b, whose 75.5 MB slice was also
+# written to HBM first (77.8 MB of temporaries), and wkv_b.  Stored (out,
+# in) the copies go, but the slice is still staged whole through fast
+# memory before the matmul reads it from there (wq 45 + 12 us a layer where
+# the read alone is 45; wq_b 101 + 101).  So wq and wk are stored (out, in)
+# and ride the scan split by head (transformer._by_head), wq_b is stored
+# (nope + rope, H, q_rank) and wkv_b as the two operands of the absorbed
+# products, heads leading (transformer._init_latent_attention): each
+# matmul reads its slice of the stack in place, as wv, wo and the FFN's do.
+#
+# What the other programs still stage, compiled the same way (PR 33), so
+# that nobody need recompile to know.  _generate_compiled (the graph's LM,
+# batch 32, 16 + 32 tokens): the whole stack of wv, bf16[16,4096,1024],
+# transposed once a call in the entry computation, 134 MB (with wq and wk
+# it was 805 MB; temporaries 907 -> 236 MB), and wv's slice through fast
+# memory in the token loop.  The 4096-bucket paged_prefill: wv's slice
+# copied transposed every layer (bf16[1,4096,1024]), one 4096 x 4096 leaf
+# transposed asynchronously a layer and once in the entry computation,
+# about 0.05 ms of a 12 ms layer.  The share's 8192-bucket prefill stages
+# wq_b's slice (75.5 MB, 0.1 ms of a 70 ms layer) and re-lays it out inside
+# the matmul: a prefill is bound by the MXU and the step by bytes, so the
+# step decides a leaf's layout.
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_STAGED = re.compile(
+    r"%?([\w.\-]+) = \w+\[([\d,]+)\]\S* (copy|fusion|dynamic-slice)\(")
+
+
+def _staged_whole(text: str, element_counts: set) -> list:
+    """The instructions of a compiled program that give a layer's weights
+    a buffer of their own: a `copy`, or a slice of the stack (alone or as
+    a `...dynamic-slice_fusion`), whose result has one of `element_counts`
+    elements, as "computation: instruction".  Outside fusions only: what
+    is fused into a matmul's operand load moves nothing, and the
+    temporaries' size says so."""
+    found, computation = [], ""
+    for line in text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header:
+            computation = header.group(1)
+        staged = _STAGED.search(line)
+        if (staged and not computation.startswith("fused_computation")
+                and (staged.group(3) != "fusion"
+                     or "dynamic-slice" in staged.group(1))
+                and math.prod(map(int, staged.group(2).split(",")))
+                in element_counts):
+            found.append(f"{computation}: {line.strip()[:120]}")
+    return found
+
+
+@pytest.mark.parametrize("served", ["mistral7b_l16", "deepseek_v2_ep4_l5"])
+def test_served_decode_step_stages_no_projection_on_the_v5e(
+        for_the_chip, monkeypatch, served):
+    if served == "mistral7b_l16":
+        s = SERVED
+        config, params, pool, int32 = _served_model(for_the_chip)
+    else:
+        s = DSV2
+        config, params, pool, int32 = _served_share(for_the_chip,
+                                                    monkeypatch)
+    slots = s["slots"]
+    compiled = paged_decode_step.lower(
+        params, config, pool, int32(slots, s["max_blocks"]), int32(slots),
+        int32(slots, 1), int32(slots), int32(slots)).compile()
+    # a layer's share of every stacked weight, in the scanned stack and
+    # among the leading dense layers (staged, if at all, in the entry
+    # computation: one layer is no loop)
+    per_layer = {math.prod(leaf.shape[1:])
+                 for name in ("layers", "dense_layers") if name in params
+                 for leaf in jax.tree_util.tree_leaves(params[name])
+                 if leaf.ndim > 2}
+    assert 4096 * 4096 in per_layer or 24576 * 1536 in per_layer
+    staged = _staged_whole(compiled.as_text(), per_layer)
+    assert not staged, staged
+    # 1.45 MB and 2.3 MB when written; the share's was 77.8 MB, wq_b's
+    # slice of the stack materialised in HBM before its transposed copy
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20

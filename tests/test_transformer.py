@@ -1271,3 +1271,146 @@ def test_a_layer_is_the_same_layer_over_every_store(case):
             np.asarray(leaf, np.float32)[:, :, :, :length],
             atol=1 if leaf.dtype == jnp.int8 else 2e-5, rtol=0,
             err_msg=name)
+
+
+# -- a stored leaf is the published-orientation draw, re-laid out -------------
+#
+# The projections a decode step would otherwise copy transposed every layer
+# (PR 33) are stored in the layout the step's matmul reads: wq and wk as
+# (out, in), the latent wq_b as (nope + rope, H, q_rank), the
+# published wkv_b as its keys' half (H, nope, rank) and its values' half
+# (H, rank, v).  init_params draws each in the published orientation
+# (in, out) from the key it always had and then transposes or splits:
+# benchmark/reference/ draws the same numbers itself.
+
+LATENT = {"q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 8,
+          "qk_rope_head_dim": 4, "v_head_dim": 8}
+
+
+def _as_stored(name: str, drawn, config: TransformerConfig):
+    """The (in, out) draw of leaf `name` in the layout it is stored in."""
+    if name in ("wq", "wk"):
+        return drawn.T
+    if name in ("wq_b", "wk_b", "wv_b"):
+        nope = config.qk_nope_head_dim
+        per_head = drawn.reshape(drawn.shape[0], config.n_heads, -1)
+        return {"wq_b": per_head.transpose(2, 1, 0),
+                "wk_b": per_head[..., :nope].transpose(1, 2, 0),
+                "wv_b": per_head[..., nope:].transpose(1, 0, 2)}[name]
+    return drawn
+
+
+def _published_draws(config: TransformerConfig) -> dict:
+    """leaf -> (index among a layer's keys, in, out) as init_params and
+    the references draw it; wk_b and wv_b are halves of one draw."""
+    d, heads, ff = config.d_model, config.n_heads, config.d_ff
+    if not config.kv_lora_rank:
+        hd, kv = config.head_dim, config.n_kv_heads
+        return {"wq": (0, d, heads * hd), "wk": (1, d, kv * hd),
+                "wv": (2, d, kv * hd), "wo": (3, heads * hd, d),
+                "w_gate": (4, d, ff), "w_up": (5, d, ff),
+                "w_down": (6, ff, d)}
+    nope, rope = config.qk_nope_head_dim, config.qk_rope_head_dim
+    rank, v = config.kv_lora_rank, config.v_head_dim
+    wkv_b = (3, rank, heads * (nope + v))
+    return {"wq_a": (0, d, config.q_lora_rank),
+            "wq_b": (1, config.q_lora_rank, heads * (nope + rope)),
+            "wkv_a": (2, d, rank + rope), "wk_b": wkv_b, "wv_b": wkv_b,
+            "wo": (4, heads * v, d), "w_gate": (5, d, ff),
+            "w_up": (6, d, ff), "w_down": (7, ff, d)}
+
+
+@pytest.mark.parametrize("kind,name", [
+    (kind, name) for kind, fields in (("dense", {}), ("latent", LATENT))
+    for name in _published_draws(dataclasses.replace(CONFIG, **fields))])
+def test_a_stored_projection_is_its_published_draw_exactly(kind, name):
+    from aiko_services_tpu.models.layers import init_dense
+    config = dataclasses.replace(CONFIG, **(LATENT if kind == "latent"
+                                            else {}))
+    seed = jax.random.PRNGKey(11)
+    stored = init_params(config, seed)["layers"][name]["w"]
+    index, rows, cols = _published_draws(config)[name]
+    _, *layer_keys = jax.random.split(seed, config.n_layers + 1)
+    for layer, layer_key in enumerate(layer_keys):
+        keys = jax.random.split(layer_key, 12 if kind == "latent" else 8)
+        drawn = init_dense(keys[index], rows, cols, config.jnp_dtype)["w"]
+        np.testing.assert_array_equal(
+            np.asarray(stored[layer]),
+            np.asarray(_as_stored(name, drawn, config)))
+
+
+def test_the_dense_model_is_the_plain_reference():
+    """benchmark/reference/transformer.py draws its weights itself, in
+    the published orientation: the stored leaves are those numbers (wq
+    and wk transposed) and the forward pass gives its logits."""
+    from benchmark.reference import transformer as reference
+    published = {
+        "vocab_size": CONFIG.vocab_size, "hidden_size": CONFIG.d_model,
+        "num_hidden_layers": CONFIG.n_layers,
+        "num_attention_heads": CONFIG.n_heads,
+        "num_key_value_heads": CONFIG.n_kv_heads,
+        "intermediate_size": CONFIG.d_ff, "head_dim": CONFIG.head_dim,
+        "rope_theta": CONFIG.rope_theta, "rms_norm_eps": CONFIG.norm_eps,
+        "torch_dtype": "float32"}
+    shape = reference.shape_of(published)
+    seed = 2 ** 31 + 77
+    params = init_params(CONFIG, jax.random.PRNGKey(seed))
+    _, layer_keys = reference._keys(shape, seed)
+    for index, key in enumerate(layer_keys):
+        for name, value in reference._layer_weights(key, shape).items():
+            np.testing.assert_array_equal(
+                np.asarray(_as_stored(name, value, CONFIG)),
+                np.asarray(params["layers"][name]["w"][index]), name)
+    tokens = np.random.default_rng(3).integers(
+        1, CONFIG.vocab_size, (2, 40)).astype(np.int32)
+    with jax.default_matmul_precision("highest"):
+        ours = np.asarray(forward(params, CONFIG, tokens))
+    theirs = np.asarray(reference.logits_at(
+        shape, seed, tokens, np.tile(np.arange(40), (2, 1))))
+    assert np.abs(ours - theirs).max() <= 1e-4 * np.abs(theirs).max()
+
+
+@pytest.mark.parametrize("name", ["wq", "wk", "wv", "wo", "w_gate", "w_up",
+                                  "w_down"])
+def test_int8_scales_are_one_an_output_channel(name):
+    """Whichever way a leaf is held, quantize_weights_int8 keeps one
+    scale for each output channel (the largest magnitude among the
+    inputs it sums, over 127), at the weight's rank with the contracted
+    axis collapsed, and the scale's spec leaves that axis unsharded."""
+    from aiko_services_tpu.models import (
+        quantize_weights_int8, quantized_param_specs)
+    params = _params()
+    entry = quantize_weights_int8(params, CONFIG)["layers"][name]
+    _, rows, cols = _published_draws(CONFIG)[name]
+    held_out_in = name in ("wq", "wk")
+    contracted = -1 if held_out_in else -2
+    weight = np.asarray(params["layers"][name]["w"])
+    assert weight.shape == ((CONFIG.n_layers, cols, rows) if held_out_in
+                            else (CONFIG.n_layers, rows, cols))
+    expected = np.abs(weight).max(axis=contracted, keepdims=True) / 127.0
+    assert expected.size == CONFIG.n_layers * cols
+    assert entry["w"].dtype == jnp.int8
+    np.testing.assert_allclose(np.asarray(entry["w_scale"]), expected,
+                               rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(entry["w"], np.float32) * expected, weight,
+        atol=float(expected.max()) * 0.5 + 1e-7, rtol=0)
+    specs = quantized_param_specs(CONFIG)["layers"][name]
+    assert tuple(specs["w_scale"])[contracted] is None
+    kept = [axis for axis in range(3) if axis != 3 + contracted]
+    assert ([tuple(specs["w_scale"])[axis] for axis in kept]
+            == [tuple(specs["w"])[axis] for axis in kept])
+
+
+def test_a_meshed_projection_shards_the_axes_it_did():
+    """(out, in) or (in, out), a column-parallel projection's outputs are
+    split over "model" and its inputs over "fsdp"; the latent model's
+    up-projections split by head."""
+    dense, latent = param_specs(CONFIG)["layers"], param_specs(
+        dataclasses.replace(CONFIG, **LATENT))["layers"]
+    assert dense["wq"]["w"] == dense["wk"]["w"] == P(None, "model", "fsdp")
+    assert dense["wv"]["w"] == P(None, "fsdp", "model")
+    assert latent["wq_b"]["w"] == P(None, None, "model", None)
+    assert latent["wk_b"]["w"] == latent["wv_b"]["w"] == P(
+        None, "model", None, None)
+    assert "wkv_b" not in latent

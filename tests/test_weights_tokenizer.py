@@ -104,15 +104,34 @@ def test_load_llama_params_shapes_and_orientation(tmp_path):
     hd = config.head_dim
     assert params["embed"]["w"].shape == (config.vocab_size, config.d_model)
     assert params["layers"]["wq"]["w"].shape == (
-        config.n_layers, config.d_model, config.n_heads * hd)
-    # orientation: our wq.w must be the transpose of HF q_proj
-    np.testing.assert_allclose(
-        np.asarray(params["layers"]["wq"]["w"][0]),
-        tensors["model.layers.0.self_attn.q_proj.weight"].T, rtol=1e-6)
+        config.n_layers, config.n_heads * hd, config.d_model)
+    assert params["layers"]["wk"]["w"].shape == (
+        config.n_layers, config.n_kv_heads * hd, config.d_model)
+    assert params["layers"]["wv"]["w"].shape == (
+        config.n_layers, config.d_model, config.n_kv_heads * hd)
     # loaded params run end-to-end
     logits = forward(params, config, jnp.ones((1, 4), jnp.int32))
     assert logits.shape == (1, 4, config.vocab_size)
     assert bool(jnp.isfinite(logits).all())
+
+
+@pytest.mark.parametrize("ours,theirs,transposed", [
+    ("wq", "self_attn.q_proj", False), ("wk", "self_attn.k_proj", False),
+    ("wv", "self_attn.v_proj", True), ("wo", "self_attn.o_proj", True),
+    ("w_gate", "mlp.gate_proj", True), ("w_up", "mlp.up_proj", True),
+    ("w_down", "mlp.down_proj", True)])
+def test_load_llama_leaf_orientation(tmp_path, ours, theirs, transposed):
+    """wq and wk are held as the checkpoint holds q_proj and k_proj,
+    (out, in), the layout the decode step's matmul reads; every other
+    projection is its tensor's transpose, (in, out)."""
+    config = _tiny_config()
+    path = tmp_path / "model.safetensors"
+    tensors = _write_hf_llama(path, config)
+    leaf = np.asarray(load_llama_params(path, config)["layers"][ours]["w"])
+    for layer in range(config.n_layers):
+        tensor = tensors[f"model.layers.{layer}.{theirs}.weight"]
+        np.testing.assert_array_equal(
+            leaf[layer], tensor.T if transposed else tensor)
 
 
 def test_load_llama_untied_head_changes_logits(tmp_path):
