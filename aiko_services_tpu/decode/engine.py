@@ -129,9 +129,12 @@ class _InFlight(NamedTuple):
     """A decode step dispatched and not yet read."""
     tokens: object   # its next_tokens (slots, 1), on the device
     rows: dict       # {slot index: slot seq} of the rows that count
-    # what a routed-expert model's step counted, int32 (2,) on the
-    # device: distinct held experts read, token-expert pairs computed
-    experts: object = None
+    # what the step counted beside its tokens, on the device, in the
+    # order paged_decode_step hands them out: a routed-expert model's
+    # int32 (2,), distinct held experts read and token-expert pairs
+    # computed; a looped stack's float32 (slots,), each slot's expected
+    # exit pass
+    counted: tuple = ()
 
 
 class _Slot:
@@ -290,11 +293,15 @@ class DecodeEngine:
                          "decode_steps": 0, "steps_ahead": 0,
                          "overrun_tokens": 0,
                          "experts_read": 0, "expert_pairs": 0,
-                         "latent_positions": 0}
-        # `experts_read`/`expert_pairs` of the newest decode step read
-        # back, as the next `engine.decode` span carries them; empty
-        # for a model without routed experts
-        self._experts_seen: dict = {}
+                         "latent_positions": 0,
+                         "ut_passes": 0, "cache_rows": 0,
+                         "exit_expected_step": 0.0}
+        # what the device counted in the newest decode step read back
+        # (`experts_read`/`expert_pairs` of routed experts,
+        # `exit_expected_step` of a looped stack), as the next
+        # `engine.decode` span carries them; empty for a model with
+        # neither
+        self._counted_seen: dict = {}
         self._update_gauges()
 
     def warm(self, buckets=()) -> None:
@@ -747,7 +754,7 @@ class DecodeEngine:
         readback and bookkeeping overlap this one."""
         ahead = self._inflight is not None
         with self._spans.span("engine.decode", decoding=len(decoding),
-                              ahead=int(ahead), **self._experts_seen,
+                              ahead=int(ahead), **self._counted_seen,
                               **self._walked(self.positions, 1,
                                              decoding)):
             write_blocks = np.zeros((self.slots_n,), np.int32)
@@ -764,8 +771,9 @@ class DecodeEngine:
             tokens = (self._inflight.tokens if ahead
                       else jnp.asarray(self.last_tokens.copy()))
             before = _jit_cache_size()
-            # a model with routed experts hands its counts out third
-            self.pool, next_tokens, *experts = paged_decode_step(
+            # routed experts and a looped stack hand their counts out
+            # after the tokens
+            self.pool, next_tokens, *counted = paged_decode_step(
                 self.params, self.config, self.pool, self.tables.copy(),
                 self.positions.copy(), tokens, write_blocks,
                 write_offsets)
@@ -776,7 +784,7 @@ class DecodeEngine:
         self.counters["decode_steps"] += 1
         self.counters["steps_ahead"] += ahead
         self.settle(report)
-        self._inflight = _InFlight(next_tokens, rows, *experts)
+        self._inflight = _InFlight(next_tokens, rows, tuple(counted))
 
     def _ends_in_flight(self, index: int) -> bool:
         """Slot `index`, as it is held now, has a token in the step
@@ -798,19 +806,25 @@ class DecodeEngine:
         cancel or a preemption, settles first)."""
         if self._inflight is None:
             return
-        (next_tokens, rows, experts), self._inflight = self._inflight, None
+        (next_tokens, rows, counted), self._inflight = self._inflight, None
         if report is None:
             report = self._carry
         with self._spans.span("engine.readback"):
             next_tokens = np.asarray(next_tokens)
-            if experts is not None:
-                # the step's own counts, read with its tokens; the next
-                # `engine.decode` span to open carries them
-                read, pairs = (int(count) for count in np.asarray(experts))
-                self._experts_seen = {"experts_read": read,
-                                      "expert_pairs": pairs}
-                self.counters["experts_read"] += read
-                self.counters["expert_pairs"] += pairs
+            # the step's own counts, read with its tokens; the next
+            # `engine.decode` span to open carries them
+            counted = [np.asarray(count) for count in counted]
+        if self.config.top_k:
+            read, pairs = (int(count) for count in counted.pop(0))
+            self._counted_seen.update(experts_read=read, expert_pairs=pairs)
+            self.counters["experts_read"] += read
+            self.counters["expert_pairs"] += pairs
+        if self.config.ut_steps > 1:
+            # mean over the rows that decoded of the exit gate's
+            # expected pass; the running sum is of these means, a step
+            expected = float(counted.pop(0)[list(rows)].mean())
+            self._counted_seen["exit_expected_step"] = round(expected, 4)
+            self.counters["exit_expected_step"] += expected
         for index, seq in rows.items():
             slot = self.slots[index]
             if slot is None or slot.seq != seq:
@@ -938,6 +952,10 @@ class DecodeEngine:
             fields = {"attention": attention}
         else:
             fields = self._walked(np.array([start]), bucket)
+        # the rows this call leaves behind and attends over
+        fields.update(self._looped(
+            slot.true_len if start is None
+            else min(start + bucket, slot.true_len)))
         return self._spans.span(
             "engine.prefill", request.request_id, bucket=bucket,
             true_len=slot.true_len,
@@ -951,8 +969,8 @@ class DecodeEngine:
         `table_blocks`, what the tables can name (what the table-wide
         gather read); over a latent pool a decode step (`decoding`: its
         slots) also `latent_positions`, the live rows those slots'
-        attention reads, this step's own among them.  Their running
-        sums ride `stats()`."""
+        attention reads, this step's own among them, and of a looped
+        stack `_looped`'s.  Their running sums ride `stats()`."""
         walked = {
             "live_blocks": int(paged_live_blocks(
                 positions, window, self.blocks.block_size,
@@ -963,7 +981,25 @@ class DecodeEngine:
                 positions[decoding].sum()) + len(decoding) * window
         for name, count in walked.items():
             self.counters[name] += count
+        if decoding is not None:
+            walked.update(self._looped(
+                int(positions[decoding].sum()) + len(decoding) * window))
         return walked
+
+    def _looped(self, positions: int) -> dict:
+        """The span fields a looped stack adds to a call that attends
+        over `positions` live positions (none for a stack of one pass):
+        `ut_passes`, the passes the program ran (its config's), and
+        `cache_rows`, those positions in every one of the model's caches
+        (n_layers x ut_steps: each row 2 x kv_heads x head_dim values).
+        Running sums in `stats()`."""
+        if self.config.ut_steps == 1:
+            return {}
+        fields = {"ut_passes": self.config.ut_steps,
+                  "cache_rows": positions * self.config.n_caches}
+        for name, count in fields.items():
+            self.counters[name] += count
+        return fields
 
     def _tail_prefill(self, index: int, report: StepReport) -> None:
         """Prefill ONLY the uncached tail of a prefix-cache hit in one
@@ -1178,7 +1214,7 @@ class DecodeEngine:
                         step_blocks[index] = self.draft_tables[
                             index, position // block_size]
                         step_offsets[index] = position % block_size
-                self.draft_pool, current = paged_decode_step(
+                self.draft_pool, current, *_ = paged_decode_step(
                     self.draft_params, self.draft_config, self.draft_pool,
                     self.draft_tables, self.draft_positions, current,
                     step_blocks, step_offsets)

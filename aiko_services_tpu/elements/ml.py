@@ -63,15 +63,17 @@ def _transformer_config(element) -> TransformerConfig:
     published = element.get_parameter("model")
     if published:
         # a published config.json's keys, whole (benchmark/configs/
-        # deepseek_v2_*.json): norm_eps, rope_theta and the YaRN keys
-        # reach the model from the file
-        if published.get("model_type") != "deepseek_v2":
+        # deepseek_v2_*.json, ouro_*.json): norm_eps, rope_theta, the
+        # YaRN keys, the passes reach the model from the file
+        reader = model_configs.PUBLISHED_READERS.get(
+            published.get("model_type"))
+        if reader is None:
             raise ValueError(
                 f"model: model_type {published.get('model_type')!r} has "
-                f"no reader (models/configs.py has deepseek_v2's)")
-        return model_configs.deepseek_v2_config(
-            published, element.get_parameter("max_seq_len"),
-            element.get_parameter("dtype"))
+                f"no reader (models/configs.py has "
+                f"{sorted(model_configs.PUBLISHED_READERS)})")
+        return reader(published, element.get_parameter("max_seq_len"),
+                      element.get_parameter("dtype"))
     preset = element.get_parameter("preset")
     if preset:
         config = _LM_PRESETS[str(preset)]

@@ -18,7 +18,8 @@ from .transformer import TransformerConfig
 __all__ = [
     "LLAMA3_8B", "LLAMA32_1B", "LM_TOY",
     "WHISPER_TINY", "WHISPER_SMALL",
-    "YOLOV8N_SHAPE", "DETECTOR_TOY", "deepseek_v2_config",
+    "YOLOV8N_SHAPE", "DETECTOR_TOY", "deepseek_v2_config", "ouro_config",
+    "PUBLISHED_READERS",
     "transformer_flops_per_token", "asr_flops_per_example",
     "tts_flops_per_example",
     "detector_flops_per_image",
@@ -99,6 +100,53 @@ def deepseek_v2_config(published: dict, max_seq_len: int | None = None,
         topk_groups=int(published["topk_group"]),
         routed_scaling=float(published["routed_scaling_factor"]),
         first_dense_layers=int(published["first_k_dense_replace"]))
+
+
+def ouro_config(published: dict, max_seq_len: int | None = None,
+                dtype: str | None = None) -> TransformerConfig:
+    """TransformerConfig from Ouro's published config.json keys
+    (huggingface.co/ByteDance/Ouro-2.6B), every one under its own name:
+    a dense decoder whose stack runs `total_ut_steps` times a token,
+    K/V of its own for every pass, sublayer outputs normed, and an exit
+    gate held to `early_exit_threshold`.  Keys whose mechanism is not
+    implemented are refused by name."""
+    unsupported = {
+        "model_type": "ouro", "hidden_act": "silu", "sliding_window": None,
+        "rope_scaling": None, "use_sliding_window": False}
+    for key, value in unsupported.items():
+        if published.get(key, value) != value:
+            raise ValueError(f"ouro: {key}={published[key]!r} is not "
+                             f"implemented (only {value!r})")
+    layers = int(published["num_hidden_layers"])
+    kinds = published.get("layer_types") or ["full_attention"] * layers
+    if len(kinds) != layers or set(kinds) != {"full_attention"}:
+        raise ValueError(
+            f"ouro: layer_types must be {layers} x 'full_attention', got "
+            f"{len(kinds)} of {sorted(set(kinds))}")
+    heads = int(published["num_attention_heads"])
+    d_model = int(published["hidden_size"])
+    if int(published.get("head_dim", d_model // heads)) * heads != d_model:
+        raise ValueError(
+            f"ouro: head_dim={published['head_dim']} is not hidden_size "
+            f"{d_model} / {heads} heads (the only head size implemented)")
+    return TransformerConfig(
+        vocab_size=int(published["vocab_size"]), d_model=d_model,
+        n_layers=layers, n_heads=heads,
+        n_kv_heads=int(published["num_key_value_heads"]),
+        d_ff=int(published["intermediate_size"]),
+        max_seq_len=int(max_seq_len
+                        or published["max_position_embeddings"]),
+        rope_theta=float(published["rope_theta"]),
+        norm_eps=float(published["rms_norm_eps"]),
+        dtype=str(dtype or published.get("torch_dtype", "bfloat16")),
+        ut_steps=int(published["total_ut_steps"]),
+        exit_threshold=float(published["early_exit_threshold"]),
+        sandwich_norm=True)
+
+
+# model_type of a published config.json -> its reader (elements/ml.py
+# hands LMGenerate's `model` parameter to it whole)
+PUBLISHED_READERS = {"deepseek_v2": deepseek_v2_config, "ouro": ouro_config}
 
 
 # small config for hermetic tests / CPU runs

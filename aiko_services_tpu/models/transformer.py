@@ -131,6 +131,20 @@ class TransformerConfig:
     topk_groups: int = 1
     routed_scaling: float = 1.0
     first_dense_layers: int = 0
+    # -- a looped stack (Ouro): ut_steps > 1 ----------------------------
+    # The whole stack runs ut_steps times a token on the same weights.
+    # Every pass keeps K/V of its own (n_caches = n_layers x ut_steps
+    # caches, _cache_index), norm_out closes every pass and its output
+    # opens the next, and an exit gate reads each pass's output: the
+    # logits are those of the first pass by which the gate's exit
+    # probabilities sum to exit_threshold, else of the last (_exit_pass).
+    # Every pass is computed whichever is chosen: a later token attends
+    # over this one's rows of every pass.
+    ut_steps: int = 1
+    exit_threshold: float = 1.0
+    # a sublayer's OUTPUT is normed too, before the residual add:
+    # h + norm(attention(norm(h))), h + norm(FFN(norm(h)))
+    sandwich_norm: bool = False
 
     def __post_init__(self):
         if self.sp_mechanism not in ("ring", "ulysses"):
@@ -158,10 +172,21 @@ class TransformerConfig:
             raise ValueError(
                 f"{self.n_routed_experts} routed experts do not divide "
                 f"into {self.n_groups} groups")
+        if self.ut_steps < 1:
+            raise ValueError(f"ut_steps must be >= 1, got {self.ut_steps}")
+        if self.ut_steps > 1 and self.sequence_parallel:
+            raise ValueError(
+                "a looped stack keeps its caches on one device (no "
+                "sequence_parallel)")
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def n_caches(self) -> int:
+        """K/V caches a position leaves behind: one a layer a pass."""
+        return self.n_layers * self.ut_steps
 
     @property
     def rotary_dim(self) -> int:
@@ -270,6 +295,9 @@ def _init_layer(key, config: TransformerConfig,
         }
     layer["attn_norm"] = init_norm(d, dtype)
     layer["mlp_norm"] = init_norm(d, dtype)
+    if config.sandwich_norm:
+        layer["attn_out_norm"] = init_norm(d, dtype)
+        layer["mlp_out_norm"] = init_norm(d, dtype)
     if routed:
         layer.update(_init_routed_ffn(ffn_keys, config))
         return layer
@@ -335,6 +363,12 @@ def init_params(config: TransformerConfig, key) -> dict:
     if lead:
         params["dense_layers"] = _stack_layers(
             [_init_layer(k, config) for k in layer_keys[:lead]])
+    if config.ut_steps > 1:
+        # the exit gate: one logit a pass from the pass's normed output
+        params["exit_gate"] = {
+            "w": init_dense(jax.random.fold_in(key, config.n_layers + 1),
+                            config.d_model, 1, config.jnp_dtype)["w"][:, 0],
+            "b": jnp.zeros((), config.jnp_dtype)}
     return params
 
 
@@ -353,6 +387,9 @@ def param_specs(config: TransformerConfig,
         "wo": {"w": row},
         "mlp_norm": {"scale": P(None, None)},
     }
+    if config.sandwich_norm:
+        layer["attn_out_norm"] = {"scale": P(None, None)}
+        layer["mlp_out_norm"] = {"scale": P(None, None)}
     if config.kv_lora_rank:
         # the low-rank projections in are replicated across "model" (one
         # latent serves every head); those out of them split by head
@@ -390,6 +427,8 @@ def param_specs(config: TransformerConfig,
         specs["layers"] = dict(layer, **dense_ffn)
     if lm_head:
         specs["lm_head"] = {"w": P(None, "fsdp")}
+    if config.ut_steps > 1:
+        specs["exit_gate"] = {"w": P(None), "b": P()}
     return specs
 
 
@@ -482,9 +521,9 @@ def init_cache(config: TransformerConfig, batch: int,
     if config.kv_lora_rank:
         # one leaf: a position's latent and shared rotary key, which is
         # key and value of every head (_project_latent)
-        return {"kv": jnp.zeros((config.n_layers, batch, 1, max_len,
+        return {"kv": jnp.zeros((config.n_caches, batch, 1, max_len,
                                  config.latent_row), config.jnp_dtype)}
-    shape = (config.n_layers, batch, config.n_kv_heads, max_len,
+    shape = (config.n_caches, batch, config.n_kv_heads, max_len,
              config.head_dim)
     if config.kv_dtype == "int8":
         scale_shape = shape[:-1] + (1,)
@@ -585,7 +624,8 @@ def _latent_flash(config: TransformerConfig, q, k, v):
 
 def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend):
     """THE decoder layer, on every path: attention norm, projections and
-    rotary, `attend`, wo and residual, MLP norm, FFN, residual.  Only
+    rotary, `attend`, wo and residual, MLP norm, FFN, residual (under
+    sandwich_norm each sublayer's output normed before its add).  Only
     `attend` differs, by where the K/V live: attend(layer, q, k, v)
     stores the new K/V and returns (attention output (B, H, L, hd), the
     store's new leaves) -- _attend_fresh, _attend_cache, _attend_pool.
@@ -598,10 +638,15 @@ def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend):
         config, layer, rms_norm(layer["attn_norm"], h, config.norm_eps),
         cos, sin)
     out, leaves = attend(layer, q, k, v)
-    h = h + dense(layer["wo"],
-                  out.transpose(0, 2, 1, 3).reshape(batch, length, -1))
+    out = dense(layer["wo"],
+                out.transpose(0, 2, 1, 3).reshape(batch, length, -1))
+    if config.sandwich_norm:
+        out = rms_norm(layer["attn_out_norm"], out, config.norm_eps)
+    h = h + out
     mlp_out, stats = _mlp_block(
         config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps))
+    if config.sandwich_norm:
+        mlp_out = rms_norm(layer["mlp_out_norm"], mlp_out, config.norm_eps)
     return h + mlp_out, stats, leaves
 
 
@@ -987,10 +1032,15 @@ def _mlp_block(config: TransformerConfig, layer, mlp_in):
 
 def _lm_head(params: dict, config: TransformerConfig, h):
     """Output norm + logits head shared by forward() and the paged
-    decode path.  Untied output head when the checkpoint ships one
-    (Llama-3-8B+, models/weights.py load_llama_params); tied embedding
-    otherwise."""
-    h = rms_norm(params["norm_out"], h, config.norm_eps)
+    decode path."""
+    return _head_logits(
+        params, rms_norm(params["norm_out"], h, config.norm_eps))
+
+
+def _head_logits(params: dict, h):
+    """The logits head over normed h.  Untied output head when the
+    checkpoint ships one (Llama-3-8B+, models/weights.py
+    load_llama_params); tied embedding otherwise."""
     head = params.get("lm_head", params["embed"])
     logits = jnp.einsum("bld,vd->blv", h.astype(jnp.float32),
                         head["w"].astype(jnp.float32))
@@ -1026,6 +1076,79 @@ def _layer_stacks(params: dict, config: TransformerConfig) -> list:
     lead = _leading_dense(config)
     stacks = [(params["dense_layers"], 0, lead)] if lead else []
     return stacks + [(params["layers"], lead, config.n_layers - lead)]
+
+
+def _cache_index(config: TransformerConfig, step, layer):
+    """Which of a position's n_caches K/V caches layer `layer` writes and
+    attends over on pass `step` of a looped stack: pass-major, a pass's
+    caches side by side (a model of one pass: the layer's own).  The
+    contiguous cache (forward), the pool (_paged_logits), paged_prefill's
+    scatter of the one into the other and benchmark/reference/ouro.py's
+    description are all laid out by this."""
+    return step * config.n_layers + layer
+
+
+def _run_passes(params: dict, config: TransformerConfig, carry,
+                scan_stack):
+    """The stacks of layers in the order they run, ut_steps times over.
+    scan_stack(carry, stack, first_cache, count) scans one stack once,
+    its layers on caches first_cache .. first_cache + count - 1, and
+    returns the carry, whose first entry is h.  A looped model closes
+    every pass with norm_out, whose output opens the next pass.
+    Returns (carry, the passes' normed outputs: [] for one pass, which
+    traces nothing it did not trace before there were passes)."""
+    outputs = []
+    for step in range(config.ut_steps):
+        for stack, first, count in _layer_stacks(params, config):
+            carry = scan_stack(carry, stack,
+                               _cache_index(config, step, first), count)
+        if config.ut_steps > 1:
+            outputs.append(rms_norm(params["norm_out"], carry[0],
+                                    config.norm_eps))
+            carry = (outputs[-1], *carry[1:])
+    return carry, outputs
+
+
+def _exit_pdf(params: dict, outputs):
+    """The exit gate over the passes' outputs [T x (..., d)], float32:
+    lam[t] = sigmoid(h[t] . w + b); p[t] = lam[t] prod_{s<t} (1 - lam[s])
+    for t < T - 1 and p[T-1] = prod_{s<T-1} (1 - lam[s]), the chance of
+    leaving after pass t.  Returns p (T, ...)."""
+    gate = params["exit_gate"]
+    lam = jax.nn.sigmoid(jnp.stack([
+        jnp.einsum("...d,d->...", h.astype(jnp.float32),
+                   gate["w"].astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+        for h in outputs]) + gate["b"].astype(jnp.float32))
+    # stayed[t] = prod_{s<t} (1 - lam[s]): still running when pass t ends
+    stayed = jnp.concatenate(
+        [jnp.ones_like(lam[:1]), jnp.cumprod(1.0 - lam, axis=0)[:-1]])
+    return jnp.concatenate([(lam * stayed)[:-1], stayed[-1:]])
+
+
+def _exit_pass(pdf, threshold: float):
+    """The pass each position leaves after: the first by which the exit
+    probabilities (T, ...) sum to `threshold`, else the last."""
+    reached = jnp.cumsum(pdf, axis=0) >= threshold
+    return jnp.where(reached.any(axis=0), jnp.argmax(reached, axis=0),
+                     pdf.shape[0] - 1)
+
+
+def _logits(params: dict, config: TransformerConfig, h, outputs):
+    """Logits from what _run_passes left.  One pass: output norm and
+    head over h.  A looped stack: the head over the output (normed
+    already) of each position's exit pass (at the published threshold 1
+    the last, but for a gate saturated in float32).  Returns (logits,
+    the exit's expected pass sum_t (t + 1) p[t] a position, float32;
+    None for one pass)."""
+    if not outputs:
+        return _lm_head(params, config, h), None
+    pdf = _exit_pdf(params, outputs)
+    chosen = _exit_pass(pdf, config.exit_threshold)
+    h = jnp.take_along_axis(jnp.stack(outputs), chosen[None, ..., None],
+                            axis=0)[0]
+    steps = jnp.arange(1, len(outputs) + 1, dtype=jnp.float32)
+    return _head_logits(params, h), jnp.tensordot(steps, pdf, axes=1)
 
 
 def forward(params: dict, config: TransformerConfig, tokens,
@@ -1078,36 +1201,38 @@ def forward(params: dict, config: TransformerConfig, tokens,
         return (h, stats_sum), new_cache
 
     carry = (h, jnp.zeros((_FFN_STATS,), jnp.float32))
-    stacks = _layer_stacks(params, config)
-    if cache is None:
-        body = layer_step
-        policy = resolve_remat_policy(remat_policy)
-        if policy is not None:
-            # remat over the scanned layer body: the standard trade --
-            # drop (policy-selected) activations in the forward pass,
-            # recompute them during backward.  prevent_cse=False is the
-            # documented setting under scan (the scan boundary already
-            # blocks the CSE that prevent_cse guards against).
-            body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-        for stack, _, _ in stacks:
-            carry, _ = _scan_layers(config, body, carry, stack, None)
-        new_cache = None
-    else:
-        written = []
-        for stack, first, count in stacks:
-            carry, part = _scan_layers(
-                config, layer_step, carry, stack,
-                cache if len(stacks) == 1 else jax.tree_util.tree_map(
-                    lambda leaf: leaf[first:first + count], cache))
-            written.append(part)
+    body = layer_step
+    policy = resolve_remat_policy(remat_policy)
+    if policy is not None:
+        # remat over the scanned layer body: the standard trade --
+        # drop (policy-selected) activations in the forward pass,
+        # recompute them during backward.  prevent_cse=False is the
+        # documented setting under scan (the scan boundary already
+        # blocks the CSE that prevent_cse guards against).
+        body = jax.checkpoint(body, policy=policy, prevent_cse=False)
+    written = []
+
+    def scan_stack(carry, stack, first, count):
+        if cache is None:
+            return _scan_layers(config, body, carry, stack, None)[0]
+        # one stack run once has the whole cache; else the caches this
+        # stack writes on this pass
+        carry, part = _scan_layers(
+            config, body, carry, stack,
+            cache if count == config.n_caches else jax.tree_util.tree_map(
+                lambda leaf: leaf[first:first + count], cache))
+        written.append(part)
+        return carry
+
+    (h, stats_sum), outputs = _run_passes(params, config, carry, scan_stack)
+    if cache is not None:
         new_cache = written[0] if len(written) == 1 else \
             jax.tree_util.tree_map(
                 lambda *parts: jnp.concatenate(parts), *written)
-    h, stats_sum = carry
-    logits = _lm_head(params, config, h)
-    if new_cache is None:
+    logits, _ = _logits(params, config, h, outputs)
+    if cache is None:
         if return_aux:
-            return logits, stats_sum[0] / max(config.n_layers, 1)
+            return logits, stats_sum[0] / max(config.n_caches, 1)
         return logits
     return logits, new_cache
 
@@ -1253,9 +1378,9 @@ def init_paged_pool(config: TransformerConfig, num_blocks: int,
     if config.kv_lora_rank:
         # the latent pool: one leaf, one row a position a layer
         return {"kv": jnp.zeros(
-            (config.n_layers, num_blocks, 1, block_size,
+            (config.n_caches, num_blocks, 1, block_size,
              config.latent_row), config.jnp_dtype)}
-    shape = (config.n_layers, num_blocks, config.n_kv_heads, block_size,
+    shape = (config.n_caches, num_blocks, config.n_kv_heads, block_size,
              config.head_dim)
     if config.kv_dtype == "int8":
         scale_shape = shape[:-1] + (1,)
@@ -1286,8 +1411,9 @@ def paged_prefill(params, config: TransformerConfig, pool, prompt,
     blocks = prompt.shape[1] // block_size
     new_pool = {}
     for name, written in local.items():
-        # (nl, 1, H, Lb, d) -> (nl, blocks, H, block_size, d), scattered
-        # into the slot's first `blocks` pool entries
+        # (caches, 1, H, Lb, d) -> (caches, blocks, H, block_size, d),
+        # scattered into the slot's first `blocks` pool entries: cache
+        # and pool lead with the same axis (_cache_index)
         entry = written[:, 0]
         layers, heads, _, depth = entry.shape
         entry = entry.reshape(layers, heads, blocks, block_size,
@@ -1373,6 +1499,19 @@ def _attend_pool(config: TransformerConfig, pool: dict, index, tables,
 
 def _paged_window(params, config: TransformerConfig, pool, tables,
                   positions, tokens, write_blocks, write_offsets):
+    """_paged_logits with each window position's greedy token in place
+    of its logits, and of the FFNs' stats the experts' counts, int32
+    (2,): distinct held experts read, token-expert pairs computed (zeros
+    without).  What the three jitted paged programs hand out."""
+    pool, logits, stats_sum, exit_steps = _paged_logits(
+        params, config, pool, tables, positions, tokens, write_blocks,
+        write_offsets)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return pool, greedy, stats_sum[1:].astype(jnp.int32), exit_steps
+
+
+def _paged_logits(params, config: TransformerConfig, pool, tables,
+                  positions, tokens, write_blocks, write_offsets):
     """The decoder over a per-slot TOKEN WINDOW and the paged pool --
     the one traced implementation behind paged_decode_step (window 1),
     paged_verify_step (speculative verification, window k+1), and
@@ -1382,22 +1521,23 @@ def _paged_window(params, config: TransformerConfig, pool, tables,
     position i sits at absolute position positions[slot] + i, its K/V
     lands at (write_blocks[slot, i], write_offsets[slot, i]), and rows
     the engine wants inert point their writes at the trash block.
-    Returns (pool, greedy (slots, W), counts) where greedy[s, i] is the
-    greedy token AFTER consuming window positions 0..i -- the tokens W
-    successive single-token decode steps would produce, which is the
-    identity the chunked-prefill and speculative tests pin -- and counts
-    int32 (2,) what the routed experts did, summed over layers: distinct
-    held experts read, token-expert pairs computed (zeros without)."""
+    Returns (pool, logits (slots, W, V), stats, exit_steps) where
+    logits[s, i] score the token AFTER consuming window positions 0..i
+    -- their argmax the tokens W successive single-token decode steps
+    would produce, which is the identity the chunked-prefill and
+    speculative tests pin -- stats float32 (_FFN_STATS,) the FFNs',
+    summed over layers and passes, and exit_steps float32 (slots, W) a
+    looped stack's expected exit pass (_logits; None for one pass)."""
     h = _embed(params, config, tokens)
     q_pos = positions[:, None] + jnp.arange(tokens.shape[1])[None, :]
     cos, sin = _rotary_tables(config, q_pos)
     cos, sin = cos[:, None], sin[:, None]        # (S, 1, W, hd/2)
 
     def layer_step(carry, xs):
-        # the pool rides the loop as CARRY and is written where it lies
-        # (indexed by layer): as scan xs -> ys every step rebuilt it
-        # whole.  Nothing here trains: of the FFN's stats only the
-        # experts' counts go on
+        # the pool rides the loop (and a looped stack's passes) as CARRY
+        # and is written where it lies (indexed by cache): as scan xs ->
+        # ys every step rebuilt it whole.  Nothing here trains: of the
+        # FFN's stats only the experts' counts go on
         h, pool, stats_sum = carry
         layer, index = xs
         h, stats, pool = _decoder_layer(
@@ -1406,14 +1546,15 @@ def _paged_window(params, config: TransformerConfig, pool, tables,
                     write_blocks, write_offsets))
         return (h, pool, stats_sum + stats), None
 
+    def scan_stack(carry, stack, first, count):
+        return _scan_layers(config, layer_step, carry, stack,
+                            first + jnp.arange(count))[0]
+
     carry = (h, pool, jnp.zeros((_FFN_STATS,), jnp.float32))
-    for stack, first, count in _layer_stacks(params, config):
-        carry, _ = _scan_layers(config, layer_step, carry, stack,
-                                first + jnp.arange(count))
-    h, new_pool, stats_sum = carry
-    logits = _lm_head(params, config, h)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return new_pool, greedy, stats_sum[1:].astype(jnp.int32)
+    (h, new_pool, stats_sum), outputs = _run_passes(params, config, carry,
+                                                    scan_stack)
+    logits, exit_steps = _logits(params, config, h, outputs)
+    return new_pool, logits, stats_sum, exit_steps
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(2,))
@@ -1436,11 +1577,14 @@ def paged_decode_step(params, config: TransformerConfig, pool, tables,
     The window-1 instantiation of _paged_window.  A model with routed
     experts returns a third value, int32 (2,): the distinct held experts
     the step read and the token-expert pairs it computed, over its
-    layers."""
-    pool, greedy, counts = _paged_window(
+    layers.  A looped stack returns, last, float32 (slots,): the pass
+    its exit gate expects each slot's token to leave after, sum_t
+    (t + 1) p[t] (what a lower exit_threshold would buy)."""
+    pool, greedy, counts, exit_steps = _paged_window(
         params, config, pool, tables, positions, tokens,
         write_blocks[:, None], write_offsets[:, None])
-    return (pool, greedy, counts) if config.top_k else (pool, greedy)
+    return ((pool, greedy) + ((counts,) if config.top_k else ())
+            + ((exit_steps[:, 0],) if config.ut_steps > 1 else ()))
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(2,))
@@ -1478,7 +1622,7 @@ def paged_prefill_chunk(params, config: TransformerConfig, pool, tokens,
     prompt end is the request's first generated token, bit-identical
     to monolithic paged_prefill's.  One executable per power-of-two
     chunk bucket."""
-    pool, greedy, _ = _paged_window(
+    pool, greedy, *_ = _paged_window(
         params, config, pool, table_row[None],
         jnp.reshape(start, (1,)), tokens, write_blocks[None],
         write_offsets[None])

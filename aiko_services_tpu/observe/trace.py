@@ -86,7 +86,11 @@
 #                               engine.step: bucket, true_len, queue_us,
 #                               attention (flash | einsum: what the
 #                               bucket's attention takes; a chunk call
-#                               instead: live_blocks, table_blocks)
+#                               instead: live_blocks, table_blocks);
+#                               of a looped stack also ut_passes and
+#                               cache_rows (as on engine.decode; here the
+#                               rows the call leaves behind, true_len or
+#                               the chunk's end, x caches)
 #   engine.decode      scoped   table build + dispatch: decoding,
 #                               ahead (1: dispatched while the step
 #                               before was unread, from its tokens on
@@ -106,7 +110,20 @@
 #                               them, so they come with that step's
 #                               tokens, two dispatches late under the
 #                               run-ahead; absent until one has.  Running
-#                               sums of all three in engine_stats()
+#                               sums of all three in engine_stats().
+#                               Of a looped stack (ut_steps > 1):
+#                               ut_passes (passes the program ran, its
+#                               config's), cache_rows (the live positions
+#                               this step's slots attend over x the
+#                               model's n_layers x ut_steps caches, host-
+#                               counted like latent_positions) and
+#                               exit_expected_step (mean over the decoding
+#                               slots of sum_t (t + 1) p[t], the pass the
+#                               exit gate expects a token to leave after:
+#                               device-counted, so of the newest step
+#                               read back, like experts_read); running
+#                               sums in engine_stats(), the last of the
+#                               steps' means
 #   engine.readback    scoped   the settle's readback of the step in
 #                               flight: in a tick after that tick's
 #                               engine.decode where it ran ahead, or

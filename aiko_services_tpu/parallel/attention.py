@@ -879,7 +879,8 @@ def paged_attention(q, pool_k, pool_v, layer, tables, positions,
     window row i of slot s attends to k_pos <= positions[s] + i, scores
     and accumulation in float32, operands in the pool's dtype -- with
     the softmax taken blockwise, so outputs agree to rounding, not
-    bitwise.  Mosaic on the chip, interpreted on CPU (_interpret).
+    bitwise.  Mosaic on the chip, interpreted on CPU (_interpret); named
+    `paged_attention` in the device trace.
 
     pool_v None is a latent pool (latent attention absorbed: many query
     heads over ONE key head): a row of pool_k is the key and its first
@@ -941,7 +942,8 @@ def paged_attention(q, pool_k, pool_v, layer, tables, positions,
         # the buffer parity and the copy in flight cross grid steps
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        name=None if pool_v is not None else "mla_paged_attention",
+        name=("paged_attention" if pool_v is not None
+              else "mla_paged_attention"),
         interpret=_interpret(),
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       tables.reshape(-1).astype(jnp.int32), positions, live,

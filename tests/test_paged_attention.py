@@ -243,6 +243,52 @@ def test_kernel_compiles_for_the_v5e_at_the_served_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+# benchmark/configs/ouro_2.6b.json: as many K/V heads as query heads (a
+# block brings 128 KiB of keys, not 64), 192 caches, 8 slots of 640
+OURO = dict(slots=8, kv_heads=16, block=32, max_blocks=20, depth=128,
+            caches=192)
+
+
+def test_kernel_compiles_for_the_v5e_at_sixteen_kv_heads(for_the_chip):
+    s = OURO
+    pool = for_the_chip((s["caches"], 168, s["kv_heads"], s["block"],
+                         s["depth"]), "bfloat16")
+    compiled = jax.jit(paged_attention).lower(
+        for_the_chip((s["slots"], s["kv_heads"], 1, s["depth"]),
+                     "bfloat16"),
+        pool, pool, for_the_chip((), "int32"),
+        for_the_chip((s["slots"], s["max_blocks"]), "int32"),
+        for_the_chip((s["slots"],), "int32")).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def _served_ouro(for_the_chip):
+    """(config, params, pool, int32): Ouro-2.6B as ouro.reason serves it,
+    placed on the described chip."""
+    import json
+    import pathlib
+    from aiko_services_tpu.models.configs import ouro_config
+    published = json.loads((pathlib.Path(__file__).parent.parent
+                            / "benchmark/configs/ouro_2.6b.json"
+                            ).read_text())
+    serve = published["serve"]
+    assert (serve["decode_slots"], serve["kv_block_size"],
+            serve["max_context"]) == (
+        OURO["slots"], OURO["block"], OURO["block"] * OURO["max_blocks"])
+    assert 161 <= serve["kv_blocks"] <= 168
+    config = ouro_config(published, serve["max_context"])
+    assert config.n_caches == OURO["caches"]
+    place = lambda tree: jax.tree_util.tree_map(       # noqa: E731
+        lambda leaf: for_the_chip(leaf.shape, leaf.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: init_params(config, jax.random.PRNGKey(0))))
+    pool = place(jax.eval_shape(lambda: init_paged_pool(
+        config, serve["kv_blocks"], serve["kv_block_size"])))
+    return config, params, pool, lambda *shape: for_the_chip(shape, "int32")
+
+
 def _served_model(for_the_chip):
     """(config, params, pool, int32): the served model's shapes, placed
     on the described chip."""
@@ -273,9 +319,14 @@ def test_served_prefill_keeps_no_scores_in_hbm_on_the_v5e(for_the_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
-def test_served_decode_step_moves_no_pool_leaf_on_the_v5e(for_the_chip):
-    s = SERVED
-    config, params, pool, int32 = _served_model(for_the_chip)
+@pytest.mark.parametrize("served", ["mistral7b_l16", "ouro_2.6b"])
+def test_served_decode_step_moves_no_pool_leaf_on_the_v5e(for_the_chip,
+                                                          served):
+    """ouro_2.6b: the pool (8.46 GB at 168 blocks) rides four layer
+    loops, one a pass, as carry; a copy of a leaf would not fit."""
+    s, model = ((SERVED, _served_model) if served == "mistral7b_l16"
+                else (OURO, _served_ouro))
+    config, params, pool, int32 = model(for_the_chip)
     slots = s["slots"]
     compiled = paged_decode_step.lower(
         params, config, pool, int32(slots, s["max_blocks"]), int32(slots),
@@ -293,6 +344,28 @@ def test_served_decode_step_moves_no_pool_leaf_on_the_v5e(for_the_chip):
     moved = [line for line in text.splitlines()
              if f"= {leaf_type}" in line and " copy(" in line]
     assert not moved, moved[:2]
+    if served == "ouro_2.6b":
+        # weights 5.13 GB + pool: what the chip holds (serve.why)
+        assert 13.2e9 < memory.argument_size_in_bytes < 13.7e9
+        assert "paged_attention" in text
+
+
+def test_served_ouro_prefill_fits_the_chip_at_the_256_bucket(for_the_chip):
+    """The longest bucket ouro.reason sends: four passes into a
+    contiguous cache of 192 x 256 rows (0.40 GB), scattered into the
+    pool.  0.81 GB of temporaries when written; with the arguments 14.41
+    GB of the chip's 16."""
+    config, params, pool, int32 = _served_ouro(for_the_chip)
+    compiled = paged_prefill.lower(
+        params, config, pool, int32(1, 256), int32(OURO["max_blocks"]),
+        int32()).compile()
+    memory = compiled.memory_analysis()
+    leaf = pool["k"]
+    leaf_bytes = int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+    assert memory.alias_size_in_bytes == 2 * leaf_bytes
+    assert memory.temp_size_in_bytes < 1 << 30
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 14.6e9)
 
 
 def test_on_the_chip_a_head_dim_off_the_lanes_keeps_the_einsum(
@@ -483,12 +556,16 @@ def _staged_whole(text: str, element_counts: set) -> list:
     return found
 
 
-@pytest.mark.parametrize("served", ["mistral7b_l16", "deepseek_v2_ep4_l5"])
+@pytest.mark.parametrize("served", ["mistral7b_l16", "deepseek_v2_ep4_l5",
+                                    "ouro_2.6b"])
 def test_served_decode_step_stages_no_projection_on_the_v5e(
         for_the_chip, monkeypatch, served):
     if served == "mistral7b_l16":
         s = SERVED
         config, params, pool, int32 = _served_model(for_the_chip)
+    elif served == "ouro_2.6b":
+        s = OURO
+        config, params, pool, int32 = _served_ouro(for_the_chip)
     else:
         s = DSV2
         config, params, pool, int32 = _served_share(for_the_chip,
@@ -504,7 +581,7 @@ def test_served_decode_step_stages_no_projection_on_the_v5e(
                  for name in ("layers", "dense_layers") if name in params
                  for leaf in jax.tree_util.tree_leaves(params[name])
                  if leaf.ndim > 2}
-    assert 4096 * 4096 in per_layer or 24576 * 1536 in per_layer
+    assert per_layer & {4096 * 4096, 24576 * 1536, 2048 * 5632}
     staged = _staged_whole(compiled.as_text(), per_layer)
     assert not staged, staged
     # 1.45 MB and 2.3 MB when written; the share's was 77.8 MB, wq_b's
