@@ -78,7 +78,8 @@ import numpy as np
 
 from ..models import (
     cache_attention_kind, init_paged_pool, paged_decode_step,
-    paged_prefill, paged_prefill_chunk, paged_verify_step)
+    paged_prefill, paged_prefill_chunk, paged_verify_step,
+    pool_write_kind)
 from ..observe.trace import NO_SPANS
 from ..parallel.attention import paged_live_blocks
 from ..utils import get_logger
@@ -290,6 +291,7 @@ class DecodeEngine:
                          "prefix_evictions": 0,
                          "live_blocks": 0, "table_blocks": 0,
                          "prefill_flash": 0, "prefill_einsum": 0,
+                         "writes_kernel": 0, "writes_updates": 0,
                          "decode_steps": 0, "steps_ahead": 0,
                          "overrun_tokens": 0,
                          "experts_read": 0, "expert_pairs": 0,
@@ -970,7 +972,12 @@ class DecodeEngine:
         gather read); over a latent pool a decode step (`decoding`: its
         slots) also `latent_positions`, the live rows those slots'
         attention reads, this step's own among them, and of a looped
-        stack `_looped`'s.  Their running sums ride `stats()`."""
+        stack `_looped`'s.  Their running sums ride `stats()`.  And
+        `write`, who puts the window's new rows into the pool: `kernel`
+        (the paged attention kernel itself, window 1) or `updates` (one
+        dynamic_update_slice a row), asked of the function the model
+        step decides by; `writes_kernel`/`writes_updates` count the
+        calls."""
         walked = {
             "live_blocks": int(paged_live_blocks(
                 positions, window, self.blocks.block_size,
@@ -981,6 +988,8 @@ class DecodeEngine:
                 positions[decoding].sum()) + len(decoding) * window
         for name, count in walked.items():
             self.counters[name] += count
+        walked["write"] = pool_write_kind(self.config, self.pool, window)
+        self.counters["writes_" + walked["write"]] += 1
         if decoding is not None:
             walked.update(self._looped(
                 int(positions[decoding].sum()) + len(decoding) * window))

@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 from ..parallel.attention import (
     attention_reference, flash_attention, flash_attention_takes,
     paged_attention, paged_attention_reference, paged_attention_takes,
+    paged_attention_writes,
     ring_attention, sp_decode_attention, ulysses_attention)
 from ..parallel.experts import expert_ffn
 from .layers import (
@@ -46,7 +47,7 @@ __all__ = [
     "quantize_weights_int8", "quantized_param_specs",
     "init_paged_pool", "paged_prefill", "paged_decode_step",
     "paged_prefill_chunk", "paged_verify_step", "cache_attention_kind",
-    "REMAT_POLICIES", "resolve_remat_policy",
+    "pool_write_kind", "REMAT_POLICIES", "resolve_remat_policy",
 ]
 
 
@@ -1352,8 +1353,12 @@ def generate_stream(params, config: TransformerConfig, prompt,
 # generate():
 #
 #   - blocks hold what the contiguous cache would (_kv_to_write; a whole
-#     prefill reshapes a forward() cache into blocks), and a step writes
-#     its K/V into the donated pool where it lies (_write_window);
+#     prefill reshapes a forward() cache into blocks), and a step's K/V
+#     goes into the donated pool where it lies: at window 1, the decode
+#     step, the paged-attention kernel writes the rows itself, the pool
+#     riding the call aliased in place; a wider window's rows, and those
+#     of a call the kernel does not take, go in one update each before
+#     the attention (_write_window; pool_write_kind says which);
 #   - the step's attention has _attend_cache's mathematics and mask --
 #     bf16/f32 operands, float32 scores and accumulation, a softmax over
 #     exactly the positions <= the query's -- taken BLOCKWISE by the
@@ -1424,11 +1429,14 @@ def paged_prefill(params, config: TransformerConfig, pool, prompt,
 
 def _write_window(leaf, value, layer, write_blocks, write_offsets):
     """Write the window's new K/V (or scales) into one pool leaf where
-    it lies.  leaf (L, num_blocks, H, block, d); value (S, H, W, d);
-    write_blocks/write_offsets (S, W).  One dynamic_update_slice per
-    window position, unrolled: on the chip a scatter, and a rolled loop
-    of updates too, make XLA re-lay the whole leaf out (two copies of it
-    a layer); these update in place in the layout the kernel reads.
+    it lies, for the calls whose rows the paged kernel does not write
+    itself (pool_write_kind "updates": a window over 1, an int8 pool, a
+    shape the kernel does not take).  leaf (L, num_blocks, H, block, d);
+    value (S, H, W, d); write_blocks/write_offsets (S, W).  One
+    dynamic_update_slice per window position, unrolled: on the chip a
+    scatter, and a rolled loop of updates too, make XLA re-lay the whole
+    leaf out (two copies of it a layer); these update in place in the
+    layout the kernel reads, each a device operation of its own.
     Later positions win where inert rows share the trash block."""
     slots, _, window, _ = value.shape
     for s in range(slots):
@@ -1438,6 +1446,29 @@ def _write_window(leaf, value, layer, write_blocks, write_offsets):
                 leaf, value[s, :, i][None, None, :, None, :],
                 (layer, write_blocks[s, i], 0, write_offsets[s, i], 0))
     return leaf
+
+
+def _paged_kernel_takes(config: TransformerConfig, pool: dict,
+                        window: int) -> bool:
+    """paged_attention_takes for a window over `pool`: its leaf says the
+    row's width and dtype, the config whether a row is a latent one."""
+    leaf = _store_leaf(pool)
+    return paged_attention_takes(
+        config.n_heads, window, leaf.shape[-1], leaf.dtype,
+        value_dim=config.kv_lora_rank or None)
+
+
+def pool_write_kind(config: TransformerConfig, pool: dict,
+                    window: int) -> str:
+    """"kernel" or "updates": who writes a paged step's new rows into
+    `pool` at a window of `window` positions.  "kernel": the paged
+    attention kernel takes the call and writes the rows itself, the pool
+    aliased through it (window 1: the decode step).  "updates":
+    _write_window, one dynamic_update_slice a slot a leaf a position,
+    before the attention.  _attend_paged decides by this, and the engine
+    names its decode and chunk spans by it."""
+    return ("kernel" if _paged_kernel_takes(config, pool, window)
+            and paged_attention_writes(window) else "updates")
 
 
 def _attend_pool_latent(config: TransformerConfig, pool: dict, index,
@@ -1450,8 +1481,6 @@ def _attend_pool_latent(config: TransformerConfig, pool: dict, index,
     up-projection is applied to them.  128 query heads over ONE key
     head whose first `rank` values are also the value: no head's key or
     value is ever made, and a row is read once."""
-    pool = {"kv": _write_window(pool["kv"], latent, index, write_blocks,
-                                write_offsets)}
     nope, rank = config.qk_nope_head_dim, config.kv_lora_rank
     absorbed = jnp.einsum("shwn,hnc->shwc", q[..., :nope],
                           layer["wk_b"]["w"],
@@ -1461,40 +1490,64 @@ def _attend_pool_latent(config: TransformerConfig, pool: dict, index,
     query = jnp.concatenate(
         [absorbed, q[..., nope:],
          jnp.zeros(q.shape[:3] + (pad,), q.dtype)], axis=-1)
-    attend = (paged_attention if paged_attention_takes(
-        config.n_heads, q.shape[2], config.latent_row, pool["kv"].dtype,
-        value_dim=rank) else paged_attention_reference)
-    weighted = attend(query, pool["kv"], None, index, tables, positions,
-                      sm_scale=config.attention_scale, value_dim=rank)
+    weighted, pool = _attend_paged(
+        config, pool, {"kv": latent}, index, tables, positions,
+        write_blocks, write_offsets, query)
     return jnp.einsum("shwc,hcv->shwv", weighted, layer["wv_b"]["w"],
                       preferred_element_type=jnp.float32
                       ).astype(q.dtype), pool
 
 
+def _attend_paged(config: TransformerConfig, pool: dict, written: dict,
+                  index, tables, positions, write_blocks, write_offsets,
+                  q):
+    """Put `written` (a row a leaf: _kv_to_write's, or the latent) into
+    cache `index` of the pool at (write_blocks, write_offsets) and
+    attend q through the block tables; returns (out, the pool's new
+    leaves).  Who writes is pool_write_kind's answer: the kernel itself
+    at window 1, else _write_window before the kernel or, for the calls
+    paged_attention_takes refuses, before its oracle."""
+    window = q.shape[2]
+    latent = dict(sm_scale=config.attention_scale,
+                  value_dim=config.kv_lora_rank) if "kv" in pool else {}
+    names = ("kv",) if latent else ("k", "v")
+    write = None
+    if pool_write_kind(config, pool, window) == "kernel":
+        write = (tuple(written[name] for name in names), write_blocks,
+                 write_offsets)
+    else:
+        pool = {name: _write_window(pool[name], rows, index, write_blocks,
+                                    write_offsets)
+                for name, rows in written.items()}
+    leaves = (_store_leaf(pool), pool.get("v"))
+    if not _paged_kernel_takes(config, pool, window):
+        scales = ((pool["k_scale"], pool["v_scale"]) if "k_scale" in pool
+                  else ())
+        return paged_attention_reference(
+            q, *leaves, index, tables, positions, *scales, **latent), pool
+    out, *leaves = paged_attention(q, *leaves, index, tables, positions,
+                                   write=write, **latent)
+    return out, {**pool, **dict(zip(names, leaves))}
+
+
 def _attend_pool(config: TransformerConfig, pool: dict, index, tables,
                  positions, write_blocks, write_offsets, layer, q, k, v):
     """Paged pool (init_paged_pool; `pool` is the whole pool, `index`
-    this layer's index into it): write the WHOLE window's K/V where it
-    lies, then attend through each slot's block table -- so later
-    window positions attend to earlier ones causally.  The kernel walks
-    the live blocks in place where paged_attention_takes; its oracle,
-    the table-wide gather + einsum, serves the rest (an int8 pool,
-    whose scales dequantize the gathered view as the contiguous int8
-    cache's do; a window too large for VMEM; on the chip a head_dim off
-    the 128 lanes)."""
+    this layer's index into it): the WHOLE window's K/V goes where it
+    lies and the window attends through each slot's block table -- so
+    later window positions attend to earlier ones causally.  The kernel
+    walks the live blocks in place where paged_attention_takes, and at
+    window 1 (the decode step) writes the new rows itself, the pool
+    riding the call aliased (pool_write_kind); its oracle, the
+    table-wide gather + einsum, serves the rest (an int8 pool, whose
+    scales dequantize the gathered view as the contiguous int8 cache's
+    do; a window too large for VMEM; on the chip a head_dim off the 128
+    lanes), after _write_window."""
     if config.kv_lora_rank:
         return _attend_pool_latent(config, pool, index, tables, positions,
                                    write_blocks, write_offsets, layer, q, k)
-    pool = {name: _write_window(pool[name], value, index, write_blocks,
-                                write_offsets)
-            for name, value in _kv_to_write(pool, k, v).items()}
-    attend = (paged_attention if paged_attention_takes(
-        config.n_heads, q.shape[2], config.head_dim, pool["k"].dtype)
-        else paged_attention_reference)
-    scales = ((pool["k_scale"], pool["v_scale"]) if "k_scale" in pool
-              else ())
-    return attend(q, pool["k"], pool["v"], index, tables, positions,
-                  *scales), pool
+    return _attend_paged(config, pool, _kv_to_write(pool, k, v), index,
+                         tables, positions, write_blocks, write_offsets, q)
 
 
 def _paged_window(params, config: TransformerConfig, pool, tables,

@@ -98,7 +98,13 @@
 #                               admission or an empty engine),
 #                               live_blocks (blocks the paged attention
 #                               walks this step), table_blocks (what the
-#                               tables can name: slots x max_blocks);
+#                               tables can name: slots x max_blocks),
+#                               write (kernel | updates: who puts the
+#                               step's new rows into the pool, the paged
+#                               attention kernel itself at window 1 or
+#                               one dynamic_update_slice a row; running
+#                               counts writes_kernel, writes_updates; on
+#                               a chunk's engine.prefill too);
 #                               over a latent pool latent_positions (the
 #                               live rows this step's slots attend over,
 #                               its own among them); with routed experts

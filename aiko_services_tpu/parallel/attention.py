@@ -34,8 +34,8 @@ from .mesh import create_mesh  # noqa: F401  (re-exported convenience)
 __all__ = [
     "attention_reference", "flash_attention", "flash_attention_takes",
     "paged_attention", "paged_attention_reference", "paged_attention_takes",
-    "paged_live_blocks", "ring_attention", "sp_decode_attention",
-    "ulysses_attention",
+    "paged_attention_writes", "paged_live_blocks", "ring_attention",
+    "sp_decode_attention", "ulysses_attention",
 ]
 
 _NEG_INF = -1e30
@@ -733,6 +733,14 @@ def paged_attention_takes(heads: int, window: int, head_dim: int,
                  or _interpret()))
 
 
+def paged_attention_writes(window: int) -> bool:
+    """Whether paged_attention, where it takes a call, writes the
+    window's new rows itself (its `write`): window 1, the decode step.
+    A wider window (a verify step, a prefill chunk) has its rows written
+    before the call, one update each (transformer._write_window)."""
+    return window == 1
+
+
 def paged_live_blocks(positions, window: int, block: int,
                       max_blocks: int):
     """Blocks the kernel walks for each slot: those holding positions
@@ -743,25 +751,58 @@ def paged_live_blocks(positions, window: int, block: int,
     return blocks.clip(1, max_blocks)
 
 
-def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
-                  q_ref, *rest,
-                  window: int, block: int, chunk_blocks: int,
+def _paged_kernel(*refs, window: int, block: int, chunk_blocks: int,
                   max_blocks: int, sm_scale: float,
-                  value_dim: int | None = None):
+                  value_dim: int | None = None, writes: bool = False):
     """One slot per grid step.  The slot's live blocks arrive in chunks
     of `chunk_blocks`; while chunk c is multiplied, chunk c + 1 (or the
     next slot's first chunk) is already on its way into the other half
     of k_buf/v_buf.  Softmax state (m, l, acc) lives in VMEM in float32
     across the chunks.  With `value_dim` the pool is a latent one: there
     is no V leaf and no V buffer, a row is fetched once and its first
-    `value_dim` values are the value."""
-    if value_dim is None:
-        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, parity_ref, m_ref, l_ref,
-         acc_ref) = rest
-        leaves = ((k_hbm, k_buf), (v_hbm, v_buf))
-    else:
-        k_hbm, o_ref, k_buf, sems, parity_ref, m_ref, l_ref, acc_ref = rest
-        leaves, v_buf = ((k_hbm, k_buf),), k_buf
+    `value_dim` values are the value.
+
+    With `writes` (window 1) the kernel also WRITES the slot's new row
+    of every leaf at (write_blocks[slot], write_offsets[slot]); the pool
+    leaves are then the aliased outputs, read and written through the
+    same refs.  One position is not a unit a copy can move in the pool's
+    tiled layout, so the aligned tile of `_tile_positions` that holds it
+    is read into a scratch of its own, the row put in by an iota-select,
+    and the tile copied back where it came from: its other rows return
+    as they were.  The fresh row is also put into the chunk buffer at
+    the slot's position, so the attention multiplies it like a row read
+    from HBM.  The order that keeps this sound:
+      - the tile's read is started a slot ahead, with the slot's first
+        chunk, and waited for before the patch;
+      - the write-back starts only after the slot's LAST chunk, the one
+        that holds its position, has been waited for, so no read of that
+        block by this slot is in flight;
+      - it is waited for before the slot's grid step ends, so the tile
+        scratch and its semaphore are free again two slots on;
+      - what is in flight meanwhile -- the next slot's first chunk and
+        tile -- touches that slot's own pages, or the trash block, which
+        several inert slots may write at once: its rows get no weight in
+        a live slot, neighbours in a tile are rewritten with the values
+        they had, and any winner of the told row is right."""
+    refs = iter(refs)
+    take = lambda count: [next(refs) for _ in range(count)]  # noqa: E731
+    leaf_count = 1 if value_dim is not None else 2
+    layer_ref, tables_ref, positions_ref, live_ref = take(4)
+    write_blocks_ref, write_offsets_ref = take(2 if writes else 0) or (
+        None, None)
+    (q_ref,) = take(1)
+    new_refs = take(leaf_count if writes else 0)
+    hbm = take(leaf_count)
+    (o_ref,) = take(1)
+    if writes:
+        # the pool comes in and goes out as one buffer: the output refs
+        # are the ones read and written
+        hbm = take(leaf_count)
+    bufs = take(leaf_count)
+    sems, parity_ref, m_ref, l_ref, acc_ref = take(5)
+    tiles, tile_sems = take(leaf_count if writes else 0), next(refs, None)
+    leaves = tuple(zip(hbm, bufs))
+    k_buf, v_buf = bufs[0], bufs[-1]
     slot = pl.program_id(0)
     slots = pl.num_programs(0)
     kv_heads, rows = q_ref.shape[1], q_ref.shape[2]
@@ -770,6 +811,13 @@ def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
     layer = layer_ref[0]
     position = positions_ref[slot]
     chunks = (live_ref[slot] + chunk_blocks - 1) // chunk_blocks
+
+    def by_head(body):
+        # a K/V head a turn of a loop that is traced ONCE and unrolled
+        # when lowered, the head a constant there: the program is the
+        # one a Python loop makes, and tracing it -- it is set-up time,
+        # under every first request -- does not grow with the heads
+        jax.lax.fori_loop(0, kv_heads, body, 0, unroll=True)
 
     def transfer(of_slot, of_chunk, half, start: bool):
         # one copy per live block and leaf; blocks past the slot's last
@@ -780,9 +828,9 @@ def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
         def one(j, carry):
             page = tables_ref[of_slot * max_blocks + first + j]
             offset = pl.multiple_of(j * block, block)
-            for leaf, (hbm, buf) in enumerate(leaves):
+            for leaf, (pool, buf) in enumerate(leaves):
                 copy = pltpu.make_async_copy(
-                    hbm.at[layer, page],
+                    pool.at[layer, page],
                     buf.at[half, :, pl.ds(offset, block), :],
                     sems.at[leaf, half])
                 if start:
@@ -793,6 +841,54 @@ def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
 
         jax.lax.fori_loop(0, count, one, 0)
 
+    if writes:
+        tile = tiles[0].shape[2]
+
+        def tile_copies(of_slot, back: bool):
+            # the told row's tile of every leaf, HBM -> scratch or back
+            start = pl.multiple_of(
+                write_offsets_ref[of_slot] // tile * tile, tile)
+            for leaf, (pool, scratch) in enumerate(zip(hbm, tiles)):
+                there = pool.at[layer, write_blocks_ref[of_slot], :,
+                                pl.ds(start, tile), :]
+                here = scratch.at[of_slot % 2]
+                yield pltpu.make_async_copy(
+                    *((here, there) if back else (there, here)),
+                    tile_sems.at[leaf, of_slot % 2])
+
+        def put_row(ref, start, index, new_ref):
+            # the `tile` positions of ref (kv_heads, n, d) from `start`,
+            # with row `index` of them replaced by the new row, whose
+            # heads lie side by side on the lanes
+            depth = ref.shape[2]
+            row = jax.lax.broadcasted_iota(jnp.int32, (tile, depth), 0)
+
+            def one(head, carry):
+                held = ref[head, pl.ds(start, tile), :]
+                new = new_ref[0, :, pl.ds(
+                    pl.multiple_of(head * depth, depth), depth)]
+                ref[head, pl.ds(start, tile), :] = jnp.where(
+                    row == index, new, held)
+                return carry
+
+            by_head(one)
+
+        def write_row(c, half):
+            for copy in tile_copies(slot, back=False):
+                copy.wait()
+            for scratch, new_ref in zip(tiles, new_refs):
+                put_row(scratch.at[slot % 2], 0,
+                        write_offsets_ref[slot] % tile, new_ref)
+            for copy in tile_copies(slot, back=True):
+                copy.start()
+            # where the attention looks for it: the slot's position in
+            # this chunk (a position past the table patches nothing)
+            local = position - c * chunk
+            start = pl.multiple_of(
+                jnp.clip(local // tile * tile, 0, chunk - tile), tile)
+            for buf, new_ref in zip(bufs, new_refs):
+                put_row(buf.at[half], start, local - start, new_ref)
+
     @pl.when(slot == 0)
     def _first():
         # dead columns are masked by position, but 0 x NaN is NaN: what
@@ -800,6 +896,9 @@ def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
         v_buf[...] = jnp.zeros_like(v_buf)
         parity_ref[0] = 0
         transfer(0, 0, 0, start=True)
+        if writes:
+            for copy in tile_copies(0, back=False):
+                copy.start()
 
     first_half = parity_ref[0]
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -813,7 +912,7 @@ def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
             jnp.int32, (rows, width), 0) % window
         column = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
         visible = c * chunk + column <= position + row_window
-        for head in range(kv_heads):
+        def one(head, carry):
             q = q_ref[0, head]                              # (rows, d)
             k_blk = k_buf[half, head, :width]               # (width, d)
             s = jax.lax.dot_general(
@@ -832,6 +931,9 @@ def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
                 p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_ref[head] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            return carry
+
+        by_head(one)
 
     def chunk_step(c, carry):
         half = (first_half + c) % 2
@@ -842,8 +944,17 @@ def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
         def _prefetch():
             transfer(next_slot, jnp.where(last, 0, c + 1), 1 - half,
                      start=True)
+            if writes:
+                @pl.when(last)
+                def _next_tile():
+                    for copy in tile_copies(slot + 1, back=False):
+                        copy.start()
 
         transfer(slot, c, half, start=False)
+        if writes:
+            @pl.when(last)
+            def _write():
+                write_row(c, half)
         if narrow == chunk:
             attend(c, half, chunk)
         else:
@@ -864,13 +975,32 @@ def _paged_kernel(layer_ref, tables_ref, positions_ref, live_ref,
 
     jax.lax.fori_loop(0, chunks, chunk_step, 0)
     parity_ref[0] = (first_half + chunks) % 2
-    for head in range(kv_heads):
+    def put_out(head, carry):
         o_ref[0, head] = (acc_ref[head] / l_ref[head, :, :1]).astype(
             o_ref.dtype)
+        return carry
+
+    by_head(put_out)
+    if writes:
+        for copy in tile_copies(slot, back=True):
+            copy.wait()
+
+
+def _sublane_rows(dtype) -> int:
+    """Rows in the dtype's sublane tile: 8 of float32, 16 of bfloat16."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def _tile_positions(dtype, block: int) -> int:
+    """Positions in the aligned tile a row write reads and writes back:
+    the dtype's sublane tile, cut to what divides the block, so that a
+    tile lies inside one (a toy pool's block of 4 on the interpreter;
+    Mosaic refuses the block copies of a block under 8)."""
+    return math.gcd(block, _sublane_rows(dtype))
 
 
 def paged_attention(q, pool_k, pool_v, layer, tables, positions,
-                    sm_scale=None, value_dim=None):
+                    sm_scale=None, value_dim=None, write=None):
     """Paged attention over the pool in place.  q (slots, heads, W, d);
     pool_k/pool_v the WHOLE pool leaves (layers, num_blocks, kv_heads,
     block, d), left in HBM, of which `layer` (an int32 scalar, traced or
@@ -880,13 +1010,24 @@ def paged_attention(q, pool_k, pool_v, layer, tables, positions,
     and accumulation in float32, operands in the pool's dtype -- with
     the softmax taken blockwise, so outputs agree to rounding, not
     bitwise.  Mosaic on the chip, interpreted on CPU (_interpret); named
-    `paged_attention` in the device trace.
+    `paged_attention` in the device trace.  Returns (out, pool_k,
+    pool_v).
+
+    `write` = (new rows, write_blocks, write_offsets) makes the kernel
+    write the step's new rows itself (paged_attention_writes: window
+    1): the rows, one (slots, kv_heads, 1, d) a leaf, land in the
+    pool's dtype at [layer, write_blocks[s], :, write_offsets[s], :]
+    before the slot attends, the leaves ride the call aliased in place
+    (input_output_aliases: a donated pool stays one buffer) and come
+    back written; nothing else in them changes.  A slot's row must lie
+    at its position in its own table or in a block no live slot reads
+    (the trash block).  Without `write` the leaves come back as given.
 
     pool_v None is a latent pool (latent attention absorbed: many query
     heads over ONE key head): a row of pool_k is the key and its first
     `value_dim` values the value, so the output is (slots, heads, W,
-    value_dim) and a live row is read once.  That kernel is named
-    `mla_paged_attention` in the device trace."""
+    value_dim) and a live row is read once; one new row.  That
+    kernel is named `mla_paged_attention` in the device trace."""
     slots, heads, window, depth = q.shape
     _, _, kv_heads, block, _ = pool_k.shape
     max_blocks = tables.shape[1]
@@ -895,7 +1036,7 @@ def paged_attention(q, pool_k, pool_v, layer, tables, positions,
     # the group's repeats x W query rows are one matmul operand; padded
     # with zero rows to the dtype's sublane tile (sliced off below)
     grouped = _pad_seq(q.reshape(slots, kv_heads, rows, depth),
-                       8 * 4 // jnp.dtype(q.dtype).itemsize)
+                       _sublane_rows(q.dtype))
     padded_rows = grouped.shape[2]
     chunk_blocks = max(1, min(_PAGED_CHUNK_BLOCKS_MAX,
                               _PAGED_CHUNK_POSITIONS // block, max_blocks))
@@ -905,26 +1046,51 @@ def paged_attention(q, pool_k, pool_v, layer, tables, positions,
 
     pools = (pool_k,) if pool_v is None else (pool_k, pool_v)
     out_depth = depth if pool_v is not None else int(value_dim)
+    scalars = [jnp.reshape(layer, (1,)).astype(jnp.int32),
+               tables.reshape(-1).astype(jnp.int32), positions, live]
+    new_rows, aliased, told_scratch, aliases = (), (), [], {}
+    if write is not None:
+        assert paged_attention_writes(window), window
+        tile = _tile_positions(pool_k.dtype, block)
+        new_rows, write_blocks, write_offsets = write
+        # (slots, kv_heads, 1, d) -> (slots, 1, kv_heads x d): the heads
+        # side by side, as the projection made them
+        new_rows = tuple(
+            new.astype(pool.dtype).transpose(0, 2, 1, 3).reshape(
+                slots, 1, kv_heads * depth)
+            for new, pool in zip(new_rows, pools))
+        scalars += [write_blocks.reshape(-1).astype(jnp.int32),
+                    write_offsets.reshape(-1).astype(jnp.int32)]
+        told_scratch = [
+            pltpu.VMEM((2, kv_heads, tile, depth), pool.dtype)
+            for pool in pools] + [pltpu.SemaphoreType.DMA((len(pools), 2))]
+        # operand -> result: the leaves follow the scalars, q and rows
+        aliased = pools
+        aliases = {len(scalars) + 1 + len(new_rows) + leaf: 1 + leaf
+                   for leaf in range(len(pools))}
     kernel = functools.partial(
         _paged_kernel, window=window, block=block,
         chunk_blocks=chunk_blocks, max_blocks=max_blocks,
         sm_scale=(1.0 / math.sqrt(depth) if sm_scale is None
                   else float(sm_scale)),
-        value_dim=None if pool_v is not None else out_depth)
-    q_spec = pl.BlockSpec((1, kv_heads, padded_rows, depth),
-                          lambda s, *_: (s, 0, 0, 0),
+        value_dim=None if pool_v is not None else out_depth,
+        writes=write is not None)
+    by_slot = lambda s, *_: (s, 0, 0, 0)                   # noqa: E731
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    o_spec = pl.BlockSpec((1, kv_heads, padded_rows, out_depth), by_slot,
                           memory_space=pltpu.VMEM)
-    o_spec = pl.BlockSpec((1, kv_heads, padded_rows, out_depth),
-                          lambda s, *_: (s, 0, 0, 0),
-                          memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
+    out, *written = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(scalars),
             grid=(slots,),
-            in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)
-                                 for _ in pools],
-            out_specs=o_spec,
+            in_specs=[pl.BlockSpec((1, kv_heads, padded_rows, depth),
+                                   by_slot, memory_space=pltpu.VMEM)] + [
+                pl.BlockSpec((1, 1, kv_heads * depth),
+                             lambda s, *_: (s, 0, 0),
+                             memory_space=pltpu.VMEM)
+                for _ in new_rows] + [in_hbm for _ in pools],
+            out_specs=[o_spec] + [in_hbm for _ in aliased],
             scratch_shapes=[
                 pltpu.VMEM((2, kv_heads, chunk, depth), pool.dtype)
                 for pool in pools] + [
@@ -936,19 +1102,22 @@ def paged_attention(q, pool_k, pool_v, layer, tables, positions,
                            jnp.float32),                   # l
                 pltpu.VMEM((kv_heads, padded_rows, out_depth),
                            jnp.float32),                   # acc
-            ]),
-        out_shape=jax.ShapeDtypeStruct(
-            grouped.shape[:3] + (out_depth,), q.dtype),
+            ] + told_scratch),
+        out_shape=[jax.ShapeDtypeStruct(
+            grouped.shape[:3] + (out_depth,), q.dtype)] + [
+            jax.ShapeDtypeStruct(pool.shape, pool.dtype)
+            for pool in aliased],
+        input_output_aliases=aliases,
         # the buffer parity and the copy in flight cross grid steps
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name=("paged_attention" if pool_v is not None
               else "mla_paged_attention"),
         interpret=_interpret(),
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-      tables.reshape(-1).astype(jnp.int32), positions, live,
-      grouped, *pools)
-    return out[:, :, :rows].reshape(slots, heads, window, out_depth)
+    )(*scalars, grouped, *new_rows, *pools)
+    pool_k, pool_v = (tuple(written) or pools) + (None,) * (2 - len(pools))
+    return (out[:, :, :rows].reshape(slots, heads, window, out_depth),
+            pool_k, pool_v)
 
 
 # -- Ring attention (sequence parallel) -------------------------------------

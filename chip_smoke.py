@@ -64,7 +64,8 @@ from aiko_services_tpu.parallel import (
     create_mesh, filter_specs, shard_pytree)
 from aiko_services_tpu.parallel.attention import (
     attention_reference, flash_attention, paged_attention,
-    paged_attention_reference, paged_attention_takes)
+    paged_attention_reference, paged_attention_takes,
+    paged_attention_writes)
 from aiko_services_tpu.pipeline import create_pipeline
 from aiko_services_tpu.runtime import (
     Process, Registrar, cache_stats, enable_compile_cache)
@@ -697,10 +698,28 @@ def phase_kernels(sizes: Sizes, report: Report, platform: str) -> None:
             keys[2], (slots, kv_heads * repeats, window, 128), dtype)
         _require(paged_attention_takes(q.shape[1], window, 128, dtype),
                  f"paged_attention refuses window {window}")
-        got, want = (np.asarray(attend(q, pool_k, pool_v, jnp.int32(1),
-                                       tables, positions), np.float32)
-                     for attend in (paged_attention,
-                                    paged_attention_reference))
+        # at window 1 the kernel writes the step's new rows itself:
+        # against the oracle over a pool with the same rows put in,
+        # which the written leaves must then equal bit for bit
+        write, pools = None, (pool_k, pool_v)
+        if paged_attention_writes(window):
+            rows = tuple(jax.random.normal(key, (slots, kv_heads, 1, 128),
+                                           dtype)
+                         for key in jax.random.split(keys[2]))
+            blocks = tables[jnp.arange(slots), positions // 32]
+            write = (rows, blocks[:, None], (positions % 32)[:, None])
+            pools = tuple(pool.at[1, blocks, :, positions % 32].set(
+                new[:, :, 0]) for pool, new in zip(pools, rows))
+        got, *written = paged_attention(
+            q, jnp.copy(pool_k), jnp.copy(pool_v), jnp.int32(1), tables,
+            positions, write=write)
+        _require(all(bool(jnp.array_equal(leaf, pool))
+                     for leaf, pool in zip(written, pools)),
+                 f"paged_attention W={window}: the pool it hands back is "
+                 f"not the pool with the told rows written")
+        got, want = (np.asarray(out, np.float32) for out in (
+            got, paged_attention_reference(q, *pools, jnp.int32(1), tables,
+                                           positions)))
         _require(np.all(np.isfinite(got)),
                  f"paged_attention W={window}: non-finite output")
         paged_error = max(paged_error, float(np.abs(got - want).max()))

@@ -159,6 +159,36 @@ def test_engine_counts_the_attention_each_whole_prefill_takes(
             done[index].tokens, reference(params, grouped, prompt, 4))
 
 
+@pytest.mark.parametrize("case", ["plain", "chunked", "int8"])
+def test_engine_counts_who_writes_each_windows_rows(tiny_model, case):
+    """stats() counts the paged window calls by who puts their new rows
+    into the pool, asking the model (pool_write_kind, by which the step
+    itself decides): every plain decode step is the kernel's, a prefill
+    chunk's window and an int8 pool keep the unrolled updates -- and the
+    tokens are the closed batch's either way."""
+    from aiko_services_tpu.decode import engine as engine_module
+    assert "paged_attention_writes" not in vars(engine_module)
+    params, config = tiny_model
+    if case == "int8":
+        config = TransformerConfig(**{**TINY, "kv_dtype": "int8"})
+    prompts = [np.arange(1, n, dtype=np.int32) for n in (6, 10, 4)]
+    engine = DecodeEngine(
+        params, config, decode_slots=3, kv_block_size=8,
+        prefill_chunk_size=4 if case == "chunked" else None)
+    for index, prompt in enumerate(prompts):
+        engine.submit(index, prompt, 4)
+    done = drain(engine)
+    stats = engine.stats()
+    assert stats["decode_steps"] > 0
+    steps, chunks = stats["decode_steps"], stats["prefill_chunks"]
+    assert (chunks > 0) == (case == "chunked")
+    assert (stats["writes_kernel"], stats["writes_updates"]) == (
+        (0, steps) if case == "int8" else (steps, chunks))
+    for index, prompt in enumerate(prompts):
+        np.testing.assert_array_equal(
+            done[index].tokens, reference(params, config, prompt, 4))
+
+
 def test_engine_eos_frees_slot_early(tiny_model):
     """A sequence hitting eos_id completes before max_new; its tokens
     are EOS-padded to the fixed width and its slot frees immediately."""

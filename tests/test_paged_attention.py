@@ -81,7 +81,9 @@ def test_kernel_matches_einsum_oracle(kv_heads, repeats, window, dtype):
                                 attention._PAGED_CHUNK_POSITIONS // BLOCK)
     assert attention._PAGED_NARROW < CHUNK     # both widths are run
     layer = jnp.int32(1)
-    out = paged_attention(q, pool_k, pool_v, layer, tables, positions)
+    out, same_k, same_v = paged_attention(q, pool_k, pool_v, layer, tables,
+                                          positions)
+    assert same_k is pool_k and same_v is pool_v     # nothing was written
     oracle = paged_attention_reference(q, pool_k, pool_v, layer, tables,
                                        positions)
     assert out.shape == q.shape and out.dtype == q.dtype
@@ -105,8 +107,9 @@ def test_latent_kernel_matches_einsum_oracle(heads, window, dtype):
     assert paged_attention_takes(heads, window, DEPTH, pool.dtype,
                                  value_dim=value_dim)
     layer = jnp.int32(1)
-    out = paged_attention(q, pool, None, layer, tables, positions,
-                          sm_scale=scale, value_dim=value_dim)
+    out, _, no_v = paged_attention(q, pool, None, layer, tables, positions,
+                                   sm_scale=scale, value_dim=value_dim)
+    assert no_v is None
     oracle = paged_attention_reference(
         q, pool, None, layer, tables, positions, sm_scale=scale,
         value_dim=value_dim)
@@ -117,14 +120,145 @@ def test_latent_kernel_matches_einsum_oracle(heads, window, dtype):
                                atol=TOLERANCE[dtype], rtol=0)
     # the scale is read: the default 1/sqrt(depth) gives other numbers
     other = paged_attention(q, pool, None, layer, tables, positions,
-                            value_dim=value_dim)
+                            value_dim=value_dim)[0]
     assert np.abs(np.asarray(other, np.float32) - out).max() > 1e-3
+
+
+# -- the kernel writes the step's new rows itself (window 1) ----------------
+
+WRITE_BLOCK = 32                # the served block: two bfloat16 tiles of 16
+WRITE_MAX_BLOCKS = 20           # capacity 640: a chunk of 512 and a tail
+# where a live slot's cursor lies: the first position, both sides of a
+# float32 tile's edge (7 | 8), of a bfloat16 tile's (15 | 16) and of a block's
+# (31 | 32), odd and even (the packed pair), inside the second chunk, the
+# table's last position
+WRITE_CURSORS = (0, 7, 8, 15, 16, 31, 32, 200, 519, 639)
+PARKED = 100                    # a live table whose told row is the trash's
+
+
+def _write_case(kv_heads, heads, dtype, seed):
+    """Live slots at WRITE_CURSORS, told to write where their table puts
+    their position; one parked slot (a live table, as a slot in the
+    middle of its prefill has: its row goes to the trash block, offset
+    3); two idle slots (every table entry the trash block, row 0)."""
+    rng = np.random.default_rng(seed)
+    positions = np.array(WRITE_CURSORS + (PARKED, 0, 0), np.int32)
+    slots, live = len(positions), len(WRITE_CURSORS)
+    num_blocks = slots * WRITE_MAX_BLOCKS + 1
+    shape = (LAYERS, num_blocks, kv_heads, WRITE_BLOCK, DEPTH)
+    pool_k = rng.normal(size=shape).astype(np.float32)
+    pool_v = rng.normal(size=shape).astype(np.float32)
+    tables = rng.permutation(np.arange(1, num_blocks))[
+        :slots * WRITE_MAX_BLOCKS].reshape(slots, WRITE_MAX_BLOCKS).astype(
+        np.int32)
+    tables[live + 1:] = TRASH_BLOCK
+    write_blocks = tables[np.arange(slots), positions // WRITE_BLOCK]
+    write_offsets = positions % WRITE_BLOCK
+    write_blocks[live], write_offsets[live] = TRASH_BLOCK, 3
+    q = rng.normal(size=(slots, heads, 1, DEPTH))
+    new_k, new_v = rng.normal(size=(2, slots, kv_heads, 1, DEPTH))
+    as_dtype = lambda *arrays: [jnp.asarray(a, dtype) for a in arrays]
+    return (*as_dtype(q, pool_k, pool_v, new_k, new_v),
+            jnp.asarray(tables), jnp.asarray(positions),
+            jnp.asarray(write_blocks[:, None]),
+            jnp.asarray(write_offsets[:, None]), live)
+
+
+def _assert_written_only_where_told(before, after, new, layer,
+                                    write_blocks, write_offsets, live):
+    """`after` is `before` with the live slots' new rows at (layer, told
+    block, :, told offset); a told row of the trash block holds what it
+    held or what any slot was told to put there; every other position
+    -- a told row's neighbours in its tile above all -- is bit for bit
+    what it was."""
+    before, after, new = (np.asarray(a, np.float32)
+                          for a in (before, after, new))
+    blocks = np.asarray(write_blocks)[:, 0]
+    offsets = np.asarray(write_offsets)[:, 0]
+    expected = before.copy()
+    for slot in range(live):
+        expected[layer, blocks[slot], :, offsets[slot]] = new[slot, :, 0]
+    told = {(int(b), int(o)) for b, o in zip(blocks[live:], offsets[live:])}
+    assert {b for b, _ in told} == {TRASH_BLOCK}
+    for block, offset in told:
+        row = after[layer, block, :, offset]
+        candidates = [before[layer, block, :, offset]] + [
+            new[slot, :, 0] for slot in range(live, len(blocks))
+            if (blocks[slot], offsets[slot]) == (block, offset)]
+        assert any(np.array_equal(row, c) for c in candidates)
+        expected[layer, block, :, offset] = row
+    np.testing.assert_array_equal(after, expected)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kv_heads,repeats", [(8, 4), (16, 1), (2, 2)])
+def test_kernel_writes_the_new_rows_and_nothing_else(kv_heads, repeats,
+                                                     dtype):
+    """The kernel with `write` against its oracle after _write_window:
+    the same attention over the written pool, and a pool that differs
+    from the one that came in only in the told rows."""
+    from aiko_services_tpu.models.transformer import _write_window
+    (q, pool_k, pool_v, new_k, new_v, tables, positions, write_blocks,
+     write_offsets, live) = _write_case(
+        kv_heads, kv_heads * repeats, jnp.dtype(dtype),
+        seed=kv_heads * 10 + repeats)
+    assert attention.paged_attention_writes(1)
+    assert not attention.paged_attention_writes(2)
+    layer = 1
+    out, after_k, after_v = jax.jit(
+        lambda *args: paged_attention(
+            *args[:3], jnp.int32(layer), tables, positions,
+            write=(args[3:], write_blocks, write_offsets)),
+        donate_argnums=(1, 2))(q, jnp.copy(pool_k), jnp.copy(pool_v),
+                               new_k, new_v)
+    for before, after, new in ((pool_k, after_k, new_k),
+                               (pool_v, after_v, new_v)):
+        assert after.shape == before.shape and after.dtype == before.dtype
+        _assert_written_only_where_told(before, after, new, layer,
+                                        write_blocks, write_offsets, live)
+    oracle = paged_attention_reference(
+        q, *(_write_window(pool, new, layer, write_blocks, write_offsets)
+             for pool, new in ((pool_k, new_k), (pool_v, new_v))),
+        layer, tables, positions)
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all()
+    # the parked and the idle slots' outputs are nobody's
+    np.testing.assert_allclose(out[:live], np.asarray(
+        oracle, np.float32)[:live], atol=TOLERANCE[dtype], rtol=0)
+    # the fresh row is attended: without it the first slot, whose only
+    # visible position it is, reads something else
+    stale, _, _ = paged_attention(q, pool_k, pool_v, jnp.int32(layer),
+                                  tables, positions)
+    assert np.abs(np.asarray(stale, np.float32)[0] - out[0]).max() > 1e-2
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_latent_kernel_writes_the_new_row_and_nothing_else(dtype):
+    """The latent pool: one leaf, one key head, one new row a slot."""
+    from aiko_services_tpu.models.transformer import _write_window
+    (q, pool, _, new, _, tables, positions, write_blocks, write_offsets,
+     live) = _write_case(1, 8, jnp.dtype(dtype), seed=7)
+    value_dim, scale, layer = 24, 0.31, 1
+    out, after, no_v = paged_attention(
+        q, pool, None, jnp.int32(layer), tables, positions, sm_scale=scale,
+        value_dim=value_dim, write=((new,), write_blocks, write_offsets))
+    assert no_v is None
+    _assert_written_only_where_told(pool, after, new, layer, write_blocks,
+                                    write_offsets, live)
+    oracle = paged_attention_reference(
+        q, _write_window(pool, new, layer, write_blocks, write_offsets),
+        None, layer, tables, positions, sm_scale=scale,
+        value_dim=value_dim)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32)[:live],
+        np.asarray(oracle, np.float32)[:live], atol=TOLERANCE[dtype],
+        rtol=0)
 
 
 def test_kernel_reads_the_layer_it_is_given():
     q, pool_k, pool_v, tables, positions = _case(2, 2, 1, jnp.float32, 5)
     outs = [np.asarray(paged_attention(q, pool_k, pool_v, jnp.int32(layer),
-                                       tables, positions))
+                                       tables, positions)[0])
             for layer in range(LAYERS)]
     assert np.abs(outs[0] - outs[1]).max() > 1e-2
     for layer, out in enumerate(outs):
@@ -232,15 +366,44 @@ def test_kernel_compiles_for_the_v5e_at_the_served_shape(
     pool = for_the_chip((s["layers"], s["blocks"], s["kv_heads"],
                          s["block"], s["depth"]), dtype)
     slots = s["slots"] if window < 64 else 1        # a prefill chunk
-    compiled = jax.jit(paged_attention).lower(
-        for_the_chip((slots, s["kv_heads"] * s["repeats"], window,
-                      s["depth"]), dtype),
-        pool, pool, for_the_chip((), "int32"),
-        for_the_chip((slots, s["max_blocks"]), "int32"),
-        for_the_chip((slots,), "int32")).compile()
+    compiled = _compile_kernel(
+        for_the_chip, pool, slots, s["kv_heads"] * s["repeats"], window,
+        s["max_blocks"])
     assert "tpu_custom_call" in compiled.as_text()
     # the pool is read where it lies: nothing of its size is allocated
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def _compile_kernel(for_the_chip, pool, slots, heads, window, max_blocks,
+                    **latent):
+    """The kernel compiled for the described chip as the model step calls
+    it: at window 1 with the rows to write and the leaves donated, which
+    must then come back as the buffers they were; pool_v None with
+    `latent`'s value_dim."""
+    _, _, kv_heads, _, depth = pool.shape
+    dtype = pool.dtype
+    leaves = 1 if latent else 2
+    int32 = lambda *shape: for_the_chip(shape, "int32")    # noqa: E731
+    writes = attention.paged_attention_writes(window)
+
+    def call(q, layer, tables, positions, told, *arrays):
+        pools = arrays[:leaves] + (None,) * (2 - leaves)
+        write = (arrays[leaves:], *told) if writes else None
+        return paged_attention(q, *pools, layer, tables, positions,
+                               write=write, **latent)
+
+    new = for_the_chip((slots, kv_heads, 1, depth), dtype)
+    compiled = jax.jit(call, donate_argnums=tuple(
+        range(5, 5 + leaves))).lower(
+        for_the_chip((slots, heads, window, depth), dtype), int32(),
+        int32(slots, max_blocks), int32(slots),
+        (int32(slots, 1), int32(slots, 1)), *(pool,) * leaves,
+        *(new,) * (leaves if writes else 0)).compile()
+    if writes:
+        leaf_bytes = int(np.prod(pool.shape)) * jnp.dtype(dtype).itemsize
+        assert (compiled.memory_analysis().alias_size_in_bytes
+                == leaves * leaf_bytes)
+    return compiled
 
 
 # benchmark/configs/ouro_2.6b.json: as many K/V heads as query heads (a
@@ -253,15 +416,19 @@ def test_kernel_compiles_for_the_v5e_at_sixteen_kv_heads(for_the_chip):
     s = OURO
     pool = for_the_chip((s["caches"], 168, s["kv_heads"], s["block"],
                          s["depth"]), "bfloat16")
-    compiled = jax.jit(paged_attention).lower(
-        for_the_chip((s["slots"], s["kv_heads"], 1, s["depth"]),
-                     "bfloat16"),
-        pool, pool, for_the_chip((), "int32"),
-        for_the_chip((s["slots"], s["max_blocks"]), "int32"),
-        for_the_chip((s["slots"],), "int32")).compile()
+    compiled = _compile_kernel(for_the_chip, pool, s["slots"],
+                               s["kv_heads"], 1, s["max_blocks"])
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_attention" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def _made_of_a_leaf(text: str, leaf, operation: str) -> list:
+    """The compiled program's lines in which `operation` makes an array
+    of a pool leaf's type."""
+    leaf_type = "bf16[" + ",".join(map(str, leaf.shape)) + "]"
+    return [line for line in text.splitlines()
+            if f"= {leaf_type}" in line and operation in line]
 
 
 def _served_ouro(for_the_chip):
@@ -340,10 +507,10 @@ def test_served_decode_step_moves_no_pool_leaf_on_the_v5e(for_the_chip,
     # gathered view, no copy of a leaf (a scatter made two a layer)
     assert memory.alias_size_in_bytes == 2 * leaf_bytes
     assert memory.temp_size_in_bytes < leaf_bytes // 16
-    leaf_type = "bf16[" + ",".join(map(str, leaf.shape)) + "]"
-    moved = [line for line in text.splitlines()
-             if f"= {leaf_type}" in line and " copy(" in line]
-    assert not moved, moved[:2]
+    assert not _made_of_a_leaf(text, leaf, " copy(")
+    # and its new rows are the kernel's to write: no update of a leaf, a
+    # device operation a slot a leaf a cache (3,072 a step of ouro_2.6b)
+    assert not _made_of_a_leaf(text, leaf, " dynamic-update-slice(")
     if served == "ouro_2.6b":
         # weights 5.13 GB + pool: what the chip holds (serve.why)
         assert 13.2e9 < memory.argument_size_in_bytes < 13.7e9
@@ -395,15 +562,9 @@ def test_latent_kernel_compiles_for_the_v5e_at_the_served_shape(
                                      value_dim=s["rank"])
     pool = for_the_chip((s["layers"], s["blocks"], 1, s["block"],
                          s["row"]), "bfloat16")
-    compiled = jax.jit(lambda q, pool, layer, tables, positions:
-                       paged_attention(q, pool, None, layer, tables,
-                                       positions, sm_scale=0.1,
-                                       value_dim=s["rank"])).lower(
-        for_the_chip((s["slots"], s["heads"], window, s["row"]),
-                     "bfloat16"),
-        pool, for_the_chip((), "int32"),
-        for_the_chip((s["slots"], s["max_blocks"]), "int32"),
-        for_the_chip((s["slots"],), "int32")).compile()
+    compiled = _compile_kernel(
+        for_the_chip, pool, s["slots"], s["heads"], window,
+        s["max_blocks"], sm_scale=0.1, value_dim=s["rank"])
     assert "tpu_custom_call" in compiled.as_text()
     assert "mla_paged_attention" in compiled.as_text()
     # the one leaf is read where it lies
@@ -482,6 +643,8 @@ def test_served_share_decode_step_copies_no_pool_and_no_expert(
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == leaf_bytes
     assert memory.temp_size_in_bytes < 1 << 27
+    assert not _made_of_a_leaf(text, leaf, " copy(")
+    assert not _made_of_a_leaf(text, leaf, " dynamic-update-slice(")
     # weights 10.07 GB + pool 0.84 GB: what the chip holds
     assert 10.8e9 < memory.argument_size_in_bytes < 11.0e9
 

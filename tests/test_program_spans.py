@@ -520,7 +520,8 @@ SERVED_SPANS = {
     "engine.step": {"waiting", "active", "decoding", "admitted"},
     "engine.prefill": REQUEST | {"bucket", "true_len", "queue_us",
                                  "attention"},
-    "engine.decode": {"decoding", "ahead", "live_blocks", "table_blocks"},
+    "engine.decode": {"decoding", "ahead", "live_blocks", "table_blocks",
+                      "write"},
     "engine.readback": set(),
     "engine.chunk": REQUEST | {"offset", "tokens", "waited_us", "first_us",
                                "ingress_us"},
@@ -576,6 +577,10 @@ class TestServedSpans:
             event[4]["ahead"] for event in decodes)
         assert 0 < stats["steps_ahead"] < stats["decode_steps"]
         assert stats["overrun_tokens"] == 0
+        # every step's rows were written by the paged kernel itself
+        assert {event[4]["write"] for event in decodes} == {"kernel"}
+        assert (stats["writes_kernel"], stats["writes_updates"]) == (
+            len(decodes), 0)
 
     def test_spans_of_one_request_share_its_trace_id(self, served_run):
         recorded, run = served_run
