@@ -23,4 +23,12 @@
 #   models/    flagship model families: LLM (Llama-style), Whisper, YOLO
 #   elements/  pipeline elements: media I/O + ML elements over models/
 
+import time as _time
+
+# The process epoch: the zero of the start-up gauges (`setup.boot_s`,
+# `setup.ready_s`; runtime/compile_cache.py).  First statement of the
+# package, which imports nothing else, so it lies within the
+# interpreter's own start of whatever imported it.
+PROCESS_EPOCH = _time.perf_counter()
+
 __version__ = "0.1.0"

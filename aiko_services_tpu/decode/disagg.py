@@ -46,7 +46,9 @@ import numpy as np
 
 from ..models import (
     init_paged_pool, paged_prefill, paged_prefill_chunk)
+from ..observe.trace import NO_SPANS
 from ..pipeline.transfer import fetch_many, get_transfer_server
+from ..runtime.compile_cache import compile_bracket, setup_interval
 from ..utils import get_logger
 from ..utils.padding import bucket_length
 from .blocks import TRASH_BLOCK, BlockManager
@@ -145,7 +147,8 @@ class PrefillEngine:
     def __init__(self, params, config, *, kv_block_size: int = 16,
                  kv_blocks: int | None = None,
                  max_context: int | None = None,
-                 prefill_chunk_size: int | None = None, registry=None):
+                 prefill_chunk_size: int | None = None, registry=None,
+                 spans=None, node: str = "prefill"):
         self.params = params
         self.config = config
         max_context = int(max_context or config.max_seq_len)
@@ -154,9 +157,19 @@ class PrefillEngine:
         if kv_blocks is None:
             kv_blocks = self.max_blocks + 1
         self.blocks = BlockManager(int(kv_blocks), int(kv_block_size))
-        self.pool = init_paged_pool(config, self.blocks.num_blocks,
-                                    self.blocks.block_size)
-        self.table = np.full((self.max_blocks,), TRASH_BLOCK, np.int32)
+        # the owning pipeline's telemetry seam and this engine's name
+        # on its start-up spans and `aiko:compile` marks, as
+        # DecodeEngine's
+        self._spans = spans if spans is not None else NO_SPANS
+        self._node = node
+        with setup_interval("state", self._spans.span(
+                "setup.state", node=node, what="pool",
+                blocks=self.blocks.num_blocks)) as interval:
+            self.pool = init_paged_pool(config, self.blocks.num_blocks,
+                                        self.blocks.block_size)
+            self.table = np.full((self.max_blocks,), TRASH_BLOCK,
+                                 np.int32)
+            interval.holds(self.pool)
         self.waiting: deque[_PrefillJob] = deque()
         self._active: _PrefillJob | None = None
         self._registry = registry
@@ -169,18 +182,19 @@ class PrefillEngine:
         self.counters = {"submitted": 0, "exported": 0, "chunks": 0,
                          "compiles": 0, "exported_bytes": 0}
 
-    def _jit_cache_size(self) -> int:
-        return (paged_prefill._cache_size()
-                + paged_prefill_chunk._cache_size())
-
     @property
     def compile_count(self) -> int:
         return self.counters["compiles"]
 
-    def _note_compiles(self, delta: int) -> None:
-        if delta > 0:
-            self.counters["compiles"] += delta
-            self._bump("prefill.compiles", delta)
+    def _compiling(self, what: str):
+        return compile_bracket(self._note_compiles, what)
+
+    def _note_compiles(self, waited_s: float, programs: int, args: dict,
+                       what: str) -> None:
+        self.counters["compiles"] += programs
+        self._bump("prefill.compiles", programs)
+        self._spans.mark("compile", waited_s, node=self._node, what=what,
+                         **args)
 
     def _bump(self, name: str, amount) -> None:
         if self._registry is not None:
@@ -261,11 +275,10 @@ class PrefillEngine:
         job = self._active
         if (self.prefill_chunk is None
                 or self.prefill_chunk >= job.bucket):
-            before = self._jit_cache_size()
-            self.pool, first = paged_prefill(
-                self.params, self.config, self.pool, job.padded[None],
-                self.table, np.int32(job.true_len))
-            self._note_compiles(self._jit_cache_size() - before)
+            with self._compiling("paged_prefill"):
+                self.pool, first = paged_prefill(
+                    self.params, self.config, self.pool,
+                    job.padded[None], self.table, np.int32(job.true_len))
             job.prefill_pos = job.bucket
             return [self._finish(job, int(first))]
         return self._step_chunk(job)
@@ -286,11 +299,10 @@ class PrefillEngine:
             if position < job.true_len:
                 write_blocks[offset] = job.blocks[position // block_size]
             write_offsets[offset] = position % block_size
-        before = self._jit_cache_size()
-        self.pool, greedy = paged_prefill_chunk(
-            self.params, self.config, self.pool, chunk, self.table,
-            np.int32(start), write_blocks, write_offsets)
-        self._note_compiles(self._jit_cache_size() - before)
+        with self._compiling("paged_prefill_chunk"):
+            self.pool, greedy = paged_prefill_chunk(
+                self.params, self.config, self.pool, chunk, self.table,
+                np.int32(start), write_blocks, write_offsets)
         self.counters["chunks"] += 1
         self._bump("prefill.chunks", 1)
         job.prefill_pos = start + take

@@ -82,6 +82,7 @@ from ..models import (
     pool_write_kind)
 from ..observe.trace import NO_SPANS
 from ..parallel.attention import paged_live_blocks
+from ..runtime.compile_cache import compile_bracket, setup_interval
 from ..utils import get_logger
 from ..utils.padding import bucket_length
 from .blocks import TRASH_BLOCK, BlockManager
@@ -161,13 +162,6 @@ class _Slot:
         return self.prefill_pos < self.true_len
 
 
-def _jit_cache_size() -> int:
-    return (paged_prefill._cache_size()
-            + paged_decode_step._cache_size()
-            + paged_prefill_chunk._cache_size()
-            + paged_verify_step._cache_size())
-
-
 class DecodeEngine:
     """Continuous-batching greedy decode over one transformer.
 
@@ -212,12 +206,21 @@ class DecodeEngine:
         self.prefix_policy = policy
         self.prefix = (PrefixCache(self.blocks, policy.cache_blocks)
                        if policy is not None else None)
-        self.pool = init_paged_pool(config, self.blocks.num_blocks,
-                                    self.blocks.block_size)
-        self.tables = np.full((self.slots_n, self.max_blocks),
-                              TRASH_BLOCK, np.int32)
-        self.positions = np.zeros((self.slots_n,), np.int32)
-        self.last_tokens = np.zeros((self.slots_n, 1), np.int32)
+        # program spans (observe/trace.py): the owning pipeline's
+        # telemetry seam, which resolves a request id to its frame;
+        # `node` names this engine in `aiko:compile` marks
+        self._spans = spans if spans is not None else NO_SPANS
+        self._node = node
+        with setup_interval("state", self._spans.span(
+                "setup.state", node=node, what="pool",
+                blocks=self.blocks.num_blocks)) as interval:
+            self.pool = init_paged_pool(config, self.blocks.num_blocks,
+                                        self.blocks.block_size)
+            self.tables = np.full((self.slots_n, self.max_blocks),
+                                  TRASH_BLOCK, np.int32)
+            self.positions = np.zeros((self.slots_n,), np.int32)
+            self.last_tokens = np.zeros((self.slots_n, 1), np.int32)
+            interval.holds(self.pool)
         self.slots: list[_Slot | None] = [None] * self.slots_n
         self.waiting: deque[_Request] = deque()
         # the decode step dispatched and not yet read.  While there is
@@ -229,11 +232,6 @@ class DecodeEngine:
         self._carry = StepReport()
         self._admission_seq = 0
         self._registry = registry
-        # program spans (observe/trace.py): the owning pipeline's
-        # telemetry seam, which resolves a request id to its frame;
-        # `node` names this engine in `aiko:compile` instants
-        self._spans = spans if spans is not None else NO_SPANS
-        self._node = node
         # chunked prefill: coerced to a power-of-two block multiple so
         # the per-chunk executables stay logarithmic; a chunk covering
         # max_context degenerates to the monolithic path
@@ -265,9 +263,13 @@ class DecodeEngine:
             # per slot: the draft is small, so the reservation is cheap
             # and speculation stays out of the target pool's
             # allocation/preemption logic entirely
-            self.draft_pool = init_paged_pool(
-                draft_config, self.slots_n * self.max_blocks + 1,
-                self.blocks.block_size)
+            draft_blocks = self.slots_n * self.max_blocks + 1
+            with setup_interval("state", self._spans.span(
+                    "setup.state", node=node, what="draft_pool",
+                    blocks=draft_blocks)) as interval:
+                self.draft_pool = init_paged_pool(
+                    draft_config, draft_blocks, self.blocks.block_size)
+                interval.holds(self.draft_pool)
             self.draft_tables = np.zeros(
                 (self.slots_n, self.max_blocks), np.int32)
             for index in range(self.slots_n):
@@ -316,20 +318,20 @@ class DecodeEngine:
         to the compile."""
         if self.has_work():
             raise RuntimeError("warm() is for an engine with no request")
-        before = _jit_cache_size()
         for bucket in sorted({self._bucket(int(length))
                               for length in buckets}):
-            self.pool, _ = paged_prefill(
-                self.params, self.config, self.pool,
-                np.zeros((1, bucket), np.int32), self.tables[0],
-                np.int32(1))
+            with self._compiling("warm"):
+                self.pool, _ = paged_prefill(
+                    self.params, self.config, self.pool,
+                    np.zeros((1, bucket), np.int32), self.tables[0],
+                    np.int32(1))
         idle = np.zeros((self.slots_n,), np.int32)
-        self.pool, tokens, *_ = paged_decode_step(
-            self.params, self.config, self.pool, self.tables.copy(),
-            self.positions.copy(), jnp.asarray(self.last_tokens.copy()),
-            idle, idle.copy())
+        with self._compiling("warm"):
+            self.pool, tokens, *_ = paged_decode_step(
+                self.params, self.config, self.pool, self.tables.copy(),
+                self.positions.copy(),
+                jnp.asarray(self.last_tokens.copy()), idle, idle.copy())
         np.asarray(tokens)          # wait for the last of them
-        self._note_compiles(_jit_cache_size() - before, "warm")
 
     # -- submission --------------------------------------------------------
 
@@ -772,15 +774,13 @@ class DecodeEngine:
             # runs: a jitted call may read a numpy argument in place
             tokens = (self._inflight.tokens if ahead
                       else jnp.asarray(self.last_tokens.copy()))
-            before = _jit_cache_size()
             # routed experts and a looped stack hand their counts out
             # after the tokens
-            self.pool, next_tokens, *counted = paged_decode_step(
-                self.params, self.config, self.pool, self.tables.copy(),
-                self.positions.copy(), tokens, write_blocks,
-                write_offsets)
-            self._note_compiles(_jit_cache_size() - before,
-                                "paged_decode_step")
+            with self._compiling("paged_decode_step"):
+                self.pool, next_tokens, *counted = paged_decode_step(
+                    self.params, self.config, self.pool,
+                    self.tables.copy(), self.positions.copy(), tokens,
+                    write_blocks, write_offsets)
         rows = {index: self.slots[index].seq for index in decoding}
         self.positions[decoding] += 1
         self.counters["decode_steps"] += 1
@@ -926,12 +926,11 @@ class DecodeEngine:
                 self._tail_prefill(index, report)
                 continue
             with self._prefill_span(slot, bucket):
-                before = _jit_cache_size()
-                self.pool, first = paged_prefill(
-                    self.params, self.config, self.pool, padded[None],
-                    self.tables[index], np.int32(true_len))
-                self._note_compiles(_jit_cache_size() - before,
-                                    "paged_prefill")
+                with self._compiling("paged_prefill"):
+                    self.pool, first = paged_prefill(
+                        self.params, self.config, self.pool,
+                        padded[None], self.tables[index],
+                        np.int32(true_len))
                 first = int(first)  # the readback waits for the prefill
             slot.prefill_pos = bucket
             self._finish_prefill(index, report, first)
@@ -1034,13 +1033,11 @@ class DecodeEngine:
                     position // block_size]
             write_offsets[offset] = position % block_size
         with self._prefill_span(slot, size, start):
-            before = _jit_cache_size()
-            self.pool, greedy = paged_prefill_chunk(
-                self.params, self.config, self.pool, chunk,
-                self.tables[index], np.int32(start), write_blocks,
-                write_offsets)
-            self._note_compiles(_jit_cache_size() - before,
-                                "paged_prefill_chunk")
+            with self._compiling("paged_prefill_chunk"):
+                self.pool, greedy = paged_prefill_chunk(
+                    self.params, self.config, self.pool, chunk,
+                    self.tables[index], np.int32(start), write_blocks,
+                    write_offsets)
             first = int(np.asarray(greedy)[slot.true_len - 1 - start])
         self._finish_prefill(index, report, first)
 
@@ -1080,12 +1077,11 @@ class DecodeEngine:
         the target's prefill output is the authoritative greedy token;
         the draft only ever proposes."""
         slot = self.slots[index]
-        before = _jit_cache_size()
-        self.draft_pool, _ = paged_prefill(
-            self.draft_params, self.draft_config, self.draft_pool,
-            slot.padded[None], self.draft_tables[index],
-            np.int32(slot.true_len))
-        self._note_compiles(_jit_cache_size() - before, "draft_prefill")
+        with self._compiling("draft_prefill"):
+            self.draft_pool, _ = paged_prefill(
+                self.draft_params, self.draft_config, self.draft_pool,
+                slot.padded[None], self.draft_tables[index],
+                np.int32(slot.true_len))
         self.draft_positions[index] = slot.true_len
 
     def _advance_prefills(self, report: StepReport) -> bool:
@@ -1140,18 +1136,17 @@ class DecodeEngine:
                         index, block_index]
             write_offsets[offset] = position % block_size
         with self._prefill_span(slot, size, start):
-            before = _jit_cache_size()
-            self.pool, greedy = paged_prefill_chunk(
-                self.params, self.config, self.pool, chunk,
-                self.tables[index], np.int32(start), write_blocks,
-                write_offsets)
-            if feed_draft:
-                self.draft_pool, _ = paged_prefill_chunk(
-                    self.draft_params, self.draft_config,
-                    self.draft_pool, chunk, self.draft_tables[index],
-                    np.int32(start), draft_blocks, write_offsets)
-            self._note_compiles(_jit_cache_size() - before,
-                                "paged_prefill_chunk")
+            with self._compiling("paged_prefill_chunk"):
+                self.pool, greedy = paged_prefill_chunk(
+                    self.params, self.config, self.pool, chunk,
+                    self.tables[index], np.int32(start), write_blocks,
+                    write_offsets)
+                if feed_draft:
+                    self.draft_pool, _ = paged_prefill_chunk(
+                        self.draft_params, self.draft_config,
+                        self.draft_pool, chunk,
+                        self.draft_tables[index], np.int32(start),
+                        draft_blocks, write_offsets)
             self.counters["prefill_chunks"] += 1
             self._bump("decode.prefill_chunks", 1)
             slot.prefill_pos = start + take
@@ -1195,66 +1190,66 @@ class DecodeEngine:
                     ingest_blocks[index, j] = self.draft_tables[
                         index, position // block_size]
                     ingest_offsets[index, j] = position % block_size
-        # draft proposals, their readbacks and the verify dispatch are
-        # `engine.decode`; `engine.readback` is the wait for the verify
-        with self._spans.span("engine.decode", decoding=len(decoding),
-                              **self._walked(self.positions, k + 1)):
-            draft_start = time.perf_counter()
-            before = _jit_cache_size()
-            self.draft_pool, draft_greedy = paged_verify_step(
-                self.draft_params, self.draft_config, self.draft_pool,
-                self.draft_tables, self.draft_positions, ingest,
-                ingest_blocks, ingest_offsets)
-            draft_greedy = np.asarray(draft_greedy)
-            proposals = np.zeros((self.slots_n, k), np.int32)
-            for index in decoding:
-                proposals[index, 0] = draft_greedy[
-                    index, pending_len[index] - 1]
-                self.draft_positions[index] += pending_len[index]
-            # 2) k-1 single draft steps extend the proposal run, writing
-            # each proposal's K/V at its own position
-            current = proposals[:, 0:1].copy()
-            for run in range(1, k):
-                step_blocks = np.full((self.slots_n,), TRASH_BLOCK, np.int32)
-                step_offsets = np.zeros((self.slots_n,), np.int32)
-                for index in decoding:
-                    position = int(self.draft_positions[index])
-                    if position < self.max_context:
-                        step_blocks[index] = self.draft_tables[
-                            index, position // block_size]
-                        step_offsets[index] = position % block_size
-                self.draft_pool, current, *_ = paged_decode_step(
+        with self._compiling("spec_round"):
+            # draft proposals, their readbacks and the verify dispatch are
+            # `engine.decode`; `engine.readback` is the wait for the verify
+            with self._spans.span("engine.decode", decoding=len(decoding),
+                                  **self._walked(self.positions, k + 1)):
+                draft_start = time.perf_counter()
+                self.draft_pool, draft_greedy = paged_verify_step(
                     self.draft_params, self.draft_config, self.draft_pool,
-                    self.draft_tables, self.draft_positions, current,
-                    step_blocks, step_offsets)
-                current = np.asarray(current)
+                    self.draft_tables, self.draft_positions, ingest,
+                    ingest_blocks, ingest_offsets)
+                draft_greedy = np.asarray(draft_greedy)
+                proposals = np.zeros((self.slots_n, k), np.int32)
                 for index in decoding:
-                    proposals[index, run] = current[index, 0]
-                    self.draft_positions[index] += 1
-            self.spec_draft_s += time.perf_counter() - draft_start
-            # 3) target verification: [last_token, p_1..p_k] in one window
-            window = np.zeros((self.slots_n, k + 1), np.int32)
-            verify_blocks = np.full((self.slots_n, k + 1), TRASH_BLOCK,
-                                    np.int32)
-            verify_offsets = np.zeros((self.slots_n, k + 1), np.int32)
-            for index in decoding:
-                slot = self.slots[index]
-                window[index, 0] = self.last_tokens[index, 0]
-                window[index, 1:] = proposals[index]
-                for j in range(k + 1):
-                    position = int(self.positions[index]) + j
-                    if position // block_size < len(slot.blocks):
-                        verify_blocks[index, j] = slot.blocks[
-                            position // block_size]
-                        verify_offsets[index, j] = position % block_size
-            verify_start = time.perf_counter()
-            self.pool, verified = paged_verify_step(
-                self.params, self.config, self.pool, self.tables,
-                self.positions, window, verify_blocks, verify_offsets)
-        with self._spans.span("engine.readback"):
-            verified = np.asarray(verified)
-        self.spec_verify_s += time.perf_counter() - verify_start
-        self._note_compiles(_jit_cache_size() - before, "spec_round")
+                    proposals[index, 0] = draft_greedy[
+                        index, pending_len[index] - 1]
+                    self.draft_positions[index] += pending_len[index]
+                # 2) k-1 single draft steps extend the proposal run, writing
+                # each proposal's K/V at its own position
+                current = proposals[:, 0:1].copy()
+                for run in range(1, k):
+                    step_blocks = np.full((self.slots_n,), TRASH_BLOCK,
+                                          np.int32)
+                    step_offsets = np.zeros((self.slots_n,), np.int32)
+                    for index in decoding:
+                        position = int(self.draft_positions[index])
+                        if position < self.max_context:
+                            step_blocks[index] = self.draft_tables[
+                                index, position // block_size]
+                            step_offsets[index] = position % block_size
+                    self.draft_pool, current, *_ = paged_decode_step(
+                        self.draft_params, self.draft_config, self.draft_pool,
+                        self.draft_tables, self.draft_positions, current,
+                        step_blocks, step_offsets)
+                    current = np.asarray(current)
+                    for index in decoding:
+                        proposals[index, run] = current[index, 0]
+                        self.draft_positions[index] += 1
+                self.spec_draft_s += time.perf_counter() - draft_start
+                # 3) target verification: [last_token, p_1..p_k] in one window
+                window = np.zeros((self.slots_n, k + 1), np.int32)
+                verify_blocks = np.full((self.slots_n, k + 1), TRASH_BLOCK,
+                                        np.int32)
+                verify_offsets = np.zeros((self.slots_n, k + 1), np.int32)
+                for index in decoding:
+                    slot = self.slots[index]
+                    window[index, 0] = self.last_tokens[index, 0]
+                    window[index, 1:] = proposals[index]
+                    for j in range(k + 1):
+                        position = int(self.positions[index]) + j
+                        if position // block_size < len(slot.blocks):
+                            verify_blocks[index, j] = slot.blocks[
+                                position // block_size]
+                            verify_offsets[index, j] = position % block_size
+                verify_start = time.perf_counter()
+                self.pool, verified = paged_verify_step(
+                    self.params, self.config, self.pool, self.tables,
+                    self.positions, window, verify_blocks, verify_offsets)
+            with self._spans.span("engine.readback"):
+                verified = np.asarray(verified)
+            self.spec_verify_s += time.perf_counter() - verify_start
         # 4) greedy-exact acceptance: verified[j] is the target's
         # greedy token after window position j, so draft_j is accepted
         # iff it EQUALS verified[j-1]; the first mismatch wins a bonus
@@ -1574,16 +1569,28 @@ class DecodeEngine:
 
     @property
     def compile_count(self) -> int:
-        """Jit-cache signatures THIS engine's calls compiled (prefill
-        buckets + the one decode step).  The zero-recompile acceptance
-        assertion reads deltas of this across an admit/evict storm."""
+        """Programs jax compiled, or took from its persistent cache,
+        inside THIS engine's calls (prefill buckets + the one decode
+        step), as jax's own events on the calling thread count them.
+        The zero-recompile acceptance assertion reads deltas of this
+        across an admit/evict storm."""
         return self.counters["compiles"]
 
-    def _note_compiles(self, delta: int, what: str) -> None:
-        if delta > 0:
-            self.counters["compiles"] += delta
-            self._bump("decode.compiles", delta)
-            self._spans.mark("compile", node=self._node, what=what)
+    def _compiling(self, what: str):
+        """The bracket around one call of a jitted program (`what`
+        names the call): two reads of the thread's record where nothing
+        compiles."""
+        return compile_bracket(self._note_compiles, what)
+
+    def _note_compiles(self, waited_s: float, programs: int, args: dict,
+                       what: str) -> None:
+        """jax compiled (or retrieved) `programs` programs inside a
+        bracketed call: count them and close the interval with an
+        `aiko:compile` mark."""
+        self.counters["compiles"] += programs
+        self._bump("decode.compiles", programs)
+        self._spans.mark("compile", waited_s, node=self._node, what=what,
+                         **args)
 
     def _bump(self, name: str, amount: int) -> None:
         if self._registry is not None:

@@ -537,9 +537,12 @@ class LMGenerate(ComputeElement):
             overrides["d_ff"] = parsed["d_ff"]
         if overrides:
             draft_config = replace(draft_config, **overrides)
-        draft_params = init_params(
-            draft_config,
-            jax.random.PRNGKey(int(parsed.get("seed", 0))))
+        with self._weights_interval(
+                "init", f"{self.definition.name}.draft") as interval:
+            draft_params = init_params(
+                draft_config,
+                jax.random.PRNGKey(int(parsed.get("seed", 0))))
+            interval.holds(draft_params)
         return draft_params, draft_config, parsed["k"]
 
     # -- disaggregated prefill (decode/disagg.py PrefillEngine) ------------
@@ -574,7 +577,9 @@ class LMGenerate(ComputeElement):
             max_context=int(max_context) if max_context else None,
             prefill_chunk_size=(int(prefill_chunk) if prefill_chunk
                                 else None),
-            registry=registry)
+            registry=registry,
+            spans=telemetry if registry is not None else None,
+            node=self.definition.name)
         self._prefill_frames = {}
         self._prefill_pump_posted = False
         return self._prefill_engine

@@ -445,13 +445,19 @@ class PipelineTelemetry:
         span.set(rows=held, target=rows,
                  path="fused" if fused else "chained")
 
-    def record_compile(self, node: str, what: str) -> None:
+    def record_compile(self, waited_s: float, programs: int,
+                       args: dict, node: str, what: str) -> None:
+        """A bracketed call of `node`'s program compiled
+        (runtime/compile_cache.py's compile_bracket hands over jax's
+        seconds, the programs compiled or retrieved and the mark's
+        arguments): counted, an instant in the ring for `aiko tune`,
+        and the `aiko:compile` mark that closes the interval."""
         if not self.enabled:
             return
-        self.registry.counter(f"pipeline.compiles_{what}").inc()
+        self.registry.counter(f"pipeline.compiles_{what}").inc(programs)
         self.tracer.instant_global(f"compile:{node}", "compile",
                                    {"what": what})
-        program_mark("compile", node=node, what=what)
+        program_mark("compile", waited_s, node=node, what=what, **args)
 
     # -- program spans for components that hold no frame ------------------
     # (the decode engine and the element that pumps it know requests by

@@ -43,6 +43,9 @@
 #                                            recovery wave; shed/
 #                                            throttle are instants
 #   compile    "compile:{node}"              (re)compilation instants
+#                                            (what `aiko tune` reads; the
+#                                            durations ride the program
+#                                            mark `aiko:compile`, below)
 #   park/fault instants                      park/resume, retries,
 #                                            deadline + breaker events
 #   program    "aiko:{layer}.{what}"         program spans (below); in
@@ -150,8 +153,45 @@
 #                               DecodeEngine.submit), where known
 #   engine.pump        scoped   LMGenerate._engine_pump; waited_us = the
 #                               pump message's mailbox wait
-#   compile            instant  a fresh program / jit signature: node,
-#                               what
+#   compile            mark     closes a bracketed call that compiled
+#                               (runtime/compile_cache.py): node, what
+#                               (the engine's call, `fused`, `element`,
+#                               or `unbracketed`: a compile on an event
+#                               loop's thread that no bracket expected),
+#                               program (jax's `fun_name`s), programs,
+#                               and jax's OWN durations on the calling
+#                               thread: trace_us, lower_us, and
+#                               backend_us (a cache miss, or no cache)
+#                               or retrieval_us + saved_us (a hit: the
+#                               key, the read and the deserialisation),
+#                               cache = hit | miss | off.  waited_us is
+#                               their sum, not the call's wall time: the
+#                               program's first execution is not in it.
+#                               One sample of `setup.compile_s`; nothing
+#                               is written for a call that compiled
+#                               nothing
+#   setup.weights      scoped   ComputeElement._ensure_ready around
+#                               setup() and the placing of the state (or
+#                               restore_state; a draft model's too): node,
+#                               source (init | load | restore), bytes,
+#                               leaves (of the state), compile_us (jax's
+#                               durations that fell inside: the eager
+#                               initialiser's small programs).  One
+#                               sample of `setup.weights_s`
+#   setup.state        scoped   DecodeEngine / PrefillEngine around the
+#                               paged pool and its tables: node, what
+#                               (pool | draft_pool), blocks, bytes,
+#                               leaves, compile_us.  One sample of
+#                               `setup.state_s`.  generate()'s contiguous
+#                               cache is made inside its jitted program
+#                               every call: no interval
+#
+# The three setup.* histograms, `setup.cache_hits`/`setup.cache_requests`
+# and the gauges `setup.boot_s` (the package's import -> the first
+# weights interval opens) and `setup.ready_s` (-> the newest interval
+# closed) are the process-global registry's, written with telemetry on
+# or off; they are disjoint (an interval inside another records no
+# sample), so boot + weights + state + compile <= ready.
 #
 # Spans of one request also carry stream, frame (and row) and the
 # frame's trace_id; a span's parent is the span enclosing it on its

@@ -30,6 +30,7 @@ from ..faults import create_injector, get_injector
 from ..observe import PipelineTelemetry
 from ..observe.trace import pop_trace_context
 from ..runtime import Actor, Lease, ServiceFilter, ServicesCache
+from ..runtime.compile_cache import compile_bracket
 from ..runtime.service import SERVICE_PROTOCOL_PIPELINE
 from ..utils import (
     generate, get_logger, load_module, parse_float, parse_int)
@@ -1642,12 +1643,18 @@ class Pipeline(Actor):
         shared = tuple(sorted(
             port["name"] for port in element.definition.output
             if not port.get("batched", True)))
-        program = self._fused_program_for(element.definition.name, kernel)
+        node_name = element.definition.name
+        program = self._fused_program_for(node_name, kernel)
         try:
-            per_frame = program(
-                context, named_arrays, target=int(target),
-                counts=tuple(int(count) for count in split_rows),
-                shared=shared)
+            # every signature's compile is marked (a lone frame's group
+            # and a full one compile apart under one closure), and the
+            # mark closes the call that compiled
+            with compile_bracket(self.telemetry.record_compile,
+                                 node_name, "fused"):
+                per_frame = program(
+                    context, named_arrays, target=int(target),
+                    counts=tuple(int(count) for count in split_rows),
+                    shared=shared)
         except Exception as error:
             return StreamEvent.ERROR, {
                 "diagnostic": f"{element.definition.name}: fused group "
@@ -1713,9 +1720,6 @@ class Pipeline(Actor):
             # must not leak one dead program per group
             programs.clear()
         programs[id(kernel)] = (kernel, fused)
-        # a fresh fused program means a fresh XLA compile per signature
-        # underneath: counted + traced so compile storms are attributable
-        self.telemetry.record_compile(node_name, "fused")
         return fused
 
     def _split_micro_outputs_all(self, outputs: dict, rows: list,

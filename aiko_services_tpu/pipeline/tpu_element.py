@@ -33,7 +33,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observe.trace import NO_SPANS
 from ..parallel.mesh import get_mesh, named_sharding, shard_pytree
+from ..runtime.compile_cache import compile_bracket, setup_interval
 from ..utils import get_logger
 from ..utils.padding import bucket_length, pad_axis_to  # noqa: F401
 from .element import PipelineElement
@@ -121,15 +123,33 @@ class ComputeElement(PipelineElement):
         RESTORE path -- which installs state without calling setup() --
         still configures the element before sharding or compute."""
 
+    def _seam(self):
+        """The pipeline's telemetry as the seam program spans go through
+        (observe/trace.py); NO_SPANS where there is none."""
+        telemetry = getattr(self.pipeline, "telemetry", None)
+        return telemetry if telemetry is not None else NO_SPANS
+
+    def _weights_interval(self, source: str, node: str | None = None):
+        """The `aiko:setup.weights` interval around the making of this
+        element's state (or of a second model `node` names, a draft);
+        `source` is init | load | restore."""
+        return setup_interval("weights", self._seam().span(
+            "setup.weights", node=node or self.definition.name,
+            source=source))
+
     def _ensure_ready(self):
         if self._compiled is not None:
             return
         self.configure()
         if self.state is None:  # restore_state may have installed it
-            state = self.setup()
-            if state is not None and self.mesh is not None:
-                state = shard_pytree(state, self.mesh, self._state_spec)
-            self.state = state
+            source = "load" if self.get_parameter("weights") else "init"
+            with self._weights_interval(source) as interval:
+                state = self.setup()
+                if state is not None and self.mesh is not None:
+                    state = shard_pytree(state, self.mesh,
+                                         self._state_spec)
+                self.state = state
+                interval.holds(state)
         signature = inspect.signature(self.compute)
         self._accepts_lengths = "lengths" in signature.parameters
 
@@ -141,6 +161,14 @@ class ComputeElement(PipelineElement):
             return outputs
 
         self._compiled = jax.jit(_call)
+
+    def _note_compile(self, waited_s: float, programs: int,
+                      args: dict) -> None:
+        """This element's own jitted call compiled (a new signature of
+        its inputs): the `aiko:compile` mark that closes it."""
+        self._seam().mark("compile", waited_s,
+                           node=self.definition.name, what="element",
+                           **args)
 
     def _place_inputs(self, inputs: dict) -> tuple[dict, dict]:
         """Returns (placed inputs, padding info {name: (axis, original)})."""
@@ -287,11 +315,14 @@ class ComputeElement(PipelineElement):
         that would double peak HBM on the restore path."""
         self.configure()  # state specs / config must exist before placing
         if state is not None:
-            if self.mesh is not None:
-                state = shard_pytree(state, self.mesh, self._state_spec)
-            else:
-                state = jax.tree_util.tree_map(jnp.asarray, state)
-            self.state = state
+            with self._weights_interval("restore") as interval:
+                if self.mesh is not None:
+                    state = shard_pytree(state, self.mesh,
+                                         self._state_spec)
+                else:
+                    state = jax.tree_util.tree_map(jnp.asarray, state)
+                self.state = state
+                interval.holds(state)
         self._ensure_ready()
 
     def process_frame(self, stream: Stream, **inputs) -> tuple:
@@ -316,7 +347,8 @@ class ComputeElement(PipelineElement):
                           if self.mesh is not None
                           else contextlib.nullcontext())
             with mesh_scope, jax.profiler.TraceAnnotation(
-                    f"element:{self.definition.name}"):
+                    f"element:{self.definition.name}"), \
+                    compile_bracket(self._note_compile):
                 outputs = self._compiled(self.state, dynamic, placed)
         except TypeError as error:
             bad = {name: type(value).__name__

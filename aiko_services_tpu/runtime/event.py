@@ -23,6 +23,7 @@ import traceback
 from collections import OrderedDict, deque
 
 from ..utils import get_logger, monotonic
+from .compile_cache import program_thread
 
 __all__ = ["EventEngine", "Mailbox"]
 
@@ -155,6 +156,15 @@ class EventEngine:
 
     def loop(self) -> None:
         self._loop_thread = threading.current_thread()
+        # what compiles on this thread outside every bracket is the
+        # program's own, and marked (runtime/compile_cache.py)
+        program_thread(self)
+        try:
+            self._work()
+        finally:
+            program_thread(None)
+
+    def _work(self) -> None:
         last_flatout = 0.0
         while True:
             with self._condition:
@@ -231,6 +241,11 @@ class EventEngine:
         is observe.trace.program_span, handed in by a telemetry seam so
         that runtime/ imports neither observe/ nor jax."""
         self._wait_span = span_factory
+
+    @property
+    def traced(self) -> bool:
+        """Whether a telemetry seam on this loop writes program spans."""
+        return self._wait_span is not None
 
     def _traced_wait_locked(self, timeout) -> None:
         """The idle wait as a span: `sched.hold` when the nearest live
