@@ -45,7 +45,7 @@ from collections import deque
 import numpy as np
 
 from ..models import (
-    init_paged_pool, paged_prefill, paged_prefill_chunk)
+    init_paged_pool, paged_prefill, paged_prefill_chunk, prefill_rows)
 from ..observe.trace import NO_SPANS
 from ..pipeline.transfer import fetch_many, get_transfer_server
 from ..runtime.compile_cache import compile_bracket, setup_interval
@@ -180,7 +180,8 @@ class PrefillEngine:
         else:
             self.prefill_chunk = None
         self.counters = {"submitted": 0, "exported": 0, "chunks": 0,
-                         "compiles": 0, "exported_bytes": 0}
+                         "compiles": 0, "exported_bytes": 0,
+                         "prefill_rows_run": 0, "prefill_rows_bucket": 0}
 
     @property
     def compile_count(self) -> int:
@@ -275,12 +276,22 @@ class PrefillEngine:
         job = self._active
         if (self.prefill_chunk is None
                 or self.prefill_chunk >= job.bucket):
-            with self._compiling("paged_prefill"):
-                self.pool, first = paged_prefill(
-                    self.params, self.config, self.pool,
-                    job.padded[None], self.table, np.int32(job.true_len))
+            # as DecodeEngine's span of a whole prefill: the rows the
+            # program runs of the bucket's, asked of the model step
+            rows = prefill_rows(self.config, job.bucket, job.true_len)
+            self.counters["prefill_rows_run"] += rows
+            self.counters["prefill_rows_bucket"] += job.bucket
+            with self._spans.span(
+                    "engine.prefill", job.request_id, bucket=job.bucket,
+                    true_len=job.true_len, rows=rows):
+                with self._compiling("paged_prefill"):
+                    self.pool, first = paged_prefill(
+                        self.params, self.config, self.pool,
+                        job.padded[None], self.table,
+                        np.int32(job.true_len))
+                first = int(first)  # the readback waits for the prefill
             job.prefill_pos = job.bucket
-            return [self._finish(job, int(first))]
+            return [self._finish(job, first)]
         return self._step_chunk(job)
 
     def _step_chunk(self, job: _PrefillJob) -> list:

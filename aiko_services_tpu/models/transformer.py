@@ -47,7 +47,8 @@ __all__ = [
     "quantize_weights_int8", "quantized_param_specs",
     "init_paged_pool", "paged_prefill", "paged_decode_step",
     "paged_prefill_chunk", "paged_verify_step", "cache_attention_kind",
-    "pool_write_kind", "REMAT_POLICIES", "resolve_remat_policy",
+    "pool_write_kind", "prefill_rows", "REMAT_POLICIES",
+    "resolve_remat_policy",
 ]
 
 
@@ -623,7 +624,84 @@ def _latent_flash(config: TransformerConfig, q, k, v):
                            causal=True, sm_scale=config.attention_scale)
 
 
-def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend):
+# Rows of a whole prefill's row tile.  A whole prefill (paged_prefill: a
+# contiguous cache from the static position 0, the prompt's true length
+# handed in) runs what a layer computes row by row over the tiles that
+# hold a live row and leaves the bucket's other rows undone.  Chosen on a
+# TPU v5e from {256, 512, 1024} (PR 38; ms a prefill, host clock around
+# the call and its readback, median of 5):
+#   the 4096 bucket of mistral7b_l16 at true_len 2048 / 3056 / 4064:
+#     traced whole 195.4 / 195.3 / 195.3;  256: 116.0 / 160.0 / 204.4;
+#     512: 108.7 / 149.5 / 190.1;  1024: 109.3 / 149.3 / 189.4
+#   the 8192 bucket of deepseek_v2_ep4_l5 at 4608 / 5632 / 7168:
+#     traced whole 345.4 / 345.2 / 345.1;  256: 281.1 / 297.2 / 320.6;
+#     512: 274.4 / 289.2 / 311.1;  1024: 280.4 / 294.8 / 310.2
+# 256 pays for its trips (every tile live, it is slower than the whole
+# trace); 1024 matches 512 tile for tile and rounds a length up further.
+_ROW_TILE = 512
+
+
+def _row_tiles_take(config: TransformerConfig, length: int) -> bool:
+    """Whether a whole prefill of `length` rows runs by row tiles: at
+    least two whole tiles, and a layer whose row-wise work is a row's
+    own (a switch FFN's capacity and a sequence-parallel attention's
+    shards span the sequence)."""
+    return (length >= 2 * _ROW_TILE and length % _ROW_TILE == 0
+            and config.n_experts == 0 and not config.sequence_parallel)
+
+
+def prefill_rows(config: TransformerConfig, bucket: int,
+                 true_len: int) -> int:
+    """The rows a whole prefill (paged_prefill) of `bucket` rows runs for
+    a prompt of `true_len` tokens: true_len rounded up to a row tile
+    where the bucket runs by row tiles, else the bucket.  _hidden decides
+    by the same predicate, and the engine names its prefill spans by
+    this."""
+    if not _row_tiles_take(config, bucket):
+        return bucket
+    return -(-true_len // _ROW_TILE) * _ROW_TILE
+
+
+def _row_tiles(live, fn, layer, *operands):
+    """fn(layer, *operands), whose every output row depends on the
+    operands' same row alone, rows being the axis before the last:
+    (B, L, d), (B, H, L, hd).  live None: the one call.  Else `live` is
+    the traced count of rows that matter, `layer` a _LayerAt, and fn runs
+    on one _ROW_TILE of rows at a time for the ceil(live / _ROW_TILE)
+    tiles that hold one -- a loop with a traced trip count, so one
+    program serves every `live` -- and the rows of the other tiles are
+    zeros in every output.  An output of one axis (the FFN's stats) is
+    summed over the tiles."""
+    if live is None:
+        return fn(layer, *operands)
+
+    def tiles(start):
+        return (jax.lax.dynamic_slice_in_dim(x, start, _ROW_TILE, x.ndim - 2)
+                for x in operands)
+
+    def put(start, whole, part):
+        if part.ndim < 2:
+            return whole + part
+        return jax.lax.dynamic_update_slice_in_dim(whole, part, start,
+                                                   whole.ndim - 2)
+
+    def body(index, outputs):
+        start = index * _ROW_TILE
+        return jax.tree_util.tree_map(
+            partial(put, start), outputs,
+            fn(layer.within(index), *tiles(start)))
+
+    length = operands[0].shape[-2]
+    outputs = jax.tree_util.tree_map(
+        lambda part: jnp.zeros(
+            part.shape if part.ndim < 2 else
+            part.shape[:-2] + (length,) + part.shape[-1:], part.dtype),
+        jax.eval_shape(lambda: fn(layer, *tiles(0))))
+    return jax.lax.fori_loop(0, -(-live // _ROW_TILE), body, outputs)
+
+
+def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend,
+                   live=None):
     """THE decoder layer, on every path: attention norm, projections and
     rotary, `attend`, wo and residual, MLP norm, FFN, residual (under
     sandwich_norm each sublayer's output normed before its add).  Only
@@ -632,23 +710,48 @@ def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend):
     store's new leaves) -- _attend_fresh, _attend_cache, _attend_pool.
     Under latent attention k is the latent row and v None; the stores
     keep the row, and attend decompressed (fresh, cache) or absorbed
-    (pool).  Returns (h, the FFN's stats, the store's new leaves)."""
-    batch, length, _ = h.shape
+    (pool).  Everything but `attend` and the routed experts is a row's
+    own: with `live` (a whole prefill's true length, _row_tiles) it runs
+    over the live row tiles only, q, k, v and h zeros past them.
+    Returns (h, the FFN's stats, the store's new leaves)."""
     project = _project_latent if config.kv_lora_rank else _project_qkv
-    q, k, v = project(
-        config, layer, rms_norm(layer["attn_norm"], h, config.norm_eps),
-        cos, sin)
+
+    def attention_in(layer, h, cos, sin):
+        return project(
+            config, layer, rms_norm(layer["attn_norm"], h, config.norm_eps),
+            cos, sin)
+
+    def attention_out(layer, h, out):
+        batch, _, length, _ = out.shape
+        out = dense(layer["wo"],
+                    out.transpose(0, 2, 1, 3).reshape(batch, length, -1))
+        if config.sandwich_norm:
+            out = rms_norm(layer["attn_out_norm"], out, config.norm_eps)
+        h = h + out
+        return h, rms_norm(layer["mlp_norm"], h, config.norm_eps)
+
+    def ffn_out(layer, h, mlp_out):
+        if config.sandwich_norm:
+            mlp_out = rms_norm(layer["mlp_out_norm"], mlp_out,
+                               config.norm_eps)
+        return h + mlp_out
+
+    def close(layer, h, out):
+        h, mlp_in = attention_out(layer, h, out)
+        mlp_out, stats = _mlp_block(config, layer, mlp_in)
+        return ffn_out(layer, h, mlp_out), stats
+
+    q, k, v = _row_tiles(live, attention_in, layer, h, cos, sin)
     out, leaves = attend(layer, q, k, v)
-    out = dense(layer["wo"],
-                out.transpose(0, 2, 1, 3).reshape(batch, length, -1))
-    if config.sandwich_norm:
-        out = rms_norm(layer["attn_out_norm"], out, config.norm_eps)
-    h = h + out
-    mlp_out, stats = _mlp_block(
-        config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps))
-    if config.sandwich_norm:
-        mlp_out = rms_norm(layer["mlp_out_norm"], mlp_out, config.norm_eps)
-    return h + mlp_out, stats, leaves
+    if live is None or not (config.top_k and "router" in layer):
+        h, stats = _row_tiles(live, close, layer, h, out)
+    else:
+        # the experts group the rows of the whole sequence: between two
+        # row loops, the dead rows sent to no expert
+        h, mlp_in = _row_tiles(live, attention_out, layer, h, out)
+        mlp_out, stats = _routed_moe(config, layer, mlp_in, live)
+        h = ffn_out(layer, h, mlp_out)
+    return h, stats, leaves
 
 
 def _sp_prefill(config: TransformerConfig, q, k, v):
@@ -935,7 +1038,37 @@ def _by_head(config: TransformerConfig, stack: dict) -> dict:
     return stack
 
 
-def _scan_layers(config: TransformerConfig, step, carry, stack, extra):
+class _LayerAt:
+    """Layer `index` of a stack of layers, each leaf sliced out of the
+    stack where it is read: layer["wo"] traces the slice there.  What a
+    whole prefill's row loops need: a layer scan's own slice is made
+    outside them, and a loop inside the layer takes what it reads as a
+    buffer of its own, so XLA copied every weight of every layer (450 MB
+    a layer of Mistral-7B's widths) where a matmul that slices the stack
+    itself reads it in place."""
+
+    def __init__(self, stack: dict, index, whole: dict):
+        self._stack, self._index, self._whole = stack, index, whole
+
+    def within(self, step) -> "_LayerAt":
+        """The layer for iteration `step` of a loop inside it: the same
+        slices, tied to the loop's counter so that XLA does not move
+        them out of the loop again (they are loop-invariant)."""
+        index, _ = jax.lax.optimization_barrier((self._index, step))
+        return _LayerAt(self._stack, index, self._whole)
+
+    def __contains__(self, name) -> bool:
+        return name in self._stack or name in self._whole
+
+    def __getitem__(self, name):
+        if name in self._whole:
+            return self._whole[name]
+        return jax.tree_util.tree_map(lambda leaf: leaf[self._index],
+                                      self._stack[name])
+
+
+def _scan_layers(config: TransformerConfig, step, carry, stack, extra,
+                 sliced_where_read: bool = False):
     """jax.lax.scan of step(carry, (layer, extra[i])) over a stack of
     layers, wq and wk split by head (_by_head).  The routed experts'
     weights do not ride the scan: a
@@ -943,21 +1076,26 @@ def _scan_layers(config: TransformerConfig, step, carry, stack, extra):
     whole every iteration (1.9 GB a layer at DeepSeek-V2's widths), so
     such a layer carries the whole stacked leaves and its index among
     them as layer["experts"], and the kernel reads the layer where it
-    lies (parallel/experts.py)."""
+    lies (parallel/experts.py).  sliced_where_read: no leaf rides the
+    scan, the layer is a _LayerAt."""
     stack = _by_head(config, stack)
-    if not (config.top_k and "router" in stack):
+    routed = bool(config.top_k and "router" in stack)
+    if not (routed or sliced_where_read):
         return jax.lax.scan(step, carry, (stack, extra))
-    held = {name: stack[name] for name in _EXPERT_LEAVES}
-    rest = {name: leaf for name, leaf in stack.items()
-            if name not in _EXPERT_LEAVES}
+    held = {name: stack[name] for name in _EXPERT_LEAVES} if routed else {}
+    rest = {name: leaf for name, leaf in stack.items() if name not in held}
+    layers = jax.tree_util.tree_leaves(stack)[0].shape[0]
 
-    def with_experts(carry, xs):
+    def with_index(carry, xs):
         layer, index, extra_i = xs
-        return step(carry, (dict(layer, experts=(held, index)), extra_i))
+        whole = {"experts": (held, index)} if routed else {}
+        layer = (_LayerAt(rest, index, whole) if sliced_where_read
+                 else dict(layer, **whole))
+        return step(carry, (layer, extra_i))
 
     return jax.lax.scan(
-        with_experts, carry,
-        (rest, jnp.arange(stack["router"]["w"].shape[0]), extra))
+        with_index, carry,
+        (None if sliced_where_read else rest, jnp.arange(layers), extra))
 
 
 def _route(config: TransformerConfig, router: dict, x):
@@ -984,26 +1122,31 @@ def _route(config: TransformerConfig, router: dict, x):
     return weights * config.routed_scaling, ids
 
 
-def _routed_moe(config: TransformerConfig, layer, x):
+def _routed_moe(config: TransformerConfig, layer, x, live=None):
     """Shared(x) + sum over a token's chosen experts of g_i E_i(x), of
     which this process adds the experts it holds (config.held) and
     leaves the rest to the shares that hold them; a token none of whose
     experts is held gets the shared experts only.  No token is dropped:
     every token-expert pair held is computed (parallel/experts.py).
-    Returns (output, stats) with stats[1:] = distinct held experts hit
-    and pairs computed."""
+    With `live` (a whole prefill's true length, _row_tiles) the rows at
+    or past it go to no expert, and the shared experts run over the live
+    row tiles.  Returns (output, stats) with stats[1:] = distinct held
+    experts hit and pairs computed: of a whole prefill the live rows'."""
     batch, length, d_model = x.shape
     tokens = x.reshape(batch * length, d_model)
     weights, ids = _route(config, layer["router"], tokens)
     low, high = config.held
     held = (ids >= low) & (ids < high)
+    if live is not None:
+        held &= jnp.tile(jnp.arange(length) < live, batch)[:, None]
     stacked, index = layer["experts"]        # _scan_layers
     routed, experts_read, pairs = expert_ffn(
         tokens, *(stacked[name]["w"] for name in _EXPERT_LEAVES),
         jnp.where(held, ids - low, high - low),
         jnp.where(held, weights, 0.0), layer=index)
-    shared = swiglu(layer["shared_gate"], layer["shared_up"],
-                    layer["shared_down"], x)
+    shared = _row_tiles(live, lambda layer, x: swiglu(
+        layer["shared_gate"], layer["shared_up"], layer["shared_down"], x),
+        layer, x)
     stats = jnp.stack([jnp.float32(0.0), experts_read.astype(jnp.float32),
                        pairs.astype(jnp.float32)])
     return shared + routed.reshape(x.shape).astype(x.dtype), stats
@@ -1171,11 +1314,37 @@ def forward(params: dict, config: TransformerConfig, tokens,
         raise ValueError(
             "return_aux is only meaningful on the cache-less (training/"
             "scoring) path; with a cache forward returns (logits, cache)")
+    h, outputs, stats_sum, new_cache = _hidden(
+        params, config, tokens, cache, pos, activation_specs, remat_policy)
+    logits, _ = _logits(params, config, h, outputs)
+    if cache is None:
+        if return_aux:
+            return logits, stats_sum[0] / max(config.n_caches, 1)
+        return logits
+    return logits, new_cache
+
+
+def _hidden(params: dict, config: TransformerConfig, tokens, cache, pos,
+            activation_specs: bool = False, remat_policy: str | None = None,
+            true_len=None):
+    """forward before its head: (h, the passes' outputs, the FFNs' stats
+    summed over layers and passes, the updated cache or None), what
+    _logits takes.  `true_len` (traced) says that only the first true_len
+    rows matter, the rest being padding: a call into a cache from the
+    static position 0 whose length _row_tiles_take takes then runs every
+    layer's row-wise work over the live row tiles alone (_decoder_layer),
+    and h, the outputs and the cache hold zeros past them."""
     if remat_policy not in (None, "none") and cache is not None:
         raise ValueError(
             "remat_policy is only meaningful on the cache-less "
             "(training/scoring) path; incremental decode saves nothing "
             "by rematerializing")
+    length = tokens.shape[1]
+    live = None
+    if (true_len is not None and cache is not None
+            and isinstance(pos, (int, np.integer)) and pos == 0
+            and _row_tiles_take(config, length)):
+        live = true_len
     if activation_specs:
         # batch on "data", sequence on "seq" -- but only the axes the
         # ambient mesh actually has (an EP-only mesh has no "seq")
@@ -1185,7 +1354,7 @@ def forward(params: dict, config: TransformerConfig, tokens,
     h = _embed(params, config, tokens)
     if activation_specs:
         h = jax.lax.with_sharding_constraint(h, act_spec)
-    positions = pos + jnp.arange(tokens.shape[1])
+    positions = pos + jnp.arange(length)
     cos, sin = _rotary_tables(config, positions)
     cos, sin = cos[None, None], sin[None, None]  # (1, 1, L, hd/2)
 
@@ -1195,7 +1364,7 @@ def forward(params: dict, config: TransformerConfig, tokens,
         h, stats, new_cache = _decoder_layer(
             config, layer, h, cos, sin,
             partial(_attend_fresh, config) if layer_cache is None
-            else partial(_attend_cache, config, layer_cache, pos))
+            else partial(_attend_cache, config, layer_cache, pos), live)
         stats_sum = stats_sum + stats
         if activation_specs:
             h = jax.lax.with_sharding_constraint(h, act_spec)
@@ -1221,21 +1390,18 @@ def forward(params: dict, config: TransformerConfig, tokens,
         carry, part = _scan_layers(
             config, body, carry, stack,
             cache if count == config.n_caches else jax.tree_util.tree_map(
-                lambda leaf: leaf[first:first + count], cache))
+                lambda leaf: leaf[first:first + count], cache),
+            sliced_where_read=live is not None)
         written.append(part)
         return carry
 
     (h, stats_sum), outputs = _run_passes(params, config, carry, scan_stack)
+    new_cache = None
     if cache is not None:
         new_cache = written[0] if len(written) == 1 else \
             jax.tree_util.tree_map(
                 lambda *parts: jnp.concatenate(parts), *written)
-    logits, _ = _logits(params, config, h, outputs)
-    if cache is None:
-        if return_aux:
-            return logits, stats_sum[0] / max(config.n_caches, 1)
-        return logits
-    return logits, new_cache
+    return h, outputs, stats_sum, new_cache
 
 
 # -- generation -------------------------------------------------------------
@@ -1407,12 +1573,22 @@ def paged_prefill(params, config: TransformerConfig, pool, prompt,
     the first Lb//block_size receive the prompt's K/V.  Returns
     (pool, first_token) where first_token is the greedy token after the
     TRUE prompt length -- causal masking makes logits at true_len-1
-    independent of the right-padding.  One executable per bucket; the
-    decode loop never recompiles (paged_decode_step below)."""
+    independent of the right-padding, and the head runs at that one
+    position.  One executable per bucket, which does the work of the
+    prompt, not of the bucket: a bucket that runs by row tiles
+    (_row_tiles_take) runs a layer's row-wise work up to true_len and the
+    blocks past them receive zeros (a decode step writes a position
+    before it reads it).  The decode loop never recompiles
+    (paged_decode_step below)."""
     block_size = _store_leaf(pool).shape[3]
     local = init_cache(config, 1, max_len=prompt.shape[1])
-    logits, local = forward(params, config, prompt, cache=local, pos=0)
-    first = jnp.argmax(logits[0, true_len - 1]).astype(jnp.int32)
+    h, outputs, _, local = _hidden(params, config, prompt, local, 0,
+                                   true_len=true_len)
+    # the head at the one position whose logits are read
+    last = partial(jax.lax.dynamic_slice_in_dim, start_index=true_len - 1,
+                   slice_size=1, axis=1)
+    logits, _ = _logits(params, config, last(h), [*map(last, outputs)])
+    first = jnp.argmax(logits[0, 0]).astype(jnp.int32)
     blocks = prompt.shape[1] // block_size
     new_pool = {}
     for name, written in local.items():

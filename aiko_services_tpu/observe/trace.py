@@ -88,8 +88,15 @@
 #   engine.prefill     scoped   one prefill call + its readback, inside
 #                               engine.step: bucket, true_len, queue_us,
 #                               attention (flash | einsum: what the
-#                               bucket's attention takes; a chunk call
-#                               instead: live_blocks, table_blocks);
+#                               bucket's attention takes) and rows (the
+#                               rows the program runs of the bucket's:
+#                               true_len rounded up to a row tile where
+#                               the bucket runs by row tiles, as
+#                               models.prefill_rows says; running sums
+#                               prefill_rows_run, prefill_rows_bucket; a
+#                               prefill engine's whole prefill carries
+#                               bucket, true_len and rows); a chunk call
+#                               instead: live_blocks, table_blocks;
 #                               of a looped stack also ut_passes and
 #                               cache_rows (as on engine.decode; here the
 #                               rows the call leaves behind, true_len or

@@ -159,6 +159,69 @@ def test_engine_counts_the_attention_each_whole_prefill_takes(
             done[index].tokens, reference(params, grouped, prompt, 4))
 
 
+@pytest.mark.parametrize("engine_kind", ["decode", "prefill"])
+@pytest.mark.parametrize("tile", [None, 8])
+def test_engine_says_the_rows_each_whole_prefill_runs(
+        tiny_model, monkeypatch, engine_kind, tile):
+    """Every whole `engine.prefill` span of the decode engine and of the
+    prefill engine carries `rows`, what models.prefill_rows says of its
+    bucket and length (the model step decides by the same predicate: the
+    engines hold none), and stats() sums them beside the buckets.  At the
+    real tile these buckets are under two tiles and run whole; with the
+    tile cut to 8 rows a 32-row bucket runs 24 rows for a prompt of 20 --
+    and the tokens are the closed batch's either way."""
+    from aiko_services_tpu.decode import PrefillEngine
+    from aiko_services_tpu.decode import disagg, engine as engine_module
+    from aiko_services_tpu.models import prefill_rows, transformer
+    assert "_ROW_TILE" not in vars(engine_module)
+    assert "_ROW_TILE" not in vars(disagg)
+    params, config = tiny_model
+    if tile is not None:
+        monkeypatch.setattr(transformer, "_ROW_TILE", tile)
+        jax.clear_caches()
+    lengths = (5, 20, 30)
+    prompts = [np.arange(1, n + 1, dtype=np.int32) for n in lengths]
+    recorded = _Order()      # the spans seam, defined below
+    try:
+        if engine_kind == "decode":
+            engine = DecodeEngine(params, config, decode_slots=3,
+                                  kv_block_size=8, spans=recorded)
+            for index, prompt in enumerate(prompts):
+                engine.submit(index, prompt, 4)
+            done = drain(engine)
+            for index, prompt in enumerate(prompts):
+                np.testing.assert_array_equal(
+                    done[index].tokens,
+                    reference(params, config, prompt, 4))
+        else:
+            engine = PrefillEngine(params, config, kv_block_size=8,
+                                   spans=recorded)
+            for index, prompt in enumerate(prompts):
+                engine.submit(index, prompt, 4)
+            firsts = {}
+            while engine.has_work():
+                for handoff in engine.step():
+                    firsts[handoff["request_id"]] = handoff["first_token"]
+            for index, prompt in enumerate(prompts):
+                assert firsts[index] == reference(params, config, prompt,
+                                                  1)[0]
+    finally:
+        if tile is not None:
+            jax.clear_caches()
+    prefills = [fields for _, fields in recorded.named("engine.prefill")]
+    assert [args["true_len"] for args in prefills] == list(lengths)
+    assert [args["bucket"] for args in prefills] == [8, 32, 32]
+    assert [args["rows"] for args in prefills] == (
+        [8, 32, 32] if tile is None else [8, 24, 32])
+    for args in prefills:
+        assert args["rows"] == prefill_rows(config, args["bucket"],
+                                            args["true_len"])
+    stats = engine.stats()
+    assert stats["prefill_rows_bucket"] == 72
+    assert stats["prefill_rows_run"] == sum(
+        args["rows"] for args in prefills)
+
+
 @pytest.mark.parametrize("case", ["plain", "chunked", "int8"])
 def test_engine_counts_who_writes_each_windows_rows(tiny_model, case):
     """stats() counts the paged window calls by who puts their new rows

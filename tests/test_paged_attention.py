@@ -482,8 +482,15 @@ def test_served_prefill_keeps_no_scores_in_hbm_on_the_v5e(for_the_chip):
     compiled = paged_prefill.lower(
         params, config, pool, int32(1, 4096), int32(SERVED["max_blocks"]),
         int32()).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # 0.49 GB since the head runs at one position (its float32 logits at
+    # all 4096 were 524 MB of the 0.79) and the row-wise work by row
+    # tiles (PR 38).  The row loops slice each weight out of the stack
+    # where they read it (_LayerAt): sliced by the layer scan outside
+    # them, every layer's weights were copied, 450 MB of temporaries more
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+    assert not _staged_whole(text, {4096 * 14336})
 
 
 @pytest.mark.parametrize("served", ["mistral7b_l16", "ouro_2.6b"])
@@ -658,10 +665,36 @@ def test_served_share_prefill_fits_the_chip_at_the_8192_bucket(
     text = compiled.as_text()
     assert "mla_flash_attention" in text and "moe_expert_ffn" in text
     memory = compiled.memory_analysis()
-    # 2.06 GB when written: q, k, v of 128 heads, the experts' row buffer
-    assert memory.temp_size_in_bytes < 5 << 29
+    # 2.06 GB when written: q, k, v of 128 heads, the experts' row buffer;
+    # 1.88 since the head runs at one position (839 MB of float32 logits
+    # at all 8192 were not the peak: 0.26 GB came off it)
+    assert memory.temp_size_in_bytes < 2.0e9
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.0e9)
+
+
+@pytest.mark.parametrize("served", ["mistral7b_l16", "deepseek_v2_ep4_l5"])
+def test_served_prefill_runs_the_head_at_one_position(
+        for_the_chip, monkeypatch, served):
+    """The lowered bucket's program (4096 of lm.longprompt, 8192 of
+    dsv2.longgen) holds no float32 logits of the bucket (524 MB, 839 MB:
+    one row was read) but the one position's, and a row loop of a traced
+    trip count for each row-wise segment of a layer."""
+    if served == "mistral7b_l16":
+        config, params, pool, int32 = _served_model(for_the_chip)
+        bucket, max_blocks, stacks = 4096, SERVED["max_blocks"], 1
+    else:
+        config, params, pool, int32 = _served_share(for_the_chip,
+                                                    monkeypatch)
+        bucket, max_blocks, stacks = 8192, DSV2["max_blocks"], 2
+    text = paged_prefill.lower(
+        params, config, pool, int32(1, bucket), int32(max_blocks),
+        int32()).as_text()
+    vocab = config.vocab_size
+    assert f"{bucket}x{vocab}xf32" not in text
+    assert f"1x1x{vocab}xf32" in text
+    # a layer scan a stack, and inside each the loops over row tiles
+    assert text.count("stablehlo.while") >= 3 * stacks
 
 
 # -- the decode step multiplies its weights where they lie -------------------

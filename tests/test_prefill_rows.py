@@ -1,0 +1,278 @@
+"""A whole prefill does the work of its prompt, not of its bucket (PR 38).
+
+paged_prefill runs every layer's row-wise work over the row tiles up to
+`true_len` and leaves the bucket's other rows undone, in the one program a
+bucket has (transformer._row_tiles, a loop with a traced trip count), and
+runs the head at the one position whose logits are read.  Held here, on
+the CPU with the kernels interpreted and the row tile cut to 8 rows of a
+32-row bucket, against the same call traced whole (the tile patched over
+the bucket: what every bucket under two tiles still traces):
+
+  - the first token, every row below true_len of every pool leaf, and the
+    token a decode step then gives, for a dense, an int8-cache, a latent +
+    routed and a looped configuration;
+  - rows of dead tiles zero and finite in every leaf;
+  - the live rows bit for bit against the SAME program with every tile
+    live (true_len = the bucket): a dead tile changes no live row.  Against
+    the whole trace they agree to rounding, not bitwise: XLA's CPU dot
+    rounds a row's products differently by how many rows it is given;
+  - one executable a bucket whatever the length, and no live-rows trace
+    where the call is not a whole prefill of at least two tiles.
+"""
+
+import dataclasses
+
+import jax
+import numpy as np
+import pytest
+
+from aiko_services_tpu.models import (
+    TransformerConfig, forward, init_cache, init_paged_pool, init_params,
+    paged_decode_step, paged_prefill, prefill_rows, transformer)
+from aiko_services_tpu.parallel import attention
+
+TILE, BUCKET, BLOCK = 8, 32, 4
+LENGTHS = (1, TILE - 1, TILE, TILE + 1, BUCKET - 1, BUCKET)
+
+DENSE = dict(vocab_size=97, d_model=32, n_layers=2, n_heads=4,
+             n_kv_heads=2, d_ff=64, max_seq_len=64, dtype="float32")
+CASES = {
+    "dense": {},
+    "int8_cache": {"kv_dtype": "int8"},
+    # DeepSeek-V2's layer, tests/test_transformer.py's toy: a leading
+    # dense layer apart from the scanned stack, two of the four groups'
+    # experts held
+    "latent_routed": {
+        "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 8,
+        "qk_rope_head_dim": 4, "v_head_dim": 8, "rope_factor": 40.0,
+        "rope_original_max": 8, "rope_mscale": 0.707,
+        "rope_mscale_all_dim": 0.707, "top_k": 2, "n_routed_experts": 8,
+        "experts_held": (2, 6), "n_shared_experts": 1, "moe_d_ff": 16,
+        "n_groups": 4, "topk_groups": 2, "routed_scaling": 4.0,
+        "first_dense_layers": 1},
+    # Ouro's: the stack run twice, sandwich norms, the exit gate
+    "looped": {"ut_steps": 2, "sandwich_norm": True,
+               "exit_threshold": 0.6},
+}
+
+
+def _model(case: str):
+    config = TransformerConfig(**{**DENSE, **CASES[case]})
+    return config, init_params(config, jax.random.PRNGKey(3))
+
+
+def _prompt(seed: int = 11):
+    return np.asarray(jax.random.randint(
+        jax.random.PRNGKey(seed), (1, BUCKET), 0, DENSE["vocab_size"]),
+        np.int32)
+
+
+TABLE = np.array([5, 2, 7, 1, 9, 3, 8, 6, 4, 0], np.int32)
+BLOCKS = BUCKET // BLOCK
+
+
+@pytest.fixture
+def tile(monkeypatch):
+    """set(rows): the row tile for the programs traced from here on.  The
+    jitted programs are cached by config and shape, not by the tile they
+    traced at, so every change clears them."""
+    def set_tile(rows: int) -> None:
+        monkeypatch.setattr(transformer, "_ROW_TILE", rows)
+        jax.clear_caches()
+    yield set_tile
+    jax.clear_caches()
+
+
+def _prefill(config, params, true_len: int):
+    """(the pool's leaves as (caches, H, bucket rows, d) through the
+    table, the first token, the token a decode step gives after it)."""
+    pool = init_paged_pool(config, 10, BLOCK)
+    pool, first = paged_prefill(params, config, pool, _prompt(), TABLE,
+                                np.int32(true_len))
+    rows = {}
+    for name, leaf in pool.items():
+        held = np.asarray(leaf)[:, TABLE[:BLOCKS]]  # (caches, blocks, H, B, d)
+        rows[name] = held.transpose(0, 2, 1, 3, 4).reshape(
+            held.shape[0], held.shape[2], BUCKET, held.shape[-1])
+    position = true_len                      # the table names 10 blocks
+    _, after, *_ = paged_decode_step(
+        params, config, pool, TABLE[None], np.array([position], np.int32),
+        np.asarray(first).reshape(1, 1), TABLE[None, position // BLOCK],
+        np.array([position % BLOCK], np.int32))
+    return rows, int(first), int(np.asarray(after)[0, 0])
+
+
+_RAN: dict = {}
+
+
+def _ran(case: str, tile) -> dict:
+    """{("whole" | "live", true_len): _prefill's} of `case` at every
+    length, each of the two programs traced once."""
+    if case not in _RAN:
+        config, params = _model(case)
+        found = {}
+        for what, rows in (("whole", 1 << 20), ("live", TILE)):
+            tile(rows)
+            for true_len in LENGTHS:
+                assert prefill_rows(config, BUCKET, true_len) == (
+                    BUCKET if what == "whole"
+                    else -(-true_len // TILE) * TILE)
+                found[what, true_len] = _prefill(config, params, true_len)
+        _RAN[case] = found
+    return _RAN[case]
+
+
+@pytest.mark.parametrize("true_len", LENGTHS)
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_live_rows_prefill_is_the_whole_prefill_below_true_len(
+        tile, case, true_len):
+    ran = _ran(case, tile)
+    whole, whole_first, whole_after = ran["whole", true_len]
+    live, first, after = ran["live", true_len]
+    every, _, _ = ran["live", BUCKET]
+    rows = -(-true_len // TILE) * TILE
+
+    assert (first, after) == (whole_first, whole_after)
+    assert set(live) == set(whole)
+    for name, leaf in live.items():
+        below = np.s_[:, :, :true_len]
+        if leaf.dtype == np.int8:
+            # a code may differ by a step where the row's value did by
+            # rounding
+            assert np.abs(leaf[below].astype(np.int32)
+                          - whole[name][below]).max() <= 1, name
+        else:
+            np.testing.assert_allclose(leaf[below], whole[name][below],
+                                       atol=2e-5, rtol=1e-5, err_msg=name)
+        # the same program with every tile live: a live tile's rows do
+        # not know how many tiles ran
+        if case in ("dense", "int8_cache"):
+            np.testing.assert_array_equal(
+                leaf[:, :, :rows], every[name][:, :, :rows], err_msg=name)
+        else:
+            # (the routed experts lay a row out among other rows; the
+            # looped stack's later pass reads the first's rounding)
+            np.testing.assert_allclose(
+                leaf[below].astype(np.float32),
+                every[name][below].astype(np.float32), atol=2e-5,
+                rtol=1e-5, err_msg=name)
+        dead = leaf[:, :, rows:]
+        assert np.isfinite(dead.astype(np.float32)).all(), name
+        if name.endswith("_scale"):
+            # zeros quantise to code 0 at the floor scale (_quantize_kv):
+            # the row dequantises to exactly zero
+            assert (dead <= 1e-8 / 127 * 1.001).all(), name
+        else:
+            assert not dead.any(), name
+
+
+def test_live_rows_prefill_counts_the_live_rows_pairs(tile):
+    """stats[1:] of a whole prefill count the live rows only: every pair
+    of a row below true_len whose expert is held, none of a row at or
+    past it (all experts held here, so the count is known)."""
+    config, params = _model("latent_routed")
+    config = dataclasses.replace(config, experts_held=())
+    params = init_params(config, jax.random.PRNGKey(3))
+    expert_layers = config.n_layers - config.first_dense_layers
+    hidden = jax.jit(
+        lambda tokens, cache, true_len: transformer._hidden(
+            params, config, tokens, cache, 0, true_len=true_len)[2])
+    tile(TILE)
+    for true_len in (1, TILE + 1, BUCKET):
+        stats = np.asarray(hidden(_prompt(), init_cache(config, 1, BUCKET),
+                                  np.int32(true_len)))
+        assert stats[2] == true_len * config.top_k * expert_layers
+        assert 1 <= stats[1] <= config.n_routed_experts * expert_layers
+    tile(1 << 20)
+    stats = np.asarray(hidden(_prompt(), init_cache(config, 1, BUCKET),
+                              np.int32(1)))
+    assert stats[2] == BUCKET * config.top_k * expert_layers
+
+
+def test_one_executable_a_bucket_whatever_the_length():
+    """At the real tile: a 1024-row bucket is two tiles, its program is
+    traced and compiled once and serves every length."""
+    config = TransformerConfig(**{**DENSE, "max_seq_len": 1024})
+    params = init_params(config, jax.random.PRNGKey(0))
+    tile_rows = transformer._ROW_TILE
+    bucket = 2 * tile_rows
+    assert prefill_rows(config, bucket, 1) == tile_rows
+    assert prefill_rows(config, bucket, tile_rows + 1) == bucket
+    prompt = np.zeros((1, bucket), np.int32)
+    prompt[0] = np.arange(bucket) % 97
+    table = np.arange(1, bucket // 32 + 1, dtype=np.int32)
+    pool = init_paged_pool(config, bucket // 32 + 1, 32)
+    before = paged_prefill._cache_size()
+    firsts = []
+    for true_len in (1, tile_rows - 1, tile_rows, tile_rows + 1, bucket):
+        pool, first = paged_prefill(params, config, pool, prompt, table,
+                                    np.int32(true_len))
+        firsts.append(int(first))
+    assert paged_prefill._cache_size() == before + 1
+    # and its answers are forward()'s at those positions
+    logits = np.asarray(forward(params, config, prompt))[0]
+    assert firsts == [int(logits[n - 1].argmax()) for n in (
+        1, tile_rows - 1, tile_rows, tile_rows + 1, bucket)]
+    # past the first tile's rows nothing was written for a short prompt
+    pool = init_paged_pool(config, bucket // 32 + 1, 32)
+    pool, _ = paged_prefill(params, config, pool, prompt, table,
+                            np.int32(5))
+    held = np.asarray(pool["k"])[:, table]
+    assert held[:, :tile_rows // 32].any()
+    assert not held[:, tile_rows // 32:].any()
+
+
+@pytest.mark.parametrize("what", [
+    "under_two_tiles", "not_whole_tiles", "switch_ffn", "no_true_len",
+    "later_position", "no_cache"])
+def test_what_is_no_whole_prefill_traces_no_row_loop(tile, what):
+    """The live-rows path is decided by what the call is: a bucket under
+    two tiles or of no whole number of tiles, a switch FFN (its capacity
+    spans the sequence), a call without a true length (forward: training,
+    scoring, generate()), from a later position, or without a cache
+    lowers to the program it lowered to before, with no loop of a traced
+    trip count."""
+    tile(TILE)
+    fields = {"n_experts": 4} if what == "switch_ffn" else {}
+    config = TransformerConfig(**{**DENSE, **fields})
+    params = init_params(config, jax.random.PRNGKey(0))
+    length = {"under_two_tiles": 2 * TILE - 4,
+              "not_whole_tiles": 2 * TILE + 4}.get(what, BUCKET)
+    tokens = np.zeros((1, length), np.int32)
+    cache = None if what == "no_cache" else init_cache(config, 1, 64)
+    true_len = None if what in ("no_true_len", "no_cache") else np.int32(3)
+    pos = 8 if what == "later_position" else 0
+    assert prefill_rows(config, length, 3) == (
+        length if what in ("under_two_tiles", "not_whole_tiles",
+                           "switch_ffn") else TILE)
+
+    def loops(true_len) -> int:
+        return jax.jit(lambda tokens, cache, true_len: transformer._hidden(
+            params, config, tokens, cache, pos, true_len=true_len)[0]
+        ).lower(tokens, cache, true_len).as_text().count("stablehlo.while")
+
+    at_the_tile = loops(true_len)
+    if what == "no_true_len":
+        # the same call, the length given: a loop a row-wise segment
+        assert loops(np.int32(3)) > at_the_tile
+    tile(1 << 20)
+    assert at_the_tile == loops(true_len)
+
+
+def test_flash_attention_sees_zeros_in_the_dead_tiles(tile, monkeypatch):
+    """Through the flash kernel (steered here by its threshold), whose
+    whole-bucket attention reads the dead tiles' zero q, k, v: first
+    token and live rows as the whole trace's."""
+    monkeypatch.setattr(attention, "_FLASH_MIN_SCORE_BYTES", 0)
+    config, params = _model("dense")
+    config = dataclasses.replace(config, max_seq_len=65)
+    tile(1 << 20)
+    whole, whole_first, whole_after = _prefill(config, params, TILE + 3)
+    tile(TILE)
+    live, first, after = _prefill(config, params, TILE + 3)
+    assert (first, after) == (whole_first, whole_after)
+    for name, leaf in live.items():
+        np.testing.assert_allclose(
+            leaf[:, :, :TILE + 3], whole[name][:, :, :TILE + 3], atol=2e-5,
+            rtol=1e-5, err_msg=name)
+        assert not leaf[:, :, 2 * TILE:].any(), name

@@ -543,7 +543,7 @@ SERVED_SPANS = {
     "engine.submit": REQUEST | {"waited_us"},
     "engine.step": {"waiting", "active", "decoding", "admitted"},
     "engine.prefill": REQUEST | {"bucket", "true_len", "queue_us",
-                                 "attention"},
+                                 "attention", "rows"},
     "engine.decode": {"decoding", "ahead", "live_blocks", "table_blocks",
                       "write"},
     "engine.readback": set(),
@@ -625,6 +625,23 @@ class TestServedSpans:
         # a bucket of 8 is far under what the flash kernel takes
         assert {args["attention"] for args in prefill.values()} == {
             "einsum"}
+
+    def test_a_whole_prefill_says_the_rows_it_ran(self, served_run):
+        """`rows` beside `bucket` on every whole `engine.prefill`: what
+        models.prefill_rows says, here the bucket (8 rows are under two
+        row tiles, so the program runs whole); `engine_stats()` sums
+        both."""
+        from aiko_services_tpu.models import prefill_rows
+        recorded, run = served_run
+        element = run["replica"].elements["lm"]
+        prefills = [event[4] for event in recorded.named("engine.prefill")]
+        assert len(prefills) == 3
+        for args in prefills:
+            assert args["rows"] == args["bucket"] == prefill_rows(
+                element.config, args["bucket"], args["true_len"])
+        stats = element.engine_stats()
+        assert stats["prefill_rows_run"] == stats["prefill_rows_bucket"] \
+            == sum(args["bucket"] for args in prefills)
 
     def test_ingress_mark_reaches_back_to_the_gateways_dispatch(
             self, served_run):
