@@ -442,6 +442,9 @@ class DecodeCheckpointer:
                  keeper: "CheckpointKeeper | str | None" = None,
                  registry=None, node: str = "",
                  on_checkpoint=None):
+        from .engine import refuse_recurrent
+        refuse_recurrent(engine.config, "a decode checkpoint (its "
+                         "snapshots carry K/V blocks, not the state)")
         self.engine = engine
         self.policy = policy
         self._keeper = keeper if keeper is not None else policy.keeper

@@ -149,6 +149,9 @@ class PrefillEngine:
                  max_context: int | None = None,
                  prefill_chunk_size: int | None = None, registry=None,
                  spans=None, node: str = "prefill"):
+        from .engine import refuse_recurrent
+        refuse_recurrent(config, "a disaggregated prefill (its hand-off "
+                         "carries K/V blocks, not the state)")
         self.params = params
         self.config = config
         max_context = int(max_context or config.max_seq_len)

@@ -77,9 +77,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import (
-    cache_attention_kind, init_paged_pool, paged_decode_step,
-    paged_prefill, paged_prefill_chunk, paged_verify_step,
-    pool_write_kind, prefill_rows)
+    cache_attention_kind, init_paged_pool, init_recurrent_state,
+    paged_decode_step, paged_prefill, paged_prefill_chunk,
+    paged_verify_step, pool_write_kind, prefill_rows, scan_kind,
+    scan_rows)
 from ..observe.trace import NO_SPANS
 from ..parallel.attention import paged_live_blocks
 from ..runtime.compile_cache import compile_bracket, setup_interval
@@ -88,11 +89,28 @@ from ..utils.padding import bucket_length
 from .blocks import TRASH_BLOCK, BlockManager
 from .prefix import PrefixCache, PrefixPolicy, chain_hashes
 
-__all__ = ["DecodeEngine", "Completion", "StepReport"]
+__all__ = ["DecodeEngine", "Completion", "StepReport",
+           "refuse_recurrent"]
 
 _LOGGER = get_logger("decode_engine")
 # decode.tick_decoding's ladder: slots decoded in one tick, not seconds
 SLOT_BOUNDS = (0, 1, 2, 4, 8, 16, 32, 64, 128)
+
+
+def refuse_recurrent(config, what: str) -> None:
+    """Raise, naming the state, where `what` is asked of a model with a
+    recurrent state (Mamba layers).  A slot's state is one row-to-row
+    carry (a convolution tail and an SSM state a layer), advanced in
+    place and kept at no earlier position: what would have to snapshot
+    it at a block boundary, carry it into a chunk, roll it back or ship
+    it is not implemented (ROADMAP.md R3), and is refused instead of
+    attempted."""
+    if getattr(config, "recurrent", False):
+        raise ValueError(
+            f"{what} is not implemented for a model with a recurrent "
+            f"state (Mamba layers: {config.n_states} states of "
+            f"{config.state_bytes // config.n_states} B a slot, kept at "
+            f"the slot's last position only)")
 
 
 @dataclass
@@ -182,6 +200,18 @@ class DecodeEngine:
         if decode_slots < 1:
             raise ValueError(f"decode_slots must be >= 1, "
                              f"got {decode_slots}")
+        for asked, what in (
+                (prefix_policy is not None, "prefix_policy (a cache hit "
+                 "would have to restore the state at a block boundary)"),
+                (prefill_chunk_size is not None, "prefill_chunk_size (a "
+                 "chunk would have to carry the state in)"),
+                (draft_params is not None or spec_k, "speculative decoding "
+                 "(a rejected window would have to roll the state back)")):
+            if asked:
+                refuse_recurrent(config, what)
+        refuse_recurrent(draft_config, "speculative decoding with such a "
+                         "draft (a rejected window would have to roll its "
+                         "state back)")
         self.params = params
         self.config = config
         self.slots_n = int(decode_slots)
@@ -221,6 +251,18 @@ class DecodeEngine:
             self.positions = np.zeros((self.slots_n,), np.int32)
             self.last_tokens = np.zeros((self.slots_n, 1), np.int32)
             interval.holds(self.pool)
+        if config.recurrent:
+            # the fifth store: a Mamba layer's state a slot, addressed by
+            # slot and sized by decode_slots, not by positions; it rides
+            # the pool's dict through the paged programs, donated and
+            # returned with it.  Written whole by an admission's prefill,
+            # advanced in place by every step, zeroed by nobody
+            with setup_interval("state", self._spans.span(
+                    "setup.state", node=node, what="recurrent",
+                    slots=self.slots_n)) as interval:
+                state = init_recurrent_state(config, self.slots_n)
+                interval.holds(state)
+            self.pool.update(state)
         self.slots: list[_Slot | None] = [None] * self.slots_n
         self.waiting: deque[_Request] = deque()
         # the decode step dispatched and not yet read.  While there is
@@ -300,7 +342,9 @@ class DecodeEngine:
                          "experts_read": 0, "expert_pairs": 0,
                          "latent_positions": 0,
                          "ut_passes": 0, "cache_rows": 0,
-                         "exit_expected_step": 0.0}
+                         "exit_expected_step": 0.0,
+                         "state_slots": 0, "state_bytes": 0,
+                         "scan_rows": 0, "scan_kernel": 0, "scan_jnp": 0}
         # what the device counted in the newest decode step read back
         # (`experts_read`/`expert_pairs` of routed experts,
         # `exit_expected_step` of a looped stack), as the next
@@ -325,7 +369,7 @@ class DecodeEngine:
                 self.pool, _ = paged_prefill(
                     self.params, self.config, self.pool,
                     np.zeros((1, bucket), np.int32), self.tables[0],
-                    np.int32(1))
+                    np.int32(1), **self._slot_of(0))
         idle = np.zeros((self.slots_n,), np.int32)
         with self._compiling("warm"):
             self.pool, tokens, *_ = paged_decode_step(
@@ -416,6 +460,11 @@ class DecodeEngine:
                 return None, 0
         return granted, migrated
 
+    def _slot_of(self, index: int) -> dict:
+        """What paged_prefill is told beside the pool and the table of a
+        model with a recurrent state: the slot whose state it writes."""
+        return {"slot": np.int32(index)} if self.config.recurrent else {}
+
     def adopt_request(self, request_id, handoff: dict,
                       timeout: float | None = None) -> StepReport:
         """Adopt a remotely prefilled request MID-FLIGHT: fetch the
@@ -434,6 +483,8 @@ class DecodeEngine:
         ordinary admission path (decode.adopt_fallbacks counts it).
         Returns a StepReport carrying the first token's emission (and
         the completion, when max_new == 1)."""
+        refuse_recurrent(self.config, "adopt_request (a prefill pool's "
+                         "hand-off carries K/V blocks, not the state)")
         report = StepReport()
         self.settle(report)
         prompt = np.asarray(handoff["prompt"], np.int32).reshape(-1)
@@ -532,6 +583,8 @@ class DecodeEngine:
         failed fetch, a full slot array, or an exhausted pool all FALL
         BACK to a plain submit() -- the existing replay re-prefill --
         with decode.restore_fallbacks counting the degradation."""
+        refuse_recurrent(self.config, "restore_request (a checkpoint "
+                         "carries K/V blocks, not the state)")
         report = StepReport()
         self.settle(report)
         if record is not None:
@@ -761,7 +814,8 @@ class DecodeEngine:
         with self._spans.span("engine.decode", decoding=len(decoding),
                               ahead=int(ahead), **self._counted_seen,
                               **self._walked(self.positions, 1,
-                                             decoding)):
+                                             decoding),
+                              **self._stateful(decoding)):
             write_blocks = np.zeros((self.slots_n,), np.int32)
             write_offsets = np.zeros((self.slots_n,), np.int32)
             for index in decoding:
@@ -931,7 +985,7 @@ class DecodeEngine:
                     self.pool, first = paged_prefill(
                         self.params, self.config, self.pool,
                         padded[None], self.tables[index],
-                        np.int32(true_len))
+                        np.int32(true_len), **self._slot_of(index))
                 first = int(first)  # the readback waits for the prefill
             slot.prefill_pos = bucket
             self._finish_prefill(index, report, first)
@@ -959,6 +1013,14 @@ class DecodeEngine:
             self.counters["prefill_rows_run"] += rows
             self.counters["prefill_rows_bucket"] += bucket
             fields = {"attention": attention, "rows": rows}
+            if self.config.recurrent:
+                # the rows a Mamba layer's scan runs, and through what,
+                # asked of the functions the model step decides by
+                scan = scan_kind(self.config, bucket)
+                fields.update(scan=scan, scan_rows=scan_rows(
+                    self.config, bucket, slot.true_len))
+                self.counters["scan_rows"] += fields["scan_rows"]
+                self.counters["scan_" + scan] += 1
         else:
             fields = self._walked(np.array([start]), bucket)
         # the rows this call leaves behind and attends over
@@ -1013,6 +1075,25 @@ class DecodeEngine:
             return {}
         fields = {"ut_passes": self.config.ut_steps,
                   "cache_rows": positions * self.config.n_caches}
+        for name, count in fields.items():
+            self.counters[name] += count
+        return fields
+
+    def _stateful(self, decoding: list) -> dict:
+        """The span fields a recurrent state adds to a decode step over
+        the slots `decoding` (none for a model without): `state_slots`,
+        the decoding slots whose state the step advances; `state_bytes`,
+        what that takes, every one of those slots' state read and written
+        once; `cache_rows`, their live positions in each of the model's
+        K/V caches (this step's own among them).  Running sums in
+        `stats()`."""
+        if not self.config.recurrent:
+            return {}
+        fields = {
+            "state_slots": len(decoding),
+            "state_bytes": 2 * len(decoding) * self.config.state_bytes,
+            "cache_rows": (int(self.positions[decoding].sum())
+                           + len(decoding)) * self.config.n_caches}
         for name, count in fields.items():
             self.counters[name] += count
         return fields

@@ -19,7 +19,7 @@ __all__ = [
     "LLAMA3_8B", "LLAMA32_1B", "LM_TOY",
     "WHISPER_TINY", "WHISPER_SMALL",
     "YOLOV8N_SHAPE", "DETECTOR_TOY", "deepseek_v2_config", "ouro_config",
-    "PUBLISHED_READERS",
+    "jamba_config", "PUBLISHED_READERS",
     "transformer_flops_per_token", "asr_flops_per_example",
     "tts_flops_per_example",
     "detector_flops_per_image",
@@ -144,9 +144,49 @@ def ouro_config(published: dict, max_seq_len: int | None = None,
         sandwich_norm=True)
 
 
+def jamba_config(published: dict, max_seq_len: int | None = None,
+                 dtype: str | None = None) -> TransformerConfig:
+    """TransformerConfig from Jamba's published config.json keys
+    (huggingface.co/ai21labs/AI21-Jamba2-3B), every one under its own
+    name: Mamba layers, with an attention layer where the layer's index
+    is `attn_layer_offset` modulo `attn_layer_period`; attention without
+    positional encoding; one dense gated MLP a layer (`num_experts` 1).
+    Keys whose mechanism is not implemented are refused by name."""
+    unsupported = {
+        "model_type": "jamba", "hidden_act": "silu", "sliding_window": None,
+        "num_experts": 1, "mamba_conv_bias": True, "mamba_proj_bias": False,
+        "tie_word_embeddings": True}
+    for key, value in unsupported.items():
+        if published.get(key, value) != value:
+            raise ValueError(f"jamba: {key}={published[key]!r} is not "
+                             f"implemented (only {value!r})")
+    layers = int(published["num_hidden_layers"])
+    period = int(published["attn_layer_period"])
+    offset = int(published["attn_layer_offset"])
+    d_model = int(published["hidden_size"])
+    rank = published.get("mamba_dt_rank", "auto")
+    return TransformerConfig(
+        vocab_size=int(published["vocab_size"]), d_model=d_model,
+        n_layers=layers, n_heads=int(published["num_attention_heads"]),
+        n_kv_heads=int(published["num_key_value_heads"]),
+        d_ff=int(published["intermediate_size"]),
+        max_seq_len=int(max_seq_len
+                        or published["max_position_embeddings"]),
+        norm_eps=float(published["rms_norm_eps"]),
+        dtype=str(dtype or published.get("torch_dtype", "bfloat16")),
+        layer_kinds=tuple("attention" if index % period == offset
+                          else "mamba" for index in range(layers)),
+        ssm_d_inner=int(published["mamba_expand"]) * d_model,
+        ssm_d_state=int(published["mamba_d_state"]),
+        ssm_d_conv=int(published["mamba_d_conv"]),
+        ssm_dt_rank=(-(-d_model // 16) if rank == "auto" else int(rank)),
+        rotary=False)
+
+
 # model_type of a published config.json -> its reader (elements/ml.py
 # hands LMGenerate's `model` parameter to it whole)
-PUBLISHED_READERS = {"deepseek_v2": deepseek_v2_config, "ouro": ouro_config}
+PUBLISHED_READERS = {"deepseek_v2": deepseek_v2_config, "ouro": ouro_config,
+                     "jamba": jamba_config}
 
 
 # small config for hermetic tests / CPU runs

@@ -35,6 +35,8 @@ from ..parallel.attention import (
     paged_attention_writes,
     ring_attention, sp_decode_attention, ulysses_attention)
 from ..parallel.experts import expert_ffn
+from ..parallel.ssm import (
+    ssm_scan, ssm_scan_rows, ssm_scan_takes, ssm_step)
 from .layers import (
     apply_rotary, dense, dense_heads, init_dense, init_dense_t, init_norm,
     repeat_kv, rms_norm, rotary_embedding, swiglu, yarn_frequencies,
@@ -48,7 +50,8 @@ __all__ = [
     "init_paged_pool", "paged_prefill", "paged_decode_step",
     "paged_prefill_chunk", "paged_verify_step", "cache_attention_kind",
     "pool_write_kind", "prefill_rows", "REMAT_POLICIES",
-    "resolve_remat_policy",
+    "resolve_remat_policy", "init_recurrent_state", "scan_kind",
+    "scan_rows",
 ]
 
 
@@ -147,6 +150,23 @@ class TransformerConfig:
     # a sublayer's OUTPUT is normed too, before the residual add:
     # h + norm(attention(norm(h))), h + norm(FFN(norm(h)))
     sandwich_norm: bool = False
+    # -- layer kinds (Jamba): layer_kinds non-empty ----------------------
+    # One entry a layer, "attention" or "mamba"; empty: every layer
+    # attends.  Like layers that follow one another are one stack and one
+    # scan (_layer_stacks).  An attention layer's K/V cache is numbered
+    # among the attention layers (n_caches), a Mamba layer's recurrent
+    # state among the Mamba layers (n_states): what a sequence carries
+    # from row to row there is the mixer's last ssm_d_conv - 1 inputs of
+    # its convolution and its SSM state, (ssm_d_state, ssm_d_inner)
+    # float32, whatever the context (init_recurrent_state).
+    layer_kinds: tuple = ()
+    ssm_d_inner: int = 0
+    ssm_d_state: int = 0
+    ssm_d_conv: int = 0
+    ssm_dt_rank: int = 0
+    # False: attention with no positional encoding (the Mamba layers
+    # carry position)
+    rotary: bool = True
 
     def __post_init__(self):
         if self.sp_mechanism not in ("ring", "ulysses"):
@@ -180,15 +200,57 @@ class TransformerConfig:
             raise ValueError(
                 "a looped stack keeps its caches on one device (no "
                 "sequence_parallel)")
+        kinds = tuple(self.layer_kinds)
+        if kinds and (len(kinds) != self.n_layers
+                      or set(kinds) - {"attention", "mamba"}):
+            raise ValueError(
+                f"layer_kinds must name {self.n_layers} layers "
+                f"'attention' or 'mamba', got {len(kinds)} of "
+                f"{sorted(set(kinds))}")
+        if self.recurrent:
+            for name in ("kv_dtype", "sequence_parallel", "kv_lora_rank",
+                         "top_k", "n_experts", "sandwich_norm"):
+                if getattr(self, name):
+                    raise ValueError(
+                        f"a model with a recurrent state (Mamba layers) "
+                        f"does not take {name}={getattr(self, name)!r}: "
+                        f"the state is float32, on one device, beside "
+                        f"plain grouped-query attention and a dense FFN")
+            if self.ut_steps > 1:
+                raise ValueError("a model with a recurrent state runs its "
+                                 "stack once a token (ut_steps 1)")
+            if min(self.ssm_d_inner, self.ssm_d_state, self.ssm_dt_rank) < 1 \
+                    or self.ssm_d_conv < 2:
+                raise ValueError(
+                    "Mamba layers need ssm_d_inner, ssm_d_state, "
+                    "ssm_dt_rank >= 1 and ssm_d_conv >= 2")
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
     @property
+    def n_states(self) -> int:
+        """Recurrent states a sequence carries: one a Mamba layer."""
+        return sum(kind == "mamba" for kind in self.layer_kinds)
+
+    @property
+    def recurrent(self) -> bool:
+        return self.n_states > 0
+
+    @property
     def n_caches(self) -> int:
-        """K/V caches a position leaves behind: one a layer a pass."""
-        return self.n_layers * self.ut_steps
+        """K/V caches a position leaves behind: one an attention layer a
+        pass."""
+        return (self.n_layers - self.n_states) * self.ut_steps
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of recurrent state a sequence carries: a Mamba layer's
+        SSM state in float32 and its convolution's tail of inputs."""
+        return self.n_states * self.ssm_d_inner * (
+            4 * self.ssm_d_state
+            + (self.ssm_d_conv - 1) * self.jnp_dtype.itemsize)
 
     @property
     def rotary_dim(self) -> int:
@@ -323,6 +385,48 @@ def _init_layer(key, config: TransformerConfig,
     return layer
 
 
+def _init_mamba_layer(key, config: TransformerConfig) -> dict:
+    """One Mamba layer's weights: the mixer's (Jamba's: the projections
+    in and out without bias, a depthwise causal convolution with one,
+    the three inner norms over delta, B and C, the step size's projection
+    with a float32 bias) and the dense FFN's.  `conv` lies (taps,
+    channels) and `a_log` (d_state, d_inner), the channels on the lanes;
+    published they are (channels, 1, taps) and (d_inner, d_state).
+    Seeded as the published initialiser has them where it matters to the
+    recurrence: A = -(1 .. d_state) a channel, D = 1, softplus(dt_bias)
+    log-uniform in [1e-3, 1e-1]; the matrices as init_dense draws them."""
+    d, ff, dtype = config.d_model, config.d_ff, config.jnp_dtype
+    inner, states = config.ssm_d_inner, config.ssm_d_state
+    rank, taps = config.ssm_dt_rank, config.ssm_d_conv
+    keys = jax.random.split(key, 10)
+    step = jnp.exp(jax.random.uniform(keys[5], (inner,), jnp.float32)
+                   * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+    return {
+        "mixer_norm": init_norm(d, dtype),
+        "w_in": init_dense(keys[0], d, 2 * inner, dtype),
+        "conv": {"w": (jax.random.normal(keys[1], (taps, inner),
+                                         jnp.float32)
+                       / math.sqrt(taps)).astype(dtype),
+                 "b": (jax.random.normal(keys[2], (inner,), jnp.float32)
+                       * 0.02).astype(dtype)},
+        "w_x": init_dense(keys[3], inner, rank + 2 * states, dtype),
+        "dt_norm": init_norm(rank, dtype),
+        "b_norm": init_norm(states, dtype),
+        "c_norm": init_norm(states, dtype),
+        "w_dt": init_dense(keys[4], rank, inner, dtype),
+        # softplus's inverse of the step size
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "a_log": jnp.broadcast_to(jnp.log(jnp.arange(
+            1, states + 1, dtype=jnp.float32))[:, None], (states, inner)),
+        "d": jnp.ones((inner,), jnp.float32),
+        "w_out": init_dense(keys[6], inner, d, dtype),
+        "mlp_norm": init_norm(d, dtype),
+        "w_gate": init_dense(keys[7], d, ff, dtype),
+        "w_up": init_dense(keys[8], d, ff, dtype),
+        "w_down": init_dense(keys[9], ff, d, dtype),
+    }
+
+
 def _stack_layers(layers: list) -> dict:
     """Per-layer weight dicts -> one dict of leaves stacked on a leading
     axis, a leaf at a time, letting each layer's copy go as its stack is
@@ -347,21 +451,44 @@ def _leading_dense(config: TransformerConfig) -> int:
     return config.first_dense_layers if config.top_k else 0
 
 
+def _kind_runs(config: TransformerConfig) -> list:
+    """[(kind, index of the run's first layer among its kind, how many)]:
+    the runs of like layers of a model with layer_kinds, in order."""
+    runs, seen = [], {"attention": 0, "mamba": 0}
+    for kind in config.layer_kinds:
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, seen[kind], 1])
+        seen[kind] += 1
+    return [tuple(run) for run in runs]
+
+
 def init_params(config: TransformerConfig, key) -> dict:
     """Seeded weights.  "layers" is the stack the scan runs; a model
     with routed experts whose first layers are dense has those apart,
-    as "dense_layers" (their FFN leaves have other shapes)."""
+    as "dense_layers" (their FFN leaves have other shapes); a model with
+    layer_kinds has "runs" instead, a stack a run of like layers, layer i
+    drawn from the i-th key whatever its kind."""
     embed_key, *layer_keys = jax.random.split(key, config.n_layers + 1)
     lead = _leading_dense(config)
     params = {
         "embed": {"w": (jax.random.normal(
             embed_key, (config.vocab_size, config.d_model), jnp.float32)
             * 0.02).astype(config.jnp_dtype)},
-        "layers": _stack_layers([
-            _init_layer(k, config, routed=config.top_k > 0)
-            for k in layer_keys[lead:]]),
         "norm_out": init_norm(config.d_model, config.jnp_dtype),
     }
+    if config.layer_kinds:
+        params["runs"], first = [], 0
+        for kind, _, count in _kind_runs(config):
+            init = _init_mamba_layer if kind == "mamba" else _init_layer
+            params["runs"].append(_stack_layers(
+                [init(k, config) for k in layer_keys[first:first + count]]))
+            first += count
+        return params
+    params["layers"] = _stack_layers([
+        _init_layer(k, config, routed=config.top_k > 0)
+        for k in layer_keys[lead:]])
     if lead:
         params["dense_layers"] = _stack_layers(
             [_init_layer(k, config) for k in layer_keys[:lead]])
@@ -417,7 +544,22 @@ def param_specs(config: TransformerConfig,
         "embed": {"w": P(None, "fsdp")},
         "norm_out": {"scale": P(None)},
     }
-    if config.top_k:
+    if config.layer_kinds:
+        # a Mamba layer's big matrices split like the FFN's; what is a
+        # channel's own (the convolution, A, D, the step's bias) and the
+        # narrow projections stay whole
+        whole2, whole3 = P(None, None), P(None, None, None)
+        mamba = dict(
+            dense_ffn, mixer_norm={"scale": whole2},
+            mlp_norm={"scale": whole2}, w_in={"w": column},
+            conv={"w": whole3, "b": whole2}, w_x={"w": whole3},
+            dt_norm={"scale": whole2}, b_norm={"scale": whole2},
+            c_norm={"scale": whole2}, w_dt={"w": whole3}, dt_bias=whole2,
+            a_log=whole3, d=whole2, w_out={"w": row})
+        specs["runs"] = [mamba if kind == "mamba"
+                         else dict(layer, **dense_ffn)
+                         for kind, _, _ in _kind_runs(config)]
+    elif config.top_k:
         specs["layers"] = dict(
             layer, **expert_ffn_specs, shared_gate={"w": column},
             shared_up={"w": column}, shared_down={"w": row})
@@ -469,6 +611,10 @@ def quantize_weights_int8(params: dict,
         raise ValueError("weight-only int8 covers the grouped-query "
                          "dense and switch layers, not latent attention "
                          "or routed experts")
+    if config.recurrent:
+        raise ValueError("weight-only int8 is not implemented for a model "
+                         "with a recurrent state (Mamba layers): "
+                         "quantize_weights_int8 covers stacks of one kind")
 
     def quant(entry: dict, axis: int) -> dict:
         w = entry["w"].astype(jnp.float32)
@@ -533,8 +679,40 @@ def init_cache(config: TransformerConfig, batch: int,
                 "k_scale": jnp.zeros(scale_shape, jnp.float32),
                 "v": jnp.zeros(shape, jnp.int8),
                 "v_scale": jnp.zeros(scale_shape, jnp.float32)}
+    # a model with Mamba layers carries their state beside the K/V, the
+    # batch in the slots' place
     return {"k": jnp.zeros(shape, config.jnp_dtype),
-            "v": jnp.zeros(shape, config.jnp_dtype)}
+            "v": jnp.zeros(shape, config.jnp_dtype),
+            **init_recurrent_state(config, batch)}
+
+
+# the leaves of a cache or a pool that are recurrent state, not K/V, each
+# with the axis its sequences (a cache's batch, a pool's slots) lie on
+_STATE_LEAVES = {"conv": 2, "ssm": 1}
+
+
+def _layer_state(config: TransformerConfig, slots: int) -> dict:
+    """One Mamba layer's state of `slots` sequences from their start."""
+    return {"conv": jnp.zeros((config.ssm_d_conv - 1, slots,
+                               config.ssm_d_inner), config.jnp_dtype),
+            "ssm": jnp.zeros((slots, config.ssm_d_state,
+                              config.ssm_d_inner), jnp.float32)}
+
+
+def init_recurrent_state(config: TransformerConfig, slots: int) -> dict:
+    """The recurrent state of `slots` sequences, zeros: {} for a model
+    with no Mamba layer, else a Mamba layer's two leaves, addressed by
+    sequence and sized by `slots`, not by positions: "conv" (n_states,
+    ssm_d_conv - 1, slots, ssm_d_inner), the mixer's last inputs of its
+    convolution, oldest first, and "ssm" (n_states, slots, ssm_d_state,
+    ssm_d_inner) float32.  Every minor pair of axes fills its tiles: the
+    channels on the lanes, the slots (conv) and the states (ssm) on the
+    sublanes.  Held (slots, 3, .) and (., d_inner, d_state), as the mixer
+    is published, three rows pad to a tile's 16 and 16 lanes to 128."""
+    if not config.recurrent:
+        return {}
+    return {name: jnp.zeros((config.n_states,) + leaf.shape, leaf.dtype)
+            for name, leaf in _layer_state(config, slots).items()}
 
 
 def cache_specs(sequence_parallel: bool = False,
@@ -571,6 +749,8 @@ def _project_qkv(config: TransformerConfig, layer, x, cos, sin):
     v = dense(layer["wv"], x).reshape(
         batch, length, config.n_kv_heads, config.head_dim
     ).transpose(0, 2, 1, 3)
+    if not config.rotary:
+        return dense_heads(layer["wq"], x), dense_heads(layer["wk"], x), v
     return (apply_rotary(dense_heads(layer["wq"], x), cos, sin),
             apply_rotary(dense_heads(layer["wk"], x), cos, sin), v)
 
@@ -645,9 +825,13 @@ def _row_tiles_take(config: TransformerConfig, length: int) -> bool:
     """Whether a whole prefill of `length` rows runs by row tiles: at
     least two whole tiles, and a layer whose row-wise work is a row's
     own (a switch FFN's capacity and a sequence-parallel attention's
-    shards span the sequence)."""
+    shards span the sequence; a Mamba layer's convolution reads the
+    three rows before a row, across a tile's edge: a model with a
+    recurrent state runs its bucket whole, and its scan alone stops at
+    the prompt's length, scan_rows)."""
     return (length >= 2 * _ROW_TILE and length % _ROW_TILE == 0
-            and config.n_experts == 0 and not config.sequence_parallel)
+            and config.n_experts == 0 and not config.sequence_parallel
+            and not config.recurrent)
 
 
 def prefill_rows(config: TransformerConfig, bucket: int,
@@ -752,6 +936,93 @@ def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend,
         mlp_out, stats = _routed_moe(config, layer, mlp_in, live)
         h = ffn_out(layer, h, mlp_out)
     return h, stats, leaves
+
+
+def scan_kind(config: TransformerConfig, length: int) -> str:
+    """"kernel" or "jnp": what a Mamba layer's selective scan over
+    `length` rows runs through (parallel/ssm.py).  _mamba_mixer's ssm_scan
+    decides by the same predicate, and the engine names its prefill spans
+    by this."""
+    return "kernel" if ssm_scan_takes(
+        length, config.ssm_d_inner, config.ssm_d_state,
+        config.jnp_dtype) else "jnp"
+
+
+def scan_rows(config: TransformerConfig, bucket: int, true_len: int) -> int:
+    """The rows a Mamba layer's scan runs of a whole prefill of `bucket`
+    rows for a prompt of `true_len` tokens: the kernel stops after the
+    block of rows that holds row true_len - 1, the oracle runs the bucket
+    (the rows past true_len with a step of 0)."""
+    return ssm_scan_rows(bucket, true_len,
+                         scan_kind(config, bucket) == "kernel")
+
+
+def _mamba_mixer(config: TransformerConfig, layer, u, tail, ssm, stop):
+    """Jamba's Mamba mixer over the normed rows u (B, L, d) of B
+    sequences, each from its own state: `tail` (taps - 1, B, d_inner),
+    the convolution's inputs before row 0, oldest first, and `ssm` (B,
+    d_state, d_inner) float32.  Returns (out (B, L, d), the new tail, the
+    new ssm): the state after row stop - 1 (`stop` traced, a whole
+    prefill's true length; None: after the last row), so that right
+    padding leaves nothing in it.
+
+        [x, z] = u W_in;   c = silu(b_conv + sum_j w_conv[j] x_{t-3+j})
+        [delta, B, C] = c W_x, each RMS-normed with a gain of its own
+        dt = delta W_dt;   the selective scan (parallel/ssm.py), float32
+        out = (y * silu(z)) W_out
+
+    One row (a decode step) is ssm_step's update; more are ssm_scan's."""
+    f32 = jnp.float32
+    inner, states = config.ssm_d_inner, config.ssm_d_state
+    rank, eps = config.ssm_dt_rank, config.norm_eps
+    length = u.shape[1]
+    xz = dense(layer["w_in"], u)
+    x, z = xz[..., :inner], xz[..., inner:]
+    taps = layer["conv"]["w"].astype(f32)                  # (taps, inner)
+    before = taps.shape[0] - 1
+    if length == 1:
+        window = jnp.concatenate([tail, x.swapaxes(0, 1)])  # (taps, B, .)
+        conv = jnp.sum(window.astype(f32) * taps[:, None], axis=0)[:, None]
+        tail = window[1:]
+    else:
+        rows = jnp.concatenate([tail.swapaxes(0, 1), x], axis=1)
+        conv = sum(rows[:, j:j + length].astype(f32) * taps[j]
+                   for j in range(before + 1))
+        # the inputs of the rows stop - taps + 1 .. stop - 1
+        tail = jax.lax.dynamic_slice_in_dim(
+            rows, length if stop is None else stop, before,
+            axis=1).swapaxes(0, 1)
+    c = jax.nn.silu(conv + layer["conv"]["b"].astype(f32)).astype(u.dtype)
+    projected = dense(layer["w_x"], c)
+    delta = rms_norm(layer["dt_norm"], projected[..., :rank], eps)
+    b = rms_norm(layer["b_norm"], projected[..., rank:rank + states], eps)
+    cc = rms_norm(layer["c_norm"], projected[..., rank + states:], eps)
+    dt = dense(layer["w_dt"], delta)
+    a = -jnp.exp(layer["a_log"].astype(f32))
+    if length == 1:
+        y, ssm = ssm_step(c[:, 0], dt[:, 0], z[:, 0], b[:, 0], cc[:, 0], a,
+                          layer["d"], layer["dt_bias"], ssm)
+        y = y[:, None]
+    else:
+        y, ssm = ssm_scan(c, dt, z, b, cc, a, layer["d"], layer["dt_bias"],
+                          ssm, stop)
+    return dense(layer["w_out"], y), tail, ssm
+
+
+def _mamba_layer(config: TransformerConfig, layer, h, state, stop=None):
+    """A Mamba layer: h + mixer(norm(h)), then the dense FFN as a decoder
+    layer has it.  `state` is the layer's {"conv", "ssm"} for h's B
+    sequences, or None: zeros, a sequence from its start.  Returns (h,
+    the FFN's stats, the new state)."""
+    if state is None:
+        state = _layer_state(config, h.shape[0])
+    out, tail, ssm = _mamba_mixer(
+        config, layer, rms_norm(layer["mixer_norm"], h, config.norm_eps),
+        state["conv"], state["ssm"], stop)
+    h = h + out
+    mlp_out, stats = _mlp_block(
+        config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps))
+    return h + mlp_out, stats, {"conv": tail, "ssm": ssm}
 
 
 def _sp_prefill(config: TransformerConfig, q, k, v):
@@ -1216,7 +1487,12 @@ def _rotary_tables(config: TransformerConfig, positions):
 def _layer_stacks(params: dict, config: TransformerConfig) -> list:
     """[(stacked layers, index of the first, how many)] in the order they
     run: the leading dense layers of a routed-expert model, then the
-    stack every model has."""
+    stack every model has; of a model with layer_kinds its runs of like
+    layers, a run's first index counted among its own kind (an attention
+    layer's among the K/V caches, a Mamba layer's among the states)."""
+    if config.layer_kinds:
+        return [(stack, first, count) for stack, (_, first, count)
+                in zip(params["runs"], _kind_runs(config))]
     lead = _leading_dense(config)
     stacks = [(params["dense_layers"], 0, lead)] if lead else []
     return stacks + [(params["layers"], lead, config.n_layers - lead)]
@@ -1361,10 +1637,15 @@ def _hidden(params: dict, config: TransformerConfig, tokens, cache, pos,
     def layer_step(carry, xs):
         h, stats_sum = carry
         layer, layer_cache = xs
-        h, stats, new_cache = _decoder_layer(
-            config, layer, h, cos, sin,
-            partial(_attend_fresh, config) if layer_cache is None
-            else partial(_attend_cache, config, layer_cache, pos), live)
+        if "w_in" in layer:
+            # a Mamba layer: its state after row true_len - 1
+            h, stats, new_cache = _mamba_layer(config, layer, h,
+                                               layer_cache, true_len)
+        else:
+            h, stats, new_cache = _decoder_layer(
+                config, layer, h, cos, sin,
+                partial(_attend_fresh, config) if layer_cache is None
+                else partial(_attend_cache, config, layer_cache, pos), live)
         stats_sum = stats_sum + stats
         if activation_specs:
             h = jax.lax.with_sharding_constraint(h, act_spec)
@@ -1380,27 +1661,31 @@ def _hidden(params: dict, config: TransformerConfig, tokens, cache, pos,
         # documented setting under scan (the scan boundary already
         # blocks the CSE that prevent_cse guards against).
         body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-    written = []
+    written = {}
 
     def scan_stack(carry, stack, first, count):
         if cache is None:
             return _scan_layers(config, body, carry, stack, None)[0]
-        # one stack run once has the whole cache; else the caches this
-        # stack writes on this pass
+        # the leaves this stack's layers write: a Mamba run the state's,
+        # else the K/V's -- whole where the stack run once has them all,
+        # else those it writes on this pass
+        names = [name for name in cache
+                 if (name in _STATE_LEAVES) == ("w_in" in stack)]
         carry, part = _scan_layers(
             config, body, carry, stack,
-            cache if count == config.n_caches else jax.tree_util.tree_map(
-                lambda leaf: leaf[first:first + count], cache),
+            {name: cache[name] if count == cache[name].shape[0]
+             else cache[name][first:first + count] for name in names},
             sliced_where_read=live is not None)
-        written.append(part)
+        for name in names:
+            written.setdefault(name, []).append(part[name])
         return carry
 
     (h, stats_sum), outputs = _run_passes(params, config, carry, scan_stack)
     new_cache = None
     if cache is not None:
-        new_cache = written[0] if len(written) == 1 else \
-            jax.tree_util.tree_map(
-                lambda *parts: jnp.concatenate(parts), *written)
+        new_cache = {name: parts[0] if len(parts) == 1
+                     else jnp.concatenate(parts)
+                     for name, parts in written.items()}
     return h, outputs, stats_sum, new_cache
 
 
@@ -1545,7 +1830,10 @@ def init_paged_pool(config: TransformerConfig, num_blocks: int,
     token positions each, shared by every decode slot through per-slot
     block tables.  Block 0 is the engine's reserved trash block
     (inactive-slot writes land there).  Same leaf names/dtypes as
-    init_cache, so the int8 KV path carries over unchanged."""
+    init_cache, so the int8 KV path carries over unchanged.  A model with
+    Mamba layers has its slots' recurrent state beside these leaves, in
+    the same dict (init_recurrent_state: by slot, not by block); the
+    engine makes both."""
     if config.kv_lora_rank:
         # the latent pool: one leaf, one row a position a layer
         return {"kv": jnp.zeros(
@@ -1565,7 +1853,7 @@ def init_paged_pool(config: TransformerConfig, num_blocks: int,
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(2,))
 def paged_prefill(params, config: TransformerConfig, pool, prompt,
-                  table_row, true_len):
+                  table_row, true_len, slot=None):
     """Prefill one request into its pool blocks.  prompt is (1, Lb)
     with Lb a multiple of the pool's block size (the engine right-pads
     to a bucket, so one executable serves every prompt length in the
@@ -1579,7 +1867,12 @@ def paged_prefill(params, config: TransformerConfig, pool, prompt,
     (_row_tiles_take) runs a layer's row-wise work up to true_len and the
     blocks past them receive zeros (a decode step writes a position
     before it reads it).  The decode loop never recompiles
-    (paged_decode_step below)."""
+    (paged_decode_step below).  A model with Mamba layers is also told
+    its `slot` (traced int32): the recurrent state after row true_len - 1
+    overwrites the whole of that slot's, whatever the bucket."""
+    if config.recurrent and slot is None:
+        raise ValueError("paged_prefill of a model with a recurrent state "
+                         "needs the slot whose state it writes")
     block_size = _store_leaf(pool).shape[3]
     local = init_cache(config, 1, max_len=prompt.shape[1])
     h, outputs, _, local = _hidden(params, config, prompt, local, 0,
@@ -1591,6 +1884,12 @@ def paged_prefill(params, config: TransformerConfig, pool, prompt,
     first = jnp.argmax(logits[0, 0]).astype(jnp.int32)
     blocks = prompt.shape[1] // block_size
     new_pool = {}
+    for name, axis in _STATE_LEAVES.items():
+        # the recurrent state after row true_len - 1, the whole of slot
+        # `slot`'s: the slot's previous occupant leaves nothing behind
+        if name in local:
+            new_pool[name] = jax.lax.dynamic_update_slice_in_dim(
+                pool[name], local.pop(name), slot, axis)
     for name, written in local.items():
         # (caches, 1, H, Lb, d) -> (caches, blocks, H, block_size, d),
         # scattered into the slot's first `blocks` pool entries: cache
@@ -1757,6 +2056,13 @@ def _paged_logits(params, config: TransformerConfig, pool, tables,
     speculative tests pin -- stats float32 (_FFN_STATS,) the FFNs',
     summed over layers and passes, and exit_steps float32 (slots, W) a
     looped stack's expected exit pass (_logits; None for one pass)."""
+    if config.recurrent and tokens.shape[1] != 1:
+        raise ValueError(
+            f"a window of {tokens.shape[1]} positions over the paged pool "
+            f"(a speculative verify step, a prefill chunk) is not "
+            f"implemented for a model with a recurrent state: a Mamba "
+            f"layer's state is advanced a row a step and cannot be rolled "
+            f"back or carried into a chunk")
     h = _embed(params, config, tokens)
     q_pos = positions[:, None] + jnp.arange(tokens.shape[1])[None, :]
     cos, sin = _rotary_tables(config, q_pos)
@@ -1769,10 +2075,21 @@ def _paged_logits(params, config: TransformerConfig, pool, tables,
         # FFN's stats only the experts' counts go on
         h, pool, stats_sum = carry
         layer, index = xs
-        h, stats, pool = _decoder_layer(
-            config, layer, h, cos, sin,
-            partial(_attend_pool, config, pool, index, tables, positions,
-                    write_blocks, write_offsets))
+        if "w_in" in layer:
+            # a Mamba layer advances every slot's state a row, in place:
+            # row s of h is slot s's
+            h, stats, state = _mamba_layer(
+                config, layer, h,
+                {name: pool[name][index] for name in _STATE_LEAVES})
+            pool = {**pool, **{
+                name: jax.lax.dynamic_update_index_in_dim(
+                    pool[name], state[name], index, 0)
+                for name in _STATE_LEAVES}}
+        else:
+            h, stats, pool = _decoder_layer(
+                config, layer, h, cos, sin,
+                partial(_attend_pool, config, pool, index, tables,
+                        positions, write_blocks, write_offsets))
         return (h, pool, stats_sum + stats), None
 
     def scan_stack(carry, stack, first, count):
@@ -1893,6 +2210,11 @@ def make_train_step(config: TransformerConfig, optimizer,
     applied to the per-layer scan body (ROADMAP #3b: the train-MFU
     recompute-share sweep)."""
     resolve_remat_policy(remat_policy)  # fail fast on typos
+    if config.recurrent:
+        raise ValueError(
+            "make_train_step is not implemented for a model with a "
+            "recurrent state (Mamba layers): the selective-scan kernel "
+            "has no backward pass")
 
     def loss_fn(params, tokens):
         logits, aux = forward(params, config, tokens[:, :-1],

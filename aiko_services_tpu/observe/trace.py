@@ -100,7 +100,16 @@
 #                               of a looped stack also ut_passes and
 #                               cache_rows (as on engine.decode; here the
 #                               rows the call leaves behind, true_len or
-#                               the chunk's end, x caches)
+#                               the chunk's end, x caches); of a model
+#                               with a recurrent state (Mamba layers) a
+#                               whole prefill also scan (kernel | jnp:
+#                               what the selective scan runs through, as
+#                               models.scan_kind says) and scan_rows (the
+#                               rows it runs of the bucket's: the kernel
+#                               stops after the block of rows that holds
+#                               row true_len - 1, models.scan_rows);
+#                               running sum scan_rows, counts
+#                               scan_kernel, scan_jnp
 #   engine.decode      scoped   table build + dispatch: decoding,
 #                               ahead (1: dispatched while the step
 #                               before was unread, from its tokens on
@@ -139,7 +148,16 @@
 #                               device-counted, so of the newest step
 #                               read back, like experts_read); running
 #                               sums in engine_stats(), the last of the
-#                               steps' means
+#                               steps' means.
+#                               Of a model with a recurrent state:
+#                               state_slots (the decoding slots whose
+#                               state the step advances), state_bytes
+#                               (host-counted: those slots' state, a
+#                               convolution tail and an SSM state a Mamba
+#                               layer, read and written once) and
+#                               cache_rows (their live positions x the
+#                               attention layers' K/V caches); running
+#                               sums in engine_stats()
 #   engine.readback    scoped   the settle's readback of the step in
 #                               flight: in a tick after that tick's
 #                               engine.decode where it ran ahead, or
@@ -187,7 +205,9 @@
 #                               sample of `setup.weights_s`
 #   setup.state        scoped   DecodeEngine / PrefillEngine around the
 #                               paged pool and its tables: node, what
-#                               (pool | draft_pool), blocks, bytes,
+#                               (pool | draft_pool; recurrent: a model's
+#                               recurrent state, by slot, with slots in
+#                               the blocks' place), blocks, bytes,
 #                               leaves, compile_us.  One sample of
 #                               `setup.state_s`.  generate()'s contiguous
 #                               cache is made inside its jitted program

@@ -426,7 +426,8 @@ def test_kernel_compiles_for_the_v5e_at_sixteen_kv_heads(for_the_chip):
 def _made_of_a_leaf(text: str, leaf, operation: str) -> list:
     """The compiled program's lines in which `operation` makes an array
     of a pool leaf's type."""
-    leaf_type = "bf16[" + ",".join(map(str, leaf.shape)) + "]"
+    kind = {"bfloat16": "bf16", "float32": "f32"}[str(leaf.dtype)]
+    leaf_type = kind + "[" + ",".join(map(str, leaf.shape)) + "]"
     return [line for line in text.splitlines()
             if f"= {leaf_type}" in line and operation in line]
 
@@ -783,3 +784,92 @@ def test_served_decode_step_stages_no_projection_on_the_v5e(
     # 1.45 MB and 2.3 MB when written; the share's was 77.8 MB, wq_b's
     # slice of the stack materialised in HBM before its transposed copy
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+# benchmark/configs/jamba2_3b.json: 26 Mamba layers whose state is a slot's
+# beside 2 attention layers of 20 query heads to ONE K/V head, 32 slots of
+# 176 blocks
+JAMBA = dict(slots=32, block=32, max_blocks=176, states=26, caches=2)
+
+
+def _served_jamba(for_the_chip, monkeypatch):
+    """(config, params, pool, int32): Jamba2-3B as jamba.think serves it,
+    the slots' recurrent state among the pool's leaves, placed on the
+    described chip; the scan kernel lowered through Mosaic."""
+    import json
+    import pathlib
+    from aiko_services_tpu.models.configs import jamba_config
+    from aiko_services_tpu.models.transformer import init_recurrent_state
+    from aiko_services_tpu.parallel import ssm
+    monkeypatch.setattr(ssm, "_interpret", lambda: False)
+    published = json.loads((pathlib.Path(__file__).parent.parent
+                            / "benchmark/configs/jamba2_3b.json"
+                            ).read_text())
+    serve = published["serve"]
+    s = JAMBA
+    assert (serve["decode_slots"], serve["kv_block_size"],
+            serve["max_context"]) == (
+        s["slots"], s["block"], s["block"] * s["max_blocks"])
+    assert serve["kv_blocks"] == s["slots"] * s["max_blocks"] + 1
+    config = jamba_config(published, serve["max_context"])
+    assert (config.n_states, config.n_caches) == (s["states"], s["caches"])
+    place = lambda tree: jax.tree_util.tree_map(       # noqa: E731
+        lambda leaf: for_the_chip(leaf.shape, leaf.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: init_params(config, jax.random.PRNGKey(0))))
+    pool = place(jax.eval_shape(lambda: {
+        **init_paged_pool(config, serve["kv_blocks"], s["block"]),
+        **init_recurrent_state(config, s["slots"])}))
+    return config, params, pool, lambda *shape: for_the_chip(shape, "int32")
+
+
+def test_served_jamba_decode_step_copies_no_state_leaf_on_the_v5e(
+        for_the_chip, monkeypatch):
+    """The step advances every slot's convolution tail and SSM state a
+    row, in place: the donated leaves (0.30 GB of state, 0.18 GB of K/V)
+    come back as the buffers they were, no copy of either state leaf, as
+    none of a pool leaf; the 20 query heads attend over their one K/V
+    head through the paged kernel, which writes the step's new rows."""
+    s = JAMBA
+    config, params, pool, int32 = _served_jamba(for_the_chip, monkeypatch)
+    assert pool["conv"].shape == (26, 3, 32, 5120)
+    assert pool["ssm"].shape == (26, 32, 16, 5120)
+    assert pool["k"].shape == (2, 5633, 1, 32, 128)
+    slots = s["slots"]
+    compiled = paged_decode_step.lower(
+        params, config, pool, int32(slots, s["max_blocks"]), int32(slots),
+        int32(slots, 1), int32(slots), int32(slots)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention" in text
+    memory = compiled.memory_analysis()
+    held = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+               for leaf in pool.values())
+    assert memory.alias_size_in_bytes == held
+    assert 32 * 26 * 358_400 < held < 32 * 26 * 358_400 + 190e6
+    assert memory.temp_size_in_bytes < 8 << 20
+    for name in ("conv", "ssm", "k", "v"):
+        assert not _made_of_a_leaf(text, pool[name], " copy("), name
+    assert not _made_of_a_leaf(text, pool["k"], " dynamic-update-slice(")
+    # weights 6.06 GB + state 0.30 + K/V 0.18: what the chip holds
+    assert 6.5e9 < memory.argument_size_in_bytes < 6.6e9
+
+
+def test_served_jamba_prefill_scans_through_the_kernel_on_the_v5e(
+        for_the_chip, monkeypatch):
+    """The 4096 bucket: 26 selective scans through `ssm_chunk_scan` (S in
+    VMEM: nothing of rows x d_inner x d_state in HBM, 1.3 GB a tensor a
+    layer), the two attention layers through the flash kernel, the slot's
+    state written into the donated leaves."""
+    s = JAMBA
+    config, params, pool, int32 = _served_jamba(for_the_chip, monkeypatch)
+    compiled = paged_prefill.lower(
+        params, config, pool, int32(1, 4096), int32(s["max_blocks"]),
+        int32(), int32()).compile()
+    text = compiled.as_text()
+    assert "ssm_chunk_scan" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1 << 29
+    for name in ("conv", "ssm"):
+        assert not _made_of_a_leaf(text, pool[name], " copy("), name
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 7.2e9)
