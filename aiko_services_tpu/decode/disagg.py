@@ -45,7 +45,8 @@ from collections import deque
 import numpy as np
 
 from ..models import (
-    init_paged_pool, paged_prefill, paged_prefill_chunk, prefill_rows)
+    init_paged_pool, paged_prefill, paged_prefill_chunk,
+    prefill_attention_rows, prefill_rows)
 from ..observe.trace import NO_SPANS
 from ..pipeline.transfer import fetch_many, get_transfer_server
 from ..runtime.compile_cache import compile_bracket, setup_interval
@@ -184,7 +185,8 @@ class PrefillEngine:
             self.prefill_chunk = None
         self.counters = {"submitted": 0, "exported": 0, "chunks": 0,
                          "compiles": 0, "exported_bytes": 0,
-                         "prefill_rows_run": 0, "prefill_rows_bucket": 0}
+                         "prefill_rows_run": 0, "prefill_rows_bucket": 0,
+                         "prefill_attn_rows": 0}
 
     @property
     def compile_count(self) -> int:
@@ -280,13 +282,18 @@ class PrefillEngine:
         if (self.prefill_chunk is None
                 or self.prefill_chunk >= job.bucket):
             # as DecodeEngine's span of a whole prefill: the rows the
-            # program runs of the bucket's, asked of the model step
+            # program, and its attention, run of the bucket's, asked of
+            # the model step
             rows = prefill_rows(self.config, job.bucket, job.true_len)
+            attn_rows = prefill_attention_rows(self.config, job.bucket,
+                                               job.true_len)
             self.counters["prefill_rows_run"] += rows
             self.counters["prefill_rows_bucket"] += job.bucket
+            self.counters["prefill_attn_rows"] += attn_rows
             with self._spans.span(
                     "engine.prefill", job.request_id, bucket=job.bucket,
-                    true_len=job.true_len, rows=rows):
+                    true_len=job.true_len, rows=rows,
+                    attn_rows=attn_rows):
                 with self._compiling("paged_prefill"):
                     self.pool, first = paged_prefill(
                         self.params, self.config, self.pool,

@@ -79,7 +79,8 @@ import numpy as np
 from ..models import (
     cache_attention_kind, init_paged_pool, init_recurrent_state,
     paged_decode_step, paged_prefill, paged_prefill_chunk,
-    paged_verify_step, pool_write_kind, prefill_rows, scan_kind,
+    paged_verify_step, pool_write_kind, prefill_attention_rows,
+    prefill_rows, scan_kind,
     scan_rows)
 from ..observe.trace import NO_SPANS
 from ..parallel.attention import paged_live_blocks
@@ -336,6 +337,7 @@ class DecodeEngine:
                          "live_blocks": 0, "table_blocks": 0,
                          "prefill_flash": 0, "prefill_einsum": 0,
                          "prefill_rows_run": 0, "prefill_rows_bucket": 0,
+                         "prefill_attn_rows": 0,
                          "writes_kernel": 0, "writes_updates": 0,
                          "decode_steps": 0, "steps_ahead": 0,
                          "overrun_tokens": 0,
@@ -996,23 +998,29 @@ class DecodeEngine:
         `queue_us` how long the request waited for its slot.  A whole
         prefill says which `attention` its bucket takes (flash, the
         blockwise kernel over the fresh K/V, or einsum), asking the
-        function the model step itself decides by, and the `rows` the
+        function the model step itself decides by, the `rows` the
         program runs of the bucket's (`prefill_rows`: the prompt's
         length rounded up to a row tile where the bucket runs by row
-        tiles); a chunk call (`start` = its first position) walks the
-        slot's table like a decode step and carries
-        `live_blocks`/`table_blocks` instead.  The running counts and
-        sums (`prefill_rows_run`, `prefill_rows_bucket`) ride
-        `stats()`."""
+        tiles) and the `attn_rows` its attention runs
+        (`prefill_attention_rows`: the length rounded up to the flash
+        kernel's query block where the kernel is told it); a chunk call
+        (`start` = its first position) walks the slot's table like a
+        decode step and carries `live_blocks`/`table_blocks` instead.
+        The running counts and sums (`prefill_rows_run`,
+        `prefill_rows_bucket`, `prefill_attn_rows`) ride `stats()`."""
         request = slot.request
         if start is None:
             attention = cache_attention_kind(self.config, self.pool, 1,
                                              bucket)
             rows = prefill_rows(self.config, bucket, slot.true_len)
+            attn_rows = prefill_attention_rows(self.config, bucket,
+                                               slot.true_len)
             self.counters["prefill_" + attention] += 1
             self.counters["prefill_rows_run"] += rows
             self.counters["prefill_rows_bucket"] += bucket
-            fields = {"attention": attention, "rows": rows}
+            self.counters["prefill_attn_rows"] += attn_rows
+            fields = {"attention": attention, "rows": rows,
+                      "attn_rows": attn_rows}
             if self.config.recurrent:
                 # the rows a Mamba layer's scan runs, and through what,
                 # asked of the functions the model step decides by

@@ -31,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.attention import (
     attention_reference, flash_attention, flash_attention_takes,
+    flash_query_block,
     paged_attention, paged_attention_reference, paged_attention_takes,
     paged_attention_writes,
     ring_attention, sp_decode_attention, ulysses_attention)
@@ -49,7 +50,8 @@ __all__ = [
     "quantize_weights_int8", "quantized_param_specs",
     "init_paged_pool", "paged_prefill", "paged_decode_step",
     "paged_prefill_chunk", "paged_verify_step", "cache_attention_kind",
-    "pool_write_kind", "prefill_rows", "REMAT_POLICIES",
+    "pool_write_kind", "prefill_rows", "prefill_attention_rows",
+    "REMAT_POLICIES",
     "resolve_remat_policy", "init_recurrent_state", "scan_kind",
     "scan_rows",
 ]
@@ -795,13 +797,15 @@ def _latent_expand(config: TransformerConfig, layer, latent):
     return jnp.concatenate([k_nope, k_rope], axis=-1), v
 
 
-def _latent_flash(config: TransformerConfig, q, k, v):
+def _latent_flash(config: TransformerConfig, q, k, v, live=None):
     """Causal blockwise attention of decompressed MLA heads: q.k over
     nope + rope (zero-padded to the lanes, which adds nothing to a
-    score), v of its own width, YaRN's softmax scale."""
+    score), v of its own width, YaRN's softmax scale; `live` a whole
+    prefill's true length (flash_attention's)."""
     pad = [(0, 0)] * 3 + [(0, -q.shape[-1] % 128)]
     return flash_attention(jnp.pad(q, pad), jnp.pad(k, pad), v,
-                           causal=True, sm_scale=config.attention_scale)
+                           causal=True, sm_scale=config.attention_scale,
+                           live=live)
 
 
 # Rows of a whole prefill's row tile.  A whole prefill (paged_prefill: a
@@ -1068,7 +1072,12 @@ def cache_attention_kind(config: TransformerConfig, store: dict, batch: int,
     `store`'s dtype (a cache, or the pool paged_prefill scatters its
     cache into: their leaves are alike).  _attend_cache decides by this,
     and the engine names its prefill spans by it."""
-    dtype = _store_leaf(store).dtype
+    return _cache_attention_kind(config, _store_leaf(store).dtype, batch,
+                                 length, pos)
+
+
+def _cache_attention_kind(config: TransformerConfig, dtype, batch: int,
+                          length: int, pos) -> str:
     if (length > 1 and isinstance(pos, (int, np.integer)) and pos == 0
             and flash_attention_takes(batch, config.n_heads, length, dtype,
                                       dtype)):
@@ -1076,8 +1085,33 @@ def cache_attention_kind(config: TransformerConfig, store: dict, batch: int,
     return "einsum"
 
 
+def prefill_attention_rows(config: TransformerConfig, bucket: int,
+                           true_len: int) -> int:
+    """The query rows the attention of a whole prefill (paged_prefill) of
+    `bucket` rows runs for a prompt of `true_len` tokens: true_len rounded
+    up to the kernel's query block where the bucket attends through the
+    flash kernel, which is then told the length (flash_attention's
+    `live`: the blocks past it take no step and fetch nothing), else the
+    bucket.  _attend_cache decides by the same predicate -- the
+    attention's own, whether or not the bucket runs by row tiles -- and
+    the engine names its prefill spans by this."""
+    dtype = jnp.int8 if config.kv_dtype == "int8" else config.jnp_dtype
+    if (config.sequence_parallel or _cache_attention_kind(
+            config, dtype, 1, bucket, 0) != "flash"):
+        return bucket
+    kv_heads, width = config.n_kv_heads, config.head_dim
+    if config.kv_lora_rank:
+        # decompressed: every head its own K/V, q.k padded to the lanes
+        kv_heads = config.n_heads
+        width = -(-(config.qk_nope_head_dim + config.qk_rope_head_dim)
+                  // 128) * 128
+    block = flash_query_block(config.n_heads, kv_heads, width, bucket,
+                              live=True)
+    return min(bucket, -(-true_len // block) * block)
+
+
 def _attend_cache_latent(config: TransformerConfig, cache: dict, pos,
-                         layer, q, latent):
+                         layer, q, latent, live=None):
     """_attend_cache for latent attention, decompressed: the fresh rows
     alone through the flash kernel where a prefill from position 0 takes
     it, else the whole buffer's rows made heads again and masked."""
@@ -1086,7 +1120,7 @@ def _attend_cache_latent(config: TransformerConfig, cache: dict, pos,
                                                 (0, 0, pos, 0))}
     if cache_attention_kind(config, cache, batch, length, pos) == "flash":
         return _latent_flash(
-            config, q, *_latent_expand(config, layer, latent)), cache
+            config, q, *_latent_expand(config, layer, latent), live), cache
     k, v = _latent_expand(config, layer, cache["kv"])
     return attention_reference(
         q, k, v, causal=True, sm_scale=config.attention_scale,
@@ -1094,14 +1128,16 @@ def _attend_cache_latent(config: TransformerConfig, cache: dict, pos,
 
 
 def _attend_cache(config: TransformerConfig, cache: dict, pos, layer,
-                  q, k, v):
+                  q, k, v, live=None):
     """Contiguous cache (init_cache; `cache` is one layer's leaves):
     write the new K/V at `pos`, then masked attention over the whole
     buffer -- or, for a prefill from the static position 0 that
     flash_attention_takes, the same causal attention over the fresh K/V
-    alone, blockwise."""
+    alone, blockwise, and told `live`, the true length of a whole
+    prefill whose other rows are padding (traced; None: every row is
+    read), where there is one."""
     if config.kv_lora_rank:
-        return _attend_cache_latent(config, cache, pos, layer, q, k)
+        return _attend_cache_latent(config, cache, pos, layer, q, k, live)
     batch, _, length, hd = q.shape
     cache = {name: jax.lax.dynamic_update_slice(cache[name], value,
                                                 (0, 0, pos, 0))
@@ -1132,7 +1168,7 @@ def _attend_cache(config: TransformerConfig, cache: dict, pos, layer,
         # blockwise, grouped K/V as they are, no (length x max_len)
         # scores in HBM.  Blockwise softmax rounds differently: logits
         # agree to tolerance, not bitwise
-        return flash_attention(q, k, v, causal=True), cache
+        return flash_attention(q, k, v, causal=True, live=live), cache
     k_eff, v_eff = cache["k"], cache["v"]
     if "k_scale" in cache:
         # dequantize into the einsum operand load (int8 codes x
@@ -1609,18 +1645,23 @@ def _hidden(params: dict, config: TransformerConfig, tokens, cache, pos,
     rows matter, the rest being padding: a call into a cache from the
     static position 0 whose length _row_tiles_take takes then runs every
     layer's row-wise work over the live row tiles alone (_decoder_layer),
-    and h, the outputs and the cache hold zeros past them."""
+    and h, the outputs and the cache hold zeros past them; and, whatever
+    _row_tiles_take says, an attention through the flash kernel is told
+    the length and runs the query blocks that hold a live row
+    (prefill_attention_rows)."""
     if remat_policy not in (None, "none") and cache is not None:
         raise ValueError(
             "remat_policy is only meaningful on the cache-less "
             "(training/scoring) path; incremental decode saves nothing "
             "by rematerializing")
     length = tokens.shape[1]
-    live = None
-    if (true_len is not None and cache is not None
-            and isinstance(pos, (int, np.integer)) and pos == 0
-            and _row_tiles_take(config, length)):
-        live = true_len
+    # a whole prefill's true length, for the attention (which takes it
+    # where it is the flash kernel's, _attend_cache) and for the row-wise
+    # work (where the bucket runs by row tiles)
+    whole = (true_len is not None and cache is not None
+             and isinstance(pos, (int, np.integer)) and pos == 0)
+    attended = true_len if whole else None
+    live = true_len if whole and _row_tiles_take(config, length) else None
     if activation_specs:
         # batch on "data", sequence on "seq" -- but only the axes the
         # ambient mesh actually has (an EP-only mesh has no "seq")
@@ -1645,7 +1686,8 @@ def _hidden(params: dict, config: TransformerConfig, tokens, cache, pos,
             h, stats, new_cache = _decoder_layer(
                 config, layer, h, cos, sin,
                 partial(_attend_fresh, config) if layer_cache is None
-                else partial(_attend_cache, config, layer_cache, pos), live)
+                else partial(_attend_cache, config, layer_cache, pos,
+                             live=attended), live)
         stats_sum = stats_sum + stats
         if activation_specs:
             h = jax.lax.with_sharding_constraint(h, act_spec)

@@ -88,15 +88,22 @@
 #   engine.prefill     scoped   one prefill call + its readback, inside
 #                               engine.step: bucket, true_len, queue_us,
 #                               attention (flash | einsum: what the
-#                               bucket's attention takes) and rows (the
+#                               bucket's attention takes), rows (the
 #                               rows the program runs of the bucket's:
 #                               true_len rounded up to a row tile where
 #                               the bucket runs by row tiles, as
-#                               models.prefill_rows says; running sums
-#                               prefill_rows_run, prefill_rows_bucket; a
+#                               models.prefill_rows says) and attn_rows
+#                               (the query rows its attention runs:
+#                               true_len rounded up to the flash kernel's
+#                               query block where the kernel is told the
+#                               length, else the bucket, as
+#                               models.prefill_attention_rows says;
+#                               running sums prefill_rows_run,
+#                               prefill_rows_bucket, prefill_attn_rows; a
 #                               prefill engine's whole prefill carries
-#                               bucket, true_len and rows); a chunk call
-#                               instead: live_blocks, table_blocks;
+#                               bucket, true_len, rows and attn_rows); a
+#                               chunk call instead: live_blocks,
+#                               table_blocks;
 #                               of a looped stack also ut_passes and
 #                               cache_rows (as on engine.decode; here the
 #                               rows the call leaves behind, true_len or

@@ -33,6 +33,7 @@ from .mesh import create_mesh  # noqa: F401  (re-exported convenience)
 
 __all__ = [
     "attention_reference", "flash_attention", "flash_attention_takes",
+    "flash_query_block",
     "paged_attention", "paged_attention_reference", "paged_attention_takes",
     "paged_attention_writes", "paged_live_blocks", "ring_attention",
     "sp_decode_attention", "ulysses_attention",
@@ -88,6 +89,32 @@ _STAT_LANES = 128  # min f32 lane width for the m/l scratch tiles
 # are held to what four heads of 128 lanes bring, so q, the output and the
 # float32 accumulator stay inside the VMEM asked for whatever the grouping.
 _FLASH_BLOCK = 1024
+# Query rows of a tile of a call told a live length (a whole prefill:
+# flash_attention's `live`), the grain its dead rows are skipped by, where
+# a K/V tile serves a group of query heads.  Measured on the v5e (PR 40),
+# {512, 1024} query rows x 1024 keys, the kernel alone in ms a layer at
+# live lengths 2032 / 3056 / 3568 / 4080 of 4096, then the whole
+# 4096-bucket paged_prefill of mistral7b_l16 in ms at true_len 2304 /
+# 2816 / 3328 / 3840 / 4064 (host clock, median of 5; not told: 129.8 /
+# 149.9 / 170.0 / 190.5 / 190.1):
+#   32 q / 8 kv heads x 128 (not told 1.392):
+#     512: 0.606 / 0.996 / 1.221 / 1.440; 119.1 / 142.0 / 165.8 / 189.7 / 189.7
+#    1024: 0.559 / 0.940 / 1.394 / 1.395; 121.8 / 142.2 / 169.9 / 189.9 / 190.1
+#   128 heads, each its own K/V, 256 to score and 128 to carry, live 4080 /
+#   5616 / 7152 / 8176 of 8192 (not told 25.94):
+#     512:  15.32 / 21.55 / 27.21 / 31.03;  1024: 11.00 / 18.19 / 22.33 / 26.55
+# A step of 512 query rows costs a grouped call 3-6 % more than its half
+# of a 1024-row step (four heads share the K/V tile it waits for) and
+# skips by half the grain: never slower in the program, 3-4 ms faster
+# for half the lengths.  Where every head has K/V of its own it is twice
+# the grid steps (0.35 us each, 128 heads x 16 x 8) and twice the K/V
+# reads for the same scores: 15-40 % slower, so those calls keep
+# _FLASH_BLOCK.  (20 heads to one K/V head: 128 rows, what the VMEM
+# leaves.)  A call that is told and skips nothing pays the operand:
+# 26.55 against 25.94, 1.395 against 1.392 (27.04 while the index maps
+# still divided the length by the block, as they did when the 128-head
+# 512 row was read).
+_FLASH_LIVE_BLOCK = 512
 _FLASH_GROUP_ELEMENTS = 4 * _FLASH_BLOCK * 128
 _FLASH_VMEM_BYTES = 48 << 20
 # the backward kernels hold four float32 score-sized tiles a step
@@ -107,14 +134,17 @@ _FLASH_MIN_SCORE_BYTES = 64 << 20
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                   causal: bool, sm_scale: float, kv_len: int, q_offset: int,
-                  with_lse: bool):
+                  with_lse: bool, last=None):
     """One (batch*kv_head, q_block, k_block) grid step of the
     online-softmax recurrence.  K/V stream through VMEM one block per
     step (HBM->VMEM via the grid pipeline -- whole-sequence K/V never
     resides on chip) and serve all `repeats` query heads of their group
     from that one read; m/l/acc scratch persists across the sequential k
     dimension.  The dots take q, k, v (and p, cast to v's dtype) as they
-    come -- bf16 in, bf16 on the MXU -- and accumulate in float32."""
+    come -- bf16 in, bf16 on the MXU -- and accumulate in float32.
+    `last` (a traced scalar, _flash_live_kernel): the last query block
+    that holds a live row; a block past it takes no step, and finishes
+    as the zeros it was initialised to."""
     if with_lse:
         lse_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -141,6 +171,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
     if causal:  # skip blocks entirely above the causal diagonal
         needed = jnp.logical_and(needed, k_base <= q_base + block_q - 1)
         inside = jnp.logical_and(inside, k_base + block_k - 1 <= q_base)
+    if last is not None:
+        needed = jnp.logical_and(needed, qi <= last)
 
     def update(masked: bool):
         k_blk = k_ref[0]                               # (block_k, d)
@@ -186,6 +218,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                 jnp.maximum(l_ref[...], 1e-30))
 
 
+def _flash_live_kernel(last_ref, *refs, **static):
+    """_flash_kernel told its sequence's last live query block, the one
+    scalar-prefetch operand: (groups,) int32."""
+    _flash_kernel(*refs, last=last_ref[pl.program_id(0)], **static)
+
+
 def _pad_seq(x, block: int):
     length = x.shape[2]
     padded = ((length + block - 1) // block) * block
@@ -222,9 +260,22 @@ def flash_attention_takes(batch: int, heads: int, length: int, dtype,
             and batch * heads * length * length * 4 > _FLASH_MIN_SCORE_BYTES)
 
 
+def flash_query_block(heads: int, kv_heads: int, head_dim: int,
+                      length: int, live: bool = False) -> int:
+    """The query rows of a tile of flash_attention over `length` rows of
+    `heads` query heads to `kv_heads` K/V heads: _FLASH_BLOCK's, held to
+    what the group's rows leave of the VMEM; of a call told a live length
+    whose K/V tiles serve a group of heads _FLASH_LIVE_BLOCK's, the grain
+    its dead rows are skipped by."""
+    repeats = heads // kv_heads
+    largest = _FLASH_LIVE_BLOCK if live and repeats > 1 else _FLASH_BLOCK
+    return _flash_block(length, max(128, min(
+        largest, _FLASH_GROUP_ELEMENTS // (repeats * head_dim) // 128 * 128)))
+
+
 def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
                     block_q: int | None = None, block_k: int | None = None,
-                    q_offset: int = 0):
+                    q_offset: int = 0, live=None):
     """Blockwise attention: q (B, H, L, D), k (B, Hkv, Lk, D), v (B, Hkv,
     Lk, Dv) with H a multiple of Hkv -- KV head g serves query heads
     g*H/Hkv onward, as repeat_kv lays them out, from one read of its
@@ -238,6 +289,19 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
     block_q/block_k default to tiles sized for the chip from the
     lengths, head_dim and the grouping (_flash_block).
 
+    `live` (a traced int32, a scalar or one a batch row) is what a whole
+    prefill knows of its right-padded bucket: only the first `live` rows
+    of the output are read.  Under causality those rows attend no key at
+    or past `live`, so a query block that starts at or past it takes no
+    step and fetches nothing, and its output is zeros (a row past `live`
+    in the last live block is computed as it always was; a `live` of 0 is
+    taken as 1).  One program
+    serves every `live`: it rides the call as a scalar-prefetch operand,
+    the grid is the bucket's.  Taken for that call only -- causal,
+    q_offset 0, as many keys as queries, forward -- and anything else
+    with a `live` raises; without one the call traces what it traced
+    before there was one.
+
     Differentiable end-to-end in Pallas: the forward kernel saves the
     per-row logsumexp, and the backward pass runs two blockwise kernels
     (dq; dk/dv) that recompute p inside VMEM -- backward peak memory is
@@ -249,34 +313,47 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
         raise ValueError(
             f"flash_attention: {heads} query heads are not a multiple of "
             f"{kv_heads} KV heads")
+    if live is not None and not (causal and q_offset == 0
+                                 and q_len == kv_len):
+        raise ValueError(
+            "flash_attention: a live length is a whole causal prefill's "
+            f"(q_offset 0, as many keys as queries), not causal={causal}, "
+            f"q_offset={q_offset}, {q_len} queries over {kv_len} keys")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)
     if block_q is None:
-        group = (heads // kv_heads) * head_dim
-        block_q = _flash_block(q_len, max(
-            128, min(_FLASH_BLOCK, _FLASH_GROUP_ELEMENTS // group // 128
-                     * 128)))
+        block_q = flash_query_block(heads, kv_heads, head_dim, q_len,
+                                    live is not None)
     if block_k is None:
         block_k = _flash_block(kv_len, _FLASH_BLOCK)
     # an explicit block longer than its axis is one block of the axis
     block_q = min(block_q, max(q_len, 1))
     block_k = min(block_k, max(kv_len, 1))
 
-    def attend(q, k, v):
+    def attend(q, k, v, *live):
+        if live:
+            return _flash_live(q, k, v, *live, float(sm_scale),
+                               int(block_q), int(block_k))
         return _flash(q, k, v, bool(causal), float(sm_scale), int(block_q),
                       int(block_k), int(q_offset))
 
+    operands = (q, k, v)
+    if live is not None:
+        operands += (jnp.broadcast_to(
+            jnp.asarray(live, jnp.int32), (batch,)),)
     spec = _ambient_mesh_spec(batch, kv_heads)
     if spec is None:
-        return attend(q, k, v)
+        return attend(*operands)
     # a Mosaic kernel cannot be partitioned automatically (on the chip
     # the lowering raises "wrap the call in a shard_map"; the CPU
     # interpreter never noticed).  Attention is independent across batch
     # rows and KV groups, so under an ambient mesh every shard runs the
     # kernel on its own rows and groups (a group's query heads are
     # contiguous, so a split of the KV heads splits the query heads alike)
-    return jax.shard_map(attend, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+    # and is told its own rows' live lengths
+    return jax.shard_map(
+        attend, in_specs=(spec, spec, spec, P(spec[0]))[:len(operands)],
+        out_specs=spec, check_vma=False)(*operands)
 
 
 def _ambient_mesh_spec(batch: int, heads: int):
@@ -344,16 +421,34 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, residuals,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_jvp, nondiff_argnums=(4, 5, 6))
+def _flash_live(q, k, v, live, sm_scale, block_q, block_k):
+    """The whole causal prefill told its live lengths: forward only."""
+    return _flash_impl(q, k, v, True, sm_scale, block_q, block_k, 0,
+                       with_lse=False, live=live)
+
+
+@_flash_live.defjvp
+def _flash_live_jvp(sm_scale, block_q, block_k, primals, tangents):
+    raise ValueError(
+        "flash_attention: a call with a live length is a prefill's "
+        "forward and has no derivative: its dead rows' output is zeros, "
+        "not attention")
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "sm_scale", "block_q", "block_k", "q_offset",
                      "with_lse"))
 def _flash_impl(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
-                with_lse: bool = True):
+                with_lse: bool = True, live=None):
     """The forward kernel's call: (out, lse) or, with_lse=False, out
     alone -- the (B, H, L) logsumexp is the backward's residual (and the
     ring's merge weight); a forward that nothing differentiates writes
-    none."""
+    none.  `live` (B,) int32: flash_attention's, from which the call's
+    one scalar-prefetch operand, the last query block with a live row
+    for every group; the operand, its grid spec and the index maps'
+    terms exist only in the trace that was given one."""
     batch, heads, q_len, head_dim = q.shape
     kv_heads, kv_len = k.shape[1], k.shape[2]
     value_dim = v.shape[3]
@@ -372,16 +467,31 @@ def _flash_impl(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
     grid = (groups, padded_q_len // block_q, k_padded.shape[1] // block_k)
     offset = int(q_offset) + (kv_len - q_len if causal else 0)
 
-    def kv_index(g, qi, ki):
+    # index maps take the grid's indices and then the scalar-prefetch
+    # operands: none, or the groups' last live query blocks
+    def fed(g, qi, told):
+        """The query block whose q, k and v the step (g, qi) is fed: its
+        own, but past the last live block that one again, so a dead
+        block's steps fetch nothing."""
+        return jnp.minimum(qi, told[0][g]) if told else qi
+
+    def q_index(g, qi, ki, *told):
+        return (g, 0, fed(g, qi, told), 0)
+
+    def kv_index(g, qi, ki, *told):
         if causal:
             # a step above the diagonal is skipped: naming the last block
             # that was needed again, it fetches nothing either
             ki = jnp.minimum(
-                ki, (qi * block_q + offset + block_q - 1) // block_k)
+                ki, (fed(g, qi, told) * block_q + offset + block_q - 1)
+                // block_k)
         return (g, ki, 0)
 
-    q_spec = pl.BlockSpec((1, repeats, block_q, head_dim),
-                          lambda g, qi, ki: (g, 0, qi, 0),
+    def out_index(g, qi, ki, *told):
+        # every block's own: a dead block's zeros land in its own rows
+        return (g, 0, qi, 0)
+
+    q_spec = pl.BlockSpec((1, repeats, block_q, head_dim), q_index,
                           memory_space=pltpu.VMEM)
     kv_spec = pl.BlockSpec((1, block_k, head_dim), kv_index,
                            memory_space=pltpu.VMEM)
@@ -389,30 +499,38 @@ def _flash_impl(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
     # under latent attention (192 to score, 128 to carry)
     v_spec = pl.BlockSpec((1, block_k, value_dim), kv_index,
                           memory_space=pltpu.VMEM)
-    o_spec = pl.BlockSpec((1, repeats, block_q, value_dim),
-                          lambda g, qi, ki: (g, 0, qi, 0),
+    o_spec = pl.BlockSpec((1, repeats, block_q, value_dim), out_index,
                           memory_space=pltpu.VMEM)
-    stat_spec = pl.BlockSpec((1, repeats, block_q, _STAT_LANES),
-                             lambda g, qi, ki: (g, 0, qi, 0),
+    stat_spec = pl.BlockSpec((1, repeats, block_q, _STAT_LANES), out_index,
                              memory_space=pltpu.VMEM)
     stat_shape = (groups, repeats, padded_q_len, _STAT_LANES)
-    kernel = functools.partial(
-        _flash_kernel, causal=causal, sm_scale=float(sm_scale),
-        kv_len=kv_len, q_offset=offset, with_lse=with_lse)
-    results = pl.pallas_call(
-        kernel,
+    static = dict(causal=causal, sm_scale=float(sm_scale), kv_len=kv_len,
+                  q_offset=offset, with_lse=with_lse)
+    specs = dict(
         grid=grid,
         in_specs=[q_spec, kv_spec, v_spec],
         out_specs=[o_spec, stat_spec][:1 + with_lse],
-        out_shape=[jax.ShapeDtypeStruct(
-            q_padded.shape[:3] + (value_dim,), q.dtype),
-                   jax.ShapeDtypeStruct(stat_shape, jnp.float32)
-                   ][:1 + with_lse],
         scratch_shapes=[
             pltpu.VMEM((repeats, block_q, _STAT_LANES), jnp.float32),  # m
             pltpu.VMEM((repeats, block_q, _STAT_LANES), jnp.float32),  # l
             pltpu.VMEM((repeats, block_q, value_dim), jnp.float32),  # acc
-        ],
+        ])
+    if live is None:
+        kernel, told = functools.partial(_flash_kernel, **static), ()
+    else:
+        kernel = functools.partial(_flash_live_kernel, **static)
+        # worked out here, once, not by every grid step's index maps (a
+        # length of 0 is taken as 1: block 0 runs)
+        told = (jnp.repeat(jnp.maximum(live - 1, 0) // block_q, kv_heads),)
+        specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **specs))
+    results = pl.pallas_call(
+        kernel,
+        **specs,
+        out_shape=[jax.ShapeDtypeStruct(
+            q_padded.shape[:3] + (value_dim,), q.dtype),
+                   jax.ShapeDtypeStruct(stat_shape, jnp.float32)
+                   ][:1 + with_lse],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_FLASH_VMEM_BYTES),
@@ -420,7 +538,7 @@ def _flash_impl(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
         # plain one stays what it compiled to
         name="mla_flash_attention" if value_dim != head_dim else None,
         interpret=_interpret(),
-    )(q_padded, k_padded, v_padded)
+    )(*told, q_padded, k_padded, v_padded)
     out = results[0].reshape(batch, heads, padded_q_len,
                              value_dim)[:, :, :q_len]
     if not with_lse:

@@ -12,7 +12,8 @@ random weights from fixed seeds), and checks what comes out:
   train     make_train_step on the 8-layer llama32_1b cut, three steps
   kernels   flash_attention against an f32 reference at the probe
             lengths and in the served prefill's form (K/V read grouped,
-            head_dim 128, the long-L tiles), forward and backward,
+            head_dim 128, the long-L tiles), forward and backward, and
+            told a live length (dead query blocks exactly zero),
             paged_attention against its einsum oracle, and the Mosaic
             custom call in the compiled train step and prefill
   pipeline  the headline 3-stage graph (speech -> LM, vision ->
@@ -63,8 +64,8 @@ from aiko_services_tpu.models.configs import LLAMA32_1B, LM_TOY
 from aiko_services_tpu.parallel import (
     create_mesh, filter_specs, shard_pytree)
 from aiko_services_tpu.parallel.attention import (
-    attention_reference, flash_attention, paged_attention,
-    paged_attention_reference, paged_attention_takes,
+    attention_reference, flash_attention, flash_query_block,
+    paged_attention, paged_attention_reference, paged_attention_takes,
     paged_attention_writes)
 from aiko_services_tpu.pipeline import create_pipeline
 from aiko_services_tpu.runtime import (
@@ -605,16 +606,43 @@ def _reference_attention(q, k, v, causal: bool):
             causal=causal)
 
 
-def _flash_probe(q, k, v, causal: bool, label: str) -> float:
-    """Forward against the reference; the largest absolute error."""
-    expected = np.asarray(_reference_attention(q, k, v, causal))
-    got = np.asarray(flash_attention(q, k, v, causal=causal), np.float32)
+def _require_close(got, expected, label: str) -> float:
+    """`got` finite and within the flash tolerances of `expected`; the
+    largest absolute error."""
     _require(np.all(np.isfinite(got)), f"{label}: non-finite output")
     error = float(np.abs(got - expected).max())
     excess = np.abs(got - expected) - FLASH_RTOL * np.abs(expected)
     _require(float(excess.max()) <= FLASH_ATOL,
              f"{label}: off the reference by {error:.3g}")
     return error
+
+
+def _flash_probe(q, k, v, causal: bool, label: str) -> float:
+    """Forward against the reference; the largest absolute error."""
+    expected = np.asarray(_reference_attention(q, k, v, causal))
+    got = np.asarray(flash_attention(q, k, v, causal=causal), np.float32)
+    return _require_close(got, expected, label)
+
+
+def _flash_live_probe(q, k, v, lives, label: str) -> float:
+    """The causal forward told a whole prefill's live length (traced:
+    one program for every length): rows below it against the
+    reference, every row from the first dead query block on exactly
+    zero; the largest absolute error."""
+    expected = np.asarray(_reference_attention(q, k, v, True))
+    block = flash_query_block(q.shape[1], k.shape[1], q.shape[3],
+                              q.shape[2], live=True)
+    attend = jax.jit(partial(flash_attention, causal=True))
+    worst = 0.0
+    for live in lives:
+        got = np.asarray(attend(q, k, v, live=jnp.int32(live)), np.float32)
+        _require(not got[:, :, -(-live // block) * block:].any(),
+                 f"{label} live={live}: a dead query block is not zeros")
+        worst = max(worst, _require_close(
+            got[:, :, :live], expected[:, :, :live],
+            f"{label} live={live}"))
+    _require(attend._cache_size() == 1, f"{label}: a program a length")
+    return worst
 
 
 def _flash_grad_probe(q, k, v, cotangent, label: str) -> float:
@@ -678,6 +706,11 @@ def phase_kernels(sizes: Sizes, report: Report, platform: str) -> None:
                                  f"grouped flash_attention L={length}")
     grad_error = max(grad_error, _flash_grad_probe(
         q, k, v, cotangent, f"grouped flash backward L={length}"))
+    # and told a live length, as a whole prefill tells it: in the first
+    # query block, in a middle one, the whole length
+    live_error = _flash_live_probe(
+        q, k, v, (1, length // 2 + 3, length),
+        f"grouped flash_attention L={length} told a live length")
 
     # the paged-attention kernel of the served decode step against its
     # einsum oracle: 8 slots on a pool of 32-position blocks, cursors on
@@ -747,6 +780,7 @@ def phase_kernels(sizes: Sizes, report: Report, platform: str) -> None:
         lengths=list(PROBE_LENGTHS), dtype=KERNEL_DTYPE,
         max_abs_err=max(worst.values()), tol=FLASH_ATOL,
         grouped_length=length, grouped_max_abs_err=grouped_error,
+        live_max_abs_err=live_error,
         grad_err=grad_error, grad_tol=FLASH_GRAD_TOL,
         paged_max_abs_err=paged_error,
         prefill_mosaic_custom_call=mosaic)

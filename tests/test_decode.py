@@ -159,6 +159,39 @@ def test_engine_counts_the_attention_each_whole_prefill_takes(
             done[index].tokens, reference(params, grouped, prompt, 4))
 
 
+def _whole_prefills(engine_kind: str, params, config, prompts,
+                    max_new: int = 4):
+    """Serve `prompts` through a decode engine or a prefill engine, their
+    tokens (the prefill engine's first tokens) the closed batch's;
+    returns (the engine, the fields of its `engine.prefill` spans in
+    order)."""
+    from aiko_services_tpu.decode import PrefillEngine
+    recorded = _Order()      # the spans seam, defined below
+    if engine_kind == "decode":
+        engine = DecodeEngine(params, config, decode_slots=len(prompts),
+                              kv_block_size=8, spans=recorded)
+        for index, prompt in enumerate(prompts):
+            engine.submit(index, prompt, max_new)
+        done = drain(engine)
+        for index, prompt in enumerate(prompts):
+            np.testing.assert_array_equal(
+                done[index].tokens,
+                reference(params, config, prompt, max_new))
+    else:
+        engine = PrefillEngine(params, config, kv_block_size=8,
+                               spans=recorded)
+        for index, prompt in enumerate(prompts):
+            engine.submit(index, prompt, max_new)
+        firsts = {}
+        while engine.has_work():
+            for handoff in engine.step():
+                firsts[handoff["request_id"]] = handoff["first_token"]
+        for index, prompt in enumerate(prompts):
+            assert firsts[index] == reference(params, config, prompt, 1)[0]
+    return engine, [fields for _, fields in
+                    recorded.named("engine.prefill")]
+
+
 @pytest.mark.parametrize("engine_kind", ["decode", "prefill"])
 @pytest.mark.parametrize("tile", [None, 8])
 def test_engine_says_the_rows_each_whole_prefill_runs(
@@ -170,7 +203,6 @@ def test_engine_says_the_rows_each_whole_prefill_runs(
     real tile these buckets are under two tiles and run whole; with the
     tile cut to 8 rows a 32-row bucket runs 24 rows for a prompt of 20 --
     and the tokens are the closed batch's either way."""
-    from aiko_services_tpu.decode import PrefillEngine
     from aiko_services_tpu.decode import disagg, engine as engine_module
     from aiko_services_tpu.models import prefill_rows, transformer
     assert "_ROW_TILE" not in vars(engine_module)
@@ -181,34 +213,12 @@ def test_engine_says_the_rows_each_whole_prefill_runs(
         jax.clear_caches()
     lengths = (5, 20, 30)
     prompts = [np.arange(1, n + 1, dtype=np.int32) for n in lengths]
-    recorded = _Order()      # the spans seam, defined below
     try:
-        if engine_kind == "decode":
-            engine = DecodeEngine(params, config, decode_slots=3,
-                                  kv_block_size=8, spans=recorded)
-            for index, prompt in enumerate(prompts):
-                engine.submit(index, prompt, 4)
-            done = drain(engine)
-            for index, prompt in enumerate(prompts):
-                np.testing.assert_array_equal(
-                    done[index].tokens,
-                    reference(params, config, prompt, 4))
-        else:
-            engine = PrefillEngine(params, config, kv_block_size=8,
-                                   spans=recorded)
-            for index, prompt in enumerate(prompts):
-                engine.submit(index, prompt, 4)
-            firsts = {}
-            while engine.has_work():
-                for handoff in engine.step():
-                    firsts[handoff["request_id"]] = handoff["first_token"]
-            for index, prompt in enumerate(prompts):
-                assert firsts[index] == reference(params, config, prompt,
-                                                  1)[0]
+        engine, prefills = _whole_prefills(engine_kind, params, config,
+                                           prompts)
     finally:
         if tile is not None:
             jax.clear_caches()
-    prefills = [fields for _, fields in recorded.named("engine.prefill")]
     assert [args["true_len"] for args in prefills] == list(lengths)
     assert [args["bucket"] for args in prefills] == [8, 32, 32]
     assert [args["rows"] for args in prefills] == (
@@ -220,6 +230,44 @@ def test_engine_says_the_rows_each_whole_prefill_runs(
     assert stats["prefill_rows_bucket"] == 72
     assert stats["prefill_rows_run"] == sum(
         args["rows"] for args in prefills)
+
+
+@pytest.mark.parametrize("engine_kind", ["decode", "prefill"])
+def test_engine_says_the_rows_each_whole_prefills_attention_runs(
+        monkeypatch, engine_kind):
+    """PR 40: every whole `engine.prefill` span of both engines carries
+    `attn_rows` beside `rows`, what models.prefill_attention_rows says of
+    its bucket and length (the engines hold no predicate of their own),
+    never over the bucket, and stats() sums them.  The flash kernel is
+    steered to toy sizes (its threshold; query blocks of 128 for a call
+    told a live length): a 512-row bucket attends 384 rows for a prompt
+    of 300, and the tokens are the closed batch's."""
+    from aiko_services_tpu.decode import disagg, engine as engine_module
+    from aiko_services_tpu.models import prefill_attention_rows
+    from aiko_services_tpu.parallel import attention
+    assert "flash_query_block" not in vars(engine_module)
+    assert "flash_query_block" not in vars(disagg)
+    monkeypatch.setattr(attention, "_FLASH_MIN_SCORE_BYTES", 0)
+    monkeypatch.setattr(attention, "_FLASH_LIVE_BLOCK", 128)
+    jax.clear_caches()
+    config = TransformerConfig(**{**TINY, "n_heads": 4, "n_kv_heads": 2,
+                                  "max_seq_len": 520})
+    params = init_params(config, jax.random.PRNGKey(2))
+    lengths = (5, 100, 300)
+    prompts = [1 + np.arange(n, dtype=np.int32) % 60 for n in lengths]
+    try:
+        engine, prefills = _whole_prefills(engine_kind, params, config,
+                                           prompts, max_new=3)
+        assert [args["bucket"] for args in prefills] == [8, 128, 512]
+        assert [args["attn_rows"] for args in prefills] == [8, 128, 384]
+        for args in prefills:
+            assert args["attn_rows"] == prefill_attention_rows(
+                config, args["bucket"], args["true_len"]) <= args["bucket"]
+        stats = engine.stats()
+        assert stats["prefill_attn_rows"] == 8 + 128 + 384
+        assert stats["prefill_rows_bucket"] == 8 + 128 + 512
+    finally:
+        jax.clear_caches()
 
 
 @pytest.mark.parametrize("case", ["plain", "chunked", "int8"])

@@ -31,6 +31,8 @@ from aiko_services_tpu.models.transformer import (
     param_specs, quantize_weights_int8)
 from aiko_services_tpu.parallel import ssm
 from benchmark.reference import jamba as reference
+from test_prefill_rows import (                         # noqa: F401
+    LIVE_BUCKET, check_live_attention_prefill, live_attention)
 
 PUBLISHED = {
     "model_type": "jamba", "vocab_size": 256, "hidden_size": 64,
@@ -383,6 +385,25 @@ def test_engine_serves_the_reference_through_the_scan_kernel(
         assert_served_is_the_references(shape, prompt, done["r"].tokens)
     finally:
         jax.clear_caches()
+
+
+def test_the_attention_layers_are_told_the_prompts_length(
+        model, live_attention, monkeypatch):
+    """PR 40: the hybrid's row tiles are refused, its two attention
+    layers' condition is the kernel's own -- a whole prefill whose bucket
+    attends through the flash kernel tells it the true length, and
+    first token, logits, K/V rows below true_len and the slot's state are
+    the whole bucket's attention's (tests/test_prefill_rows.py's check:
+    `wo` and the Mamba layers after it see zeros in the dead blocks'
+    rows, which nothing below true_len reads)."""
+    config, params, _ = model
+    config = dataclasses.replace(config, max_seq_len=LIVE_BUCKET)
+    assert not transformer._row_tiles_take(config, 2048)
+    check_live_attention_prefill(
+        config, params, monkeypatch,
+        lambda: {**init_paged_pool(config, LIVE_BUCKET // 32 + 1, 32),
+                 **transformer.init_recurrent_state(config, 2)},
+        slot=np.int32(1))
 
 
 # -- (d) what is refused by name ------------------------------------------------

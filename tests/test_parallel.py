@@ -506,3 +506,159 @@ class TestFlashMultiBlock:
         actual = np.asarray(flash_attention(q, k, v, causal=True))
         expected = np.asarray(self._naive(q, k, v, True))
         np.testing.assert_allclose(actual, expected, atol=2e-3, rtol=2e-3)
+
+
+# -- a whole prefill's live length (PR 40) -----------------------------------
+
+# the three shapes the cells run, at a length the interpreter affords:
+# (query heads, K/V heads, width to score over, width to carry)
+LIVE_SHAPES = {
+    "dense_32q_8kv": (32, 8, 128, 128),       # mistral7b_l16
+    "latent_128_heads": (128, 128, 256, 128),  # deepseek_v2_ep4_l5
+    "hybrid_20q_1kv": (20, 1, 128, 128),      # jamba2_3b
+}
+LIVE_LENGTH, LIVE_BLOCK = 64, 16
+_LIVE_RAN: dict = {}
+
+
+def pallas_calls(jaxpr):
+    """Every pallas_call equation of a jaxpr, those inside its loops,
+    branches and jitted calls among them."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, tuple) else (value,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from pallas_calls(inner)
+
+
+def _live_case(shape: str):
+    """(q, k, v, the jitted live call, the oracle's float32 output) of
+    `shape`, made and traced once."""
+    if shape not in _LIVE_RAN:
+        heads, kv_heads, width, value_width = LIVE_SHAPES[shape]
+        keys = jax.random.split(jax.random.PRNGKey(len(shape)), 3)
+        q = jax.random.normal(keys[0], (1, heads, LIVE_LENGTH, width))
+        k = jax.random.normal(keys[1], (1, kv_heads, LIVE_LENGTH, width))
+        v = jax.random.normal(keys[2],
+                              (1, kv_heads, LIVE_LENGTH, value_width))
+        repeats = heads // kv_heads
+        expected = np.asarray(attention_reference(
+            q, jnp.repeat(k, repeats, axis=1), jnp.repeat(v, repeats, axis=1),
+            causal=True))
+        call = jax.jit(lambda q, k, v, live: flash_attention(
+            q, k, v, causal=True, block_q=LIVE_BLOCK, block_k=2 * LIVE_BLOCK,
+            live=live))
+        _LIVE_RAN[shape] = q, k, v, call, expected
+    return _LIVE_RAN[shape]
+
+
+class TestFlashLive:
+    """flash_attention told a whole prefill's live length: the rows below
+    it are the oracle's, every query block from the first dead one on is
+    exactly zero (written, not left), and `live` is traced, so one
+    program serves every length."""
+
+    @pytest.mark.parametrize("live", [
+        1, LIVE_BLOCK - 1, LIVE_BLOCK, LIVE_BLOCK + 1, LIVE_LENGTH - 1,
+        LIVE_LENGTH])
+    @pytest.mark.parametrize("shape", sorted(LIVE_SHAPES))
+    def test_live_rows_are_the_oracles_and_dead_blocks_zero(self, shape,
+                                                            live):
+        q, k, v, call, expected = _live_case(shape)
+        actual = np.asarray(call(q, k, v, np.int32(live)))
+        assert actual.shape == expected.shape
+        np.testing.assert_allclose(actual[:, :, :live],
+                                   expected[:, :, :live], atol=2e-5, rtol=0)
+        dead = -(-live // LIVE_BLOCK) * LIVE_BLOCK
+        assert not actual[:, :, dead:].any()
+        assert call._cache_size() == 1
+
+    def test_a_live_length_a_batch_row(self):
+        q, k, v = _grouped_qkv(4, 64, jnp.float32, seed=3)     # batch 2
+        expected = np.asarray(_grouped_reference(q, k, v))
+        actual = np.asarray(flash_attention(
+            q, k, v, causal=True, block_q=16, block_k=16,
+            live=np.array([5, 40], np.int32)))
+        for row, (live, dead) in enumerate([(5, 16), (40, 48)]):
+            np.testing.assert_allclose(actual[row, :, :live],
+                                       expected[row, :, :live], atol=2e-5,
+                                       rtol=0)
+            assert not actual[row, :, dead:].any()
+
+    def test_under_an_ambient_mesh_a_shard_is_told_its_own_rows(self):
+        """The kernel runs in a shard_map under an ambient mesh (batch
+        over "data", K/V heads over "model"): the live lengths ride it by
+        batch row."""
+        q, k, v = _grouped_qkv(2, 64, jnp.float32, kv_heads=4, seed=4)
+        expected = np.asarray(_grouped_reference(q, k, v))
+        told = jax.jit(lambda q, k, v, live: flash_attention(
+            q, k, v, causal=True, block_q=16, block_k=16, live=live))
+        live = np.array([40, 5], np.int32)                   # batch 2
+        with jax.set_mesh(create_mesh({"data": 2, "model": 4})):
+            assert "shard_map" in str(jax.make_jaxpr(told)(q, k, v, live))
+            actual = np.asarray(told(q, k, v, live))
+        for row, (rows, dead) in enumerate([(40, 48), (5, 16)]):
+            np.testing.assert_allclose(actual[row, :, :rows],
+                                       expected[row, :, :rows], atol=2e-5,
+                                       rtol=0)
+            assert not actual[row, :, dead:].any()
+
+    @pytest.mark.parametrize("what", ["not_causal", "q_offset",
+                                      "more_keys", "grad"])
+    def test_what_is_no_whole_prefill_raises(self, what):
+        q, k, v = _qkv(seq=32)
+        live = jnp.int32(7)
+        with pytest.raises(ValueError, match="live length"):
+            if what == "not_causal":
+                flash_attention(q, k, v, live=live)
+            elif what == "q_offset":
+                flash_attention(q, k, v, causal=True, q_offset=4, live=live)
+            elif what == "more_keys":
+                flash_attention(q[:, :, :16], k, v, causal=True, live=live)
+            else:
+                jax.grad(lambda q: flash_attention(
+                    q, k, v, causal=True, live=live).sum())(q)
+
+    def test_without_a_live_length_the_call_lowers_to_what_it_did(self):
+        """The operand, its grid spec and the index maps' terms exist
+        only in the trace that was given a `live`: without one the call
+        has no scalar-prefetch operand and lowers to the text it lowered
+        to at PR 39 (whisper's attention, training, the ring's hops),
+        operation for operation; with one the call is another."""
+        import collections
+        import hashlib
+        import re
+        q = jax.ShapeDtypeStruct((1, 8, 256, 128), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, 2, 256, 128), jnp.bfloat16)
+
+        def plain(q, k, v):
+            return flash_attention(q, k, v, causal=True, block_q=64,
+                                   block_k=128)
+
+        def told(q, k, v, live):
+            return flash_attention(q, k, v, causal=True, block_q=64,
+                                   block_k=128, live=live)
+
+        live = jax.ShapeDtypeStruct((), jnp.int32)
+        (call,) = pallas_calls(jax.make_jaxpr(plain)(q, k, k).jaxpr)
+        assert call.params["grid_mapping"].num_index_operands == 0
+        assert len(call.invars) == 3
+        (call,) = pallas_calls(jax.make_jaxpr(told)(q, k, k, live).jaxpr)
+        assert call.params["grid_mapping"].num_index_operands == 1
+        assert len(call.invars) == 4
+
+        text = jax.jit(plain).lower(q, k, k).as_text()
+        operations = collections.Counter(re.findall(r"stablehlo\.\w+", text))
+        # read on the parent commit (c686836), the interpreted kernel
+        assert sum(operations.values()) == 2897
+        assert (operations["stablehlo.while"],
+                operations["stablehlo.dot_general"],
+                operations["stablehlo.minimum"],
+                operations["stablehlo.compare"]) == (1, 16, 2, 310)
+        assert hashlib.sha256(text.encode()).hexdigest() == (
+            "2366e65241395d1e7ec6605d0a0cbe3c"
+            "9cda40bb649e680bf64da90a662de4af")
+        assert jax.jit(told).lower(q, k, k, live).as_text() != text

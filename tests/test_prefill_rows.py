@@ -28,8 +28,10 @@ import pytest
 
 from aiko_services_tpu.models import (
     TransformerConfig, forward, init_cache, init_paged_pool, init_params,
-    paged_decode_step, paged_prefill, prefill_rows, transformer)
+    paged_decode_step, paged_prefill, prefill_attention_rows, prefill_rows,
+    transformer)
 from aiko_services_tpu.parallel import attention
+from test_parallel import pallas_calls
 
 TILE, BUCKET, BLOCK = 8, 32, 4
 LENGTHS = (1, TILE - 1, TILE, TILE + 1, BUCKET - 1, BUCKET)
@@ -276,3 +278,126 @@ def test_flash_attention_sees_zeros_in_the_dead_tiles(tile, monkeypatch):
             leaf[:, :, :TILE + 3], whole[name][:, :, :TILE + 3], atol=2e-5,
             rtol=1e-5, err_msg=name)
         assert not leaf[:, :, 2 * TILE:].any(), name
+
+
+# -- the attention does the work of its prompt too (PR 40) -----------------
+#
+# A whole prefill whose bucket attends through the flash kernel hands the
+# kernel its true length (flash_attention's `live`), whether or not the
+# bucket runs by row tiles: here it does not (256 rows are under two row
+# tiles), so the attention is all that differs from the parent's trace,
+# which is the same forward with no length handed in.  The kernel is
+# steered to toy sizes by its threshold and its query blocks (two blocks
+# of 128 in the 256-row bucket).  tests/test_jamba.py holds the
+# hybrid.
+
+LIVE_BUCKET, LIVE_QUERY_BLOCK = 256, 128
+LIVE_LENGTHS = (100, 200)               # in the first block, in the last
+
+
+@pytest.fixture
+def live_attention(monkeypatch):
+    monkeypatch.setattr(attention, "_FLASH_MIN_SCORE_BYTES", 0)
+    # the query block of a call told a live length: of grouped heads,
+    # and of heads with K/V of their own (the latent's)
+    monkeypatch.setattr(attention, "_FLASH_LIVE_BLOCK", LIVE_QUERY_BLOCK)
+    monkeypatch.setattr(attention, "_FLASH_BLOCK", LIVE_QUERY_BLOCK)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def attention_of_the_whole_bucket(monkeypatch):
+    """From here on the model step hands the kernel no length: the
+    parent's attention, through the same entry points."""
+    whole = attention.flash_attention
+    monkeypatch.setattr(
+        transformer, "flash_attention",
+        lambda *args, live=None, **keywords: whole(*args, **keywords))
+    jax.clear_caches()
+
+
+def check_live_attention_prefill(config, params, monkeypatch, pool_of,
+                                 **slot):
+    """paged_prefill and _hidden of a LIVE_BUCKET-row bucket at
+    LIVE_LENGTHS, the kernel told the length against the kernel over the
+    whole bucket: the first token, the logits at true_len - 1 and every
+    pool row below true_len."""
+    bucket, block = LIVE_BUCKET, 32
+    prompt = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(5), (1, bucket), 1, config.vocab_size), np.int32)
+    table = np.arange(1, bucket // block + 1, dtype=np.int32)
+
+    def ran(true_len):
+        pool, first = paged_prefill(params, config, pool_of(), prompt,
+                                    table, np.int32(true_len), **slot)
+        h, outputs, _, _ = transformer._hidden(
+            params, config, prompt, init_cache(config, 1, bucket), 0,
+            true_len=np.int32(true_len))
+        at = slice(true_len - 1, true_len)
+        logits, _ = transformer._logits(
+            params, config, h[:, at], [out[:, at] for out in outputs])
+        return pool, int(first), np.asarray(logits)
+
+    def flash_calls():
+        traced = jax.make_jaxpr(
+            lambda tokens, true_len: transformer._hidden(
+                params, config, tokens, init_cache(config, 1, bucket), 0,
+                true_len=true_len)[0])(prompt, np.int32(3))
+        return [call for call in pallas_calls(traced.jaxpr)
+                if call.params["name"] in (None, "mla_flash_attention")]
+
+    def told(call) -> bool:
+        return call.params["grid_mapping"].num_index_operands == 1
+
+    for true_len in LIVE_LENGTHS:
+        assert prefill_attention_rows(config, bucket, true_len) == (
+            -(-true_len // LIVE_QUERY_BLOCK) * LIVE_QUERY_BLOCK)
+    live = {true_len: ran(true_len) for true_len in LIVE_LENGTHS}
+    # what the trace took: every layer's flash call the length
+    assert flash_calls() and all(map(told, flash_calls()))
+    attention_of_the_whole_bucket(monkeypatch)
+    assert flash_calls() and not any(map(told, flash_calls()))
+    for true_len in LIVE_LENGTHS:
+        pool, first, logits = live[true_len]
+        whole_pool, whole_first, whole_logits = ran(true_len)
+        assert first == whole_first
+        np.testing.assert_allclose(logits, whole_logits, atol=2e-5, rtol=0)
+        for name, leaf in pool.items():
+            if name in ("conv", "ssm"):                 # a slot's state
+                np.testing.assert_allclose(leaf, whole_pool[name],
+                                           atol=2e-5, rtol=0, err_msg=name)
+                continue
+            # (caches, blocks, H, block, d): whole blocks below true_len
+            below = np.s_[:, table[:true_len // block]]
+            np.testing.assert_allclose(
+                np.asarray(leaf)[below], np.asarray(whole_pool[name])[below],
+                atol=2e-5, rtol=0, err_msg=name)
+
+
+@pytest.mark.parametrize("case", ["dense", "latent_routed"])
+def test_live_attention_prefill_is_the_whole_buckets_below_true_len(
+        live_attention, monkeypatch, case):
+    config, params = _model(case)
+    config = dataclasses.replace(config, max_seq_len=LIVE_BUCKET)
+    assert not transformer._row_tiles_take(config, LIVE_BUCKET)
+    check_live_attention_prefill(
+        config, params, monkeypatch,
+        lambda: init_paged_pool(config, LIVE_BUCKET // 32 + 1, 32))
+
+
+@pytest.mark.parametrize("what,rows", [
+    ("flash", 128), ("einsum", 256), ("int8_cache", 256),
+    ("sequence_parallel", 256)])
+def test_prefill_attention_rows_follow_what_the_attention_takes(
+        live_attention, monkeypatch, what, rows):
+    """The bucket where the kernel is not told the length: the einsum's
+    bucket, an int8 cache (attended over as quantised), a
+    sequence-parallel prefill (the ring's)."""
+    fields = {"int8_cache": {"kv_dtype": "int8"},
+              "sequence_parallel": {"sequence_parallel": True}}.get(what, {})
+    if what == "einsum":
+        monkeypatch.setattr(attention, "_FLASH_MIN_SCORE_BYTES", 64 << 20)
+    config = TransformerConfig(**{**DENSE, **fields})
+    assert prefill_attention_rows(config, LIVE_BUCKET, 100) == rows
+    assert prefill_attention_rows(config, LIVE_BUCKET, 129) == 256

@@ -543,7 +543,7 @@ SERVED_SPANS = {
     "engine.submit": REQUEST | {"waited_us"},
     "engine.step": {"waiting", "active", "decoding", "admitted"},
     "engine.prefill": REQUEST | {"bucket", "true_len", "queue_us",
-                                 "attention", "rows"},
+                                 "attention", "rows", "attn_rows"},
     "engine.decode": {"decoding", "ahead", "live_blocks", "table_blocks",
                       "write"},
     "engine.readback": set(),
@@ -639,8 +639,13 @@ class TestServedSpans:
         for args in prefills:
             assert args["rows"] == args["bucket"] == prefill_rows(
                 element.config, args["bucket"], args["true_len"])
+            # and `attn_rows` (PR 40), the rows its attention ran: the
+            # bucket too, the einsum's (tests/test_decode.py steers the
+            # flash kernel, which is told the length)
+            assert args["attn_rows"] == args["bucket"]
         stats = element.engine_stats()
         assert stats["prefill_rows_run"] == stats["prefill_rows_bucket"] \
+            == stats["prefill_attn_rows"] \
             == sum(args["bucket"] for args in prefills)
 
     def test_ingress_mark_reaches_back_to_the_gateways_dispatch(
