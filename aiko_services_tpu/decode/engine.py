@@ -100,18 +100,19 @@ SLOT_BOUNDS = (0, 1, 2, 4, 8, 16, 32, 64, 128)
 
 def refuse_recurrent(config, what: str) -> None:
     """Raise, naming the state, where `what` is asked of a model with a
-    recurrent state (Mamba layers).  A slot's state is one row-to-row
-    carry (a convolution tail and an SSM state a layer), advanced in
-    place and kept at no earlier position: what would have to snapshot
-    it at a block boundary, carry it into a chunk, roll it back or ship
-    it is not implemented (ROADMAP.md R3), and is refused instead of
-    attempted."""
+    recurrent state (layers of kind `config.recurrent_kind`).  A slot's
+    state is one row-to-row carry (a convolution tail and, a layer, a
+    Mamba layer's SSM state or a delta layer's matrix a head), advanced
+    in place and kept at no earlier position: what would have to
+    snapshot it at a block boundary, carry it into a chunk, roll it back
+    or ship it is not implemented (ROADMAP.md R3), and is refused instead
+    of attempted."""
     if getattr(config, "recurrent", False):
         raise ValueError(
             f"{what} is not implemented for a model with a recurrent "
-            f"state (Mamba layers: {config.n_states} states of "
-            f"{config.state_bytes // config.n_states} B a slot, kept at "
-            f"the slot's last position only)")
+            f"state ({config.recurrent_kind} layers: {config.n_states} "
+            f"states of {config.state_bytes // config.n_states} B a slot, "
+            f"kept at the slot's last position only)")
 
 
 @dataclass
@@ -253,7 +254,7 @@ class DecodeEngine:
             self.last_tokens = np.zeros((self.slots_n, 1), np.int32)
             interval.holds(self.pool)
         if config.recurrent:
-            # the fifth store: a Mamba layer's state a slot, addressed by
+            # the fifth store: a recurrent layer's state a slot, addressed by
             # slot and sized by decode_slots, not by positions; it rides
             # the pool's dict through the paged programs, donated and
             # returned with it.  Written whole by an admission's prefill,
@@ -1022,7 +1023,7 @@ class DecodeEngine:
             fields = {"attention": attention, "rows": rows,
                       "attn_rows": attn_rows}
             if self.config.recurrent:
-                # the rows a Mamba layer's scan runs, and through what,
+                # the rows a recurrent layer's scan runs, and through what,
                 # asked of the functions the model step decides by
                 scan = scan_kind(self.config, bucket)
                 fields.update(scan=scan, scan_rows=scan_rows(

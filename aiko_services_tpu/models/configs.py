@@ -19,7 +19,7 @@ __all__ = [
     "LLAMA3_8B", "LLAMA32_1B", "LM_TOY",
     "WHISPER_TINY", "WHISPER_SMALL",
     "YOLOV8N_SHAPE", "DETECTOR_TOY", "deepseek_v2_config", "ouro_config",
-    "jamba_config", "PUBLISHED_READERS",
+    "jamba_config", "qwen3_next_config", "PUBLISHED_READERS",
     "transformer_flops_per_token", "asr_flops_per_example",
     "tts_flops_per_example",
     "detector_flops_per_image",
@@ -183,10 +183,80 @@ def jamba_config(published: dict, max_seq_len: int | None = None,
         rotary=False)
 
 
+def qwen3_next_config(published: dict, max_seq_len: int | None = None,
+                      dtype: str | None = None) -> TransformerConfig:
+    """TransformerConfig from Qwen3-Next's published config.json keys
+    (huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct), every one under
+    its own name: Gated DeltaNet layers (`linear_*`), with a gated
+    softmax-attention layer of `head_dim` every `full_attention_interval`
+    layers (rotary over `partial_rotary_factor` of a head, q and k normed
+    a head); every layer's FFN `num_experts_per_tok` of the routed
+    experts, their weights renormalised, plus one shared expert behind a
+    gate.  `router_experts` and `experts_held` describe one process's
+    share, as deepseek_v2_config has them, `num_experts` being what is
+    held.  The head is tied to the embedding whatever
+    `tie_word_embeddings` says (seeded weights; the configuration file's
+    `assumed`).  Keys whose mechanism is not implemented are refused by
+    name."""
+    unsupported = {
+        "model_type": "qwen3_next", "hidden_act": "silu",
+        "sliding_window": None, "use_sliding_window": False,
+        "mlp_only_layers": [], "decoder_sparse_step": 1,
+        "rope_scaling": None, "norm_topk_prob": True,
+        "attention_bias": False}
+    for key, value in unsupported.items():
+        if published.get(key, value) != value:
+            raise ValueError(f"qwen3_next: {key}={published[key]!r} is not "
+                             f"implemented (only {value!r})")
+    router = int(published.get("router_experts", published["num_experts"]))
+    held = tuple(int(edge) for edge in
+                 published.get("experts_held", (0, router)))
+    if held[1] - held[0] != int(published["num_experts"]):
+        raise ValueError(
+            f"qwen3_next: experts_held {held} is not the "
+            f"{published['num_experts']} experts num_experts says are held")
+    expert_width = int(published["moe_intermediate_size"])
+    shared, odd = divmod(int(published["shared_expert_intermediate_size"]),
+                         expert_width)
+    if odd or shared < 1:
+        raise ValueError(
+            f"qwen3_next: shared_expert_intermediate_size="
+            f"{published['shared_expert_intermediate_size']} is not a "
+            f"multiple of moe_intermediate_size {expert_width} (the only "
+            f"shared width implemented)")
+    layers = int(published["num_hidden_layers"])
+    interval = int(published["full_attention_interval"])
+    return TransformerConfig(
+        vocab_size=int(published["vocab_size"]),
+        d_model=int(published["hidden_size"]), n_layers=layers,
+        n_heads=int(published["num_attention_heads"]),
+        n_kv_heads=int(published["num_key_value_heads"]),
+        d_ff=int(published["intermediate_size"]),
+        max_seq_len=int(max_seq_len
+                        or published["max_position_embeddings"]),
+        rope_theta=float(published["rope_theta"]),
+        norm_eps=float(published["rms_norm_eps"]),
+        dtype=str(dtype or published.get("torch_dtype", "bfloat16")),
+        top_k=int(published["num_experts_per_tok"]),
+        n_routed_experts=router, experts_held=held,
+        n_shared_experts=shared, moe_d_ff=expert_width,
+        norm_topk=True, shared_expert_gate=True,
+        layer_kinds=tuple("attention" if (index + 1) % interval == 0
+                          else "delta" for index in range(layers)),
+        attn_head_dim=int(published["head_dim"]),
+        rotary_fraction=float(published["partial_rotary_factor"]),
+        qk_norm=True, gated_attention=True,
+        delta_key_heads=int(published["linear_num_key_heads"]),
+        delta_value_heads=int(published["linear_num_value_heads"]),
+        delta_key_dim=int(published["linear_key_head_dim"]),
+        delta_value_dim=int(published["linear_value_head_dim"]),
+        delta_conv=int(published["linear_conv_kernel_dim"]))
+
+
 # model_type of a published config.json -> its reader (elements/ml.py
 # hands LMGenerate's `model` parameter to it whole)
 PUBLISHED_READERS = {"deepseek_v2": deepseek_v2_config, "ouro": ouro_config,
-                     "jamba": jamba_config}
+                     "jamba": jamba_config, "qwen3_next": qwen3_next_config}
 
 
 # small config for hermetic tests / CPU runs

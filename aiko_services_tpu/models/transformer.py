@@ -35,6 +35,9 @@ from ..parallel.attention import (
     paged_attention, paged_attention_reference, paged_attention_takes,
     paged_attention_writes,
     ring_attention, sp_decode_attention, ulysses_attention)
+from ..parallel.delta import (
+    delta_scan, delta_step,
+    delta_step_reference)
 from ..parallel.experts import expert_ffn
 from ..parallel.ssm import (
     ssm_scan, ssm_scan_rows, ssm_scan_takes, ssm_step)
@@ -55,6 +58,10 @@ __all__ = [
     "resolve_remat_policy", "init_recurrent_state", "scan_kind",
     "scan_rows",
 ]
+
+
+# the layer kinds that carry a recurrent state; a model has one of them
+_RECURRENT_KINDS = ("mamba", "delta")
 
 
 @dataclass(frozen=True)
@@ -152,15 +159,16 @@ class TransformerConfig:
     # a sublayer's OUTPUT is normed too, before the residual add:
     # h + norm(attention(norm(h))), h + norm(FFN(norm(h)))
     sandwich_norm: bool = False
-    # -- layer kinds (Jamba): layer_kinds non-empty ----------------------
-    # One entry a layer, "attention" or "mamba"; empty: every layer
-    # attends.  Like layers that follow one another are one stack and one
-    # scan (_layer_stacks).  An attention layer's K/V cache is numbered
-    # among the attention layers (n_caches), a Mamba layer's recurrent
-    # state among the Mamba layers (n_states): what a sequence carries
-    # from row to row there is the mixer's last ssm_d_conv - 1 inputs of
-    # its convolution and its SSM state, (ssm_d_state, ssm_d_inner)
-    # float32, whatever the context (init_recurrent_state).
+    # -- layer kinds (Jamba, Qwen3-Next): layer_kinds non-empty ----------
+    # One entry a layer, "attention" or the model's one recurrent kind,
+    # "mamba" or "delta"; empty: every layer attends.  Like layers that
+    # follow one another are one stack and one scan (_layer_stacks).  An
+    # attention layer's K/V cache is numbered among the attention layers
+    # (n_caches), a recurrent layer's state among the recurrent layers
+    # (n_states).  What a sequence carries from row to row in a Mamba
+    # layer is the mixer's last ssm_d_conv - 1 inputs of its convolution
+    # and its SSM state, (ssm_d_state, ssm_d_inner) float32, whatever the
+    # context (init_recurrent_state); a delta layer's is below.
     layer_kinds: tuple = ()
     ssm_d_inner: int = 0
     ssm_d_state: int = 0
@@ -169,6 +177,29 @@ class TransformerConfig:
     # False: attention with no positional encoding (the Mamba layers
     # carry position)
     rotary: bool = True
+    # -- gated attention beside Gated DeltaNet layers (Qwen3-Next) -------
+    # attn_head_dim > 0: the heads' size where it is not d_model /
+    # n_heads.  rotary_fraction: the share of a head's columns, from the
+    # first, that rotate.  qk_norm: q and k RMS-normed a head, with gains
+    # of their own, before they rotate.  gated_attention: wq is twice as
+    # wide, a head's second half a gate: out = attention * sigmoid(gate).
+    attn_head_dim: int = 0
+    rotary_fraction: float = 1.0
+    qk_norm: bool = False
+    gated_attention: bool = False
+    # routed experts: the chosen weights divided by their sum; the shared
+    # expert behind a gate of its own, sigmoid(x . w)
+    norm_topk: bool = False
+    shared_expert_gate: bool = False
+    # layer kind "delta": a Gated DeltaNet mixer (parallel/delta.py).
+    # What a sequence carries from row to row there is the last
+    # delta_conv - 1 inputs of the convolution over [q | k | v] and, a
+    # value head, S (delta_key_dim, delta_value_dim) float32.
+    delta_key_heads: int = 0
+    delta_value_heads: int = 0
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_conv: int = 0
 
     def __post_init__(self):
         if self.sp_mechanism not in ("ring", "ulysses"):
@@ -204,37 +235,57 @@ class TransformerConfig:
                 "sequence_parallel)")
         kinds = tuple(self.layer_kinds)
         if kinds and (len(kinds) != self.n_layers
-                      or set(kinds) - {"attention", "mamba"}):
+                      or set(kinds) - {"attention", *_RECURRENT_KINDS}
+                      or len(set(kinds) & set(_RECURRENT_KINDS)) > 1):
             raise ValueError(
                 f"layer_kinds must name {self.n_layers} layers "
-                f"'attention' or 'mamba', got {len(kinds)} of "
-                f"{sorted(set(kinds))}")
+                f"'attention' or one of {_RECURRENT_KINDS}, got "
+                f"{len(kinds)} of {sorted(set(kinds))}")
         if self.recurrent:
+            kind = self.recurrent_kind
             for name in ("kv_dtype", "sequence_parallel", "kv_lora_rank",
-                         "top_k", "n_experts", "sandwich_norm"):
+                         "n_experts", "sandwich_norm"):
                 if getattr(self, name):
                     raise ValueError(
-                        f"a model with a recurrent state (Mamba layers) "
+                        f"a model with a recurrent state ({kind} layers) "
                         f"does not take {name}={getattr(self, name)!r}: "
                         f"the state is float32, on one device, beside "
-                        f"plain grouped-query attention and a dense FFN")
+                        f"plain grouped-query attention")
             if self.ut_steps > 1:
                 raise ValueError("a model with a recurrent state runs its "
                                  "stack once a token (ut_steps 1)")
-            if min(self.ssm_d_inner, self.ssm_d_state, self.ssm_dt_rank) < 1 \
-                    or self.ssm_d_conv < 2:
+            if kind == "mamba" and (
+                    min(self.ssm_d_inner, self.ssm_d_state,
+                        self.ssm_dt_rank) < 1 or self.ssm_d_conv < 2):
                 raise ValueError(
-                    "Mamba layers need ssm_d_inner, ssm_d_state, "
+                    "mamba layers need ssm_d_inner, ssm_d_state, "
                     "ssm_dt_rank >= 1 and ssm_d_conv >= 2")
+            if kind == "delta" and (
+                    min(self.delta_key_heads, self.delta_value_heads,
+                        self.delta_key_dim, self.delta_value_dim) < 1
+                    or self.delta_conv < 2
+                    or self.delta_value_heads % self.delta_key_heads):
+                raise ValueError(
+                    "delta layers need delta_key_dim, delta_value_dim >= "
+                    "1, delta_conv >= 2 and delta_value_heads a multiple "
+                    "of delta_key_heads >= 1")
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
+
+    @property
+    def recurrent_kind(self) -> str:
+        """The kind of the layers that carry a recurrent state: "mamba",
+        "delta", or "" where every layer attends."""
+        return next((kind for kind in self.layer_kinds
+                     if kind in _RECURRENT_KINDS), "")
 
     @property
     def n_states(self) -> int:
-        """Recurrent states a sequence carries: one a Mamba layer."""
-        return sum(kind == "mamba" for kind in self.layer_kinds)
+        """Recurrent states a sequence carries: one a layer of the
+        recurrent kind."""
+        return sum(kind in _RECURRENT_KINDS for kind in self.layer_kinds)
 
     @property
     def recurrent(self) -> bool:
@@ -247,16 +298,31 @@ class TransformerConfig:
         return (self.n_layers - self.n_states) * self.ut_steps
 
     @property
+    def delta_conv_channels(self) -> int:
+        """Channels of a delta layer's convolution: [q | k | v]."""
+        return (2 * self.delta_key_heads * self.delta_key_dim
+                + self.delta_value_heads * self.delta_value_dim)
+
+    @property
     def state_bytes(self) -> int:
-        """Bytes of recurrent state a sequence carries: a Mamba layer's
-        SSM state in float32 and its convolution's tail of inputs."""
+        """Bytes of recurrent state a sequence carries, over the layers
+        of the recurrent kind: a layer's state in float32 (mamba: d_state
+        x d_inner; delta: key_dim x value_dim a value head) and its
+        convolution's tail of inputs in the serving dtype."""
+        item = self.jnp_dtype.itemsize
+        if self.recurrent_kind == "delta":
+            return self.n_states * (
+                4 * self.delta_value_heads * self.delta_key_dim
+                * self.delta_value_dim
+                + (self.delta_conv - 1) * self.delta_conv_channels * item)
         return self.n_states * self.ssm_d_inner * (
-            4 * self.ssm_d_state
-            + (self.ssm_d_conv - 1) * self.jnp_dtype.itemsize)
+            4 * self.ssm_d_state + (self.ssm_d_conv - 1) * item)
 
     @property
     def rotary_dim(self) -> int:
-        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
+        if self.kv_lora_rank:
+            return self.qk_rope_head_dim
+        return int(self.head_dim * self.rotary_fraction)
 
     @property
     def latent_row(self) -> int:
@@ -327,7 +393,7 @@ def _init_routed_ffn(keys, config: TransformerConfig) -> dict:
                        dtype)["w"] for expert in range(*config.held)])}
 
     shared = config.n_shared_experts * ff
-    return {
+    ffn = {
         "w_gate": held(keys[0], d, ff), "w_up": held(keys[1], d, ff),
         "w_down": held(keys[2], ff, d),
         "router": init_dense(keys[3], d, config.n_routed_experts, dtype),
@@ -335,6 +401,11 @@ def _init_routed_ffn(keys, config: TransformerConfig) -> dict:
         "shared_up": init_dense(keys[5], d, shared, dtype),
         "shared_down": init_dense(keys[6], shared, d, dtype),
     }
+    if config.shared_expert_gate:
+        # the shared expert's own gate, sigmoid(x . w): one column
+        ffn["shared_mix"] = init_dense(jax.random.fold_in(keys[4], 1), d, 1,
+                                       dtype)
+    return ffn
 
 
 def _init_layer(key, config: TransformerConfig,
@@ -353,14 +424,20 @@ def _init_layer(key, config: TransformerConfig,
         layer = _init_latent_attention(keys, config)
     else:
         layer = {
-            # (out, in): the decode step reads them in place (_by_head)
-            "wq": init_dense_t(keys[0], d, config.n_heads * hd, dtype),
+            # (out, in): the decode step reads them in place (_by_head);
+            # gated, a head's query then its gate
+            "wq": init_dense_t(
+                keys[0], d, config.n_heads * hd
+                * (2 if config.gated_attention else 1), dtype),
             "wk": init_dense_t(keys[1], d, config.n_kv_heads * hd, dtype),
             "wv": init_dense(keys[2], d, config.n_kv_heads * hd, dtype),
             "wo": init_dense(keys[3], config.n_heads * hd, d, dtype),
         }
     layer["attn_norm"] = init_norm(d, dtype)
     layer["mlp_norm"] = init_norm(d, dtype)
+    if config.qk_norm:
+        layer["q_norm"] = init_norm(hd, dtype)
+        layer["k_norm"] = init_norm(hd, dtype)
     if config.sandwich_norm:
         layer["attn_out_norm"] = init_norm(d, dtype)
         layer["mlp_out_norm"] = init_norm(d, dtype)
@@ -429,6 +506,52 @@ def _init_mamba_layer(key, config: TransformerConfig) -> dict:
     }
 
 
+def _init_delta_layer(key, config: TransformerConfig) -> dict:
+    """One Gated DeltaNet layer's weights: the mixer's (Qwen3-Next's: the
+    projections without bias, [q | k | v | z] in one and [b | a] in
+    another, a depthwise causal convolution over [q | k | v] without one,
+    a decay rate and a step bias a value head in float32, the output's
+    norm a head with one gain shared by the heads) and the FFN's, routed
+    where the model's is.  `conv` lies (taps, channels); published it is
+    (channels, 1, taps).  Seeded as the architecture's training
+    initialiser has them where it matters to the recurrence: A uniform in
+    (0, 16) and softplus(dt_bias) log-uniform in [1e-3, 1e-1], so that a
+    head's decay a row spans e^-1.6 to e^-0.0001 and its state carries
+    from a row or two to thousands (the published modelling code's
+    dt_bias of ones, which weights overwrite, makes every head forget
+    within a row or two); the matrices as init_dense draws them."""
+    d, dtype = config.d_model, config.jnp_dtype
+    channels, taps = config.delta_conv_channels, config.delta_conv
+    values = config.delta_value_heads * config.delta_value_dim
+    keys = jax.random.split(key, 12)
+    step = jnp.exp(jax.random.uniform(
+        jax.random.fold_in(keys[3], 1), (config.delta_value_heads,),
+        jnp.float32) * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+    layer = {
+        "mixer_norm": init_norm(d, dtype),
+        "w_qkvz": init_dense(keys[0], d, channels + values, dtype),
+        "conv": {"w": (jax.random.normal(keys[1], (taps, channels),
+                                         jnp.float32)
+                       / math.sqrt(taps)).astype(dtype)},
+        "w_ba": init_dense(keys[2], d, 2 * config.delta_value_heads, dtype),
+        "a_log": jnp.log(jax.random.uniform(
+            keys[3], (config.delta_value_heads,), jnp.float32) * 16.0),
+        # softplus's inverse of the step size
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "delta_norm": init_norm(config.delta_value_dim, dtype),
+        "w_out": init_dense(keys[4], values, d, dtype),
+        "mlp_norm": init_norm(d, dtype),
+    }
+    if config.top_k:
+        layer.update(_init_routed_ffn(keys[5:], config))
+    else:
+        layer.update(
+            w_gate=init_dense(keys[5], d, config.d_ff, dtype),
+            w_up=init_dense(keys[6], d, config.d_ff, dtype),
+            w_down=init_dense(keys[7], config.d_ff, d, dtype))
+    return layer
+
+
 def _stack_layers(layers: list) -> dict:
     """Per-layer weight dicts -> one dict of leaves stacked on a leading
     axis, a leaf at a time, letting each layer's copy go as its stack is
@@ -456,7 +579,7 @@ def _leading_dense(config: TransformerConfig) -> int:
 def _kind_runs(config: TransformerConfig) -> list:
     """[(kind, index of the run's first layer among its kind, how many)]:
     the runs of like layers of a model with layer_kinds, in order."""
-    runs, seen = [], {"attention": 0, "mamba": 0}
+    runs, seen = [], dict.fromkeys(("attention", *_RECURRENT_KINDS), 0)
     for kind in config.layer_kinds:
         if runs and runs[-1][0] == kind:
             runs[-1][2] += 1
@@ -482,10 +605,13 @@ def init_params(config: TransformerConfig, key) -> dict:
     }
     if config.layer_kinds:
         params["runs"], first = [], 0
+        inits = {"mamba": _init_mamba_layer, "delta": _init_delta_layer,
+                 "attention": partial(_init_layer,
+                                      routed=config.top_k > 0)}
         for kind, _, count in _kind_runs(config):
-            init = _init_mamba_layer if kind == "mamba" else _init_layer
             params["runs"].append(_stack_layers(
-                [init(k, config) for k in layer_keys[first:first + count]]))
+                [inits[kind](k, config)
+                 for k in layer_keys[first:first + count]]))
             first += count
         return params
     params["layers"] = _stack_layers([
@@ -546,25 +672,36 @@ def param_specs(config: TransformerConfig,
         "embed": {"w": P(None, "fsdp")},
         "norm_out": {"scale": P(None)},
     }
+    whole2, whole3 = P(None, None), P(None, None, None)
+    routed_ffn = dict(
+        expert_ffn_specs, shared_gate={"w": column},
+        shared_up={"w": column}, shared_down={"w": row})
+    if config.shared_expert_gate:
+        routed_ffn["shared_mix"] = {"w": whole3}
+    if config.qk_norm:
+        layer.update(q_norm={"scale": whole2}, k_norm={"scale": whole2})
     if config.layer_kinds:
-        # a Mamba layer's big matrices split like the FFN's; what is a
-        # channel's own (the convolution, A, D, the step's bias) and the
-        # narrow projections stay whole
-        whole2, whole3 = P(None, None), P(None, None, None)
-        mamba = dict(
-            dense_ffn, mixer_norm={"scale": whole2},
-            mlp_norm={"scale": whole2}, w_in={"w": column},
-            conv={"w": whole3, "b": whole2}, w_x={"w": whole3},
-            dt_norm={"scale": whole2}, b_norm={"scale": whole2},
-            c_norm={"scale": whole2}, w_dt={"w": whole3}, dt_bias=whole2,
-            a_log=whole3, d=whole2, w_out={"w": row})
-        specs["runs"] = [mamba if kind == "mamba"
-                         else dict(layer, **dense_ffn)
-                         for kind, _, _ in _kind_runs(config)]
+        # a recurrent layer's big matrices split like the FFN's; what is
+        # a channel's or a head's own (the convolution, A, D, the step's
+        # bias) and the narrow projections stay whole
+        ffn = routed_ffn if config.top_k else dense_ffn
+        recurrent = dict(
+            ffn, mixer_norm={"scale": whole2}, mlp_norm={"scale": whole2})
+        kinds = {
+            "attention": dict(layer, **ffn),
+            "mamba": dict(
+                recurrent, w_in={"w": column},
+                conv={"w": whole3, "b": whole2}, w_x={"w": whole3},
+                dt_norm={"scale": whole2}, b_norm={"scale": whole2},
+                c_norm={"scale": whole2}, w_dt={"w": whole3},
+                dt_bias=whole2, a_log=whole3, d=whole2, w_out={"w": row}),
+            "delta": dict(
+                recurrent, w_qkvz={"w": column}, conv={"w": whole3},
+                w_ba={"w": whole3}, a_log=whole2, dt_bias=whole2,
+                delta_norm={"scale": whole2}, w_out={"w": row})}
+        specs["runs"] = [kinds[kind] for kind, _, _ in _kind_runs(config)]
     elif config.top_k:
-        specs["layers"] = dict(
-            layer, **expert_ffn_specs, shared_gate={"w": column},
-            shared_up={"w": column}, shared_down={"w": row})
+        specs["layers"] = dict(layer, **routed_ffn)
         if _leading_dense(config):
             specs["dense_layers"] = dict(layer, **dense_ffn)
     elif config.n_experts > 0:
@@ -609,14 +746,15 @@ def quantize_weights_int8(params: dict,
     ~2x decode throughput at fixed batch.  Norms and biases stay f32;
     MoE expert FFNs stay unquantized (their dispatch einsums bypass
     dense()).  NOT for training -- optax rejects int8 leaves loudly."""
+    if config.recurrent:
+        raise ValueError(
+            f"weight-only int8 is not implemented for a model with a "
+            f"recurrent state ({config.recurrent_kind} layers): "
+            f"quantize_weights_int8 covers stacks of one kind")
     if config.kv_lora_rank or config.top_k:
         raise ValueError("weight-only int8 covers the grouped-query "
                          "dense and switch layers, not latent attention "
                          "or routed experts")
-    if config.recurrent:
-        raise ValueError("weight-only int8 is not implemented for a model "
-                         "with a recurrent state (Mamba layers): "
-                         "quantize_weights_int8 covers stacks of one kind")
 
     def quant(entry: dict, axis: int) -> dict:
         w = entry["w"].astype(jnp.float32)
@@ -681,8 +819,8 @@ def init_cache(config: TransformerConfig, batch: int,
                 "k_scale": jnp.zeros(scale_shape, jnp.float32),
                 "v": jnp.zeros(shape, jnp.int8),
                 "v_scale": jnp.zeros(scale_shape, jnp.float32)}
-    # a model with Mamba layers carries their state beside the K/V, the
-    # batch in the slots' place
+    # a model with recurrent layers carries their state beside the K/V,
+    # the batch in the slots' place
     return {"k": jnp.zeros(shape, config.jnp_dtype),
             "v": jnp.zeros(shape, config.jnp_dtype),
             **init_recurrent_state(config, batch)}
@@ -690,11 +828,19 @@ def init_cache(config: TransformerConfig, batch: int,
 
 # the leaves of a cache or a pool that are recurrent state, not K/V, each
 # with the axis its sequences (a cache's batch, a pool's slots) lie on
-_STATE_LEAVES = {"conv": 2, "ssm": 1}
+_STATE_LEAVES = {"conv": 2, "ssm": 1, "delta": 1}
 
 
 def _layer_state(config: TransformerConfig, slots: int) -> dict:
-    """One Mamba layer's state of `slots` sequences from their start."""
+    """One recurrent layer's state of `slots` sequences from their
+    start, by the model's recurrent kind."""
+    if config.recurrent_kind == "delta":
+        return {"conv": jnp.zeros((config.delta_conv - 1, slots,
+                                   config.delta_conv_channels),
+                                  config.jnp_dtype),
+                "delta": jnp.zeros((slots, config.delta_value_heads,
+                                    config.delta_key_dim,
+                                    config.delta_value_dim), jnp.float32)}
     return {"conv": jnp.zeros((config.ssm_d_conv - 1, slots,
                                config.ssm_d_inner), config.jnp_dtype),
             "ssm": jnp.zeros((slots, config.ssm_d_state,
@@ -703,14 +849,19 @@ def _layer_state(config: TransformerConfig, slots: int) -> dict:
 
 def init_recurrent_state(config: TransformerConfig, slots: int) -> dict:
     """The recurrent state of `slots` sequences, zeros: {} for a model
-    with no Mamba layer, else a Mamba layer's two leaves, addressed by
-    sequence and sized by `slots`, not by positions: "conv" (n_states,
-    ssm_d_conv - 1, slots, ssm_d_inner), the mixer's last inputs of its
-    convolution, oldest first, and "ssm" (n_states, slots, ssm_d_state,
-    ssm_d_inner) float32.  Every minor pair of axes fills its tiles: the
-    channels on the lanes, the slots (conv) and the states (ssm) on the
-    sublanes.  Held (slots, 3, .) and (., d_inner, d_state), as the mixer
-    is published, three rows pad to a tile's 16 and 16 lanes to 128."""
+    with no recurrent layer, else that kind of layer's two leaves,
+    addressed by sequence and sized by `slots`, not by positions.  Mamba:
+    "conv" (n_states, ssm_d_conv - 1, slots, ssm_d_inner), the mixer's
+    last inputs of its convolution, oldest first, and "ssm" (n_states,
+    slots, ssm_d_state, ssm_d_inner) float32.  Every minor pair of axes
+    fills its tiles: the channels on the lanes, the slots (conv) and the
+    states (ssm) on the sublanes.  Held (slots, 3, .) and (., d_inner,
+    d_state), as the mixer is published, three rows pad to a tile's 16
+    and 16 lanes to 128.  Delta: "conv" (n_states, delta_conv - 1, slots,
+    the [q | k | v] channels) and "delta" (n_states, slots, value heads,
+    delta_key_dim, delta_value_dim) float32, a head's S whole in its
+    minor pair, so that a decode step reads and writes it where it lies
+    (parallel/delta.py gdn_step)."""
     if not config.recurrent:
         return {}
     return {name: jnp.zeros((config.n_states,) + leaf.shape, leaf.dtype)
@@ -744,17 +895,36 @@ def _quantize_kv(x):
 
 # -- forward ----------------------------------------------------------------
 
+def _rotate(config: TransformerConfig, x, cos, sin):
+    """apply_rotary over the first rotary_dim columns of x's heads (all
+    of them but under a rotary_fraction), the rest as they are."""
+    width = config.rotary_dim
+    if width == x.shape[-1]:
+        return apply_rotary(x, cos, sin)
+    return jnp.concatenate(
+        [apply_rotary(x[..., :width], cos, sin), x[..., width:]], axis=-1)
+
+
 def _project_qkv(config: TransformerConfig, layer, x, cos, sin):
     """x (B, L, d_model) -> rotated q (B, H, L, hd), rotated k and plain v
-    (B, Hkv, L, hd)."""
+    (B, Hkv, L, hd); of a gated attention also, fourth, the heads' gates
+    (B, H, L, hd), the second half of what wq gives a head."""
     batch, length, _ = x.shape
     v = dense(layer["wv"], x).reshape(
         batch, length, config.n_kv_heads, config.head_dim
     ).transpose(0, 2, 1, 3)
-    if not config.rotary:
-        return dense_heads(layer["wq"], x), dense_heads(layer["wk"], x), v
-    return (apply_rotary(dense_heads(layer["wq"], x), cos, sin),
-            apply_rotary(dense_heads(layer["wk"], x), cos, sin), v)
+
+    def finish(heads, norm):
+        # a head's norm where the model has one, then its rotation
+        if config.qk_norm:
+            heads = rms_norm(layer[norm], heads, config.norm_eps)
+        return _rotate(config, heads, cos, sin) if config.rotary else heads
+
+    q, gate = dense_heads(layer["wq"], x), ()
+    if config.gated_attention:
+        q, gate = q[..., :config.head_dim], (q[..., config.head_dim:],)
+    q = finish(q, "q_norm")
+    return (q, finish(dense_heads(layer["wk"], x), "k_norm"), v, *gate)
 
 
 def _project_latent(config: TransformerConfig, layer, x, cos, sin):
@@ -838,6 +1008,15 @@ def _row_tiles_take(config: TransformerConfig, length: int) -> bool:
             and not config.recurrent)
 
 
+def _tiled(config: TransformerConfig, true_len, length: int):
+    """What _row_tiles takes as `live` of a whole prefill of `length`
+    rows for a prompt of `true_len` (traced, or None: no whole prefill):
+    true_len where the bucket runs by row tiles, else None."""
+    if true_len is None or not _row_tiles_take(config, length):
+        return None
+    return true_len
+
+
 def prefill_rows(config: TransformerConfig, bucket: int,
                  true_len: int) -> int:
     """The rows a whole prefill (paged_prefill) of `bucket` rows runs for
@@ -889,7 +1068,7 @@ def _row_tiles(live, fn, layer, *operands):
 
 
 def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend,
-                   live=None):
+                   true_len=None):
     """THE decoder layer, on every path: attention norm, projections and
     rotary, `attend`, wo and residual, MLP norm, FFN, residual (under
     sandwich_norm each sublayer's output normed before its add).  Only
@@ -899,8 +1078,11 @@ def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend,
     Under latent attention k is the latent row and v None; the stores
     keep the row, and attend decompressed (fresh, cache) or absorbed
     (pool).  Everything but `attend` and the routed experts is a row's
-    own: with `live` (a whole prefill's true length, _row_tiles) it runs
-    over the live row tiles only, q, k, v and h zeros past them.
+    own: given `true_len` (a whole prefill's true length) it runs over the
+    live row tiles only where the bucket runs by row tiles (_tiled), q, k,
+    v and h zeros past them; the rows at or past it go to no routed expert
+    either way (_routed_moe).  A gated attention's output is multiplied by
+    sigmoid(gate) before wo.
     Returns (h, the FFN's stats, the store's new leaves)."""
     project = _project_latent if config.kv_lora_rank else _project_qkv
 
@@ -926,39 +1108,66 @@ def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend,
 
     def close(layer, h, out):
         h, mlp_in = attention_out(layer, h, out)
-        mlp_out, stats = _mlp_block(config, layer, mlp_in)
+        mlp_out, stats = _mlp_block(config, layer, mlp_in, true_len)
         return ffn_out(layer, h, mlp_out), stats
 
-    q, k, v = _row_tiles(live, attention_in, layer, h, cos, sin)
+    live = _tiled(config, true_len, h.shape[1])
+    q, k, v, *gate = _row_tiles(live, attention_in, layer, h, cos, sin)
     out, leaves = attend(layer, q, k, v)
+    if gate:
+        out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+            gate[0].astype(jnp.float32))).astype(out.dtype)
     if live is None or not (config.top_k and "router" in layer):
         h, stats = _row_tiles(live, close, layer, h, out)
     else:
         # the experts group the rows of the whole sequence: between two
         # row loops, the dead rows sent to no expert
         h, mlp_in = _row_tiles(live, attention_out, layer, h, out)
-        mlp_out, stats = _routed_moe(config, layer, mlp_in, live)
+        mlp_out, stats = _routed_moe(config, layer, mlp_in, true_len)
         h = ffn_out(layer, h, mlp_out)
     return h, stats, leaves
 
 
 def scan_kind(config: TransformerConfig, length: int) -> str:
-    """"kernel" or "jnp": what a Mamba layer's selective scan over
-    `length` rows runs through (parallel/ssm.py).  _mamba_mixer's ssm_scan
-    decides by the same predicate, and the engine names its prefill spans
-    by this."""
+    """What a recurrent layer's scan over `length` rows runs through:
+    "kernel" or "jnp".  A Mamba layer's selective scan (parallel/ssm.py)
+    decides by ssm_scan_takes, as ssm_scan does; a delta layer's chunkwise
+    recurrence (parallel/delta.py) is XLA's.  The engine names its prefill
+    spans by this."""
+    if config.recurrent_kind == "delta":
+        return "jnp"
     return "kernel" if ssm_scan_takes(
         length, config.ssm_d_inner, config.ssm_d_state,
         config.jnp_dtype) else "jnp"
 
 
 def scan_rows(config: TransformerConfig, bucket: int, true_len: int) -> int:
-    """The rows a Mamba layer's scan runs of a whole prefill of `bucket`
-    rows for a prompt of `true_len` tokens: the kernel stops after the
-    block of rows that holds row true_len - 1, the oracle runs the bucket
-    (the rows past true_len with a step of 0)."""
+    """The rows a recurrent layer's scan runs of a whole prefill of
+    `bucket` rows for a prompt of `true_len` tokens: a kernel stops after
+    the block of rows that holds row true_len - 1, XLA's form runs the
+    bucket (the rows past true_len leaving the state alone)."""
     return ssm_scan_rows(bucket, true_len,
-                         scan_kind(config, bucket) == "kernel")
+                         scan_kind(config, bucket) != "jnp")
+
+
+def _causal_conv(x, tail, taps, stop):
+    """A depthwise causal convolution over the rows x (B, L, C) of B
+    sequences whose inputs before row 0 are `tail` (taps - 1, B, C),
+    oldest first; taps (taps, C) float32.  Returns (the sums (B, L, C)
+    float32, the new tail: the inputs of the rows before row `stop`, or
+    before the end where it is None)."""
+    f32 = jnp.float32
+    length, before = x.shape[1], taps.shape[0] - 1
+    if length == 1:
+        window = jnp.concatenate([tail, x.swapaxes(0, 1)])  # (taps, B, .)
+        return (jnp.sum(window.astype(f32) * taps[:, None], axis=0)[:, None],
+                window[1:])
+    rows = jnp.concatenate([tail.swapaxes(0, 1), x], axis=1)
+    conv = sum(rows[:, j:j + length].astype(f32) * taps[j]
+               for j in range(before + 1))
+    return conv, jax.lax.dynamic_slice_in_dim(
+        rows, length if stop is None else stop, before,
+        axis=1).swapaxes(0, 1)
 
 
 def _mamba_mixer(config: TransformerConfig, layer, u, tail, ssm, stop):
@@ -982,20 +1191,8 @@ def _mamba_mixer(config: TransformerConfig, layer, u, tail, ssm, stop):
     length = u.shape[1]
     xz = dense(layer["w_in"], u)
     x, z = xz[..., :inner], xz[..., inner:]
-    taps = layer["conv"]["w"].astype(f32)                  # (taps, inner)
-    before = taps.shape[0] - 1
-    if length == 1:
-        window = jnp.concatenate([tail, x.swapaxes(0, 1)])  # (taps, B, .)
-        conv = jnp.sum(window.astype(f32) * taps[:, None], axis=0)[:, None]
-        tail = window[1:]
-    else:
-        rows = jnp.concatenate([tail.swapaxes(0, 1), x], axis=1)
-        conv = sum(rows[:, j:j + length].astype(f32) * taps[j]
-                   for j in range(before + 1))
-        # the inputs of the rows stop - taps + 1 .. stop - 1
-        tail = jax.lax.dynamic_slice_in_dim(
-            rows, length if stop is None else stop, before,
-            axis=1).swapaxes(0, 1)
+    # the new tail: the inputs of the rows stop - taps + 1 .. stop - 1
+    conv, tail = _causal_conv(x, tail, layer["conv"]["w"].astype(f32), stop)
     c = jax.nn.silu(conv + layer["conv"]["b"].astype(f32)).astype(u.dtype)
     projected = dense(layer["w_x"], c)
     delta = rms_norm(layer["dt_norm"], projected[..., :rank], eps)
@@ -1027,6 +1224,104 @@ def _mamba_layer(config: TransformerConfig, layer, h, state, stop=None):
     mlp_out, stats = _mlp_block(
         config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps))
     return h + mlp_out, stats, {"conv": tail, "ssm": ssm}
+
+
+def _delta_mixer(config: TransformerConfig, layer, u, tail, state, stop):
+    """Qwen3-Next's Gated DeltaNet mixer over the normed rows u (B, L, d)
+    of B sequences, each from its own state: `tail` (taps - 1, B,
+    channels), the convolution's inputs before row 0, oldest first, and
+    `state`, S (B, value heads, d_k, d_v) float32 -- or, for a decode
+    step over the slots' states, (the stack of the layers' (layers, B,
+    ...), this layer's index), which the step's kernel reads and writes
+    where it lies.  Returns (out (B, L, d), the new tail, the new state
+    in the form it came): the state after row stop - 1, as _mamba_mixer.
+
+        [q | k | v | z] = u W_qkvz;   [b | a] = u W_ba
+        [q | k | v] = silu(conv([q | k | v]))      no bias
+        q = q / |q| / sqrt(d_k),  k = k / |k|      a key head, each
+                                    serving value_heads / key_heads heads
+        beta = sigmoid(b);   g = -exp(A_log) softplus(a + dt_bias)
+        o = the gated delta rule (parallel/delta.py), S float32
+        out = (rms_norm(o) * gain * silu(z)) W_out     the norm a head
+
+    One row (a decode step) is delta_step's update; more are
+    delta_scan's."""
+    f32 = jnp.float32
+    key_heads, heads = config.delta_key_heads, config.delta_value_heads
+    key_dim, value_dim = config.delta_key_dim, config.delta_value_dim
+    keys, channels = key_heads * key_dim, config.delta_conv_channels
+    batch, length, _ = u.shape
+    mixed = dense(layer["w_qkvz"], u)
+    z = mixed[..., channels:].reshape(batch, length, heads, value_dim)
+    ba = dense(layer["w_ba"], u).astype(f32)
+    conv, tail = _causal_conv(mixed[..., :channels], tail,
+                              layer["conv"]["w"].astype(f32), stop)
+    c = jax.nn.silu(conv).astype(u.dtype)
+
+    def unit(x):
+        # (B, L, key heads x d_k) -> (B, value heads, L, d_k) float32,
+        # each head of unit length
+        x = x.reshape(batch, length, key_heads, key_dim).astype(f32)
+        x = x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+        return jnp.repeat(x, heads // key_heads, axis=2).swapaxes(1, 2)
+
+    q = unit(c[..., :keys]) * key_dim ** -0.5
+    k = unit(c[..., keys:2 * keys])
+    v = c[..., 2 * keys:].reshape(batch, length, heads,
+                                  value_dim).swapaxes(1, 2)
+    beta = jax.nn.sigmoid(ba[..., :heads]).swapaxes(1, 2)  # (B, H, L)
+    g = (-jnp.exp(layer["a_log"]) * jax.nn.softplus(
+        ba[..., heads:] + layer["dt_bias"])).swapaxes(1, 2)
+    if length > 1:
+        out, state = delta_scan(q.astype(u.dtype), k.astype(u.dtype), v, g,
+                                beta, state, stop)
+    else:
+        row = (q[:, :, 0], k[:, :, 0], v[:, :, 0].astype(f32), g[:, :, 0],
+               beta[:, :, 0])
+        if isinstance(state, tuple):
+            out, stack = delta_step(*row, *state)
+            state = (stack, state[1])
+        else:
+            out, state = delta_step_reference(*row, state)
+        out = out[:, :, None]
+    out = rms_norm(layer["delta_norm"],
+                   out.swapaxes(1, 2).astype(u.dtype), config.norm_eps)
+    out = (out.astype(f32) * jax.nn.silu(z.astype(f32))).astype(u.dtype)
+    return (dense(layer["w_out"], out.reshape(batch, length, -1)), tail,
+            state)
+
+
+def _delta_layer(config: TransformerConfig, layer, h, state, stop=None):
+    """A Gated DeltaNet layer: h + mixer(norm(h)), then the FFN as a
+    decoder layer has it.  `state` is the layer's {"conv", "delta"} for
+    h's B sequences (its "delta" as _delta_mixer takes it), or None:
+    zeros, a sequence from its start.  Returns (h, the FFN's stats, the
+    new state)."""
+    if state is None:
+        state = _layer_state(config, h.shape[0])
+    out, tail, delta = _delta_mixer(
+        config, layer, rms_norm(layer["mixer_norm"], h, config.norm_eps),
+        state["conv"], state["delta"], stop)
+    h = h + out
+    mlp_out, stats = _mlp_block(
+        config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps),
+        stop)
+    return h + mlp_out, stats, {"conv": tail, "delta": delta}
+
+
+def _layer_kind(layer) -> str:
+    """The kind of a layer (or of a stack of like layers), by the leaf
+    only that kind's mixer has."""
+    if "w_in" in layer:
+        return "mamba"
+    return "delta" if "w_qkvz" in layer else "attention"
+
+
+def _recurrent_layer(layer):
+    """The body of a recurrent layer, by its kind; None for a layer that
+    attends."""
+    return {"mamba": _mamba_layer, "delta": _delta_layer}.get(
+        _layer_kind(layer))
 
 
 def _sp_prefill(config: TransformerConfig, q, k, v):
@@ -1411,7 +1706,9 @@ def _route(config: TransformerConfig, router: dict, x):
     scores in float32 over all n_routed_experts, a group's score its
     largest, the best topk_groups groups kept, the top_k scores among
     their experts chosen (ties to the lower index, jax.lax.top_k's
-    order), weighted routed_scaling x score and not renormalised."""
+    order), weighted routed_scaling x score, renormalised (divided by
+    their sum) only under norm_topk.  One group of all the experts is
+    plain top-k."""
     tokens = x.shape[0]
     scores = jax.nn.softmax(jnp.einsum(
         "td,de->te", x.astype(jnp.float32),
@@ -1426,34 +1723,42 @@ def _route(config: TransformerConfig, router: dict, x):
     weights, ids = jax.lax.top_k(
         jnp.where(jnp.repeat(group_mask, per_group, axis=1), scores, 0.0),
         config.top_k)
+    if config.norm_topk:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights * config.routed_scaling, ids
 
 
-def _routed_moe(config: TransformerConfig, layer, x, live=None):
+def _routed_moe(config: TransformerConfig, layer, x, true_len=None):
     """Shared(x) + sum over a token's chosen experts of g_i E_i(x), of
     which this process adds the experts it holds (config.held) and
     leaves the rest to the shares that hold them; a token none of whose
     experts is held gets the shared experts only.  No token is dropped:
     every token-expert pair held is computed (parallel/experts.py).
-    With `live` (a whole prefill's true length, _row_tiles) the rows at
-    or past it go to no expert, and the shared experts run over the live
-    row tiles.  Returns (output, stats) with stats[1:] = distinct held
-    experts hit and pairs computed: of a whole prefill the live rows'."""
+    Given `true_len` (a whole prefill's true length) the rows at or past
+    it go to no expert, and the shared experts run over the live row
+    tiles where the bucket runs by row tiles (_tiled).  Under
+    shared_expert_gate the shared experts' output is multiplied by
+    sigmoid(x . shared_mix).  Returns (output, stats) with stats[1:] =
+    distinct held experts hit and pairs computed: of a whole prefill the
+    live rows'."""
     batch, length, d_model = x.shape
     tokens = x.reshape(batch * length, d_model)
     weights, ids = _route(config, layer["router"], tokens)
     low, high = config.held
     held = (ids >= low) & (ids < high)
-    if live is not None:
-        held &= jnp.tile(jnp.arange(length) < live, batch)[:, None]
+    if true_len is not None:
+        held &= jnp.tile(jnp.arange(length) < true_len, batch)[:, None]
     stacked, index = layer["experts"]        # _scan_layers
     routed, experts_read, pairs = expert_ffn(
         tokens, *(stacked[name]["w"] for name in _EXPERT_LEAVES),
         jnp.where(held, ids - low, high - low),
         jnp.where(held, weights, 0.0), layer=index)
-    shared = _row_tiles(live, lambda layer, x: swiglu(
-        layer["shared_gate"], layer["shared_up"], layer["shared_down"], x),
-        layer, x)
+    shared = _row_tiles(
+        _tiled(config, true_len, length), lambda layer, x: swiglu(
+            layer["shared_gate"], layer["shared_up"], layer["shared_down"],
+            x), layer, x)
+    if config.shared_expert_gate:
+        shared = shared * jax.nn.sigmoid(dense(layer["shared_mix"], x))
     stats = jnp.stack([jnp.float32(0.0), experts_read.astype(jnp.float32),
                        pairs.astype(jnp.float32)])
     return shared + routed.reshape(x.shape).astype(x.dtype), stats
@@ -1465,12 +1770,12 @@ def _routed_moe(config: TransformerConfig, layer, x, live=None):
 _FFN_STATS = 3
 
 
-def _mlp_block(config: TransformerConfig, layer, mlp_in):
+def _mlp_block(config: TransformerConfig, layer, mlp_in, true_len=None):
     """One layer's FFN (dense SwiGLU, switch MoE, or routed + shared
-    experts where the layer has a router).  Returns (output, stats
-    float32 (_FFN_STATS,))."""
+    experts where the layer has a router; `true_len` is _routed_moe's).
+    Returns (output, stats float32 (_FFN_STATS,))."""
     if config.top_k and "router" in layer:
-        return _routed_moe(config, layer, mlp_in)
+        return _routed_moe(config, layer, mlp_in, true_len)
     stats = jnp.zeros((_FFN_STATS,), jnp.float32)
     if config.n_experts > 0:
         out, aux = _switch_moe(config, layer, mlp_in)
@@ -1525,7 +1830,7 @@ def _layer_stacks(params: dict, config: TransformerConfig) -> list:
     run: the leading dense layers of a routed-expert model, then the
     stack every model has; of a model with layer_kinds its runs of like
     layers, a run's first index counted among its own kind (an attention
-    layer's among the K/V caches, a Mamba layer's among the states)."""
+    layer's among the K/V caches, a recurrent layer's among the states)."""
     if config.layer_kinds:
         return [(stack, first, count) for stack, (_, first, count)
                 in zip(params["runs"], _kind_runs(config))]
@@ -1661,7 +1966,7 @@ def _hidden(params: dict, config: TransformerConfig, tokens, cache, pos,
     whole = (true_len is not None and cache is not None
              and isinstance(pos, (int, np.integer)) and pos == 0)
     attended = true_len if whole else None
-    live = true_len if whole and _row_tiles_take(config, length) else None
+    live = _tiled(config, attended, length)
     if activation_specs:
         # batch on "data", sequence on "seq" -- but only the axes the
         # ambient mesh actually has (an EP-only mesh has no "seq")
@@ -1678,16 +1983,17 @@ def _hidden(params: dict, config: TransformerConfig, tokens, cache, pos,
     def layer_step(carry, xs):
         h, stats_sum = carry
         layer, layer_cache = xs
-        if "w_in" in layer:
-            # a Mamba layer: its state after row true_len - 1
-            h, stats, new_cache = _mamba_layer(config, layer, h,
-                                               layer_cache, true_len)
+        recurrent = _recurrent_layer(layer)
+        if recurrent:
+            # a recurrent layer: its state after row true_len - 1
+            h, stats, new_cache = recurrent(config, layer, h, layer_cache,
+                                            true_len)
         else:
             h, stats, new_cache = _decoder_layer(
                 config, layer, h, cos, sin,
                 partial(_attend_fresh, config) if layer_cache is None
                 else partial(_attend_cache, config, layer_cache, pos,
-                             live=attended), live)
+                             live=attended), attended)
         stats_sum = stats_sum + stats
         if activation_specs:
             h = jax.lax.with_sharding_constraint(h, act_spec)
@@ -1708,11 +2014,11 @@ def _hidden(params: dict, config: TransformerConfig, tokens, cache, pos,
     def scan_stack(carry, stack, first, count):
         if cache is None:
             return _scan_layers(config, body, carry, stack, None)[0]
-        # the leaves this stack's layers write: a Mamba run the state's,
-        # else the K/V's -- whole where the stack run once has them all,
-        # else those it writes on this pass
-        names = [name for name in cache
-                 if (name in _STATE_LEAVES) == ("w_in" in stack)]
+        # the leaves this stack's layers write: a recurrent run the
+        # state's, else the K/V's -- whole where the stack run once has
+        # them all, else those it writes on this pass
+        names = [name for name in cache if (name in _STATE_LEAVES) == (
+            _layer_kind(stack) in _RECURRENT_KINDS)]
         carry, part = _scan_layers(
             config, body, carry, stack,
             {name: cache[name] if count == cache[name].shape[0]
@@ -1873,7 +2179,7 @@ def init_paged_pool(config: TransformerConfig, num_blocks: int,
     block tables.  Block 0 is the engine's reserved trash block
     (inactive-slot writes land there).  Same leaf names/dtypes as
     init_cache, so the int8 KV path carries over unchanged.  A model with
-    Mamba layers has its slots' recurrent state beside these leaves, in
+    recurrent layers has its slots' recurrent state beside these leaves, in
     the same dict (init_recurrent_state: by slot, not by block); the
     engine makes both."""
     if config.kv_lora_rank:
@@ -1909,7 +2215,7 @@ def paged_prefill(params, config: TransformerConfig, pool, prompt,
     (_row_tiles_take) runs a layer's row-wise work up to true_len and the
     blocks past them receive zeros (a decode step writes a position
     before it reads it).  The decode loop never recompiles
-    (paged_decode_step below).  A model with Mamba layers is also told
+    (paged_decode_step below).  A model with recurrent layers is also told
     its `slot` (traced int32): the recurrent state after row true_len - 1
     overwrites the whole of that slot's, whatever the bucket."""
     if config.recurrent and slot is None:
@@ -2102,9 +2408,9 @@ def _paged_logits(params, config: TransformerConfig, pool, tables,
         raise ValueError(
             f"a window of {tokens.shape[1]} positions over the paged pool "
             f"(a speculative verify step, a prefill chunk) is not "
-            f"implemented for a model with a recurrent state: a Mamba "
-            f"layer's state is advanced a row a step and cannot be rolled "
-            f"back or carried into a chunk")
+            f"implemented for a model with a recurrent state: a "
+            f"{config.recurrent_kind} layer's state is advanced a row a "
+            f"step and cannot be rolled back or carried into a chunk")
     h = _embed(params, config, tokens)
     q_pos = positions[:, None] + jnp.arange(tokens.shape[1])[None, :]
     cos, sin = _rotary_tables(config, q_pos)
@@ -2117,16 +2423,26 @@ def _paged_logits(params, config: TransformerConfig, pool, tables,
         # FFN's stats only the experts' counts go on
         h, pool, stats_sum = carry
         layer, index = xs
-        if "w_in" in layer:
+        kind = _layer_kind(layer)
+        if kind == "mamba":
             # a Mamba layer advances every slot's state a row, in place:
             # row s of h is slot s's
+            names = ("conv", "ssm")
             h, stats, state = _mamba_layer(
-                config, layer, h,
-                {name: pool[name][index] for name in _STATE_LEAVES})
+                config, layer, h, {name: pool[name][index] for name in names})
             pool = {**pool, **{
                 name: jax.lax.dynamic_update_index_in_dim(
                     pool[name], state[name], index, 0)
-                for name in _STATE_LEAVES}}
+                for name in names}}
+        elif kind == "delta":
+            # a delta layer alike; its S goes to delta_step as the whole
+            # leaf and the layer's index, and comes back as the leaf
+            h, stats, state = _delta_layer(
+                config, layer, h, {"conv": pool["conv"][index],
+                                   "delta": (pool["delta"], index)})
+            pool = {**pool, "delta": state["delta"][0],
+                    "conv": jax.lax.dynamic_update_index_in_dim(
+                        pool["conv"], state["conv"], index, 0)}
         else:
             h, stats, pool = _decoder_layer(
                 config, layer, h, cos, sin,
@@ -2254,9 +2570,9 @@ def make_train_step(config: TransformerConfig, optimizer,
     resolve_remat_policy(remat_policy)  # fail fast on typos
     if config.recurrent:
         raise ValueError(
-            "make_train_step is not implemented for a model with a "
-            "recurrent state (Mamba layers): the selective-scan kernel "
-            "has no backward pass")
+            f"make_train_step is not implemented for a model with a "
+            f"recurrent state ({config.recurrent_kind} layers): the scan "
+            f"kernels have no backward pass")
 
     def loss_fn(params, tokens):
         logits, aux = forward(params, config, tokens[:, :-1],

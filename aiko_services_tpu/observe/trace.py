@@ -108,15 +108,17 @@
 #                               cache_rows (as on engine.decode; here the
 #                               rows the call leaves behind, true_len or
 #                               the chunk's end, x caches); of a model
-#                               with a recurrent state (Mamba layers) a
-#                               whole prefill also scan (kernel | jnp:
-#                               what the selective scan runs through, as
-#                               models.scan_kind says) and scan_rows (the
-#                               rows it runs of the bucket's: the kernel
-#                               stops after the block of rows that holds
-#                               row true_len - 1, models.scan_rows);
-#                               running sum scan_rows, counts
-#                               scan_kernel, scan_jnp
+#                               with a recurrent state (mamba or delta
+#                               layers) a whole prefill also scan (what
+#                               the layers' scan runs through, as
+#                               models.scan_kind says: kernel | jnp of a
+#                               selective scan, jnp of the gated delta
+#                               rule's chunks) and scan_rows (the rows it
+#                               runs of the bucket's: the kernel stops
+#                               after the block of rows that holds row
+#                               true_len - 1, models.scan_rows); running
+#                               sum scan_rows, counts scan_kernel,
+#                               scan_jnp
 #   engine.decode      scoped   table build + dispatch: decoding,
 #                               ahead (1: dispatched while the step
 #                               before was unread, from its tokens on
@@ -161,7 +163,8 @@
 #                               state the step advances), state_bytes
 #                               (host-counted: those slots' state, a
 #                               convolution tail and an SSM state a Mamba
-#                               layer, read and written once) and
+#                               layer or a matrix a head a delta layer,
+#                               read and written once) and
 #                               cache_rows (their live positions x the
 #                               attention layers' K/V caches); running
 #                               sums in engine_stats()

@@ -878,3 +878,107 @@ def test_served_jamba_prefill_scans_through_the_kernel_on_the_v5e(
         assert not _made_of_a_leaf(text, pool[name], " copy("), name
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 7.2e9)
+
+
+# One chip's share of Qwen3-Next as qnext.assist serves it
+# (benchmark/configs/qwen3_next_ep4_l8.json): 6 Gated DeltaNet layers whose
+# state is a slot's beside 2 gated attention layers of 16 query heads of
+# 256 over 2 K/V heads, 128 of 512 experts held a layer; 64 slots of 280
+# blocks
+QNEXT = dict(slots=64, block=32, max_blocks=280, states=6, caches=2)
+
+
+def _served_qnext(for_the_chip, monkeypatch):
+    """(config, params, pool, int32): the share as qnext.assist serves it,
+    the slots' recurrent state among the pool's leaves, placed on the
+    described chip; the delta and expert kernels lowered through Mosaic."""
+    import json
+    import pathlib
+    from aiko_services_tpu.models.configs import qwen3_next_config
+    from aiko_services_tpu.models.transformer import init_recurrent_state
+    from aiko_services_tpu.parallel import delta, experts
+    monkeypatch.setattr(delta, "_interpret", lambda: False)
+    monkeypatch.setattr(experts, "_interpret", lambda: False)
+    published = json.loads((pathlib.Path(__file__).parent.parent
+                            / "benchmark/configs/qwen3_next_ep4_l8.json"
+                            ).read_text())
+    serve = published["serve"]
+    s = QNEXT
+    assert (serve["decode_slots"], serve["kv_block_size"],
+            serve["max_context"]) == (
+        s["slots"], s["block"], s["block"] * s["max_blocks"])
+    assert serve["kv_blocks"] == s["slots"] * s["max_blocks"] + 1
+    config = qwen3_next_config(
+        {key: value for key, value in published.items()
+         if key not in ("serve", "deployment", "assumed", "reduced")},
+        serve["max_context"])
+    assert (config.n_states, config.n_caches) == (s["states"], s["caches"])
+    place = lambda tree: jax.tree_util.tree_map(       # noqa: E731
+        lambda leaf: for_the_chip(leaf.shape, leaf.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: init_params(config, jax.random.PRNGKey(0))))
+    pool = place(jax.eval_shape(lambda: {
+        **init_paged_pool(config, serve["kv_blocks"], s["block"]),
+        **init_recurrent_state(config, s["slots"])}))
+    return config, params, pool, lambda *shape: for_the_chip(shape, "int32")
+
+
+def test_served_qnext_decode_step_copies_no_state_leaf_on_the_v5e(
+        for_the_chip, monkeypatch):
+    """The step advances every slot's convolution tail and S a row, in
+    place: the donated leaves (0.82 GB of state, 2.35 GB of K/V) come
+    back as the buffers they were, no copy of either state leaf, as none
+    of a pool leaf; S through `gdn_step`, which reads a slot's heads
+    where they lie; the 16 query heads of 256 attend over their 2 K/V
+    heads through the paged kernel, which writes the step's new rows;
+    the experts through the grouped matmul."""
+    s = QNEXT
+    config, params, pool, int32 = _served_qnext(for_the_chip, monkeypatch)
+    assert pool["conv"].shape == (6, 3, 64, 8192)
+    assert pool["delta"].shape == (6, 64, 32, 128, 128)
+    assert pool["k"].shape == (2, 17921, 2, 32, 256)
+    slots = s["slots"]
+    compiled = paged_decode_step.lower(
+        params, config, pool, int32(slots, s["max_blocks"]), int32(slots),
+        int32(slots, 1), int32(slots), int32(slots)).compile()
+    text = compiled.as_text()
+    for kernel in ("gdn_step", "paged_attention", "moe_expert_ffn"):
+        assert kernel in text, kernel
+    memory = compiled.memory_analysis()
+    held = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+               for leaf in pool.values())
+    assert memory.alias_size_in_bytes == held
+    assert held == 64 * 6 * 2_146_304 + 2 * 2 * 17921 * 2 * 32 * 256 * 2
+    # the experts' row buffer, twice: 8,832 rows of 2048
+    assert memory.temp_size_in_bytes < 96 << 20
+    for name in ("conv", "delta", "k", "v"):
+        assert not _made_of_a_leaf(text, pool[name], " copy("), name
+    assert not _made_of_a_leaf(text, pool["k"], " dynamic-update-slice(")
+    assert not _made_of_a_leaf(text, pool["delta"],
+                               " dynamic-update-slice(")
+    # weights 7.18 GB + state 0.82 + K/V 2.35: what the chip holds
+    assert 10.3e9 < memory.argument_size_in_bytes < 10.4e9
+
+
+@pytest.mark.parametrize("bucket", [4096, 8192])
+def test_served_qnext_prefill_fits_the_chip_at_its_warm_buckets(
+        for_the_chip, monkeypatch, bucket):
+    """The buckets qnext.assist sends: six chunkwise delta rules whose S
+    crosses the chunks in XLA's scan, two attention layers of 256-wide
+    heads through the flash kernel, the experts' row buffer (114,688 rows
+    at 8192), the slot's state written into the donated leaves; with the
+    arguments under 12 GB of the chip's 16."""
+    s = QNEXT
+    config, params, pool, int32 = _served_qnext(for_the_chip, monkeypatch)
+    compiled = paged_prefill.lower(
+        params, config, pool, int32(1, bucket), int32(s["max_blocks"]),
+        int32(), int32()).compile()
+    text = compiled.as_text()
+    for kernel in ("flash_attention", "moe_expert_ffn"):
+        assert kernel in text, kernel
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.5e9
+    for name in ("conv", "delta"):
+        assert not _made_of_a_leaf(text, pool[name], " copy("), name
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 12e9)
