@@ -999,13 +999,13 @@ def _row_tiles_take(config: TransformerConfig, length: int) -> bool:
     """Whether a whole prefill of `length` rows runs by row tiles: at
     least two whole tiles, and a layer whose row-wise work is a row's
     own (a switch FFN's capacity and a sequence-parallel attention's
-    shards span the sequence; a Mamba layer's convolution reads the
-    three rows before a row, across a tile's edge: a model with a
-    recurrent state runs its bucket whole, and its scan alone stops at
-    the prompt's length, scan_rows)."""
+    shards span the sequence).  A recurrent layer's rows are not their
+    own -- its convolution reads the three rows before a row and its
+    state crosses them all -- and it runs by row tiles all the same, the
+    convolution's tail and the state carried from tile to tile
+    (_stateful_layer)."""
     return (length >= 2 * _ROW_TILE and length % _ROW_TILE == 0
-            and config.n_experts == 0 and not config.sequence_parallel
-            and not config.recurrent)
+            and config.n_experts == 0 and not config.sequence_parallel)
 
 
 def _tiled(config: TransformerConfig, true_len, length: int):
@@ -1029,7 +1029,7 @@ def prefill_rows(config: TransformerConfig, bucket: int,
     return -(-true_len // _ROW_TILE) * _ROW_TILE
 
 
-def _row_tiles(live, fn, layer, *operands):
+def _row_tiles(live, fn, layer, *operands, carry=None, outputs=None):
     """fn(layer, *operands), whose every output row depends on the
     operands' same row alone, rows being the axis before the last:
     (B, L, d), (B, H, L, hd).  live None: the one call.  Else `live` is
@@ -1038,7 +1038,14 @@ def _row_tiles(live, fn, layer, *operands):
     tiles that hold one -- a loop with a traced trip count, so one
     program serves every `live` -- and the rows of the other tiles are
     zeros in every output.  An output of one axis (the FFN's stats) is
-    summed over the tiles."""
+    summed over the tiles.
+    Given a `carry` (what crosses a tile's edge: a recurrent layer's
+    state) the loop threads it through the tiles in order:
+    fn(layer, carry, rows, *operands), told how many of its tile's rows
+    are live, returns (carry, outputs), and so does _row_tiles, the carry
+    as the last live tile left it.  Such a caller hands in `outputs`, the
+    whole outputs' zeros: a row-wise segment is traced once more for
+    their shapes, in milliseconds; a layer with a kernel in it is not."""
     if live is None:
         return fn(layer, *operands)
 
@@ -1052,19 +1059,28 @@ def _row_tiles(live, fn, layer, *operands):
         return jax.lax.dynamic_update_slice_in_dim(whole, part, start,
                                                    whole.ndim - 2)
 
-    def body(index, outputs):
+    def body(index, state):
+        carry, outputs = state
         start = index * _ROW_TILE
-        return jax.tree_util.tree_map(
-            partial(put, start), outputs,
-            fn(layer.within(index), *tiles(start)))
+        if carry is None:
+            parts = fn(layer.within(index), *tiles(start))
+        else:
+            carry, parts = fn(layer.within(index), carry,
+                              jnp.minimum(live - start, _ROW_TILE),
+                              *tiles(start))
+        return carry, jax.tree_util.tree_map(partial(put, start), outputs,
+                                             parts)
 
-    length = operands[0].shape[-2]
-    outputs = jax.tree_util.tree_map(
-        lambda part: jnp.zeros(
-            part.shape if part.ndim < 2 else
-            part.shape[:-2] + (length,) + part.shape[-1:], part.dtype),
-        jax.eval_shape(lambda: fn(layer, *tiles(0))))
-    return jax.lax.fori_loop(0, -(-live // _ROW_TILE), body, outputs)
+    if carry is None:
+        length = operands[0].shape[-2]
+        outputs = jax.tree_util.tree_map(
+            lambda part: jnp.zeros(
+                part.shape if part.ndim < 2 else
+                part.shape[:-2] + (length,) + part.shape[-1:], part.dtype),
+            jax.eval_shape(lambda: fn(layer, *tiles(0))))
+    state = jax.lax.fori_loop(0, -(-live // _ROW_TILE), body,
+                              (carry, outputs))
+    return state[1] if carry is None else state
 
 
 def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend,
@@ -1129,24 +1145,27 @@ def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend,
 
 
 def scan_kind(config: TransformerConfig, length: int) -> str:
-    """What a recurrent layer's scan over `length` rows runs through:
-    "kernel" or "jnp".  A Mamba layer's selective scan (parallel/ssm.py)
-    decides by ssm_scan_takes, as ssm_scan does; a delta layer's chunkwise
-    recurrence (parallel/delta.py) is XLA's.  The engine names its prefill
-    spans by this."""
+    """What a recurrent layer's scan runs through in a whole prefill of
+    `length` rows: "kernel" or "jnp".  A Mamba layer's selective scan
+    (parallel/ssm.py) decides by ssm_scan_takes, as ssm_scan does, on the
+    rows a call is handed (a row tile's where the bucket runs by row
+    tiles); a delta layer's chunkwise recurrence (parallel/delta.py) is
+    XLA's.  The engine names its prefill spans by this."""
     if config.recurrent_kind == "delta":
         return "jnp"
+    rows = _ROW_TILE if _row_tiles_take(config, length) else length
     return "kernel" if ssm_scan_takes(
-        length, config.ssm_d_inner, config.ssm_d_state,
+        rows, config.ssm_d_inner, config.ssm_d_state,
         config.jnp_dtype) else "jnp"
 
 
 def scan_rows(config: TransformerConfig, bucket: int, true_len: int) -> int:
     """The rows a recurrent layer's scan runs of a whole prefill of
-    `bucket` rows for a prompt of `true_len` tokens: a kernel stops after
-    the block of rows that holds row true_len - 1, XLA's form runs the
-    bucket (the rows past true_len leaving the state alone)."""
-    return ssm_scan_rows(bucket, true_len,
+    `bucket` rows for a prompt of `true_len` tokens, of the rows the
+    layer runs at all (prefill_rows): a kernel stops after the block of
+    rows that holds row true_len - 1, XLA's form runs them all (the rows
+    past true_len leaving the state alone)."""
+    return ssm_scan_rows(prefill_rows(config, bucket, true_len), true_len,
                          scan_kind(config, bucket) != "jnp")
 
 
@@ -1208,22 +1227,6 @@ def _mamba_mixer(config: TransformerConfig, layer, u, tail, ssm, stop):
         y, ssm = ssm_scan(c, dt, z, b, cc, a, layer["d"], layer["dt_bias"],
                           ssm, stop)
     return dense(layer["w_out"], y), tail, ssm
-
-
-def _mamba_layer(config: TransformerConfig, layer, h, state, stop=None):
-    """A Mamba layer: h + mixer(norm(h)), then the dense FFN as a decoder
-    layer has it.  `state` is the layer's {"conv", "ssm"} for h's B
-    sequences, or None: zeros, a sequence from its start.  Returns (h,
-    the FFN's stats, the new state)."""
-    if state is None:
-        state = _layer_state(config, h.shape[0])
-    out, tail, ssm = _mamba_mixer(
-        config, layer, rms_norm(layer["mixer_norm"], h, config.norm_eps),
-        state["conv"], state["ssm"], stop)
-    h = h + out
-    mlp_out, stats = _mlp_block(
-        config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps))
-    return h + mlp_out, stats, {"conv": tail, "ssm": ssm}
 
 
 def _delta_mixer(config: TransformerConfig, layer, u, tail, state, stop):
@@ -1291,30 +1294,101 @@ def _delta_mixer(config: TransformerConfig, layer, u, tail, state, stop):
             state)
 
 
-def _delta_layer(config: TransformerConfig, layer, h, state, stop=None):
-    """A Gated DeltaNet layer: h + mixer(norm(h)), then the FFN as a
-    decoder layer has it.  `state` is the layer's {"conv", "delta"} for
-    h's B sequences (its "delta" as _delta_mixer takes it), or None:
-    zeros, a sequence from its start.  Returns (h, the FFN's stats, the
-    new state)."""
-    if state is None:
-        state = _layer_state(config, h.shape[0])
-    out, tail, delta = _delta_mixer(
-        config, layer, rms_norm(layer["mixer_norm"], h, config.norm_eps),
-        state["conv"], state["delta"], stop)
-    h = h + out
-    mlp_out, stats = _mlp_block(
-        config, layer, rms_norm(layer["mlp_norm"], h, config.norm_eps),
-        stop)
-    return h + mlp_out, stats, {"conv": tail, "delta": delta}
-
-
 def _layer_kind(layer) -> str:
     """The kind of a layer (or of a stack of like layers), by the leaf
     only that kind's mixer has."""
     if "w_in" in layer:
         return "mamba"
     return "delta" if "w_qkvz" in layer else "attention"
+
+
+# a recurrent kind's mixer and the leaf of its state beside "conv"
+_MIXERS = {"mamba": (_mamba_mixer, "ssm"), "delta": (_delta_mixer, "delta")}
+
+
+def _recurrent_rows(config: TransformerConfig, layer, carry, stop, h,
+                    apart: bool = False):
+    """A recurrent layer over the rows h (B, L, d), a whole sequence's or
+    a row tile's, from `carry`, the convolution's tail and the recurrent
+    state before row 0: norm, mixer, residual, MLP norm, FFN, residual.
+    Returns (the carry after row stop - 1, (h, the FFN's stats)) -- or,
+    `apart` (the routed experts, which group the rows of the whole
+    sequence, not of a tile), (the carry, (h before the FFN, the FFN's
+    input))."""
+    mixer, _ = _MIXERS[_layer_kind(layer)]
+    out, *carry = mixer(
+        config, layer, rms_norm(layer["mixer_norm"], h, config.norm_eps),
+        *carry, stop)
+    h = h + out
+    mlp_in = rms_norm(layer["mlp_norm"], h, config.norm_eps)
+    if apart:
+        return tuple(carry), (h, mlp_in)
+    mlp_out, stats = _mlp_block(config, layer, mlp_in, stop)
+    return tuple(carry), (h + mlp_out, stats)
+
+
+# _recurrent_rows of one row tile, the layer's leaves sliced out of their
+# stack handed in: a jit of its own.  Once its layers are _LayerAts every
+# run of layers scans a body of its own (Jamba2-3B: three of Mamba layers,
+# where the bucket run whole shares one), and start-up pays for each
+# (PR 43: +1.45 s a prefill program, refused on setup_s).  The runs' loops
+# slice their weights and call this: one traced and one lowered layer,
+# scan kernel and all, and since a tile's shapes do not know the bucket,
+# one a process, not one a bucket's program
+_recurrent_tile = jax.jit(_recurrent_rows,
+                          static_argnames=("config", "apart"))
+
+
+def _stateful_layer(config: TransformerConfig, layer, h, state, stop):
+    """_mamba_layer and _delta_layer: h + mixer(norm(h)), then the FFN as
+    a decoder layer has it (_recurrent_rows), the kind by the layer's
+    leaves.  Where the bucket runs by row tiles (_tiled) the layer runs a
+    tile at a time over the tiles that hold a live row, in one loop that
+    carries what crosses a tile's edge and nothing else -- the
+    convolution's tail and the recurrent state, each tile stopping at its
+    share of `stop` -- so the last live tile leaves the state after row
+    stop - 1 and the tail at `stop`, as the bucket run whole does, and h
+    is zeros past the live tiles.  The routed experts stay between the
+    loop and the residual, as in _decoder_layer."""
+    _, leaf = _MIXERS[_layer_kind(layer)]
+    if state is None:
+        state = _layer_state(config, h.shape[0])
+    carry = (state["conv"], state[leaf])
+    live = _tiled(config, stop, h.shape[1])
+    if live is None:
+        carry, (h, stats) = _recurrent_rows(config, layer, carry, stop, h)
+    else:
+        apart = bool(config.top_k and "router" in layer)
+        zeros = jnp.zeros_like(h)
+        carry, (h, stats) = _row_tiles(
+            live, lambda layer, *rows: _recurrent_tile(
+                config, layer.sliced(), *rows, apart=apart),
+            layer, h, carry=carry, outputs=(
+                zeros, zeros if apart else jnp.zeros((_FFN_STATS,),
+                                                     jnp.float32)))
+        if apart:
+            mlp_out, stats = _routed_moe(config, layer, stats, stop)
+            h = h + mlp_out
+    return h, stats, dict(zip(("conv", leaf), carry))
+
+
+def _mamba_layer(config: TransformerConfig, layer, h, state, stop=None):
+    """A Mamba layer: h + mixer(norm(h)), then the dense FFN as a decoder
+    layer has it.  `state` is the layer's {"conv", "ssm"} for h's B
+    sequences, or None: zeros, a sequence from its start; `stop` (traced,
+    a whole prefill's true length; None: every row counts) the mixer's,
+    and by it the layer runs by row tiles where the bucket does
+    (_stateful_layer).  Returns (h, the FFN's stats, the new state)."""
+    return _stateful_layer(config, layer, h, state, stop)
+
+
+def _delta_layer(config: TransformerConfig, layer, h, state, stop=None):
+    """A Gated DeltaNet layer: h + mixer(norm(h)), then the FFN as a
+    decoder layer has it.  `state` is the layer's {"conv", "delta"} for
+    h's B sequences (its "delta" as _delta_mixer takes it), or None:
+    zeros, a sequence from its start; `stop` as _mamba_layer's.  Returns
+    (h, the FFN's stats, the new state)."""
+    return _stateful_layer(config, layer, h, state, stop)
 
 
 def _recurrent_layer(layer):
@@ -1659,6 +1733,12 @@ class _LayerAt:
         index, _ = jax.lax.optimization_barrier((self._index, step))
         return _LayerAt(self._stack, index, self._whole)
 
+    def sliced(self) -> dict:
+        """The layer's leaves, each sliced out of its stack here: what a
+        jit of the layer's rows takes (the leaves held whole, the routed
+        experts', are not among them)."""
+        return {name: self[name] for name in self._stack}
+
     def __contains__(self, name) -> bool:
         return name in self._stack or name in self._whole
 
@@ -1949,8 +2029,10 @@ def _hidden(params: dict, config: TransformerConfig, tokens, cache, pos,
     _logits takes.  `true_len` (traced) says that only the first true_len
     rows matter, the rest being padding: a call into a cache from the
     static position 0 whose length _row_tiles_take takes then runs every
-    layer's row-wise work over the live row tiles alone (_decoder_layer),
-    and h, the outputs and the cache hold zeros past them; and, whatever
+    layer's work over the live row tiles alone (_decoder_layer's row-wise
+    segments; a recurrent layer whole, its state carried from tile to
+    tile, _stateful_layer), and h, the outputs and the cache hold zeros
+    past them; and, whatever
     _row_tiles_take says, an attention through the flash kernel is told
     the length and runs the query blocks that hold a live row
     (prefill_attention_rows)."""
@@ -2212,9 +2294,9 @@ def paged_prefill(params, config: TransformerConfig, pool, prompt,
     independent of the right-padding, and the head runs at that one
     position.  One executable per bucket, which does the work of the
     prompt, not of the bucket: a bucket that runs by row tiles
-    (_row_tiles_take) runs a layer's row-wise work up to true_len and the
-    blocks past them receive zeros (a decode step writes a position
-    before it reads it).  The decode loop never recompiles
+    (_row_tiles_take) runs a layer's row-wise work, and a recurrent layer,
+    up to true_len and the blocks past them receive zeros (a decode step
+    writes a position before it reads it).  The decode loop never recompiles
     (paged_decode_step below).  A model with recurrent layers is also told
     its `slot` (traced int32): the recurrent state after row true_len - 1
     overwrites the whole of that slot's, whatever the bucket."""

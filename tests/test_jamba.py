@@ -32,7 +32,9 @@ from aiko_services_tpu.models.transformer import (
 from aiko_services_tpu.parallel import ssm
 from benchmark.reference import jamba as reference
 from test_prefill_rows import (                         # noqa: F401
-    LIVE_BUCKET, check_live_attention_prefill, live_attention)
+    EDGE_BUCKET, EDGE_LENGTHS, EDGE_TILE, LIVE_BUCKET,
+    assert_tiled_is_the_whole_buckets, check_live_attention_prefill,
+    hidden_whole_and_tiled, live_attention)
 
 PUBLISHED = {
     "model_type": "jamba", "vocab_size": 256, "hidden_size": 64,
@@ -387,18 +389,83 @@ def test_engine_serves_the_reference_through_the_scan_kernel(
         jax.clear_caches()
 
 
+# -- (c') a whole prefill by row tiles, the state carried (ISSUE 44) ------------
+#
+# tests/test_prefill_rows.py holds the hybrid's tiled prefill to the whole
+# bucket's at every length around a tile's edge, its scan the oracle's (a
+# toy tile is under the kernel's rows).  Here the same through the Pallas
+# scan, interpreted: tiles of 16 rows of a 64-row bucket, each tile one
+# kernel call, the state handed from call to call.
+
+
+@pytest.fixture
+def scan_kernel_at_toy_sizes(monkeypatch):
+    """set(row tile): the scan kernel taken from 8 rows, the row tile as
+    told, for the programs traced from here on."""
+    monkeypatch.setattr(ssm, "_SCAN_MIN_ROWS", 8)
+    monkeypatch.setattr(ssm, "_SCAN_ROWS", EDGE_TILE)
+
+    def set_tile(rows: int) -> None:
+        monkeypatch.setattr(transformer, "_ROW_TILE", rows)
+        jax.clear_caches()
+    yield set_tile
+    jax.clear_caches()
+
+
+_THROUGH_THE_KERNEL: dict = {}
+
+
+@pytest.mark.parametrize("true_len", EDGE_LENGTHS)
+def test_a_tiled_prefill_through_the_scan_kernel_is_the_whole_buckets(
+        model, scan_kernel_at_toy_sizes, true_len):
+    """Every Mamba layer's tail and state, and what reads them: the
+    tiles' kernel calls, the state carried between them, leave what the
+    bucket's one call leaves (assert_tiled_is_the_whole_buckets)."""
+    if not _THROUGH_THE_KERNEL:
+        config, params, _ = model
+        assert transformer.scan_kind(config, EDGE_BUCKET) == "kernel"
+        _THROUGH_THE_KERNEL.update(hidden_whole_and_tiled(
+            config, params, np.asarray(some_tokens(1, EDGE_BUCKET, seed=13)),
+            scan_kernel_at_toy_sizes))
+    assert_tiled_is_the_whole_buckets(_THROUGH_THE_KERNEL, true_len,
+                                      TOLERANCE)
+
+
+def test_engine_serves_the_reference_by_row_tiles(
+        model, scan_kernel_at_toy_sizes):
+    """The engine over a bucket of four row tiles of which three hold a
+    live row: the counters say what ran, the scan's rows with the
+    layer's, and the tokens are the reference's."""
+    config, params, shape = model
+    scan_kernel_at_toy_sizes(EDGE_TILE)
+    assert transformer._row_tiles_take(config, EDGE_BUCKET)
+    engine = DecodeEngine(params, config, decode_slots=1, kv_block_size=8,
+                          max_context=EDGE_BUCKET + 16)
+    prompt = np.asarray(some_tokens(1, 37, seed=41))[0]   # bucket 64
+    engine.submit("r", prompt, 11)
+    done = drain(engine)
+    stats = engine.stats()
+    assert (stats["prefill_rows_run"], stats["prefill_rows_bucket"]) == (
+        48, EDGE_BUCKET)
+    assert (stats["scan_kernel"], stats["scan_jnp"]) == (1, 0)
+    assert stats["scan_rows"] == 48               # 37 up to a block of 16
+    assert_served_is_the_references(shape, prompt, done["r"].tokens)
+
+
 def test_the_attention_layers_are_told_the_prompts_length(
         model, live_attention, monkeypatch):
-    """PR 40: the hybrid's row tiles are refused, its two attention
-    layers' condition is the kernel's own -- a whole prefill whose bucket
-    attends through the flash kernel tells it the true length, and
-    first token, logits, K/V rows below true_len and the slot's state are
-    the whole bucket's attention's (tests/test_prefill_rows.py's check:
-    `wo` and the Mamba layers after it see zeros in the dead blocks'
-    rows, which nothing below true_len reads)."""
+    """PR 40: the attention layers' condition is the kernel's own, whether
+    or not the bucket runs by row tiles (this one, under two tiles, does
+    not) -- a whole prefill whose bucket attends through the flash kernel
+    tells it the true length, and first token, logits, K/V rows below
+    true_len and the slot's state are the whole bucket's attention's
+    (tests/test_prefill_rows.py's check: `wo` and the Mamba layers after
+    it see zeros in the dead blocks' rows, which nothing below true_len
+    reads)."""
     config, params, _ = model
     config = dataclasses.replace(config, max_seq_len=LIVE_BUCKET)
-    assert not transformer._row_tiles_take(config, 2048)
+    assert transformer._row_tiles_take(config, 2048)
+    assert not transformer._row_tiles_take(config, LIVE_BUCKET)
     check_live_attention_prefill(
         config, params, monkeypatch,
         lambda: {**init_paged_pool(config, LIVE_BUCKET // 32 + 1, 32),

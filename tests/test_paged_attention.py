@@ -873,11 +873,54 @@ def test_served_jamba_prefill_scans_through_the_kernel_on_the_v5e(
     text = compiled.as_text()
     assert "ssm_chunk_scan" in text
     memory = compiled.memory_analysis()
+    # 146 MB with the bucket run whole; 67 MB by row tiles (ISSUE 44)
     assert memory.temp_size_in_bytes < 1 << 29
     for name in ("conv", "ssm"):
         assert not _made_of_a_leaf(text, pool[name], " copy("), name
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 7.2e9)
+    # the tile loop inside a Mamba layer reads w_in where it lies in the
+    # run's stack (52 MB a layer: _LayerAt's slice, tied to the loop)
+    assert not _staged_whole(text, {2560 * 2 * 5120})
+
+
+def _traced(monkeypatch, kind: str) -> list:
+    """A list that grows by one each time the mixer of recurrent layers
+    of `kind` is traced from here on."""
+    from aiko_services_tpu.models import transformer
+    mixer, leaf = transformer._MIXERS[kind]
+    calls = []
+
+    def counted(*args):
+        calls.append(kind)
+        return mixer(*args)
+
+    monkeypatch.setitem(transformer._MIXERS, kind, (counted, leaf))
+    return calls
+
+
+def test_served_jamba_prefill_traces_a_mamba_layer_and_each_kernel_once(
+        for_the_chip, monkeypatch):
+    """What start-up pays for the 4096 bucket (ISSUE 44; PR 43 was
+    refused on setup_s, +1.45 s a program of tracing and lowering): by
+    row tiles each of the five runs of layers scans a body of its own,
+    and still the Mamba layer is traced once (the three runs share one
+    jitted tile, the 26 layers one body as on the parent) and each Pallas
+    kernel lowered once (`ssm_chunk_scan` and the flash kernel: two
+    custom calls, as on the parent)."""
+    s = JAMBA
+    config, params, pool, int32 = _served_jamba(for_the_chip, monkeypatch)
+    calls = _traced(monkeypatch, "mamba")
+    jax.clear_caches()
+    text = paged_prefill.lower(
+        params, config, pool, int32(1, 4096), int32(s["max_blocks"]),
+        int32(), int32()).as_text()
+    jax.clear_caches()
+    assert len(calls) == 1
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+    assert text.count("func.func private @_recurrent_rows") == 1
+    # the runs' layer scans, a tile loop a Mamba run, two an attention run
+    assert text.count("stablehlo.while") == 5 + 3 + 2 * 2
 
 
 # One chip's share of Qwen3-Next as qnext.assist serves it
@@ -982,3 +1025,25 @@ def test_served_qnext_prefill_fits_the_chip_at_its_warm_buckets(
         assert not _made_of_a_leaf(text, pool[name], " copy("), name
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 12e9)
+    # the tile loop inside a delta layer reads w_qkvz where it lies in the
+    # run's stack (50 MB a layer)
+    assert not _staged_whole(text, {2048 * (8192 + 4096)})
+
+
+def test_served_qnext_prefill_traces_a_delta_layer_once(for_the_chip,
+                                                        monkeypatch):
+    """The 8192 bucket's start-up cost, as Jamba's: the two runs of delta
+    layers share one traced and one lowered tile, chunkwise rule and all,
+    and no kernel is lowered more often than on the parent (the flash
+    kernel once, the experts' once a run's body: five custom calls)."""
+    s = QNEXT
+    config, params, pool, int32 = _served_qnext(for_the_chip, monkeypatch)
+    calls = _traced(monkeypatch, "delta")
+    jax.clear_caches()
+    text = paged_prefill.lower(
+        params, config, pool, int32(1, 8192), int32(s["max_blocks"]),
+        int32(), int32()).as_text()
+    jax.clear_caches()
+    assert len(calls) == 1
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 5
+    assert text.count("func.func private @_recurrent_rows") == 1

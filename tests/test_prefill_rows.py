@@ -10,7 +10,12 @@ the bucket: what every bucket under two tiles still traces):
 
   - the first token, every row below true_len of every pool leaf, and the
     token a decode step then gives, for a dense, an int8-cache, a latent +
-    routed and a looped configuration;
+    routed and a looped configuration, and for the two hybrids (ISSUE 44):
+    Mamba layers among attention layers, and Gated DeltaNet layers with
+    routed experts, whose recurrent layers run by row tiles too, the
+    convolution's tail and the state carried from tile to tile -- the
+    slot's state is the whole bucket's at lengths on, just past and just
+    short of a tile's edge (the convolution's three rows on both sides);
   - rows of dead tiles zero and finite in every leaf;
   - the live rows bit for bit against the SAME program with every tile
     live (true_len = the bucket): a dead tile changes no live row.  Against
@@ -35,6 +40,11 @@ from test_parallel import pallas_calls
 
 TILE, BUCKET, BLOCK = 8, 32, 4
 LENGTHS = (1, TILE - 1, TILE, TILE + 1, BUCKET - 1, BUCKET)
+# of a model with a recurrent state: at a tile's edge k, one and three rows
+# past it and one row short of it, for k = 1 and 2 (two and one live tiles
+# short of the bucket), at the third edge, and the bucket
+EDGES = tuple(k * TILE + more for k in (1, 2) for more in (0, 1, 3, -1))
+HYBRID_LENGTHS = EDGES + (3 * TILE, BUCKET)
 
 DENSE = dict(vocab_size=97, d_model=32, n_layers=2, n_heads=4,
              n_kv_heads=2, d_ff=64, max_seq_len=64, dtype="float32")
@@ -55,7 +65,30 @@ CASES = {
     # Ouro's: the stack run twice, sandwich norms, the exit gate
     "looped": {"ut_steps": 2, "sandwich_norm": True,
                "exit_threshold": 0.6},
+    # Jamba's: runs of 1, 1 and 2 layers, no rotary, 4 taps
+    "mamba_hybrid": {
+        "n_layers": 4, "n_kv_heads": 1, "rotary": False,
+        "layer_kinds": ("mamba", "attention", "mamba", "mamba"),
+        "ssm_d_inner": 64, "ssm_d_state": 8, "ssm_d_conv": 4,
+        "ssm_dt_rank": 4},
+    # Qwen3-Next's: runs of 2, 1 and 1 layers, every FFN routed experts
+    # (all held) and a gated shared expert, gated attention of 16-wide
+    # heads a quarter of which rotates, 2 key heads serving 4 value heads
+    "delta_hybrid": {
+        "n_layers": 4, "n_kv_heads": 1,
+        "layer_kinds": ("delta", "delta", "attention", "delta"),
+        "top_k": 2, "n_routed_experts": 8, "n_shared_experts": 1,
+        "moe_d_ff": 16, "norm_topk": True, "shared_expert_gate": True,
+        "attn_head_dim": 16, "rotary_fraction": 0.25, "qk_norm": True,
+        "gated_attention": True, "delta_key_heads": 2,
+        "delta_value_heads": 4, "delta_key_dim": 8, "delta_value_dim": 12,
+        "delta_conv": 4},
 }
+HYBRIDS = ("delta_hybrid", "mamba_hybrid")
+
+
+def _lengths(case: str) -> tuple:
+    return HYBRID_LENGTHS if case in HYBRIDS else LENGTHS
 
 
 def _model(case: str):
@@ -88,11 +121,16 @@ def tile(monkeypatch):
 def _prefill(config, params, true_len: int):
     """(the pool's leaves as (caches, H, bucket rows, d) through the
     table, the first token, the token a decode step gives after it)."""
-    pool = init_paged_pool(config, 10, BLOCK)
+    pool = {**init_paged_pool(config, 10, BLOCK),
+            **transformer.init_recurrent_state(config, 1)}
+    slot = {"slot": np.int32(0)} if config.recurrent else {}
     pool, first = paged_prefill(params, config, pool, _prompt(), TABLE,
-                                np.int32(true_len))
+                                np.int32(true_len), **slot)
     rows = {}
     for name, leaf in pool.items():
+        if name in transformer._STATE_LEAVES:
+            rows[name] = np.asarray(leaf)           # the one slot's state
+            continue
         held = np.asarray(leaf)[:, TABLE[:BLOCKS]]  # (caches, blocks, H, B, d)
         rows[name] = held.transpose(0, 2, 1, 3, 4).reshape(
             held.shape[0], held.shape[2], BUCKET, held.shape[-1])
@@ -115,7 +153,7 @@ def _ran(case: str, tile) -> dict:
         found = {}
         for what, rows in (("whole", 1 << 20), ("live", TILE)):
             tile(rows)
-            for true_len in LENGTHS:
+            for true_len in _lengths(case):
                 assert prefill_rows(config, BUCKET, true_len) == (
                     BUCKET if what == "whole"
                     else -(-true_len // TILE) * TILE)
@@ -124,8 +162,9 @@ def _ran(case: str, tile) -> dict:
     return _RAN[case]
 
 
-@pytest.mark.parametrize("true_len", LENGTHS)
-@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("case,true_len", [
+    (case, true_len) for case in sorted(CASES)
+    for true_len in _lengths(case)])
 def test_live_rows_prefill_is_the_whole_prefill_below_true_len(
         tile, case, true_len):
     ran = _ran(case, tile)
@@ -137,6 +176,13 @@ def test_live_rows_prefill_is_the_whole_prefill_below_true_len(
     assert (first, after) == (whole_first, whole_after)
     assert set(live) == set(whole)
     for name, leaf in live.items():
+        if name in transformer._STATE_LEAVES:
+            # the state after row true_len - 1, the tail at true_len: what
+            # the last live tile left is what the bucket run whole leaves
+            np.testing.assert_allclose(leaf, whole[name], atol=2e-5,
+                                       rtol=1e-5, err_msg=name)
+            assert np.abs(leaf).max() > 1e-3, name
+            continue
         below = np.s_[:, :, :true_len]
         if leaf.dtype == np.int8:
             # a code may differ by a step where the row's value did by
@@ -280,6 +326,67 @@ def test_flash_attention_sees_zeros_in_the_dead_tiles(tile, monkeypatch):
             leaf[:, :, :TILE + 3], whole[name][:, :, :TILE + 3], atol=2e-5,
             rtol=1e-5, err_msg=name)
         assert not leaf[:, :, 2 * TILE:].any(), name
+
+
+# -- a hybrid's tiles through its kernel or its chunkwise form (ISSUE 44) ---
+#
+# The hybrid cases above run a toy tile, under the scan kernel's rows and
+# under a chunk of the delta rule: the oracles'.  tests/test_jamba.py and
+# tests/test_qwen3_next.py steer the kernel and the chunkwise form to tiles
+# of 16 rows of a 64-row bucket and hold them with these two.
+
+EDGE_TILE, EDGE_BUCKET = 16, 64
+EDGE_LENGTHS = tuple(k * EDGE_TILE + more for k in (1, 2)
+                     for more in (0, 1, 3, -1)) + (3 * EDGE_TILE,)
+
+
+def hidden_whole_and_tiled(config, params, prompt, set_tile) -> dict:
+    """{("whole" | "tiled", true_len): (logits at true_len - 1, h, the
+    FFNs' stats, the cache)} of _hidden over `prompt` (1, EDGE_BUCKET) at
+    EDGE_LENGTHS, each of the two programs traced once; set_tile(rows)
+    sets the row tile and clears the jitted programs."""
+    @jax.jit
+    def hidden(true_len):
+        h, outputs, stats, cache = transformer._hidden(
+            params, config, prompt, init_cache(config, 1, EDGE_BUCKET), 0,
+            true_len=true_len)
+        last = jax.lax.dynamic_slice_in_dim(h, true_len - 1, 1, 1)
+        return (transformer._logits(params, config, last, outputs)[0], h,
+                stats, cache)
+
+    found = {}
+    for what, rows in (("whole", 1 << 20), ("tiled", EDGE_TILE)):
+        set_tile(rows)
+        for true_len in EDGE_LENGTHS:
+            with jax.default_matmul_precision("highest"):
+                found[what, true_len] = jax.tree_util.tree_map(
+                    np.asarray, hidden(np.int32(true_len)))
+    return found
+
+
+def assert_tiled_is_the_whole_buckets(ran: dict, true_len: int,
+                                      atol: float) -> None:
+    """Logits at true_len - 1, h and K/V rows below true_len and every
+    recurrent layer's tail and state: the tiles' calls, the state carried
+    between them, leave what the bucket's one call leaves; h and the K/V
+    are zeros past the live tiles."""
+    logits, h, _, cache = ran["tiled", true_len]
+    whole_logits, whole_h, _, whole_cache = ran["whole", true_len]
+    rows = -(-true_len // EDGE_TILE) * EDGE_TILE
+    np.testing.assert_allclose(logits, whole_logits, atol=atol, rtol=0)
+    np.testing.assert_allclose(h[:, :true_len], whole_h[:, :true_len],
+                               atol=atol, rtol=0)
+    assert not h[:, rows:].any()
+    for name, leaf in cache.items():
+        if name in transformer._STATE_LEAVES:
+            np.testing.assert_allclose(leaf, whole_cache[name], atol=atol,
+                                       rtol=0, err_msg=name)
+            assert np.abs(leaf).max() > 1e-3, name
+            continue
+        np.testing.assert_allclose(
+            leaf[:, :, :, :true_len], whole_cache[name][:, :, :, :true_len],
+            atol=atol, rtol=0, err_msg=name)
+        assert not leaf[:, :, :, rows:].any(), name
 
 
 # -- the attention does the work of its prompt too (PR 40) -----------------

@@ -40,6 +40,9 @@ from aiko_services_tpu.models.transformer import (
     init_params, make_train_step, param_specs, quantize_weights_int8)
 from aiko_services_tpu.parallel import delta
 from benchmark.reference import qwen3_next as reference
+from test_prefill_rows import (
+    EDGE_BUCKET, EDGE_LENGTHS, EDGE_TILE, assert_tiled_is_the_whole_buckets,
+    hidden_whole_and_tiled)
 
 PUBLISHED = {
     "model_type": "qwen3_next", "vocab_size": 256, "hidden_size": 64,
@@ -589,6 +592,70 @@ def test_engine_serves_the_reference_through_the_chunkwise_scan(
         assert_served_is_the_references(model, prompt, done["r"].tokens)
     finally:
         jax.clear_caches()
+
+
+# -- (d') a whole prefill by row tiles, the state carried (ISSUE 44) ------------
+#
+# tests/test_prefill_rows.py holds a delta hybrid's tiled prefill to the
+# whole bucket's at every length around a tile's edge, its rule the oracle's
+# (a toy tile is under a chunk).  Here the same through the chunkwise form:
+# tiles of 16 rows of a 64-row bucket, each tile two chunks of 8 rows, S and
+# the convolution's tail handed from tile to tile, the routed experts
+# between the layer's loop and its residual.
+
+
+@pytest.fixture
+def chunks_at_toy_sizes(monkeypatch):
+    """set(row tile): chunks of 8 rows, the row tile as told, for the
+    programs traced from here on."""
+    monkeypatch.setattr(delta, "_CHUNK", 8)
+
+    def set_tile(rows: int) -> None:
+        monkeypatch.setattr(transformer, "_ROW_TILE", rows)
+        jax.clear_caches()
+    yield set_tile
+    jax.clear_caches()
+
+
+_THROUGH_THE_CHUNKS: dict = {}
+
+
+@pytest.mark.parametrize("true_len", EDGE_LENGTHS)
+def test_a_tiled_prefill_through_the_chunkwise_rule_is_the_whole_buckets(
+        model, chunks_at_toy_sizes, true_len):
+    """Every delta layer's tail and S, what reads them, and every live
+    row's expert pairs: the tiles' chunkwise calls, S carried between
+    them, leave what the bucket's one call leaves
+    (assert_tiled_is_the_whole_buckets)."""
+    if not _THROUGH_THE_CHUNKS:
+        config, params, _, _ = model
+        _THROUGH_THE_CHUNKS.update(hidden_whole_and_tiled(
+            config, params, np.asarray(some_tokens(1, EDGE_BUCKET, seed=13)),
+            chunks_at_toy_sizes))
+    for what in ("tiled", "whole"):
+        assert _THROUGH_THE_CHUNKS[what, true_len][2][2] == true_len * 3 * 8
+    assert_tiled_is_the_whole_buckets(_THROUGH_THE_CHUNKS, true_len, 1e-4)
+
+
+def test_engine_serves_the_reference_by_row_tiles(model,
+                                                  chunks_at_toy_sizes):
+    """The engine over a bucket of four row tiles of which three hold a
+    live row: the counters say what ran, the rule's rows with the
+    layer's, and the tokens are the reference's."""
+    config, params, _, _ = model
+    chunks_at_toy_sizes(EDGE_TILE)
+    assert transformer._row_tiles_take(config, EDGE_BUCKET)
+    engine = DecodeEngine(params, config, decode_slots=1, kv_block_size=8,
+                          max_context=EDGE_BUCKET + 16)
+    prompt = np.asarray(some_tokens(1, 37, seed=41))[0]     # bucket 64
+    engine.submit("r", prompt, 11)
+    done = drain(engine)
+    stats = engine.stats()
+    assert (stats["prefill_rows_run"], stats["prefill_rows_bucket"]) == (
+        48, EDGE_BUCKET)
+    assert (stats["scan_jnp"], stats["scan_kernel"]) == (1, 0)
+    assert stats["scan_rows"] == 48
+    assert_served_is_the_references(model, prompt, done["r"].tokens)
 
 
 # -- (e) what is refused by name ------------------------------------------------
