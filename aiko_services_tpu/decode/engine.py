@@ -81,7 +81,7 @@ from ..models import (
     paged_decode_step, paged_prefill, paged_prefill_chunk,
     paged_verify_step, pool_write_kind, prefill_attention_rows,
     prefill_rows, scan_kind,
-    scan_rows)
+    scan_rows, state_step_kind)
 from ..observe.trace import NO_SPANS
 from ..parallel.attention import paged_live_blocks
 from ..runtime.compile_cache import compile_bracket, setup_interval
@@ -347,7 +347,8 @@ class DecodeEngine:
                          "ut_passes": 0, "cache_rows": 0,
                          "exit_expected_step": 0.0,
                          "state_slots": 0, "state_bytes": 0,
-                         "scan_rows": 0, "scan_kernel": 0, "scan_jnp": 0}
+                         "scan_rows": 0, "scan_kernel": 0, "scan_jnp": 0,
+                         "state_step_kernel": 0, "state_step_jnp": 0}
         # what the device counted in the newest decode step read back
         # (`experts_read`/`expert_pairs` of routed experts,
         # `exit_expected_step` of a looped stack), as the next
@@ -1095,9 +1096,15 @@ class DecodeEngine:
         what that takes, every one of those slots' state read and written
         once; `cache_rows`, their live positions in each of the model's
         K/V caches (this step's own among them).  Running sums in
-        `stats()`."""
+        `stats()`.  And `state_step`, what advances the state: `kernel`
+        (the layer's blocks of the stacked leaf read once and written
+        once where they lie) or `jnp` (XLA's passes over the layer's
+        slice), asked of the function the model step decides by;
+        `state_step_kernel`/`state_step_jnp` count the steps."""
         if not self.config.recurrent:
             return {}
+        kind = state_step_kind(self.config)
+        self.counters["state_step_" + kind] += 1
         fields = {
             "state_slots": len(decoding),
             "state_bytes": 2 * len(decoding) * self.config.state_bytes,
@@ -1105,7 +1112,7 @@ class DecodeEngine:
                            + len(decoding)) * self.config.n_caches}
         for name, count in fields.items():
             self.counters[name] += count
-        return fields
+        return {**fields, "state_step": kind}
 
     def _tail_prefill(self, index: int, report: StepReport) -> None:
         """Prefill ONLY the uncached tail of a prefix-cache hit in one

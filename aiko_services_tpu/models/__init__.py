@@ -5,7 +5,7 @@ from .transformer import (                                    # noqa: F401
     init_paged_pool, paged_prefill, paged_decode_step,
     paged_prefill_chunk, paged_verify_step, cache_attention_kind,
     pool_write_kind, prefill_rows, prefill_attention_rows,
-    init_recurrent_state, scan_kind, scan_rows,
+    init_recurrent_state, scan_kind, scan_rows, state_step_kind,
     REMAT_POLICIES, resolve_remat_policy)
 from .tokenizer import BPETokenizer, train_bpe                # noqa: F401
 from .weights import (                                        # noqa: F401

@@ -37,10 +37,11 @@ from ..parallel.attention import (
     ring_attention, sp_decode_attention, ulysses_attention)
 from ..parallel.delta import (
     delta_scan, delta_step,
-    delta_step_reference)
+    delta_step_reference, delta_step_takes)
 from ..parallel.experts import expert_ffn
 from ..parallel.ssm import (
-    ssm_scan, ssm_scan_rows, ssm_scan_takes, ssm_step)
+    ssm_scan, ssm_scan_rows, ssm_scan_takes, ssm_stack_step, ssm_step,
+    ssm_step_takes)
 from .layers import (
     apply_rotary, dense, dense_heads, init_dense, init_dense_t, init_norm,
     repeat_kv, rms_norm, rotary_embedding, swiglu, yarn_frequencies,
@@ -56,7 +57,7 @@ __all__ = [
     "pool_write_kind", "prefill_rows", "prefill_attention_rows",
     "REMAT_POLICIES",
     "resolve_remat_policy", "init_recurrent_state", "scan_kind",
-    "scan_rows",
+    "scan_rows", "state_step_kind",
 ]
 
 
@@ -853,11 +854,12 @@ def init_recurrent_state(config: TransformerConfig, slots: int) -> dict:
     addressed by sequence and sized by `slots`, not by positions.  Mamba:
     "conv" (n_states, ssm_d_conv - 1, slots, ssm_d_inner), the mixer's
     last inputs of its convolution, oldest first, and "ssm" (n_states,
-    slots, ssm_d_state, ssm_d_inner) float32.  Every minor pair of axes
-    fills its tiles: the channels on the lanes, the slots (conv) and the
-    states (ssm) on the sublanes.  Held (slots, 3, .) and (., d_inner,
-    d_state), as the mixer is published, three rows pad to a tile's 16
-    and 16 lanes to 128.  Delta: "conv" (n_states, delta_conv - 1, slots,
+    slots, ssm_d_state, ssm_d_inner) float32, which a decode step reads
+    and writes where it lies (parallel/ssm.py ssm_row_step).  Every minor
+    pair of axes fills its tiles: the channels on the lanes, the slots
+    (conv) and the states (ssm) on the sublanes.  Held (slots, 3, .) and
+    (., d_inner, d_state), as the mixer is published, three rows pad to a
+    tile's 16 and 16 lanes to 128.  Delta: "conv" (n_states, delta_conv - 1, slots,
     the [q | k | v] channels) and "delta" (n_states, slots, value heads,
     delta_key_dim, delta_value_dim) float32, a head's S whole in its
     minor pair, so that a decode step reads and writes it where it lies
@@ -1159,6 +1161,23 @@ def scan_kind(config: TransformerConfig, length: int) -> str:
         config.jnp_dtype) else "jnp"
 
 
+def state_step_kind(config: TransformerConfig) -> str:
+    """What advances the slots' recurrent state in a paged decode step:
+    "kernel" (`ssm_row_step` of a Mamba layer, `gdn_step` of a delta
+    layer: the layer's blocks of the stacked leaf read once and written
+    once where they lie) or "jnp" (XLA's passes over the layer's slice).
+    The step hands the mixers the whole leaf and the layer's index, and
+    they decide by ssm_step_takes / delta_step_takes, as here.  The
+    engine names its decode spans by this."""
+    if config.recurrent_kind == "delta":
+        takes = delta_step_takes(config.delta_key_dim, config.delta_value_dim,
+                                 config.delta_value_heads)
+    else:
+        takes = ssm_step_takes(config.ssm_d_state, config.ssm_d_inner,
+                               jnp.float32)
+    return "kernel" if takes else "jnp"
+
+
 def scan_rows(config: TransformerConfig, bucket: int, true_len: int) -> int:
     """The rows a recurrent layer's scan runs of a whole prefill of
     `bucket` rows for a prompt of `true_len` tokens, of the rows the
@@ -1193,17 +1212,21 @@ def _mamba_mixer(config: TransformerConfig, layer, u, tail, ssm, stop):
     """Jamba's Mamba mixer over the normed rows u (B, L, d) of B
     sequences, each from its own state: `tail` (taps - 1, B, d_inner),
     the convolution's inputs before row 0, oldest first, and `ssm` (B,
-    d_state, d_inner) float32.  Returns (out (B, L, d), the new tail, the
-    new ssm): the state after row stop - 1 (`stop` traced, a whole
-    prefill's true length; None: after the last row), so that right
-    padding leaves nothing in it.
+    d_state, d_inner) float32 -- or, for a decode step over the slots'
+    states, (the stack of the layers' (layers, B, ...), this layer's
+    index), which the step's kernel reads and writes where it lies.
+    Returns (out (B, L, d), the new tail, the new ssm in the form it
+    came): the state after row stop - 1 (`stop` traced, a whole prefill's
+    true length; None: after the last row), so that right padding leaves
+    nothing in it.
 
         [x, z] = u W_in;   c = silu(b_conv + sum_j w_conv[j] x_{t-3+j})
         [delta, B, C] = c W_x, each RMS-normed with a gain of its own
         dt = delta W_dt;   the selective scan (parallel/ssm.py), float32
         out = (y * silu(z)) W_out
 
-    One row (a decode step) is ssm_step's update; more are ssm_scan's."""
+    One row (a decode step) is ssm_step's update (ssm_stack_step's on a
+    stack); more are ssm_scan's."""
     f32 = jnp.float32
     inner, states = config.ssm_d_inner, config.ssm_d_state
     rank, eps = config.ssm_dt_rank, config.norm_eps
@@ -1220,8 +1243,13 @@ def _mamba_mixer(config: TransformerConfig, layer, u, tail, ssm, stop):
     dt = dense(layer["w_dt"], delta)
     a = -jnp.exp(layer["a_log"].astype(f32))
     if length == 1:
-        y, ssm = ssm_step(c[:, 0], dt[:, 0], z[:, 0], b[:, 0], cc[:, 0], a,
-                          layer["d"], layer["dt_bias"], ssm)
+        row = (c[:, 0], dt[:, 0], z[:, 0], b[:, 0], cc[:, 0], a, layer["d"],
+               layer["dt_bias"])
+        if isinstance(ssm, tuple):
+            y, stack = ssm_stack_step(*row, *ssm)
+            ssm = (stack, ssm[1])
+        else:
+            y, ssm = ssm_step(*row, ssm)
         y = y[:, None]
     else:
         y, ssm = ssm_scan(c, dt, z, b, cc, a, layer["d"], layer["dt_bias"],
@@ -2506,23 +2534,16 @@ def _paged_logits(params, config: TransformerConfig, pool, tables,
         h, pool, stats_sum = carry
         layer, index = xs
         kind = _layer_kind(layer)
-        if kind == "mamba":
-            # a Mamba layer advances every slot's state a row, in place:
-            # row s of h is slot s's
-            names = ("conv", "ssm")
-            h, stats, state = _mamba_layer(
-                config, layer, h, {name: pool[name][index] for name in names})
-            pool = {**pool, **{
-                name: jax.lax.dynamic_update_index_in_dim(
-                    pool[name], state[name], index, 0)
-                for name in names}}
-        elif kind == "delta":
-            # a delta layer alike; its S goes to delta_step as the whole
-            # leaf and the layer's index, and comes back as the leaf
-            h, stats, state = _delta_layer(
+        if kind in _MIXERS:
+            # a recurrent layer advances every slot's state a row, in
+            # place: row s of h is slot s's.  Its S goes to the mixer's
+            # step as the whole leaf and the layer's index, and comes
+            # back as the leaf
+            leaf = _MIXERS[kind][1]
+            h, stats, state = _recurrent_layer(layer)(
                 config, layer, h, {"conv": pool["conv"][index],
-                                   "delta": (pool["delta"], index)})
-            pool = {**pool, "delta": state["delta"][0],
+                                   leaf: (pool[leaf], index)})
+            pool = {**pool, leaf: state[leaf][0],
                     "conv": jax.lax.dynamic_update_index_in_dim(
                         pool["conv"], state["conv"], index, 0)}
         else:

@@ -167,7 +167,14 @@
 #                               read and written once) and
 #                               cache_rows (their live positions x the
 #                               attention layers' K/V caches); running
-#                               sums in engine_stats()
+#                               sums in engine_stats(); state_step
+#                               (kernel | jnp: what advances the state,
+#                               the layer's blocks of the stacked leaf
+#                               read and written once where they lie --
+#                               ssm_row_step, gdn_step -- or XLA's passes
+#                               over the layer's slice, as
+#                               models.state_step_kind says; running
+#                               counts state_step_kernel, state_step_jnp)
 #   engine.readback    scoped   the settle's readback of the step in
 #                               flight: in a tick after that tick's
 #                               engine.decode where it ran ahead, or

@@ -270,3 +270,146 @@ def ssm_scan(c, dt, z, b, cc, a, d, dt_bias, state, stop=None):
     takes = ssm_scan_takes(c.shape[1], c.shape[2], b.shape[-1], c.dtype)
     scan = ssm_chunk_scan if takes else ssm_scan_reference
     return scan(c, dt, z, b, cc, a, d, dt_bias, state, stop)
+
+
+# -- one row of every slot, the state where it lies ---------------------------
+#
+# A decode step advances every slot's S a row.  Written as ssm_step's two
+# expressions over a layer's slice of the stacked leaf, XLA updates the
+# slice in place in one fusion and reads what it wrote again for y in a
+# second: S crosses HBM three times.  ssm_row_step is the same products and
+# sums with S crossing twice, read once and written once where it lies.
+
+__all__ += ["ssm_row_step", "ssm_stack_step", "ssm_step_takes"]
+
+# A block is 32 slots x 512 channels of S, 1 MiB.  Read on the chip (PR 45:
+# 26 layers of 32 slots x 16 x 5120, the update alone, us a layer; XLA's two
+# fusions 54.2; wider blocks taken 512 channels at a time inside the
+# kernel): 32 x 512 37.5, 16 x 512 37.6, 16 x 1024 37.8, 32 x 1024 38.1,
+# 16 x 2560 38.0, 32 x 2560 36.6, 16 x 5120 36.2, and with float32 rows (a
+# tile of 8 slots) 8 x 1024 39.6, 8 x 2560 39.2, 8 x 5120 39.8 against
+# 32 x 512's 39.0.  All within 4 %: the stream of S in and out sets the
+# pace, so the block that Mosaic compiles fastest (0.35 s here; 16 x 5120
+# 1.7, 32 x 2560 1.5).  In the served step 32.4 us a call, 79 % of HBM.
+_STEP_SLOTS = 32        # slots of a block
+_STEP_CHANNELS = 512    # channels of a block: a slot's S is 8 vregs
+_STEP_GROUP = 8         # slots whose y make one float32 tile
+_STEP_VMEM_BYTES = 64 << 20
+
+
+def ssm_step_takes(states: int, inner: int, dtype) -> bool:
+    """Whether the kernel `ssm_row_step` advances a decode step's states,
+    decided by what the state is: float32, a d_state that fills float32
+    sublanes, on the chip channels on the 128 lanes, and no ambient mesh
+    (a Mosaic kernel cannot be partitioned)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return (jnp.dtype(dtype) == jnp.dtype(jnp.float32) and states % 8 == 0
+            and (mesh.empty or mesh.size == 1)
+            and (inner % 128 == 0 or _interpret()))
+
+
+def _block(size: int, most: int, unit: int) -> int:
+    """The largest block of at most `most` that is whole `unit`s and
+    divides `size`; `size` itself where there is none."""
+    for block in range(min(most, size) // unit * unit, 0, -unit):
+        if size % block == 0:
+            return block
+    return size
+
+
+def _row_kernel(layer_ref, c_ref, dt_ref, z_ref, b_ref, cc_ref, a_ref,
+                d_ref, bias_ref, state_ref, out_ref, new_ref, ys):
+    """One (block of channels, block of slots) grid step: each slot's S
+    read, advanced a row, written and read out for y, in VMEM."""
+    del layer_ref
+    f32 = jnp.float32
+    slots, channels = c_ref.shape
+    lanes = channels // b_ref.shape[-1]
+    c = c_ref[...].astype(f32)                              # (slots, Dc)
+    delta = _softplus(dt_ref[...].astype(f32) + bias_ref[...])
+    drive = delta * c
+    a = a_ref[...]                                          # (N, Dc)
+    for first in range(0, slots, _STEP_GROUP):
+        group = min(_STEP_GROUP, slots - first)
+        sublane = jax.lax.broadcasted_iota(jnp.int32, (group, channels), 0)
+        tile = jnp.zeros(sublane.shape, f32)
+        for t in range(group):
+            slot = first + t
+            s = (jnp.exp(delta[slot:slot + 1] * a) * state_ref[0, slot]
+                 + drive[slot:slot + 1]
+                 * pltpu.repeat(b_ref[slot], lanes, axis=1))
+            new_ref[0, slot] = s
+            y = jnp.sum(s * pltpu.repeat(cc_ref[slot], lanes, axis=1),
+                        axis=0, keepdims=True)
+            tile = jnp.where(sublane == t, y, tile)
+        ys[first:first + group, :] = tile
+    zf = z_ref[...].astype(f32)
+    out_ref[...] = ((ys[...] + d_ref[...] * c)
+                    * zf * jax.nn.sigmoid(zf)).astype(out_ref.dtype)
+
+
+def ssm_row_step(c, dt, z, b, cc, a, d, dt_bias, states, layer,
+                 slots: int = _STEP_SLOTS, channels: int = _STEP_CHANNELS):
+    """ssm_step over layer `layer` of a stack of layers' states (layers,
+    B, d_state, d_inner) float32, as a Pallas kernel named `ssm_row_step`
+    in the device trace: the stack rides the call aliased, the layer's
+    blocks (of `slots` slots and `channels` channels, or the whole of an
+    axis they do not divide) are read once and written once where they
+    lie, and nothing of S's size is staged.  Returns (out (B, d_inner) in
+    c's dtype, the stack)."""
+    f32 = jnp.float32
+    batch, inner = c.shape
+    n = b.shape[-1]
+    # a tile of the rows' dtype: 8 sublanes of float32, 16 of bf16
+    slots = _block(batch, slots, 32 // jnp.dtype(c.dtype).itemsize)
+    channels = _block(inner, channels, 128)
+    lanes = min(128, channels)
+
+    def rows(x):
+        # (B, N) -> (B, N, lanes): a state's scalar along the lanes
+        return jnp.broadcast_to(x.astype(f32)[..., None], (batch, n, lanes))
+
+    row_spec = pl.BlockSpec((slots, channels),
+                            lambda i, s, layer_ref: (s, i))
+    lane_spec = pl.BlockSpec((slots, n, lanes),
+                             lambda i, s, layer_ref: (s, 0, 0))
+    channel_spec = pl.BlockSpec((1, channels),
+                                lambda i, s, layer_ref: (0, i))
+    state_spec = pl.BlockSpec(
+        (1, slots, n, channels),
+        lambda i, s, layer_ref: (layer_ref[0], s, 0, i))
+    out, states = pl.pallas_call(
+        _row_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(inner // channels, batch // slots),
+            in_specs=[row_spec, row_spec, row_spec, lane_spec, lane_spec,
+                      pl.BlockSpec((n, channels),
+                                   lambda i, s, layer_ref: (0, i)),
+                      channel_spec, channel_spec, state_spec],
+            out_specs=[row_spec, state_spec],
+            scratch_shapes=[pltpu.VMEM((slots, channels), f32)]),
+        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype),
+                   jax.ShapeDtypeStruct(states.shape, f32)],
+        # operand 9 (after the prefetched layer): the stack of states
+        input_output_aliases={9: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_STEP_VMEM_BYTES),
+        name="ssm_row_step",
+        interpret=_interpret(),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), c, dt, z, rows(b),
+      rows(cc), a.astype(f32), d.astype(f32)[None],
+      dt_bias.astype(f32)[None], states)
+    return out, states
+
+
+def ssm_stack_step(c, dt, z, b, cc, a, d, dt_bias, states, layer):
+    """One row of every sequence against layer `layer` of the stack of
+    states (layers, B, d_state, d_inner): the kernel where ssm_step_takes,
+    else ssm_step on the layer's slice, put back in place.  Returns (out
+    (B, d_inner) in c's dtype, the stack)."""
+    if ssm_step_takes(b.shape[-1], c.shape[-1], states.dtype):
+        return ssm_row_step(c, dt, z, b, cc, a, d, dt_bias, states, layer)
+    out, state = ssm_step(c, dt, z, b, cc, a, d, dt_bias, states[layer])
+    return out, jax.lax.dynamic_update_index_in_dim(states, state, layer, 0)

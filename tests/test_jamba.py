@@ -31,6 +31,7 @@ from aiko_services_tpu.models.transformer import (
     param_specs, quantize_weights_int8)
 from aiko_services_tpu.parallel import ssm
 from benchmark.reference import jamba as reference
+from test_decode import _Order
 from test_prefill_rows import (                         # noqa: F401
     EDGE_BUCKET, EDGE_LENGTHS, EDGE_TILE, LIVE_BUCKET,
     assert_tiled_is_the_whole_buckets, check_live_attention_prefill,
@@ -231,6 +232,86 @@ def test_what_takes_the_scan_kernel_is_decided_by_shape_and_dtype():
     assert ssm.ssm_scan_rows(2048, 2048, True) == 2048
 
 
+# -- (b') a decode row: the kernel against ssm_step, on a layer of a stack ----
+
+def _step_case(layers, slots, inner, states=16, seed=11):
+    """(a row's operands as ssm_step takes them, a stack of `layers`
+    layers' states)."""
+    c, dt, z, b, cc, a, d, dt_bias, _ = _scan_case(slots, 1, inner, states,
+                                                   seed)
+    stack = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                              (layers, slots, states, inner))
+    return (c[:, 0], dt[:, 0], z[:, 0], b[:, 0], cc[:, 0], a, d,
+            dt_bias), stack
+
+
+@pytest.mark.parametrize("layer", [0, 2, 4])
+@pytest.mark.parametrize("inner", [256, 5120])
+@pytest.mark.parametrize("slots", [1, 8, 32])
+def test_row_step_kernel_is_the_oracle_on_its_layer_of_the_stack(
+        slots, inner, layer):
+    """`ssm_row_step` (interpreted) against ssm_step on the layer's
+    slice: the same out and the same new state to float32 rounding (the
+    16-term sum in another order), and every other layer of the stack
+    back bit for bit."""
+    row, stack = _step_case(5, slots, inner)
+    want_out, want_state = ssm.ssm_step(*row, stack[layer])
+    out, new = ssm.ssm_row_step(*row, stack, jnp.int32(layer))
+    assert out.shape == want_out.shape and out.dtype == want_out.dtype
+    np.testing.assert_allclose(out, want_out, atol=1e-5)
+    np.testing.assert_allclose(new[layer], want_state, atol=1e-6)
+    for other in range(5):
+        if other != layer:
+            np.testing.assert_array_equal(new[other], stack[other])
+
+
+@pytest.mark.parametrize("slots,channels", [(16, 128), (32, 256), (8, 512)])
+def test_row_step_kernel_by_blocks_of_slots_and_channels(slots, channels):
+    """A grid of several blocks either way (48 slots in blocks of 16,
+    768 channels in blocks of 128 or 256), bf16 rows as the served model
+    hands them; a block size that does not divide is the whole axis."""
+    row, stack = _step_case(2, 48, 768, seed=17)
+    row = tuple(x.astype(jnp.bfloat16) for x in row[:5]) + row[5:]
+    want_out, want_state = ssm.ssm_step(*row, stack[1])
+    out, new = ssm.ssm_row_step(*row, stack, jnp.int32(1), slots=slots,
+                                channels=channels)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               want_out.astype(jnp.float32), atol=0.05)
+    np.testing.assert_allclose(new[1], want_state, atol=1e-6)
+    np.testing.assert_array_equal(new[0], stack[0])
+
+
+def test_what_takes_the_step_kernel_is_decided_by_shape_dtype_and_mesh(
+        monkeypatch):
+    assert ssm.ssm_step_takes(16, 5120, "float32")
+    assert ssm.ssm_step_takes(16, 72, jnp.float32)      # interpreted here
+    assert not ssm.ssm_step_takes(16, 5120, "bfloat16")  # S is float32
+    assert not ssm.ssm_step_takes(12, 5120, "float32")   # sublanes
+    with jax.sharding.set_mesh(jax.make_mesh((2,), ("x",))):
+        assert not ssm.ssm_step_takes(16, 5120, "float32")
+    monkeypatch.setattr(ssm, "_interpret", lambda: False)
+    assert ssm.ssm_step_takes(16, 5120, "float32")
+    assert not ssm.ssm_step_takes(16, 72, "float32")     # lanes
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_a_step_on_a_stack_is_ssm_step_either_way(kernel, monkeypatch):
+    """ssm_stack_step, what the paged step's Mamba layer calls: the
+    kernel where ssm_step_takes, else ssm_step on the layer's slice put
+    back in place (channels off the 128 lanes where nothing is
+    interpreted); layer 1 written, layers 0 and 2 as they were."""
+    monkeypatch.setattr(ssm, "_interpret", lambda: kernel)
+    row, stack = _step_case(3, 4, 72)
+    assert ssm.ssm_step_takes(16, 72, stack.dtype) == kernel
+    want_out, want_state = ssm.ssm_step(*row, stack[1])
+    out, new = ssm.ssm_stack_step(*row, stack, jnp.int32(1))
+    np.testing.assert_allclose(out, want_out, atol=1e-5)
+    np.testing.assert_allclose(new[1], want_state, atol=1e-6)
+    np.testing.assert_array_equal(new[0], stack[0])
+    np.testing.assert_array_equal(new[2], stack[2])
+
+
 # -- (c) the stores: cache, pool, engine ---------------------------------------
 
 def test_cached_prefill_and_decode_are_the_reference(model):
@@ -387,6 +468,68 @@ def test_engine_serves_the_reference_through_the_scan_kernel(
         assert_served_is_the_references(shape, prompt, done["r"].tokens)
     finally:
         jax.clear_caches()
+
+
+@pytest.mark.parametrize("kernel", [True, False])
+def test_engine_serves_the_reference_through_the_step_kernel(
+        model, monkeypatch, kernel):
+    """ISSUE 45: the paged step hands a Mamba layer the whole `ssm` leaf
+    and its index, and the state is advanced by `ssm_row_step`
+    (interpreted) -- or, where ssm_step_takes says no, by ssm_step on the
+    layer's slice: every decode span says which, as
+    models.state_step_kind does, the counts are the steps' and the
+    tokens the reference's either way."""
+    config, params, shape = model
+    if not kernel:
+        # d_inner is 128 here and S float32: nothing of the toy model
+        # makes the predicate say no, so the test does
+        monkeypatch.setattr(ssm, "ssm_step_takes",
+                            lambda states, inner, dtype: False)
+        monkeypatch.setattr(transformer, "ssm_step_takes",
+                            lambda states, inner, dtype: False)
+    kind = "kernel" if kernel else "jnp"
+    jax.clear_caches()
+    try:
+        assert transformer.state_step_kind(config) == kind
+        spans = _Order()
+        engine = DecodeEngine(params, config, decode_slots=2,
+                              kv_block_size=8, max_context=64, spans=spans)
+        rng = np.random.default_rng(5)
+        prompts = {name: rng.integers(1, 256, size=length).astype(np.int32)
+                   for name, length in (("a", 9), ("b", 17), ("c", 5))}
+        for name, prompt in prompts.items():
+            engine.submit(name, prompt, 13)
+        done = drain(engine)
+        stats = engine.stats()
+        decodes = [fields for _, fields in spans.named("engine.decode")]
+        assert decodes and {fields["state_step"] for fields in decodes} == {
+            kind}
+        other = "jnp" if kernel else "kernel"
+        assert stats["state_step_" + kind] == stats["decode_steps"] == len(
+            decodes)
+        assert stats["state_step_" + other] == 0
+        for name, prompt in prompts.items():
+            assert_served_is_the_references(shape, prompt, done[name].tokens,
+                                            name)
+    finally:
+        jax.clear_caches()
+
+
+def test_a_model_without_a_recurrent_state_names_no_state_step():
+    """The span field and the counts are a recurrent model's: a dense
+    model's decode spans carry no `state_step` and its counts stay 0."""
+    config = TransformerConfig(vocab_size=64, d_model=32, n_layers=2,
+                               n_heads=2, n_kv_heads=1, d_ff=48,
+                               max_seq_len=32, dtype="float32")
+    spans = _Order()
+    engine = DecodeEngine(init_params(config, jax.random.PRNGKey(0)), config,
+                          decode_slots=1, kv_block_size=8, spans=spans)
+    engine.submit("r", np.arange(1, 6, dtype=np.int32), 5)
+    drain(engine)
+    decodes = [fields for _, fields in spans.named("engine.decode")]
+    assert decodes and not any("state_step" in fields for fields in decodes)
+    stats = engine.stats()
+    assert (stats["state_step_kernel"], stats["state_step_jnp"]) == (0, 0)
 
 
 # -- (c') a whole prefill by row tiles, the state carried (ISSUE 44) ------------

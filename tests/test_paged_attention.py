@@ -833,8 +833,11 @@ def test_served_jamba_decode_step_copies_no_state_leaf_on_the_v5e(
     """The step advances every slot's convolution tail and SSM state a
     row, in place: the donated leaves (0.30 GB of state, 0.18 GB of K/V)
     come back as the buffers they were, no copy of either state leaf, as
-    none of a pool leaf; the 20 query heads attend over their one K/V
-    head through the paged kernel, which writes the step's new rows."""
+    none of a pool leaf; the SSM state through `ssm_row_step`, which
+    takes the whole leaf aliased and writes a layer's blocks where they
+    lie (ISSUE 45: no dynamic-update-slice of the leaf is left); the 20
+    query heads attend over their one K/V head through the paged kernel,
+    which writes the step's new rows."""
     s = JAMBA
     config, params, pool, int32 = _served_jamba(for_the_chip, monkeypatch)
     assert pool["conv"].shape == (26, 3, 32, 5120)
@@ -846,6 +849,7 @@ def test_served_jamba_decode_step_copies_no_state_leaf_on_the_v5e(
         int32(slots, 1), int32(slots), int32(slots)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_attention" in text
+    assert "ssm_row_step" in text
     memory = compiled.memory_analysis()
     held = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
                for leaf in pool.values())
@@ -854,7 +858,9 @@ def test_served_jamba_decode_step_copies_no_state_leaf_on_the_v5e(
     assert memory.temp_size_in_bytes < 8 << 20
     for name in ("conv", "ssm", "k", "v"):
         assert not _made_of_a_leaf(text, pool[name], " copy("), name
-    assert not _made_of_a_leaf(text, pool["k"], " dynamic-update-slice(")
+    for name in ("ssm", "k"):
+        assert not _made_of_a_leaf(text, pool[name],
+                                   " dynamic-update-slice("), name
     # weights 6.06 GB + state 0.30 + K/V 0.18: what the chip holds
     assert 6.5e9 < memory.argument_size_in_bytes < 6.6e9
 
