@@ -45,8 +45,8 @@ from collections import deque
 import numpy as np
 
 from ..models import (
-    init_paged_pool, paged_prefill, paged_prefill_chunk,
-    prefill_attention_rows, prefill_rows)
+    RECORD_COUNTERS, init_paged_pool, paged_prefill, paged_prefill_chunk,
+    prefill_record)
 from ..observe.trace import NO_SPANS
 from ..pipeline.transfer import fetch_many, get_transfer_server
 from ..runtime.compile_cache import compile_bracket, setup_interval
@@ -185,8 +185,7 @@ class PrefillEngine:
             self.prefill_chunk = None
         self.counters = {"submitted": 0, "exported": 0, "chunks": 0,
                          "compiles": 0, "exported_bytes": 0,
-                         "prefill_rows_run": 0, "prefill_rows_bucket": 0,
-                         "prefill_attn_rows": 0}
+                         **RECORD_COUNTERS}
 
     @property
     def compile_count(self) -> int:
@@ -281,19 +280,15 @@ class PrefillEngine:
         job = self._active
         if (self.prefill_chunk is None
                 or self.prefill_chunk >= job.bucket):
-            # as DecodeEngine's span of a whole prefill: the rows the
-            # program, and its attention, run of the bucket's, asked of
-            # the model step
-            rows = prefill_rows(self.config, job.bucket, job.true_len)
-            attn_rows = prefill_attention_rows(self.config, job.bucket,
-                                               job.true_len)
-            self.counters["prefill_rows_run"] += rows
-            self.counters["prefill_rows_bucket"] += job.bucket
-            self.counters["prefill_attn_rows"] += attn_rows
+            # as DecodeEngine's span of a whole prefill: what the model
+            # says of the call, its counts onto the running counters
+            fields, counts = prefill_record(
+                self.config, self.pool, job.bucket, job.true_len)
+            for name, count in counts.items():
+                self.counters[name] = self.counters.get(name, 0) + count
             with self._spans.span(
                     "engine.prefill", job.request_id, bucket=job.bucket,
-                    true_len=job.true_len, rows=rows,
-                    attn_rows=attn_rows):
+                    true_len=job.true_len, **fields):
                 with self._compiling("paged_prefill"):
                     self.pool, first = paged_prefill(
                         self.params, self.config, self.pool,

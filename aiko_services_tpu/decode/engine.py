@@ -77,11 +77,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import (
-    cache_attention_kind, init_paged_pool, init_recurrent_state,
+    RECORD_COUNTERS, init_paged_pool, init_recurrent_state,
     paged_decode_step, paged_prefill, paged_prefill_chunk,
-    paged_verify_step, pool_write_kind, prefill_attention_rows,
-    prefill_rows, scan_kind,
-    scan_rows, state_step_kind)
+    paged_verify_step, prefill_record, step_counts, window_record)
 from ..observe.trace import NO_SPANS
 from ..parallel.attention import paged_live_blocks
 from ..runtime.compile_cache import compile_bracket, setup_interval
@@ -151,11 +149,8 @@ class _InFlight(NamedTuple):
     """A decode step dispatched and not yet read."""
     tokens: object   # its next_tokens (slots, 1), on the device
     rows: dict       # {slot index: slot seq} of the rows that count
-    # what the step counted beside its tokens, on the device, in the
-    # order paged_decode_step hands them out: a routed-expert model's
-    # int32 (2,), distinct held experts read and token-expert pairs
-    # computed; a looped stack's float32 (slots,), each slot's expected
-    # exit pass
+    # what the step counted beside its tokens, on the device, as
+    # paged_decode_step hands it out; models.step_counts names it
     counted: tuple = ()
 
 
@@ -336,24 +331,11 @@ class DecodeEngine:
                          "prefix_blocks_shared": 0,
                          "prefix_evictions": 0,
                          "live_blocks": 0, "table_blocks": 0,
-                         "prefill_flash": 0, "prefill_einsum": 0,
-                         "prefill_rows_run": 0, "prefill_rows_bucket": 0,
-                         "prefill_attn_rows": 0,
-                         "writes_kernel": 0, "writes_updates": 0,
                          "decode_steps": 0, "steps_ahead": 0,
-                         "overrun_tokens": 0,
-                         "experts_read": 0, "expert_pairs": 0,
-                         "latent_positions": 0,
-                         "ut_passes": 0, "cache_rows": 0,
-                         "exit_expected_step": 0.0,
-                         "state_slots": 0, "state_bytes": 0,
-                         "scan_rows": 0, "scan_kernel": 0, "scan_jnp": 0,
-                         "state_step_kernel": 0, "state_step_jnp": 0}
+                         "overrun_tokens": 0, **RECORD_COUNTERS}
         # what the device counted in the newest decode step read back
-        # (`experts_read`/`expert_pairs` of routed experts,
-        # `exit_expected_step` of a looped stack), as the next
-        # `engine.decode` span carries them; empty for a model with
-        # neither
+        # (models.step_counts' fields), as the next `engine.decode` span
+        # carries them; empty for a model that counts nothing
         self._counted_seen: dict = {}
         self._update_gauges()
 
@@ -818,8 +800,7 @@ class DecodeEngine:
         with self._spans.span("engine.decode", decoding=len(decoding),
                               ahead=int(ahead), **self._counted_seen,
                               **self._walked(self.positions, 1,
-                                             decoding),
-                              **self._stateful(decoding)):
+                                             decoding)):
             write_blocks = np.zeros((self.slots_n,), np.int32)
             write_offsets = np.zeros((self.slots_n,), np.int32)
             for index in decoding:
@@ -833,8 +814,7 @@ class DecodeEngine:
             # runs: a jitted call may read a numpy argument in place
             tokens = (self._inflight.tokens if ahead
                       else jnp.asarray(self.last_tokens.copy()))
-            # routed experts and a looped stack hand their counts out
-            # after the tokens
+            # what the step counts on the device comes after the tokens
             with self._compiling("paged_decode_step"):
                 self.pool, next_tokens, *counted = paged_decode_step(
                     self.params, self.config, self.pool,
@@ -875,17 +855,8 @@ class DecodeEngine:
             # the step's own counts, read with its tokens; the next
             # `engine.decode` span to open carries them
             counted = [np.asarray(count) for count in counted]
-        if self.config.top_k:
-            read, pairs = (int(count) for count in counted.pop(0))
-            self._counted_seen.update(experts_read=read, expert_pairs=pairs)
-            self.counters["experts_read"] += read
-            self.counters["expert_pairs"] += pairs
-        if self.config.ut_steps > 1:
-            # mean over the rows that decoded of the exit gate's
-            # expected pass; the running sum is of these means, a step
-            expected = float(counted.pop(0)[list(rows)].mean())
-            self._counted_seen["exit_expected_step"] = round(expected, 4)
-            self.counters["exit_expected_step"] += expected
+        self._counted_seen = self._noted(
+            step_counts(self.config, counted, rows))
         for index, seq in rows.items():
             slot = self.slots[index]
             if slot is None or slot.seq != seq:
@@ -994,125 +965,52 @@ class DecodeEngine:
             slot.prefill_pos = bucket
             self._finish_prefill(index, report, first)
 
+    def _noted(self, record) -> dict:
+        """Write down what the model says of one of its calls (`record`,
+        one of models' (fields, counts)): the counts onto the running
+        counters of `stats()`, the fields back for the call's span."""
+        fields, counts = record
+        for name, count in counts.items():
+            self.counters[name] = self.counters.get(name, 0) + count
+        return fields
+
     def _prefill_span(self, slot: "_Slot", bucket: int, start=None):
         """The `engine.prefill` span around one prefill call and its
         readback: `bucket` is the padded length the call runs at,
-        `queue_us` how long the request waited for its slot.  A whole
-        prefill says which `attention` its bucket takes (flash, the
-        blockwise kernel over the fresh K/V, or einsum), asking the
-        function the model step itself decides by, the `rows` the
-        program runs of the bucket's (`prefill_rows`: the prompt's
-        length rounded up to a row tile where the bucket runs by row
-        tiles) and the `attn_rows` its attention runs
-        (`prefill_attention_rows`: the length rounded up to the flash
-        kernel's query block where the kernel is told it); a chunk call
-        (`start` = its first position) walks the slot's table like a
-        decode step and carries `live_blocks`/`table_blocks` instead.
-        The running counts and sums (`prefill_rows_run`,
-        `prefill_rows_bucket`, `prefill_attn_rows`) ride `stats()`."""
+        `queue_us` how long the request waited for its slot, the rest
+        what the model says of a whole prefill (models.prefill_record);
+        a chunk call (`start` = its first position) walks the slot's
+        table like a decode step and carries `_walked`'s fields."""
         request = slot.request
         if start is None:
-            attention = cache_attention_kind(self.config, self.pool, 1,
-                                             bucket)
-            rows = prefill_rows(self.config, bucket, slot.true_len)
-            attn_rows = prefill_attention_rows(self.config, bucket,
-                                               slot.true_len)
-            self.counters["prefill_" + attention] += 1
-            self.counters["prefill_rows_run"] += rows
-            self.counters["prefill_rows_bucket"] += bucket
-            self.counters["prefill_attn_rows"] += attn_rows
-            fields = {"attention": attention, "rows": rows,
-                      "attn_rows": attn_rows}
-            if self.config.recurrent:
-                # the rows a recurrent layer's scan runs, and through what,
-                # asked of the functions the model step decides by
-                scan = scan_kind(self.config, bucket)
-                fields.update(scan=scan, scan_rows=scan_rows(
-                    self.config, bucket, slot.true_len))
-                self.counters["scan_rows"] += fields["scan_rows"]
-                self.counters["scan_" + scan] += 1
+            fields = self._noted(prefill_record(
+                self.config, self.pool, bucket, slot.true_len))
         else:
-            fields = self._walked(np.array([start]), bucket)
-        # the rows this call leaves behind and attends over
-        fields.update(self._looped(
-            slot.true_len if start is None
-            else min(start + bucket, slot.true_len)))
+            fields = self._walked(np.array([start]), bucket,
+                                  true_len=slot.true_len)
         return self._spans.span(
             "engine.prefill", request.request_id, bucket=bucket,
             true_len=slot.true_len,
             queue_us=round(((request.admitted_at or request.submitted_at)
                             - request.submitted_at) * 1e6), **fields)
 
-    def _walked(self, positions, window: int, decoding=None) -> dict:
+    def _walked(self, positions, window: int, decoding=None,
+                true_len=None) -> dict:
         """The span fields of one paged window call over the target
-        pool: `live_blocks`, the blocks the attention walks (every
-        slot's positions + window, an idle slot's one trash block), and
-        `table_blocks`, what the tables can name (what the table-wide
-        gather read); over a latent pool a decode step (`decoding`: its
-        slots) also `latent_positions`, the live rows those slots'
-        attention reads, this step's own among them, and of a looped
-        stack `_looped`'s.  Their running sums ride `stats()`.  And
-        `write`, who puts the window's new rows into the pool: `kernel`
-        (the paged attention kernel itself, window 1) or `updates` (one
-        dynamic_update_slice a row), asked of the function the model
-        step decides by; `writes_kernel`/`writes_updates` count the
-        calls."""
+        pool, their sums in `stats()`: the pool's geometry, `live_blocks`
+        (the blocks the attention walks: every slot's positions + window,
+        an idle slot's one trash block) and `table_blocks` (what the
+        tables can name, what the table-wide gather read); and what the
+        model says of the call (models.window_record: a decode step names
+        its slots `decoding`, a prefill chunk its prompt's `true_len`)."""
         walked = {
             "live_blocks": int(paged_live_blocks(
                 positions, window, self.blocks.block_size,
                 self.max_blocks).sum()),
             "table_blocks": len(positions) * self.max_blocks}
-        if decoding is not None and "kv" in self.pool:
-            walked["latent_positions"] = int(
-                positions[decoding].sum()) + len(decoding) * window
-        for name, count in walked.items():
-            self.counters[name] += count
-        walked["write"] = pool_write_kind(self.config, self.pool, window)
-        self.counters["writes_" + walked["write"]] += 1
-        if decoding is not None:
-            walked.update(self._looped(
-                int(positions[decoding].sum()) + len(decoding) * window))
-        return walked
-
-    def _looped(self, positions: int) -> dict:
-        """The span fields a looped stack adds to a call that attends
-        over `positions` live positions (none for a stack of one pass):
-        `ut_passes`, the passes the program ran (its config's), and
-        `cache_rows`, those positions in every one of the model's caches
-        (n_layers x ut_steps: each row 2 x kv_heads x head_dim values).
-        Running sums in `stats()`."""
-        if self.config.ut_steps == 1:
-            return {}
-        fields = {"ut_passes": self.config.ut_steps,
-                  "cache_rows": positions * self.config.n_caches}
-        for name, count in fields.items():
-            self.counters[name] += count
-        return fields
-
-    def _stateful(self, decoding: list) -> dict:
-        """The span fields a recurrent state adds to a decode step over
-        the slots `decoding` (none for a model without): `state_slots`,
-        the decoding slots whose state the step advances; `state_bytes`,
-        what that takes, every one of those slots' state read and written
-        once; `cache_rows`, their live positions in each of the model's
-        K/V caches (this step's own among them).  Running sums in
-        `stats()`.  And `state_step`, what advances the state: `kernel`
-        (the layer's blocks of the stacked leaf read once and written
-        once where they lie) or `jnp` (XLA's passes over the layer's
-        slice), asked of the function the model step decides by;
-        `state_step_kernel`/`state_step_jnp` count the steps."""
-        if not self.config.recurrent:
-            return {}
-        kind = state_step_kind(self.config)
-        self.counters["state_step_" + kind] += 1
-        fields = {
-            "state_slots": len(decoding),
-            "state_bytes": 2 * len(decoding) * self.config.state_bytes,
-            "cache_rows": (int(self.positions[decoding].sum())
-                           + len(decoding)) * self.config.n_caches}
-        for name, count in fields.items():
-            self.counters[name] += count
-        return {**fields, "state_step": kind}
+        fields, counts = window_record(
+            self.config, self.pool, window, positions, decoding, true_len)
+        return self._noted(({**walked, **fields}, {**walked, **counts}))
 
     def _tail_prefill(self, index: int, report: StepReport) -> None:
         """Prefill ONLY the uncached tail of a prefix-cache hit in one

@@ -3,9 +3,9 @@ from .transformer import (                                    # noqa: F401
     cache_specs, decode_step, generate, generate_stream, make_train_step,
     count_params, quantize_weights_int8, quantized_param_specs,
     init_paged_pool, paged_prefill, paged_decode_step,
-    paged_prefill_chunk, paged_verify_step, cache_attention_kind,
-    pool_write_kind, prefill_rows, prefill_attention_rows,
-    init_recurrent_state, scan_kind, scan_rows, state_step_kind,
+    paged_prefill_chunk, paged_verify_step, prefill_rows,
+    prefill_attention_rows, init_recurrent_state, RECORD_COUNTERS,
+    prefill_record, window_record, step_counts,
     REMAT_POLICIES, resolve_remat_policy)
 from .tokenizer import BPETokenizer, train_bpe                # noqa: F401
 from .weights import (                                        # noqa: F401

@@ -2636,6 +2636,107 @@ def paged_prefill_chunk(params, config: TransformerConfig, pool, tokens,
     return pool, greedy[0]
 
 
+# -- what a paged call did and counted -------------------------------------
+#
+# The engines' account of the programs above (observe/trace.py's table):
+# host functions, no device work, answering by the predicates the traced
+# code decides by.  Each returns (fields, counts): the model's fields of the
+# call's span, and what the call adds to the running counters of `stats()`.
+# An engine opens the span with the one, sums the other and knows no name in
+# either: a choice to report, or a model's own field, is written here.
+
+__all__ += ["RECORD_COUNTERS", "prefill_record", "window_record",
+            "step_counts"]
+
+# every counter a record adds to, at its zero: every model's `stats()` has it
+RECORD_COUNTERS = {
+    "prefill_flash": 0, "prefill_einsum": 0, "prefill_rows_run": 0,
+    "prefill_rows_bucket": 0, "prefill_attn_rows": 0, "scan_rows": 0,
+    "scan_kernel": 0, "scan_jnp": 0, "writes_kernel": 0,
+    "writes_updates": 0, "latent_positions": 0, "ut_passes": 0,
+    "cache_rows": 0, "state_slots": 0, "state_bytes": 0,
+    "state_step_kernel": 0, "state_step_jnp": 0, "experts_read": 0,
+    "expert_pairs": 0, "exit_expected_step": 0.0}
+
+
+def _looped_record(config: TransformerConfig, positions: int) -> dict:
+    """A looped stack's passes and the rows of its caches that hold the
+    `positions` a call leaves behind or attends over; else nothing."""
+    if config.ut_steps == 1:
+        return {}
+    return {"ut_passes": config.ut_steps,
+            "cache_rows": positions * config.n_caches}
+
+
+def prefill_record(config: TransformerConfig, pool: dict, bucket: int,
+                   true_len: int):
+    """(fields, counts) of a whole prefill (paged_prefill) of `bucket`
+    rows into `pool` for a prompt of `true_len` tokens: the `attention`
+    the bucket takes, the `rows` the program and the `attn_rows` its
+    attention run of it; a recurrent layer's `scan` and `scan_rows`; a
+    looped stack's passes over the rows the call leaves behind."""
+    attention = cache_attention_kind(config, pool, 1, bucket)
+    rows = prefill_rows(config, bucket, true_len)
+    attn_rows = prefill_attention_rows(config, bucket, true_len)
+    fields = {"attention": attention, "rows": rows, "attn_rows": attn_rows}
+    counts = {"prefill_" + attention: 1, "prefill_rows_run": rows,
+              "prefill_rows_bucket": bucket, "prefill_attn_rows": attn_rows}
+    # the numbers that are a field and a running sum under one name
+    sums = _looped_record(config, true_len)
+    if config.recurrent:
+        fields["scan"] = scan_kind(config, bucket)
+        counts["scan_" + fields["scan"]] = 1
+        sums["scan_rows"] = scan_rows(config, bucket, true_len)
+    return {**fields, **sums}, {**counts, **sums}
+
+
+def window_record(config: TransformerConfig, pool: dict, window: int,
+                  positions, decoding=None, true_len=None):
+    """(fields, counts) of a call of `window` positions a slot over
+    `pool` (_paged_window) at the slots' `positions`: who puts the new
+    rows into the pool (`write`).  A decode step names the slots
+    `decoding` and says what they attend over, its own rows among them,
+    and what advances their recurrent state (`state_step`), each slot's
+    read and written once.  A prefill chunk names the prompt's `true_len`
+    (a looped stack's rows end there); a verify step names neither."""
+    fields = {"write": pool_write_kind(config, pool, window)}
+    counts = {"writes_" + fields["write"]: 1}
+    sums = {}
+    if decoding is not None:
+        live = int(positions[decoding].sum()) + len(decoding) * window
+        sums.update(_looped_record(config, live))
+        if "kv" in pool:
+            sums["latent_positions"] = live
+        if config.recurrent:
+            fields["state_step"] = state_step_kind(config)
+            counts["state_step_" + fields["state_step"]] = 1
+            sums.update(state_slots=len(decoding),
+                        state_bytes=2 * len(decoding) * config.state_bytes,
+                        cache_rows=live * config.n_caches)
+    elif true_len is not None:
+        sums.update(_looped_record(
+            config, min(int(positions[0]) + window, true_len)))
+    return {**fields, **sums}, {**counts, **sums}
+
+
+def step_counts(config: TransformerConfig, counted, rows):
+    """(fields, counts) of what a paged_decode_step counted on the
+    device: `counted`, what it returned after the tokens, read back, and
+    `rows`, the slots that decoded: routed experts' two counts, and a
+    looped stack's expected exit pass, its mean over those rows (the
+    running sum is of these means, a step)."""
+    counted, counts = iter(counted), {}
+    if config.top_k:
+        counts["experts_read"], counts["expert_pairs"] = map(
+            int, next(counted))
+    fields = dict(counts)
+    if config.ut_steps > 1:
+        expected = float(next(counted)[list(rows)].mean())
+        counts["exit_expected_step"] = expected
+        fields["exit_expected_step"] = round(expected, 4)
+    return fields, counts
+
+
 # -- training ---------------------------------------------------------------
 
 # Named jax.checkpoint_policies entries the remat sweep accepts

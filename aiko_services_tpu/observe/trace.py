@@ -86,47 +86,60 @@
 #   engine.step        scoped   one engine tick: waiting, active,
 #                               decoding, admitted
 #   engine.prefill     scoped   one prefill call + its readback, inside
-#                               engine.step: bucket, true_len, queue_us,
-#                               attention (flash | einsum: what the
-#                               bucket's attention takes), rows (the
+#                               engine.step: bucket, true_len, queue_us
+#                               (the engine's own; a prefill engine's
+#                               whole prefill: bucket, true_len), and
+#                               the MODEL'S FIELDS of the call.  Those,
+#                               here and on engine.decode, are what the
+#                               model's record of the call says
+#                               (models.prefill_record, window_record,
+#                               step_counts, beside the paged programs
+#                               in models/transformer.py: each answers
+#                               by the predicates the traced code
+#                               decides by and returns the span's fields
+#                               and the increments of the running
+#                               counters of engine_stats(); the engines
+#                               write both down and know no name in
+#                               either, and models.RECORD_COUNTERS holds
+#                               every counter at its zero, so stats()
+#                               has every key for every model).  A whole
+#                               prefill: attention (flash | einsum: what
+#                               the bucket's attention takes; counts
+#                               prefill_flash, prefill_einsum), rows (the
 #                               rows the program runs of the bucket's:
 #                               true_len rounded up to a row tile where
-#                               the bucket runs by row tiles, as
-#                               models.prefill_rows says) and attn_rows
-#                               (the query rows its attention runs:
-#                               true_len rounded up to the flash kernel's
-#                               query block where the kernel is told the
-#                               length, else the bucket, as
-#                               models.prefill_attention_rows says;
+#                               the bucket runs by row tiles) and
+#                               attn_rows (the query rows its attention
+#                               runs: true_len rounded up to the flash
+#                               kernel's query block where the kernel is
+#                               told the length, else the bucket);
 #                               running sums prefill_rows_run,
-#                               prefill_rows_bucket, prefill_attn_rows; a
-#                               prefill engine's whole prefill carries
-#                               bucket, true_len, rows and attn_rows); a
-#                               chunk call instead: live_blocks,
-#                               table_blocks;
-#                               of a looped stack also ut_passes and
-#                               cache_rows (as on engine.decode; here the
-#                               rows the call leaves behind, true_len or
-#                               the chunk's end, x caches); of a model
-#                               with a recurrent state (mamba or delta
-#                               layers) a whole prefill also scan (what
-#                               the layers' scan runs through, as
-#                               models.scan_kind says: kernel | jnp of a
-#                               selective scan, jnp of the gated delta
-#                               rule's chunks) and scan_rows (the rows it
-#                               runs of the bucket's: the kernel stops
-#                               after the block of rows that holds row
-#                               true_len - 1, models.scan_rows); running
-#                               sum scan_rows, counts scan_kernel,
-#                               scan_jnp
-#   engine.decode      scoped   table build + dispatch: decoding,
-#                               ahead (1: dispatched while the step
-#                               before was unread, from its tokens on
-#                               the device; 0: from the host's, after an
-#                               admission or an empty engine),
-#                               live_blocks (blocks the paged attention
-#                               walks this step), table_blocks (what the
-#                               tables can name: slots x max_blocks),
+#                               prefill_rows_bucket, prefill_attn_rows.
+#                               A chunk call instead: live_blocks,
+#                               table_blocks (the pool's geometry: the
+#                               engine's own) and write.  Of a looped
+#                               stack also ut_passes and cache_rows (as
+#                               on engine.decode; here the rows the call
+#                               leaves behind, true_len or the chunk's
+#                               end, x caches); of a model with a
+#                               recurrent state (mamba or delta layers)
+#                               a whole prefill also scan (what the
+#                               layers' scan runs through: kernel | jnp
+#                               of a selective scan, jnp of the gated
+#                               delta rule's chunks) and scan_rows (the
+#                               rows it runs of the bucket's: the kernel
+#                               stops after the block of rows that holds
+#                               row true_len - 1); running sum
+#                               scan_rows, counts scan_kernel, scan_jnp
+#   engine.decode      scoped   table build + dispatch.  The engine's
+#                               own: decoding, ahead (1: dispatched
+#                               while the step before was unread, from
+#                               its tokens on the device; 0: from the
+#                               host's, after an admission or an empty
+#                               engine), live_blocks (blocks the paged
+#                               attention walks this step), table_blocks
+#                               (what the tables can name: slots x
+#                               max_blocks).  The model's record's:
 #                               write (kernel | updates: who puts the
 #                               step's new rows into the pool, the paged
 #                               attention kernel itself at window 1 or
@@ -172,9 +185,8 @@
 #                               the layer's blocks of the stacked leaf
 #                               read and written once where they lie --
 #                               ssm_row_step, gdn_step -- or XLA's passes
-#                               over the layer's slice, as
-#                               models.state_step_kind says; running
-#                               counts state_step_kernel, state_step_jnp)
+#                               over the layer's slice; running counts
+#                               state_step_kernel, state_step_jnp)
 #   engine.readback    scoped   the settle's readback of the step in
 #                               flight: in a tick after that tick's
 #                               engine.decode where it ran ahead, or

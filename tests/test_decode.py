@@ -7,6 +7,8 @@
 
 import queue
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -298,6 +300,132 @@ def test_engine_counts_who_writes_each_windows_rows(tiny_model, case):
     for index, prompt in enumerate(prompts):
         np.testing.assert_array_equal(
             done[index].tokens, reference(params, config, prompt, 4))
+
+
+# -- the seam: the model says what its programs did, the engines write it --
+# down (ISSUE 46).  What a span's model fields and stats()'s model
+# counters are is models.prefill_record / window_record / step_counts'
+# business; the tests above pin today's names and values, these the seam.
+
+PREDICATES = ("cache_attention_kind", "pool_write_kind", "prefill_rows",
+              "prefill_attention_rows", "scan_kind", "scan_rows",
+              "state_step_kind")
+# every key stats() held before the records (PR 45), by engine
+ENGINE_KEYS = {
+    "decode": (
+        "active_slots", "free_blocks", "waiting", "slots", "blocks",
+        "block_size", "admitted", "completed", "preempted",
+        "deferred_admissions", "cancelled", "compiles", "prefill_chunks",
+        "chunk_interleaves", "spec_windows", "spec_drafted",
+        "spec_accepted", "adopted", "adopt_fallbacks", "kv_migrated_bytes",
+        "restores", "restore_fallbacks", "restore_replayed_tokens",
+        "prefix_hits", "prefix_partial_hits", "prefix_blocks_shared",
+        "prefix_evictions", "live_blocks", "table_blocks", "prefill_flash",
+        "prefill_einsum", "prefill_rows_run", "prefill_rows_bucket",
+        "prefill_attn_rows", "writes_kernel", "writes_updates",
+        "decode_steps", "steps_ahead", "overrun_tokens", "experts_read",
+        "expert_pairs", "latent_positions", "ut_passes", "cache_rows",
+        "exit_expected_step", "state_slots", "state_bytes", "scan_rows",
+        "scan_kernel", "scan_jnp", "state_step_kernel", "state_step_jnp"),
+    "prefill": (
+        "waiting", "active", "block_size", "free_blocks", "submitted",
+        "exported", "chunks", "compiles", "exported_bytes",
+        "prefill_rows_run", "prefill_rows_bucket", "prefill_attn_rows")}
+# what a dense model with one pass, plain K/V and no state never counts
+NOT_A_DENSE_MODELS = (
+    "experts_read", "expert_pairs", "latent_positions", "ut_passes",
+    "cache_rows", "exit_expected_step", "state_slots", "state_bytes",
+    "scan_rows", "scan_kernel", "scan_jnp", "state_step_kernel",
+    "state_step_jnp")
+
+
+def _saying_more(record, field, count):
+    """`record` with one more field and one more counted name."""
+    def wrapped(*args, **kwargs):
+        fields, counts = record(*args, **kwargs)
+        return {**fields, field: 7}, {**counts, count: 3}
+    return wrapped
+
+
+@pytest.mark.parametrize("engine_kind", ["decode", "prefill"])
+def test_engines_write_down_whatever_the_record_says(
+        tiny_model, monkeypatch, engine_kind):
+    """A record that says one more field and counts one more name shows
+    both, on the call's span and in stats(), with no edit to decode/: the
+    engines know no name a record returns."""
+    from aiko_services_tpu.decode import disagg, engine as engine_module
+    module = engine_module if engine_kind == "decode" else disagg
+    monkeypatch.setattr(module, "prefill_record", _saying_more(
+        module.prefill_record, "said", "prefills_said"))
+    if engine_kind == "decode":
+        monkeypatch.setattr(module, "window_record", _saying_more(
+            module.window_record, "walked", "windows_said"))
+        monkeypatch.setattr(module, "step_counts", _saying_more(
+            module.step_counts, "device_said", "steps_said"))
+    params, config = tiny_model
+    prompts = [np.arange(1, n, dtype=np.int32) for n in (6, 10, 4)]
+    engine, prefills = _whole_prefills(engine_kind, params, config, prompts)
+    stats = engine.stats()
+    assert [fields["said"] for fields in prefills] == [7, 7, 7]
+    assert stats["prefills_said"] == 9
+    # and what the record said before is all there
+    assert {"attention", "rows", "attn_rows"} <= set(prefills[0])
+    assert stats["prefill_einsum"] == 3
+    if engine_kind == "prefill":
+        return
+    decodes = [fields for _, fields in engine._spans.named("engine.decode")]
+    assert decodes and all(fields["walked"] == 7 for fields in decodes)
+    assert stats["windows_said"] == 3 * len(decodes)
+    assert stats["steps_said"] == 3 * stats["decode_steps"]
+    # what the device counted rides the spans opened after its readback
+    assert "device_said" not in decodes[0]
+    assert decodes[-1]["device_said"] == 7
+
+
+@pytest.mark.parametrize("module_name", ["engine", "disagg"])
+def test_engines_hold_no_predicate_and_read_no_model_field(module_name):
+    """decode/engine.py and decode/disagg.py import the programs and the
+    records, none of the predicates the records answer by, and read none
+    of the config's fields that say which model has which field
+    (`state_bytes` only where refuse_recurrent words its message)."""
+    import importlib
+    import inspect
+    module = importlib.import_module(
+        f"aiko_services_tpu.decode.{module_name}")
+    assert not set(PREDICATES) & set(vars(module))
+    source = inspect.getsource(module)
+    for field in ("top_k", "ut_steps", "n_caches"):
+        assert f"config.{field}" not in source
+    assert source.count("config.state_bytes") == (module_name == "engine")
+    assert "config.state_bytes" in inspect.getsource(
+        importlib.import_module("aiko_services_tpu.decode.engine"
+                                ).refuse_recurrent)
+    for gone in ("_looped", "_stateful"):
+        assert f"def {gone}(" not in source
+
+
+@pytest.mark.parametrize("engine_kind", ["decode", "prefill"])
+def test_stats_hold_every_key_for_every_model(tiny_model, engine_kind):
+    """stats() of a dense model holds every key it held before the
+    records, at zero where the model has no such field: the engine's own
+    literal and, from the model, RECORD_COUNTERS."""
+    from aiko_services_tpu.decode import PrefillEngine
+    from aiko_services_tpu.models import RECORD_COUNTERS
+    params, config = tiny_model
+    make = (partial(DecodeEngine, decode_slots=2)
+            if engine_kind == "decode" else PrefillEngine)
+    fresh = make(params, config, kv_block_size=8).stats()
+    assert set(ENGINE_KEYS[engine_kind]) | set(RECORD_COUNTERS) <= set(fresh)
+    assert set(NOT_A_DENSE_MODELS) < set(RECORD_COUNTERS)
+    assert all(fresh[name] == 0 for name in RECORD_COUNTERS)
+    engine, _ = _whole_prefills(
+        engine_kind, params, config,
+        [np.arange(1, n, dtype=np.int32) for n in (6, 10)])
+    stats = engine.stats()
+    assert set(fresh) <= set(stats)
+    assert stats["prefill_rows_bucket"] == 8 + 16
+    assert all(stats[name] == 0 for name in NOT_A_DENSE_MODELS)
+    assert isinstance(stats["exit_expected_step"], float)
 
 
 def test_engine_eos_frees_slot_early(tiny_model):
