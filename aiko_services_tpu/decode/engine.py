@@ -100,17 +100,23 @@ def refuse_recurrent(config, what: str) -> None:
     """Raise, naming the state, where `what` is asked of a model with a
     recurrent state (layers of kind `config.recurrent_kind`).  A slot's
     state is one row-to-row carry (a convolution tail and, a layer, a
-    Mamba layer's SSM state or a delta layer's matrix a head), advanced
-    in place and kept at no earlier position: what would have to
-    snapshot it at a block boundary, carry it into a chunk, roll it back
-    or ship it is not implemented (ROADMAP.md R3), and is refused instead
-    of attempted."""
+    Mamba layer's SSM state or a delta layer's matrix a head; a lightning
+    layer's matrix a head alone), advanced in place and kept at no
+    earlier position: what would have to snapshot it at a block boundary,
+    carry it into a chunk, roll it back or ship it is not implemented
+    (ROADMAP.md R3), and is refused instead of attempted.  Where the
+    model's attention selects its blocks the refusal names its store of
+    compressed keys too, which rides the pool's dict beside K/V and is
+    advanced by the decode step alone."""
     if getattr(config, "recurrent", False):
+        compressed = (
+            "; its attention layers' store of compressed keys is advanced "
+            "by the decode step alone" if config.sparse_topk else "")
         raise ValueError(
             f"{what} is not implemented for a model with a recurrent "
             f"state ({config.recurrent_kind} layers: {config.n_states} "
             f"states of {config.state_bytes // config.n_states} B a slot, "
-            f"kept at the slot's last position only)")
+            f"kept at the slot's last position only{compressed})")
 
 
 @dataclass
@@ -241,6 +247,9 @@ class DecodeEngine:
         with setup_interval("state", self._spans.span(
                 "setup.state", node=node, what="pool",
                 blocks=self.blocks.num_blocks)) as interval:
+            # (of an attention that selects its blocks also "kc", the
+            # compressed keys a block owns: blocks like K/V's, so a
+            # block's table entry names them too)
             self.pool = init_paged_pool(config, self.blocks.num_blocks,
                                         self.blocks.block_size)
             self.tables = np.full((self.slots_n, self.max_blocks),

@@ -19,7 +19,8 @@ __all__ = [
     "LLAMA3_8B", "LLAMA32_1B", "LM_TOY",
     "WHISPER_TINY", "WHISPER_SMALL",
     "YOLOV8N_SHAPE", "DETECTOR_TOY", "deepseek_v2_config", "ouro_config",
-    "jamba_config", "qwen3_next_config", "PUBLISHED_READERS",
+    "jamba_config", "qwen3_next_config", "minicpm_sala_config",
+    "PUBLISHED_READERS",
     "transformer_flops_per_token", "asr_flops_per_example",
     "tts_flops_per_example",
     "detector_flops_per_image",
@@ -253,10 +254,86 @@ def qwen3_next_config(published: dict, max_seq_len: int | None = None,
         delta_conv=int(published["linear_conv_kernel_dim"]))
 
 
+# InfLLM-v2's sizes as the MiniCPM4 family publishes them (`sparse_config`
+# of openbmb/MiniCPM4-8B's config.json); MiniCPM-SALA's config.json as the
+# catalog has it names none, so a file may carry its own `sparse_config`
+_MINICPM_SPARSE = {"kernel_size": 32, "kernel_stride": 16, "block_size": 64,
+                   "topk": 64, "init_blocks": 1, "window_size": 2048,
+                   "dense_len": 8192}
+
+
+def minicpm_sala_config(published: dict, max_seq_len: int | None = None,
+                        dtype: str | None = None) -> TransformerConfig:
+    """TransformerConfig from openbmb/MiniCPM-SALA's config.json keys
+    (`model_type` minicpm_sala), or a cut of it: `mixer_types` names each
+    layer "minicpm4" (attention without positional encoding that selects
+    its blocks, InfLLM-v2; q and k normed a head; the output gated) or
+    "lightning-attn" (lightning attention with rotary q and k, an output
+    norm and an output gate); muP's scale_emb on the embedding, scale_depth
+    / sqrt(mup_denominator) on every residual branch (the published depth,
+    whatever the cut: `mup_denominator`, else num_hidden_layers), hidden_size
+    / dim_model_base under the logits; an untied head.  Anything the
+    program does not implement raises by name."""
+    unsupported = {
+        "model_type": "minicpm_sala", "hidden_act": "silu",
+        "attention_bias": False, "attn_use_rope": False, "qk_norm": True,
+        "lightning_use_rope": True, "use_output_gate": True,
+        "use_output_norm": True, "attn_use_output_gate": True,
+        "tie_word_embeddings": False, "lightning_scale": "1/sqrt(d)",
+        "rope_scaling": None}
+    for key, value in unsupported.items():
+        if published.get(key, value) != value:
+            raise ValueError(f"minicpm_sala: {key}={published[key]!r} is "
+                             f"not implemented (only {value!r})")
+    kinds = {"minicpm4": "attention", "lightning-attn": "lightning"}
+    mixers = list(published["mixer_types"])
+    layers = int(published["num_hidden_layers"])
+    if len(mixers) != layers or set(mixers) - set(kinds):
+        raise ValueError(
+            f"minicpm_sala: mixer_types must name {layers} layers of "
+            f"{sorted(kinds)}, got {len(mixers)} of {sorted(set(mixers))}")
+    heads, hd = int(published["lightning_nh"]), int(
+        published["lightning_head_dim"])
+    if int(published.get("lightning_nkv", heads)) != heads:
+        raise ValueError(
+            f"minicpm_sala: lightning_nkv={published['lightning_nkv']} is "
+            f"not implemented (only lightning_nh, {heads}: a head its own "
+            f"key and value)")
+    sparse = {**_MINICPM_SPARSE, **(published.get("sparse_config") or {})}
+    block = int(sparse["block_size"])
+    hidden = int(published["hidden_size"])
+    return TransformerConfig(
+        vocab_size=int(published["vocab_size"]), d_model=hidden,
+        n_layers=layers, n_heads=int(published["num_attention_heads"]),
+        n_kv_heads=int(published["num_key_value_heads"]),
+        d_ff=int(published["intermediate_size"]),
+        max_seq_len=int(max_seq_len
+                        or published["max_position_embeddings"]),
+        rope_theta=float(published["rope_theta"]),
+        norm_eps=float(published["rms_norm_eps"]),
+        dtype=str(dtype or published.get("torch_dtype", "bfloat16")),
+        layer_kinds=tuple(kinds[mixer] for mixer in mixers),
+        attn_head_dim=int(published["head_dim"]), rotary=False,
+        qk_norm=True, gated_attention=True,
+        lightning_heads=heads, lightning_head_dim=hd,
+        sparse_topk=int(sparse["topk"]), sparse_block=block,
+        sparse_kernel=int(sparse["kernel_size"]),
+        sparse_stride=int(sparse["kernel_stride"]),
+        sparse_init=int(sparse["init_blocks"]),
+        sparse_local=int(sparse["window_size"]) // block,
+        sparse_dense_len=int(sparse["dense_len"]),
+        embed_scale=float(published["scale_emb"]),
+        residual_scale=float(published["scale_depth"]) / float(
+            published.get("mup_denominator", layers)) ** 0.5,
+        logit_divisor=hidden / float(published["dim_model_base"]),
+        untied_head=True)
+
+
 # model_type of a published config.json -> its reader (elements/ml.py
 # hands LMGenerate's `model` parameter to it whole)
 PUBLISHED_READERS = {"deepseek_v2": deepseek_v2_config, "ouro": ouro_config,
-                     "jamba": jamba_config, "qwen3_next": qwen3_next_config}
+                     "jamba": jamba_config, "qwen3_next": qwen3_next_config,
+                     "minicpm_sala": minicpm_sala_config}
 
 
 # small config for hermetic tests / CPU runs

@@ -39,6 +39,12 @@ from ..parallel.delta import (
     delta_scan, delta_step,
     delta_step_reference, delta_step_takes)
 from ..parallel.experts import expert_ffn
+from ..parallel.lightning import (
+    decay_rates, lightning_scan, lightning_step, lightning_step_reference,
+    lightning_step_takes)
+from ..parallel.sparse import (
+    SparseSizes, blocks_read, completed_key, decode_tables, due_compressed,
+    sparse_prefill_attention, sparse_tile)
 from ..parallel.ssm import (
     ssm_scan, ssm_scan_rows, ssm_scan_takes, ssm_stack_step, ssm_step,
     ssm_step_takes)
@@ -62,7 +68,7 @@ __all__ = [
 
 
 # the layer kinds that carry a recurrent state; a model has one of them
-_RECURRENT_KINDS = ("mamba", "delta")
+_RECURRENT_KINDS = ("mamba", "delta", "lightning")
 
 
 @dataclass(frozen=True)
@@ -201,6 +207,35 @@ class TransformerConfig:
     delta_key_dim: int = 0
     delta_value_dim: int = 0
     delta_conv: int = 0
+    # -- lightning attention beside attention that selects (MiniCPM-SALA) --
+    # layer kind "lightning": a linear-attention mixer (parallel/
+    # lightning.py) of lightning_heads heads of lightning_head_dim whose
+    # decay is fixed a head; what a sequence carries from row to row is S
+    # (lightning_head_dim squared) float32 a head and nothing else: no
+    # convolution, and its q and k rotate (rope_theta, the whole head).
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    # sparse_topk > 0: an attention layer's query at a position from
+    # sparse_dense_len on reads sparse_topk blocks of sparse_block
+    # positions (the pool's block), chosen by the compressed keys, the
+    # mean of sparse_kernel keys every sparse_stride positions, which a
+    # fifth store beside K/V holds (parallel/sparse.py): the first
+    # sparse_init blocks, the sparse_local last, the best of the rest.
+    sparse_topk: int = 0
+    sparse_block: int = 64
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_init: int = 1
+    sparse_local: int = 32
+    sparse_dense_len: int = 8192
+    # muP's three scalars as the model applies them: the embedding times
+    # embed_scale, every residual branch times residual_scale, the normed
+    # output divided by logit_divisor before the head
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
+    # the seeded initialiser makes a head of its own ("lm_head")
+    untied_head: bool = False
 
     def __post_init__(self):
         if self.sp_mechanism not in ("ring", "ulysses"):
@@ -261,6 +296,10 @@ class TransformerConfig:
                 raise ValueError(
                     "mamba layers need ssm_d_inner, ssm_d_state, "
                     "ssm_dt_rank >= 1 and ssm_d_conv >= 2")
+            if kind == "lightning" and min(self.lightning_heads,
+                                           self.lightning_head_dim) < 1:
+                raise ValueError("lightning layers need lightning_heads "
+                                 "and lightning_head_dim >= 1")
             if kind == "delta" and (
                     min(self.delta_key_heads, self.delta_value_heads,
                         self.delta_key_dim, self.delta_value_dim) < 1
@@ -271,6 +310,27 @@ class TransformerConfig:
                     "1, delta_conv >= 2 and delta_value_heads a multiple "
                     "of delta_key_heads >= 1")
 
+        if self.sparse_topk:
+            self.sparse_sizes.check()
+            for name in ("kv_dtype", "sequence_parallel", "kv_lora_rank"):
+                if getattr(self, name):
+                    raise ValueError(
+                        f"attention that selects its blocks does not take "
+                        f"{name}={getattr(self, name)!r}: the compressed "
+                        f"keys are means of plain K rows on one device")
+            if self.ut_steps > 1:
+                raise ValueError("attention that selects its blocks runs "
+                                 "its stack once a token (ut_steps 1)")
+
+    @property
+    def sparse_sizes(self) -> SparseSizes:
+        """The block selection's sizes (parallel/sparse.py)."""
+        return SparseSizes(
+            block=self.sparse_block, kernel=self.sparse_kernel,
+            stride=self.sparse_stride, topk=self.sparse_topk,
+            init=self.sparse_init, local=self.sparse_local,
+            dense_len=self.sparse_dense_len)
+
     @property
     def head_dim(self) -> int:
         return self.attn_head_dim or self.d_model // self.n_heads
@@ -278,7 +338,7 @@ class TransformerConfig:
     @property
     def recurrent_kind(self) -> str:
         """The kind of the layers that carry a recurrent state: "mamba",
-        "delta", or "" where every layer attends."""
+        "delta", "lightning", or "" where every layer attends."""
         return next((kind for kind in self.layer_kinds
                      if kind in _RECURRENT_KINDS), "")
 
@@ -308,9 +368,13 @@ class TransformerConfig:
     def state_bytes(self) -> int:
         """Bytes of recurrent state a sequence carries, over the layers
         of the recurrent kind: a layer's state in float32 (mamba: d_state
-        x d_inner; delta: key_dim x value_dim a value head) and its
+        x d_inner; delta: key_dim x value_dim a value head; lightning:
+        head_dim squared a head) and, where the mixer has one, its
         convolution's tail of inputs in the serving dtype."""
         item = self.jnp_dtype.itemsize
+        if self.recurrent_kind == "lightning":
+            return (self.n_states * 4 * self.lightning_heads
+                    * self.lightning_head_dim ** 2)
         if self.recurrent_kind == "delta":
             return self.n_states * (
                 4 * self.delta_value_heads * self.delta_key_dim
@@ -439,6 +503,15 @@ def _init_layer(key, config: TransformerConfig,
     if config.qk_norm:
         layer["q_norm"] = init_norm(hd, dtype)
         layer["k_norm"] = init_norm(hd, dtype)
+    if config.sparse_topk:
+        # with gains of 1 a softmax over thousands of seeded random keys
+        # is flat, every choice of blocks gives nearly the same output and
+        # nothing downstream could tell selection from none: the seeded q
+        # gain makes q . Kc / sqrt(d) of unit-RMS heads spread by about 3
+        # (a mean of `kernel` independent unit keys has 1 / sqrt(kernel)
+        # of a key's length).  Weights overwrite it
+        layer["q_norm"] = {"scale": jnp.full(
+            (hd,), 3.0 * math.sqrt(config.sparse_kernel), dtype)}
     if config.sandwich_norm:
         layer["attn_out_norm"] = init_norm(d, dtype)
         layer["mlp_out_norm"] = init_norm(d, dtype)
@@ -553,6 +626,29 @@ def _init_delta_layer(key, config: TransformerConfig) -> dict:
     return layer
 
 
+def _init_lightning_layer(key, config: TransformerConfig) -> dict:
+    """One lightning layer's weights: the mixer's (MiniCPM-SALA's: the
+    projections without bias, [q | k | v | g] in one, g the output's gate;
+    q and k normed a head with gains of their own, the output's norm a
+    head with one gain shared by the heads) and the dense FFN's.  The
+    decay is no weight: the family's fixed slopes (decay_rates)."""
+    d, ff, dtype = config.d_model, config.d_ff, config.jnp_dtype
+    hd = config.lightning_head_dim
+    inner = config.lightning_heads * hd
+    keys = jax.random.split(key, 8)
+    return {
+        "mixer_norm": init_norm(d, dtype),
+        "w_qkvg": init_dense(keys[0], d, 4 * inner, dtype),
+        "q_norm": init_norm(hd, dtype), "k_norm": init_norm(hd, dtype),
+        "out_norm": init_norm(hd, dtype),
+        "w_out": init_dense(keys[1], inner, d, dtype),
+        "mlp_norm": init_norm(d, dtype),
+        "w_gate": init_dense(keys[2], d, ff, dtype),
+        "w_up": init_dense(keys[3], d, ff, dtype),
+        "w_down": init_dense(keys[4], ff, d, dtype),
+    }
+
+
 def _stack_layers(layers: list) -> dict:
     """Per-layer weight dicts -> one dict of leaves stacked on a leading
     axis, a leaf at a time, letting each layer's copy go as its stack is
@@ -595,7 +691,8 @@ def init_params(config: TransformerConfig, key) -> dict:
     with routed experts whose first layers are dense has those apart,
     as "dense_layers" (their FFN leaves have other shapes); a model with
     layer_kinds has "runs" instead, a stack a run of like layers, layer i
-    drawn from the i-th key whatever its kind."""
+    drawn from the i-th key whatever its kind; a model with untied_head an
+    "lm_head" of its own."""
     embed_key, *layer_keys = jax.random.split(key, config.n_layers + 1)
     lead = _leading_dense(config)
     params = {
@@ -604,9 +701,15 @@ def init_params(config: TransformerConfig, key) -> dict:
             * 0.02).astype(config.jnp_dtype)},
         "norm_out": init_norm(config.d_model, config.jnp_dtype),
     }
+    if config.untied_head:
+        # (vocab, d) as the embedding lies: _head_logits contracts either
+        params["lm_head"] = init_dense_t(
+            jax.random.fold_in(key, config.n_layers + 2), config.d_model,
+            config.vocab_size, config.jnp_dtype)
     if config.layer_kinds:
         params["runs"], first = [], 0
         inits = {"mamba": _init_mamba_layer, "delta": _init_delta_layer,
+                 "lightning": _init_lightning_layer,
                  "attention": partial(_init_layer,
                                       routed=config.top_k > 0)}
         for kind, _, count in _kind_runs(config):
@@ -699,7 +802,11 @@ def param_specs(config: TransformerConfig,
             "delta": dict(
                 recurrent, w_qkvz={"w": column}, conv={"w": whole3},
                 w_ba={"w": whole3}, a_log=whole2, dt_bias=whole2,
-                delta_norm={"scale": whole2}, w_out={"w": row})}
+                delta_norm={"scale": whole2}, w_out={"w": row}),
+            "lightning": dict(
+                recurrent, w_qkvg={"w": column}, q_norm={"scale": whole2},
+                k_norm={"scale": whole2}, out_norm={"scale": whole2},
+                w_out={"w": row})}
         specs["runs"] = [kinds[kind] for kind, _, _ in _kind_runs(config)]
     elif config.top_k:
         specs["layers"] = dict(layer, **routed_ffn)
@@ -709,7 +816,7 @@ def param_specs(config: TransformerConfig,
         specs["layers"] = dict(layer, **expert_ffn_specs)
     else:
         specs["layers"] = dict(layer, **dense_ffn)
-    if lm_head:
+    if lm_head or config.untied_head:
         specs["lm_head"] = {"w": P(None, "fsdp")}
     if config.ut_steps > 1:
         specs["exit_gate"] = {"w": P(None), "b": P()}
@@ -821,20 +928,41 @@ def init_cache(config: TransformerConfig, batch: int,
                 "v": jnp.zeros(shape, jnp.int8),
                 "v_scale": jnp.zeros(scale_shape, jnp.float32)}
     # a model with recurrent layers carries their state beside the K/V,
-    # the batch in the slots' place
+    # the batch in the slots' place; attention that selects its blocks,
+    # the compressed keys
     return {"k": jnp.zeros(shape, config.jnp_dtype),
             "v": jnp.zeros(shape, config.jnp_dtype),
+            **_compressed_store(config, batch, max_len),
             **init_recurrent_state(config, batch)}
+
+
+def _compressed_store(config: TransformerConfig, blocks: int,
+                      block_size: int) -> dict:
+    """The store of compressed keys of an attention that selects its
+    blocks ({} of any other): "kc" (n_caches, blocks, K/V heads,
+    block_size / sparse_stride, head_dim), a block's entries the
+    compressed keys that START in it (parallel/sparse.py).  A contiguous
+    cache is one block of max_len positions a sequence."""
+    if not config.sparse_topk:
+        return {}
+    return {"kc": jnp.zeros(
+        (config.n_caches, blocks, config.n_kv_heads,
+         block_size // config.sparse_stride, config.head_dim),
+        config.jnp_dtype)}
 
 
 # the leaves of a cache or a pool that are recurrent state, not K/V, each
 # with the axis its sequences (a cache's batch, a pool's slots) lie on
-_STATE_LEAVES = {"conv": 2, "ssm": 1, "delta": 1}
+_STATE_LEAVES = {"conv": 2, "ssm": 1, "delta": 1, "lightning": 1}
 
 
 def _layer_state(config: TransformerConfig, slots: int) -> dict:
     """One recurrent layer's state of `slots` sequences from their
     start, by the model's recurrent kind."""
+    if config.recurrent_kind == "lightning":
+        return {"lightning": jnp.zeros(
+            (slots, config.lightning_heads, config.lightning_head_dim,
+             config.lightning_head_dim), jnp.float32)}
     if config.recurrent_kind == "delta":
         return {"conv": jnp.zeros((config.delta_conv - 1, slots,
                                    config.delta_conv_channels),
@@ -863,7 +991,9 @@ def init_recurrent_state(config: TransformerConfig, slots: int) -> dict:
     the [q | k | v] channels) and "delta" (n_states, slots, value heads,
     delta_key_dim, delta_value_dim) float32, a head's S whole in its
     minor pair, so that a decode step reads and writes it where it lies
-    (parallel/delta.py gdn_step)."""
+    (parallel/delta.py gdn_step).  Lightning: "lightning" (n_states, slots,
+    heads, head_dim, head_dim) float32 alone, laid out and advanced the
+    same way (parallel/lightning.py lightning_step)."""
     if not config.recurrent:
         return {}
     return {name: jnp.zeros((config.n_states,) + leaf.shape, leaf.dtype)
@@ -1085,6 +1215,15 @@ def _row_tiles(live, fn, layer, *operands, carry=None, outputs=None):
     return state[1] if carry is None else state
 
 
+def _residual(config: TransformerConfig, h, branch):
+    """h + residual_scale x branch (muP's depth scale; 1 of every model
+    that has none: the plain sum)."""
+    if config.residual_scale == 1.0:
+        return h + branch
+    return h + (branch.astype(jnp.float32)
+                * config.residual_scale).astype(h.dtype)
+
+
 def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend,
                    true_len=None):
     """THE decoder layer, on every path: attention norm, projections and
@@ -1115,14 +1254,14 @@ def _decoder_layer(config: TransformerConfig, layer, h, cos, sin, attend,
                     out.transpose(0, 2, 1, 3).reshape(batch, length, -1))
         if config.sandwich_norm:
             out = rms_norm(layer["attn_out_norm"], out, config.norm_eps)
-        h = h + out
+        h = _residual(config, h, out)
         return h, rms_norm(layer["mlp_norm"], h, config.norm_eps)
 
     def ffn_out(layer, h, mlp_out):
         if config.sandwich_norm:
             mlp_out = rms_norm(layer["mlp_out_norm"], mlp_out,
                                config.norm_eps)
-        return h + mlp_out
+        return _residual(config, h, mlp_out)
 
     def close(layer, h, out):
         h, mlp_in = attention_out(layer, h, out)
@@ -1152,7 +1291,10 @@ def scan_kind(config: TransformerConfig, length: int) -> str:
     (parallel/ssm.py) decides by ssm_scan_takes, as ssm_scan does, on the
     rows a call is handed (a row tile's where the bucket runs by row
     tiles); a delta layer's chunkwise recurrence (parallel/delta.py) is
-    XLA's.  The engine names its prefill spans by this."""
+    XLA's, and so is a lightning layer's (parallel/lightning.py), named
+    for what it is.  The engine names its prefill spans by this."""
+    if config.recurrent_kind == "lightning":
+        return "lightning_chunk"
     if config.recurrent_kind == "delta":
         return "jnp"
     rows = _ROW_TILE if _row_tiles_take(config, length) else length
@@ -1169,7 +1311,10 @@ def state_step_kind(config: TransformerConfig) -> str:
     The step hands the mixers the whole leaf and the layer's index, and
     they decide by ssm_step_takes / delta_step_takes, as here.  The
     engine names its decode spans by this."""
-    if config.recurrent_kind == "delta":
+    if config.recurrent_kind == "lightning":
+        takes = lightning_step_takes(config.lightning_head_dim,
+                                     config.lightning_heads)
+    elif config.recurrent_kind == "delta":
         takes = delta_step_takes(config.delta_key_dim, config.delta_value_dim,
                                  config.delta_value_heads)
     else:
@@ -1185,7 +1330,7 @@ def scan_rows(config: TransformerConfig, bucket: int, true_len: int) -> int:
     rows that holds row true_len - 1, XLA's form runs them all (the rows
     past true_len leaving the state alone)."""
     return ssm_scan_rows(prefill_rows(config, bucket, true_len), true_len,
-                         scan_kind(config, bucket) != "jnp")
+                         scan_kind(config, bucket) == "kernel")
 
 
 def _causal_conv(x, tail, taps, stop):
@@ -1322,23 +1467,88 @@ def _delta_mixer(config: TransformerConfig, layer, u, tail, state, stop):
             state)
 
 
+def _lightning_mixer(config: TransformerConfig, layer, u, state, stop, cos,
+                     sin):
+    """MiniCPM-SALA's lightning-attention mixer over the normed rows u (B,
+    L, d) of B sequences whose rows' rotary tables are cos, sin (B or 1,
+    1, L, head_dim / 2), each from its own `state`, S (B, heads, d_h, d_h)
+    float32 -- or, for a decode step over the slots' states, (the stack of
+    the layers' (layers, B, ...), this layer's index), which the step's
+    kernel reads and writes where it lies.  Returns (out (B, L, d), the
+    new state in the form it came): the state after row stop - 1, as
+    _mamba_mixer.
+
+        [q | k | v | g] = u W_qkvg
+        q = rope(norm(q)) / sqrt(d_h),  k = rope(norm(k))   a head, gains
+                                                            of their own
+        S_t = lambda_h S_{t-1} + k_t^T v_t;  o_t = q_t S_t  float32
+        out = (rms_norm(o) * gain * sigmoid(g)) W_out       the norm a head
+
+    One row (a decode step) is lightning_step's update; more are
+    lightning_scan's."""
+    f32 = jnp.float32
+    heads, hd = config.lightning_heads, config.lightning_head_dim
+    batch, length, _ = u.shape
+    mixed, inner = dense(layer["w_qkvg"], u), heads * hd
+    q, k, v, gate = (
+        mixed[..., part * inner:(part + 1) * inner].reshape(
+            batch, length, heads, hd).swapaxes(1, 2) for part in range(4))
+    q = apply_rotary(rms_norm(layer["q_norm"], q, config.norm_eps), cos, sin)
+    k = apply_rotary(rms_norm(layer["k_norm"], k, config.norm_eps), cos, sin)
+    q = (q.astype(f32) * hd ** -0.5).astype(u.dtype)
+    decay = decay_rates(heads)
+    if length > 1:
+        out, state = lightning_scan(q, k, v, decay, state, stop)
+    else:
+        row = (q[:, :, 0].astype(f32), k[:, :, 0].astype(f32),
+               v[:, :, 0].astype(f32), decay)
+        if isinstance(state, tuple):
+            out, stack = lightning_step(*row, *state)
+            state = (stack, state[1])
+        else:
+            out, state = lightning_step_reference(*row, state)
+        out = out[:, :, None]
+    out = rms_norm(layer["out_norm"], out.swapaxes(1, 2).astype(u.dtype),
+                   config.norm_eps)
+    out = (out.astype(f32) * jax.nn.sigmoid(
+        gate.swapaxes(1, 2).astype(f32))).astype(u.dtype)
+    return dense(layer["w_out"], out.reshape(batch, length, -1)), state
+
+
 def _layer_kind(layer) -> str:
     """The kind of a layer (or of a stack of like layers), by the leaf
     only that kind's mixer has."""
     if "w_in" in layer:
         return "mamba"
+    if "w_qkvg" in layer:
+        return "lightning"
     return "delta" if "w_qkvz" in layer else "attention"
 
 
-# a recurrent kind's mixer and the leaf of its state beside "conv"
-_MIXERS = {"mamba": (_mamba_mixer, "ssm"), "delta": (_delta_mixer, "delta")}
+# a recurrent kind's mixer and the leaves of its state, as it takes them
+_MIXERS = {"mamba": (_mamba_mixer, ("conv", "ssm")),
+           "delta": (_delta_mixer, ("conv", "delta")),
+           "lightning": (_lightning_mixer, ("lightning",))}
+
+
+def _lightning_rope(config: TransformerConfig, positions) -> dict:
+    """What a lightning layer is handed by name for the rows at
+    `positions` (..., L): {"rope": (cos, sin)}, each (..., 1, L,
+    lightning_head_dim / 2), a heads axis put in; {} of a model that has
+    no such layer."""
+    if config.recurrent_kind != "lightning":
+        return {}
+    return {"rope": tuple(
+        jnp.expand_dims(table, -3) for table in rotary_embedding(
+            positions, config.lightning_head_dim, config.rope_theta))}
 
 
 def _recurrent_rows(config: TransformerConfig, layer, carry, stop, h,
-                    apart: bool = False):
+                    *rope, apart: bool = False):
     """A recurrent layer over the rows h (B, L, d), a whole sequence's or
-    a row tile's, from `carry`, the convolution's tail and the recurrent
-    state before row 0: norm, mixer, residual, MLP norm, FFN, residual.
+    a row tile's, from `carry`, the convolution's tail (where the mixer
+    has one) and the recurrent state before row 0: norm, mixer, residual,
+    MLP norm, FFN, residual; `rope` a lightning layer's rows' tables.
     Returns (the carry after row stop - 1, (h, the FFN's stats)) -- or,
     `apart` (the routed experts, which group the rows of the whole
     sequence, not of a tile), (the carry, (h before the FFN, the FFN's
@@ -1346,13 +1556,13 @@ def _recurrent_rows(config: TransformerConfig, layer, carry, stop, h,
     mixer, _ = _MIXERS[_layer_kind(layer)]
     out, *carry = mixer(
         config, layer, rms_norm(layer["mixer_norm"], h, config.norm_eps),
-        *carry, stop)
-    h = h + out
+        *carry, stop, *rope)
+    h = _residual(config, h, out)
     mlp_in = rms_norm(layer["mlp_norm"], h, config.norm_eps)
     if apart:
         return tuple(carry), (h, mlp_in)
     mlp_out, stats = _mlp_block(config, layer, mlp_in, stop)
-    return tuple(carry), (h + mlp_out, stats)
+    return tuple(carry), (_residual(config, h, mlp_out), stats)
 
 
 # _recurrent_rows of one row tile, the layer's leaves sliced out of their
@@ -1367,37 +1577,41 @@ _recurrent_tile = jax.jit(_recurrent_rows,
                           static_argnames=("config", "apart"))
 
 
-def _stateful_layer(config: TransformerConfig, layer, h, state, stop):
-    """_mamba_layer and _delta_layer: h + mixer(norm(h)), then the FFN as
-    a decoder layer has it (_recurrent_rows), the kind by the layer's
-    leaves.  Where the bucket runs by row tiles (_tiled) the layer runs a
-    tile at a time over the tiles that hold a live row, in one loop that
+def _stateful_layer(config: TransformerConfig, layer, h, state, stop,
+                    rope=()):
+    """_mamba_layer, _delta_layer and _lightning_layer: h + mixer(norm(h)),
+    then the FFN as a decoder layer has it (_recurrent_rows), the kind by
+    the layer's leaves; `rope` a lightning layer's rows' rotary tables (.,
+    1, L, .), which ride the row tiles as h does.  Where the bucket runs
+    by row tiles (_tiled) the layer runs a tile at a time over the tiles
+    that hold a live row, in one loop that
     carries what crosses a tile's edge and nothing else -- the
     convolution's tail and the recurrent state, each tile stopping at its
     share of `stop` -- so the last live tile leaves the state after row
     stop - 1 and the tail at `stop`, as the bucket run whole does, and h
     is zeros past the live tiles.  The routed experts stay between the
     loop and the residual, as in _decoder_layer."""
-    _, leaf = _MIXERS[_layer_kind(layer)]
+    _, leaves = _MIXERS[_layer_kind(layer)]
     if state is None:
         state = _layer_state(config, h.shape[0])
-    carry = (state["conv"], state[leaf])
+    carry = tuple(state[leaf] for leaf in leaves)
     live = _tiled(config, stop, h.shape[1])
     if live is None:
-        carry, (h, stats) = _recurrent_rows(config, layer, carry, stop, h)
+        carry, (h, stats) = _recurrent_rows(config, layer, carry, stop, h,
+                                            *rope)
     else:
         apart = bool(config.top_k and "router" in layer)
         zeros = jnp.zeros_like(h)
         carry, (h, stats) = _row_tiles(
             live, lambda layer, *rows: _recurrent_tile(
                 config, layer.sliced(), *rows, apart=apart),
-            layer, h, carry=carry, outputs=(
+            layer, h, *rope, carry=carry, outputs=(
                 zeros, zeros if apart else jnp.zeros((_FFN_STATS,),
                                                      jnp.float32)))
         if apart:
             mlp_out, stats = _routed_moe(config, layer, stats, stop)
             h = h + mlp_out
-    return h, stats, dict(zip(("conv", leaf), carry))
+    return h, stats, dict(zip(leaves, carry))
 
 
 def _mamba_layer(config: TransformerConfig, layer, h, state, stop=None):
@@ -1419,11 +1633,22 @@ def _delta_layer(config: TransformerConfig, layer, h, state, stop=None):
     return _stateful_layer(config, layer, h, state, stop)
 
 
+def _lightning_layer(config: TransformerConfig, layer, h, state, stop=None,
+                     rope=()):
+    """A lightning-attention layer: h + scale x mixer(norm(h)), then the
+    dense FFN as a decoder layer has it.  `state` is the layer's
+    {"lightning"} for h's B sequences (as _lightning_mixer takes it), or
+    None: zeros, a sequence from its start; `stop` as _mamba_layer's;
+    `rope` the rows' rotary tables (_lightning_rope, a heads axis put in).
+    Returns (h, the FFN's stats, the new state)."""
+    return _stateful_layer(config, layer, h, state, stop, rope)
+
+
 def _recurrent_layer(layer):
     """The body of a recurrent layer, by its kind; None for a layer that
     attends."""
-    return {"mamba": _mamba_layer, "delta": _delta_layer}.get(
-        _layer_kind(layer))
+    return {"mamba": _mamba_layer, "delta": _delta_layer,
+            "lightning": _lightning_layer}.get(_layer_kind(layer))
 
 
 def _sp_prefill(config: TransformerConfig, q, k, v):
@@ -1434,9 +1659,38 @@ def _sp_prefill(config: TransformerConfig, q, k, v):
     return ring_attention(q, k, v, causal=True)
 
 
+def _attend_selecting(config: TransformerConfig, q, k, v, live=None,
+                      flash: bool = True):
+    """Causal attention from position 0 of an attention that selects its
+    blocks: the rows under sparse_dense_len over every earlier row
+    (blockwise where `flash`, else the masked einsum), the rows from it on
+    over the blocks they choose (parallel/sparse.py), a sequence at a
+    time.  `live` is a whole prefill's true length.  Returns (out, the
+    compressed keys (B, G, ceil(L / block) x block / stride, hd))."""
+    sizes = config.sparse_sizes
+    length = q.shape[2]
+    rows = min(length, sizes.dense_len)
+    first = [x[:, :, :rows] for x in (q, k, v)]
+    if flash:
+        out = flash_attention(*first, causal=True, live=(
+            None if live is None else jnp.minimum(live, rows)))
+    else:
+        repeats = config.n_heads // config.n_kv_heads
+        out = attention_reference(first[0], repeat_kv(first[1], repeats),
+                                  repeat_kv(first[2], repeats), causal=True)
+    chosen, compressed = jax.vmap(
+        lambda q, k, v: sparse_prefill_attention(
+            q[None], k[None], v[None], sizes, live))(q, k, v)
+    return jnp.concatenate([out, chosen[:, 0, :, rows:]],
+                           axis=2), compressed[:, 0]
+
+
 def _attend_fresh(config: TransformerConfig, layer, q, k, v):
     """No KV store (training, scoring): causal attention over the fresh
-    K/V, blockwise."""
+    K/V, blockwise; of an attention that selects, over the blocks each
+    row chooses."""
+    if config.sparse_topk:
+        return _attend_selecting(config, q, k, v)[0], None
     if config.kv_lora_rank:
         return _latent_flash(config, q,
                              *_latent_expand(config, layer, k)), None
@@ -1464,8 +1718,9 @@ def _store_leaf(store: dict):
 
 def cache_attention_kind(config: TransformerConfig, store: dict, batch: int,
                          length: int, pos=0) -> str:
-    """"flash" or "einsum": what a forward of (batch, length) tokens at
-    `pos` attends through when its K/V go to a contiguous cache of
+    """"flash", "einsum" or, of an attention that selects its blocks past
+    sparse_dense_len rows, "sparse": what a forward of (batch, length)
+    tokens at `pos` attends through when its K/V go to a contiguous cache of
     `store`'s dtype (a cache, or the pool paged_prefill scatters its
     cache into: their leaves are alike).  _attend_cache decides by this,
     and the engine names its prefill spans by it."""
@@ -1475,6 +1730,9 @@ def cache_attention_kind(config: TransformerConfig, store: dict, batch: int,
 
 def _cache_attention_kind(config: TransformerConfig, dtype, batch: int,
                           length: int, pos) -> str:
+    if config.sparse_topk and length > config.sparse_dense_len:
+        # its first sparse_dense_len rows attend as a bucket of that many
+        return "sparse"
     if (length > 1 and isinstance(pos, (int, np.integer)) and pos == 0
             and flash_attention_takes(batch, config.n_heads, length, dtype,
                                       dtype)):
@@ -1493,8 +1751,13 @@ def prefill_attention_rows(config: TransformerConfig, bucket: int,
     attention's own, whether or not the bucket runs by row tiles -- and
     the engine names its prefill spans by this."""
     dtype = jnp.int8 if config.kv_dtype == "int8" else config.jnp_dtype
-    if (config.sequence_parallel or _cache_attention_kind(
-            config, dtype, 1, bucket, 0) != "flash"):
+    kind = _cache_attention_kind(config, dtype, 1, bucket, 0)
+    if kind == "sparse":
+        # the selection's query tiles past dense_len that hold a live row
+        tile = sparse_tile(bucket, config.sparse_sizes)
+        return min(bucket, max(config.sparse_dense_len,
+                               -(-true_len // tile) * tile))
+    if config.sequence_parallel or kind != "flash":
         return bucket
     kv_heads, width = config.n_kv_heads, config.head_dim
     if config.kv_lora_rank:
@@ -1524,6 +1787,34 @@ def _attend_cache_latent(config: TransformerConfig, cache: dict, pos,
         q_offset=pos - (k.shape[2] - length)), cache
 
 
+def _attend_cache_selecting(config: TransformerConfig, cache: dict, pos, q,
+                            k, v, live=None):
+    """_attend_cache for an attention that selects its blocks: a prefill
+    from the static position 0 (_attend_selecting), which also leaves the
+    sequence's compressed keys in the cache's "kc".  Anything else -- a
+    step or a window at a later position of a contiguous cache -- is
+    refused: the compressed keys are carried through the paged pool's
+    decode step (_attend_pool) and through nothing else."""
+    batch, _, length, _ = q.shape
+    if not (isinstance(pos, (int, np.integer)) and pos == 0 and length > 1):
+        raise ValueError(
+            "a contiguous cache at a later position (decode_step, "
+            "generate(), generate_stream()) is not implemented for an "
+            "attention that selects its blocks: the store of compressed "
+            "keys is carried through the paged pool's decode step only")
+    cache = dict(cache, **{
+        name: jax.lax.dynamic_update_slice(cache[name], value, (0, 0, 0, 0))
+        for name, value in (("k", k), ("v", v))})
+    out, compressed = _attend_selecting(
+        config, q, k, v, live, flash=_cache_attention_kind(
+            config, cache["k"].dtype, batch,
+            min(length, config.sparse_dense_len), 0) == "flash")
+    compressed = compressed[:, :, :cache["kc"].shape[2]]
+    cache["kc"] = jax.lax.dynamic_update_slice(cache["kc"], compressed,
+                                               (0, 0, 0, 0))
+    return out, cache
+
+
 def _attend_cache(config: TransformerConfig, cache: dict, pos, layer,
                   q, k, v, live=None):
     """Contiguous cache (init_cache; `cache` is one layer's leaves):
@@ -1535,6 +1826,8 @@ def _attend_cache(config: TransformerConfig, cache: dict, pos, layer,
     read), where there is one."""
     if config.kv_lora_rank:
         return _attend_cache_latent(config, cache, pos, layer, q, k, live)
+    if config.sparse_topk:
+        return _attend_cache_selecting(config, cache, pos, q, k, v, live)
     batch, _, length, hd = q.shape
     cache = {name: jax.lax.dynamic_update_slice(cache[name], value,
                                                 (0, 0, pos, 0))
@@ -1712,6 +2005,8 @@ def _embed(params: dict, config: TransformerConfig, tokens):
     jnp.take's default FILL mode, whose NaN embeddings silently poison
     every downstream activation."""
     h = jnp.take(params["embed"]["w"], tokens, axis=0, mode="clip")
+    if config.embed_scale != 1.0:
+        h = h * jnp.asarray(config.embed_scale, h.dtype)
     if h.dtype == jnp.int8:
         # int8 embed (quantize_weights_int8): gather the rows' scales
         # alongside and dequantize only the gathered tokens
@@ -1897,8 +2192,10 @@ def _mlp_block(config: TransformerConfig, layer, mlp_in, true_len=None):
 def _lm_head(params: dict, config: TransformerConfig, h):
     """Output norm + logits head shared by forward() and the paged
     decode path."""
-    return _head_logits(
-        params, rms_norm(params["norm_out"], h, config.norm_eps))
+    h = rms_norm(params["norm_out"], h, config.norm_eps)
+    if config.logit_divisor != 1.0:
+        h = (h.astype(jnp.float32) / config.logit_divisor).astype(h.dtype)
+    return _head_logits(params, h)
 
 
 def _head_logits(params: dict, h):
@@ -2089,6 +2386,8 @@ def _hidden(params: dict, config: TransformerConfig, tokens, cache, pos,
     positions = pos + jnp.arange(length)
     cos, sin = _rotary_tables(config, positions)
     cos, sin = cos[None, None], sin[None, None]  # (1, 1, L, hd/2)
+    # a lightning layer's own tables, handed to it by name
+    rope = _lightning_rope(config, positions)
 
     def layer_step(carry, xs):
         h, stats_sum = carry
@@ -2097,7 +2396,7 @@ def _hidden(params: dict, config: TransformerConfig, tokens, cache, pos,
         if recurrent:
             # a recurrent layer: its state after row true_len - 1
             h, stats, new_cache = recurrent(config, layer, h, layer_cache,
-                                            true_len)
+                                            true_len, **rope)
         else:
             h, stats, new_cache = _decoder_layer(
                 config, layer, h, cos, sin,
@@ -2292,6 +2591,11 @@ def init_paged_pool(config: TransformerConfig, num_blocks: int,
     recurrent layers has its slots' recurrent state beside these leaves, in
     the same dict (init_recurrent_state: by slot, not by block); the
     engine makes both."""
+    if config.sparse_topk and block_size != config.sparse_block:
+        raise ValueError(
+            f"an attention that selects blocks of {config.sparse_block} "
+            f"positions needs a pool of such blocks (kv_block_size), not "
+            f"of {block_size}: a chosen block is a pool block")
     if config.kv_lora_rank:
         # the latent pool: one leaf, one row a position a layer
         return {"kv": jnp.zeros(
@@ -2306,7 +2610,8 @@ def init_paged_pool(config: TransformerConfig, num_blocks: int,
                 "v": jnp.zeros(shape, jnp.int8),
                 "v_scale": jnp.zeros(scale_shape, jnp.float32)}
     return {"k": jnp.zeros(shape, config.jnp_dtype),
-            "v": jnp.zeros(shape, config.jnp_dtype)}
+            "v": jnp.zeros(shape, config.jnp_dtype),
+            **_compressed_store(config, num_blocks, block_size)}
 
 
 @partial(jax.jit, static_argnames=("config",), donate_argnums=(2,))
@@ -2352,9 +2657,10 @@ def paged_prefill(params, config: TransformerConfig, pool, prompt,
         # (caches, 1, H, Lb, d) -> (caches, blocks, H, block_size, d),
         # scattered into the slot's first `blocks` pool entries: cache
         # and pool lead with the same axis (_cache_index)
+        # (the compressed keys: block_size / stride entries a block)
         entry = written[:, 0]
-        layers, heads, _, depth = entry.shape
-        entry = entry.reshape(layers, heads, blocks, block_size,
+        layers, heads, rows, depth = entry.shape
+        entry = entry.reshape(layers, heads, blocks, rows // blocks,
                               depth).transpose(0, 2, 1, 3, 4)
         new_pool[name] = pool[name].at[:, table_row[:blocks]].set(entry)
     return new_pool, first
@@ -2463,6 +2769,58 @@ def _attend_paged(config: TransformerConfig, pool: dict, written: dict,
     return out, {**pool, **dict(zip(names, leaves))}
 
 
+def _attend_pool_selecting(config: TransformerConfig, pool: dict, index,
+                           tables, positions, write_blocks, write_offsets,
+                           q, k, v):
+    """_attend_pool for an attention that selects its blocks, a decode
+    step (window 1): the new rows go into the pool where they lie
+    (_write_window); a slot whose row completes a compressed key has the
+    mean of its `kernel` newest rows put into the store of compressed keys
+    (the others write the trash block's); every slot's query scores its
+    compressed keys and chooses its blocks, a K/V head; and the paged
+    kernel attends over the pool seen a K/V head a page, given the chosen
+    pages in order -- every page up to its own of a slot under
+    sparse_dense_len -- so that the K/V it reads are the chosen blocks'
+    (parallel/sparse.py decode_tables)."""
+    slots, heads, window, depth = q.shape
+    if window != 1:
+        raise ValueError(
+            f"a window of {window} positions over the paged pool (a "
+            f"speculative verify step, a prefill chunk) is not implemented "
+            f"for an attention that selects its blocks: the store of "
+            f"compressed keys is advanced a row a step")
+    sizes, groups = config.sparse_sizes, config.n_kv_heads
+    pool = dict(pool, **{
+        name: _write_window(pool[name], rows, index, write_blocks,
+                            write_offsets)
+        for name, rows in (("k", k), ("v", v))})
+    due, which = due_compressed(positions, sizes)
+    owner = jnp.take_along_axis(
+        tables, (which // sizes.per_block)[:, None], axis=1)[:, 0]
+    pool["kc"] = _write_window(
+        pool["kc"], completed_key(pool["k"], index, tables, positions,
+                                  sizes), index,
+        jnp.where(due, owner, 0)[:, None],          # else the trash block
+        jnp.where(due, which % sizes.per_block, 0)[:, None])
+    pages, at = decode_tables(q, pool["kc"], index, tables, positions, sizes)
+
+    def by_head(leaf):
+        # (L, blocks, G, block, d) as (L, blocks x G, 1, block, d): a K/V
+        # head's block a page of its own, the bytes where they lie
+        return leaf.reshape(leaf.shape[0], -1, 1, *leaf.shape[3:])
+
+    attend = (paged_attention if paged_attention_takes(
+        heads // groups, 1, depth, pool["k"].dtype)
+        else paged_attention_reference)
+    with jax.named_scope("sparse_attention"):
+        out = attend(q.reshape(slots * groups, heads // groups, 1, depth),
+                     by_head(pool["k"]), by_head(pool["v"]), index, pages,
+                     at)
+    if isinstance(out, tuple):
+        out = out[0]
+    return out.reshape(q.shape), pool
+
+
 def _attend_pool(config: TransformerConfig, pool: dict, index, tables,
                  positions, write_blocks, write_offsets, layer, q, k, v):
     """Paged pool (init_paged_pool; `pool` is the whole pool, `index`
@@ -2479,6 +2837,9 @@ def _attend_pool(config: TransformerConfig, pool: dict, index, tables,
     if config.kv_lora_rank:
         return _attend_pool_latent(config, pool, index, tables, positions,
                                    write_blocks, write_offsets, layer, q, k)
+    if config.sparse_topk:
+        return _attend_pool_selecting(config, pool, index, tables, positions,
+                                      write_blocks, write_offsets, q, k, v)
     return _attend_paged(config, pool, _kv_to_write(pool, k, v), index,
                          tables, positions, write_blocks, write_offsets, q)
 
@@ -2525,6 +2886,7 @@ def _paged_logits(params, config: TransformerConfig, pool, tables,
     q_pos = positions[:, None] + jnp.arange(tokens.shape[1])[None, :]
     cos, sin = _rotary_tables(config, q_pos)
     cos, sin = cos[:, None], sin[:, None]        # (S, 1, W, hd/2)
+    rope = _lightning_rope(config, q_pos)
 
     def layer_step(carry, xs):
         # the pool rides the loop (and a looped stack's passes) as CARRY
@@ -2539,13 +2901,14 @@ def _paged_logits(params, config: TransformerConfig, pool, tables,
             # place: row s of h is slot s's.  Its S goes to the mixer's
             # step as the whole leaf and the layer's index, and comes
             # back as the leaf
-            leaf = _MIXERS[kind][1]
+            *tail, leaf = _MIXERS[kind][1]
             h, stats, state = _recurrent_layer(layer)(
-                config, layer, h, {"conv": pool["conv"][index],
-                                   leaf: (pool[leaf], index)})
-            pool = {**pool, leaf: state[leaf][0],
-                    "conv": jax.lax.dynamic_update_index_in_dim(
-                        pool["conv"], state["conv"], index, 0)}
+                config, layer, h, {
+                    **{name: pool[name][index] for name in tail},
+                    leaf: (pool[leaf], index)}, **rope)
+            pool = {**pool, leaf: state[leaf][0], **{
+                name: jax.lax.dynamic_update_index_in_dim(
+                    pool[name], state[name], index, 0) for name in tail}}
         else:
             h, stats, pool = _decoder_layer(
                 config, layer, h, cos, sin,
@@ -2656,7 +3019,9 @@ RECORD_COUNTERS = {
     "writes_updates": 0, "latent_positions": 0, "ut_passes": 0,
     "cache_rows": 0, "state_slots": 0, "state_bytes": 0,
     "state_step_kernel": 0, "state_step_jnp": 0, "experts_read": 0,
-    "expert_pairs": 0, "exit_expected_step": 0.0}
+    "expert_pairs": 0, "exit_expected_step": 0.0,
+    "prefill_sparse": 0, "scan_lightning_chunk": 0, "select_rows": 0,
+    "sparse_blocks_read": 0, "sparse_blocks_live": 0, "compressed_rows": 0}
 
 
 def _looped_record(config: TransformerConfig, positions: int) -> dict:
@@ -2687,6 +3052,10 @@ def prefill_record(config: TransformerConfig, pool: dict, bucket: int,
         fields["scan"] = scan_kind(config, bucket)
         counts["scan_" + fields["scan"]] = 1
         sums["scan_rows"] = scan_rows(config, bucket, true_len)
+    if config.sparse_topk:
+        # the rows that chose their blocks, an attention layer
+        sums["select_rows"] = (max(true_len - config.sparse_dense_len, 0)
+                               if attention == "sparse" else 0)
     return {**fields, **sums}, {**counts, **sums}
 
 
@@ -2713,6 +3082,16 @@ def window_record(config: TransformerConfig, pool: dict, window: int,
             sums.update(state_slots=len(decoding),
                         state_bytes=2 * len(decoding) * config.state_bytes,
                         cache_rows=live * config.n_caches)
+        if config.sparse_topk:
+            # a K/V head of an attention layer: the blocks its query reads
+            # beside those it chose from, and the compressed keys it scored
+            sizes, at = config.sparse_sizes, positions[decoding]
+            each = config.n_kv_heads * config.n_caches
+            sums.update(
+                sparse_blocks_read=each * int(blocks_read(at, sizes).sum()),
+                sparse_blocks_live=each * int((at // sizes.block + 1).sum()),
+                compressed_rows=each * int(np.maximum(
+                    (at - sizes.kernel + 1) // sizes.stride + 1, 0).sum()))
     elif true_len is not None:
         sums.update(_looped_record(
             config, min(int(positions[0]) + window, true_len)))

@@ -131,6 +131,15 @@
 #                               stops after the block of rows that holds
 #                               row true_len - 1); running sum
 #                               scan_rows, counts scan_kernel, scan_jnp
+#                               (lightning layers: scan lightning_chunk,
+#                               count scan_lightning_chunk).  Of an
+#                               attention that selects its blocks
+#                               attention is sparse for a bucket past
+#                               sparse_dense_len (count prefill_sparse),
+#                               attn_rows then the selection's query
+#                               tiles that hold a live row, and
+#                               select_rows the rows that chose their
+#                               blocks, a layer (running sum)
 #   engine.decode      scoped   table build + dispatch.  The engine's
 #                               own: decoding, ahead (1: dispatched
 #                               while the step before was unread, from
@@ -186,7 +195,16 @@
 #                               read and written once where they lie --
 #                               ssm_row_step, gdn_step -- or XLA's passes
 #                               over the layer's slice; running counts
-#                               state_step_kernel, state_step_jnp)
+#                               state_step_kernel, state_step_jnp).
+#                               Of an attention that selects its blocks
+#                               (host-counted from the positions, a K/V
+#                               head of an attention layer a slot):
+#                               sparse_blocks_read (the blocks the
+#                               step's attention reads: sparse_topk from
+#                               sparse_dense_len on), sparse_blocks_live
+#                               (the blocks it chose from) and
+#                               compressed_rows (the compressed keys it
+#                               scored); running sums in engine_stats()
 #   engine.readback    scoped   the settle's readback of the step in
 #                               flight: in a tick after that tick's
 #                               engine.decode where it ran ahead, or

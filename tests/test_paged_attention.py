@@ -1053,3 +1053,109 @@ def test_served_qnext_prefill_traces_a_delta_layer_once(for_the_chip,
     assert len(calls) == 1
     assert text.count("stablehlo.custom_call @tpu_custom_call") == 5
     assert text.count("func.func private @_recurrent_rows") == 1
+
+
+# One pipeline stage of MiniCPM-SALA as sala.longdoc serves it
+# (benchmark/configs/minicpm_sala_pp4_l8.json): 6 lightning-attention
+# layers whose state is a slot's beside 2 attention layers that select 64
+# of a context's blocks of 64 by the compressed keys stored beside K/V; 16
+# slots of 560 blocks
+SALA = dict(slots=16, block=64, max_blocks=560, states=6, caches=2)
+
+
+def _served_sala(for_the_chip, monkeypatch):
+    """(config, params, pool, int32): the stage as sala.longdoc serves it,
+    the slots' states and the compressed keys among the pool's leaves,
+    placed on the described chip; the lightning step lowered through
+    Mosaic."""
+    import json
+    import pathlib
+    from aiko_services_tpu.models.configs import minicpm_sala_config
+    from aiko_services_tpu.models.transformer import init_recurrent_state
+    from aiko_services_tpu.parallel import lightning
+    monkeypatch.setattr(lightning, "_interpret", lambda: False)
+    published = json.loads((pathlib.Path(__file__).parent.parent
+                            / "benchmark/configs/minicpm_sala_pp4_l8.json"
+                            ).read_text())
+    serve = published["serve"]
+    s = SALA
+    assert (serve["decode_slots"], serve["kv_block_size"],
+            serve["max_context"]) == (
+        s["slots"], s["block"], s["block"] * s["max_blocks"])
+    assert serve["kv_blocks"] == s["slots"] * s["max_blocks"] + 1
+    config = minicpm_sala_config(
+        {key: value for key, value in published.items()
+         if key not in ("serve", "deployment", "assumed", "reduced")},
+        serve["max_context"])
+    assert (config.n_states, config.n_caches) == (s["states"], s["caches"])
+    place = lambda tree: jax.tree_util.tree_map(       # noqa: E731
+        lambda leaf: for_the_chip(leaf.shape, leaf.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: init_params(config, jax.random.PRNGKey(0))))
+    pool = place(jax.eval_shape(lambda: {
+        **init_paged_pool(config, serve["kv_blocks"], s["block"]),
+        **init_recurrent_state(config, s["slots"])}))
+    return config, params, pool, lambda *shape: for_the_chip(shape, "int32")
+
+
+def test_served_sala_decode_step_stages_nothing_on_the_v5e(for_the_chip,
+                                                           monkeypatch):
+    """The step advances every slot's S a row in place through
+    `lightning_step` and attends over the chosen blocks through the paged
+    kernel, the pool seen a K/V head a page: the donated leaves (0.20 GB
+    of state, 1.17 GB of K/V, 37 MB of compressed keys) come back as the
+    buffers they were, no copy of any of them and no weight staged (a
+    5-D split of [q | k | v | g]'s product once made XLA copy 134 MB of
+    W_qkvg a layer a step)."""
+    s = SALA
+    config, params, pool, int32 = _served_sala(for_the_chip, monkeypatch)
+    assert pool["lightning"].shape == (6, 16, 32, 128, 128)
+    assert pool["k"].shape == (2, 8961, 2, 64, 128)
+    assert pool["kc"].shape == (2, 8961, 2, 4, 128)
+    slots = s["slots"]
+    compiled = paged_decode_step.lower(
+        params, config, pool, int32(slots, s["max_blocks"]), int32(slots),
+        int32(slots, 1), int32(slots), int32(slots)).compile()
+    text = compiled.as_text()
+    for kernel in ("lightning_step", "paged_attention"):
+        assert kernel in text, kernel
+    memory = compiled.memory_analysis()
+    held = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+               for leaf in pool.values())
+    assert memory.alias_size_in_bytes == held
+    assert held == (16 * 6 * (2 << 20) + 2 * 2 * 8961 * 2 * 64 * 128 * 2
+                    + 2 * 8961 * 2 * 4 * 128 * 2)
+    assert memory.temp_size_in_bytes < 32 << 20
+    for name in ("lightning", "k", "v", "kc"):
+        assert not _made_of_a_leaf(text, pool[name], " copy("), name
+    assert not _made_of_a_leaf(text, pool["lightning"],
+                               " dynamic-update-slice(")
+    # weights 5.64 GB + state 0.20 + K/V 1.17 + compressed keys 0.04
+    assert 7.0e9 < memory.argument_size_in_bytes < 7.1e9
+
+
+@pytest.mark.parametrize("bucket", [16384, 32768])
+def test_served_sala_prefill_fits_the_chip_at_its_warm_buckets(
+        for_the_chip, monkeypatch, bucket):
+    """The buckets sala.longdoc sends: six chunkwise lightning rules by
+    row tiles, two sparse layers whose first 8192 rows attend through the
+    flash kernel and whose other rows gather the blocks they choose, a
+    tile of 128 rows at a time; the slot's state written into the donated
+    leaf; with the arguments under 10 GB of the chip's 16."""
+    s = SALA
+    config, params, pool, int32 = _served_sala(for_the_chip, monkeypatch)
+    compiled = paged_prefill.lower(
+        params, config, pool, int32(1, bucket), int32(s["max_blocks"]),
+        int32(), int32()).compile()
+    text = compiled.as_text()
+    assert "flash_attention" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2.5e9
+    assert not _made_of_a_leaf(text, pool["lightning"], " copy(")
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 10e9)
+    # the tile loop inside a lightning layer reads W_qkvg where it lies in
+    # the run's stack (134 MB a layer; at 16384 rows the hidden rows have
+    # as many elements, so the larger bucket is asked)
+    if bucket == 32768:
+        assert not _staged_whole(text, {4096 * 16384})

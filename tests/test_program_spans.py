@@ -790,3 +790,48 @@ class TestTelemetryOff:
             assert not pipeline.telemetry.snapshot().get("histograms")
         finally:
             process.terminate()
+
+
+# -- a model's own fields -------------------------------------------------------
+
+# what the engine's spans carry, beside a dense model's, for a model whose
+# lightning layers' state is a slot's and whose attention selects its blocks
+# (ISSUE 47; the model's record says them, models/transformer.py)
+SELECTING_HYBRID_FIELDS = {
+    "engine.prefill": {"scan", "scan_rows", "select_rows"},
+    "engine.decode": {"state_step", "state_slots", "state_bytes",
+                      "cache_rows", "sparse_blocks_read",
+                      "sparse_blocks_live", "compressed_rows"},
+}
+
+
+class TestAModelsOwnFields:
+    @pytest.mark.parametrize("name", sorted(SELECTING_HYBRID_FIELDS))
+    def test_a_selecting_hybrids_spans_carry_the_dense_fields_and_its_own(
+            self, name):
+        """The engine writes down what the model's record says and no
+        other field: a dense model's (SERVED_SPANS, less the request's
+        own, which the seam resolves) and the table's."""
+        from aiko_services_tpu.decode import DecodeEngine
+        from aiko_services_tpu.models.transformer import init_params
+        from test_decode import _Order
+        from test_minicpm_sala import PUBLISHED
+        from aiko_services_tpu.models.configs import minicpm_sala_config
+        config = minicpm_sala_config(PUBLISHED, max_seq_len=256)
+        spans = _Order()
+        engine = DecodeEngine(
+            init_params(config, jax.random.PRNGKey(0)), config,
+            decode_slots=1, kv_block_size=8, max_context=160, spans=spans)
+        engine.submit("r", np.arange(1, 71, dtype=np.int32), 6)
+        while engine.has_work():
+            engine.step()
+        events = spans.named(name)
+        assert events
+        for _, fields in events:
+            assert set(fields) == (SERVED_SPANS[name] - REQUEST
+                                   | SELECTING_HYBRID_FIELDS[name])
+        stats = engine.stats()
+        for counter in ("select_rows", "sparse_blocks_read",
+                        "sparse_blocks_live", "compressed_rows",
+                        "scan_lightning_chunk", "prefill_sparse"):
+            assert stats[counter] > 0, counter
