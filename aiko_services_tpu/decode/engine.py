@@ -149,6 +149,9 @@ class StepReport:
     emitted: list = field(default_factory=list)
     admitted: int = 0
     active: int = 0
+    # where step() was handed one: called with each such triple the
+    # moment it surfaces, and `emitted` stays empty
+    emit: object = None
 
 
 class _InFlight(NamedTuple):
@@ -742,14 +745,27 @@ class DecodeEngine:
 
     # -- the engine step ---------------------------------------------------
 
-    def step(self) -> StepReport:
+    def step(self, emit=None) -> StepReport:
         """One engine tick: admit waiting requests into free slots,
         advance every mid-prefill slot by one chunk, grow/preempt block
         allocations, then run ONE fused decode (or speculative verify)
         step over the decoding slots.  Chunked prefill progress and
         decode progress share the tick -- that interleaving is what
-        stops a long prompt from convoying every co-scheduled slot."""
+        stops a long prompt from convoying every co-scheduled slot.
+
+        `emit`, where given, is called with each (request_id, offset,
+        token) the moment the host holds it, before whatever the tick
+        dispatches next: a prefill's token leaves ahead of the next
+        admission's prefill.  What the device is given, and in what
+        order, is the same either way; without `emit` the tokens leave
+        in `report.emitted` when the tick ends.  Completions come with
+        the report in both cases, so after their last token."""
         report, self._carry = self._carry, StepReport()
+        if emit is not None:
+            # what a settle between steps surfaced is the oldest
+            carried, report.emitted, report.emit = report.emitted, [], emit
+            for emitted in carried:
+                emit(emitted)
         started = time.perf_counter()
         with self._spans.span("engine.step",
                               waiting=len(self.waiting)) as tick:
@@ -1532,10 +1548,12 @@ class DecodeEngine:
                 and request.generated[-1] == self.eos_id)
 
     def _surface(self, report: StepReport, request: _Request) -> None:
+        """The one place a token leaves the engine: at once through the
+        report's `emit`, else onto its `emitted`."""
+        emit = report.emit or report.emitted.append
         while request.emitted_upto < len(request.generated):
             offset = request.emitted_upto
-            report.emitted.append(
-                (request.request_id, offset, request.generated[offset]))
+            emit((request.request_id, offset, request.generated[offset]))
             request.emitted_upto = offset + 1
 
     def _complete(self, index: int) -> Completion:

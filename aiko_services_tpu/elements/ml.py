@@ -754,8 +754,8 @@ class LMGenerate(ComputeElement):
                     report = engine.adopt_request(
                         key + (row,), record,
                         timeout=(float(timeout) if timeout else None))
-                    for rid, _offset, token in report.emitted:
-                        self._buffer_streamed_token(rid, token)
+                    for emitted in report.emitted:
+                        self._buffer_streamed_token(emitted)
                     for completion in report.completions:
                         self._finish_request(completion)
                 self._note_adopt_span(stream, key,
@@ -827,8 +827,8 @@ class LMGenerate(ComputeElement):
                 # from offset 0, so its buffer keeps the default start
                 entry["buffers"][row] = [min(resume, max_new), [],
                                          time.perf_counter(), None]
-            for rid, _offset, token in report.emitted:
-                self._buffer_streamed_token(rid, token)
+            for emitted in report.emitted:
+                self._buffer_streamed_token(emitted)
             for completion in report.completions:
                 self._finish_request(completion)
         # restores ride the adopt span category: both are KV
@@ -946,9 +946,10 @@ class LMGenerate(ComputeElement):
 
     def _pump(self, engine):
         try:
-            report = engine.step()
-            for request_id, offset, token in report.emitted:
-                self._buffer_streamed_token(request_id, token)
+            # a token goes to its row's buffer the moment the engine
+            # holds it (a prefill's before the tick's next prefill is
+            # dispatched); the completions follow, each after its last
+            report = engine.step(emit=self._buffer_streamed_token)
             for completion in report.completions:
                 self._finish_request(completion)
             if getattr(self, "_checkpointer", None) is not None:
@@ -996,7 +997,13 @@ class LMGenerate(ComputeElement):
                 {"stream_id": stream_id, "frame_id": frame_id,
                  "node": self.definition.name, "event": "error"}, {}])
 
-    def _buffer_streamed_token(self, request_id, token):
+    def _buffer_streamed_token(self, emitted):
+        """One (request_id, offset, token) of the engine's into its row's
+        chunk: the row's first token is published at once, a chunk of
+        its own at offset 0 (it is what a reader waits for); every chunk
+        after it fills to `stream_chunk`.  A row restored past offset 0
+        has no first token to hurry."""
+        request_id, _offset, token = emitted
         entry = self._engine_frames.get(request_id[:2])
         if entry is None or not entry["stream_tokens"]:
             return
@@ -1013,7 +1020,7 @@ class LMGenerate(ComputeElement):
             buffer = entry["buffers"][row] = [
                 0, [], since or time.perf_counter(), None]
         buffer[1].append(int(token))
-        if len(buffer[1]) >= entry["chunk"]:
+        if buffer[0] == 0 or len(buffer[1]) >= entry["chunk"]:
             self._flush_stream_buffer(request_id[:2], entry, row)
 
     def _flush_stream_buffer(self, key, entry, row):

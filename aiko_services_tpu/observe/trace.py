@@ -222,7 +222,11 @@
 #                               short profile tells how it began,
 #                               first_us (its first token -> its first
 #                               chunk) and ingress_us (dispatch ->
-#                               DecodeEngine.submit), where known
+#                               DecodeEngine.submit), where known.  A
+#                               request's offset-0 chunk is its first
+#                               token alone, published inside the
+#                               engine.step of its prefill, right after
+#                               that engine.prefill
 #   engine.pump        scoped   LMGenerate._engine_pump; waited_us = the
 #                               pump message's mailbox wait
 #   compile            mark     closes a bracketed call that compiled

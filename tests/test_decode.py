@@ -708,18 +708,19 @@ class _Order:
                 > len(self.named("engine.readback")))
 
 
-def _serve(engine, arrivals, synchronous=False, each_tick=None):
+def _serve(engine, arrivals, synchronous=False, each_tick=None, emit=None):
     """Drive `engine` over `arrivals` ({tick: [(id, prompt, max_new)]})
     until idle.  `synchronous` reads every step back in its own tick:
     the engine as it was before it ran ahead, the twin whose decisions
     (admissions, preemptions) the run-ahead engine has to repeat.
-    Returns ({id: Completion}, {id: [(offset, token)]})."""
+    `emit` is handed to every step(), and the reports then hold no
+    token.  Returns ({id: Completion}, {id: [(offset, token)]})."""
     done, emitted = {}, {}
     tick = 0
     while engine.has_work() or any(at >= tick for at in arrivals):
         for request_id, prompt, max_new in arrivals.get(tick, ()):
             engine.submit(request_id, prompt, max_new)
-        report = engine.step()
+        report = engine.step(emit)
         if synchronous:
             engine.settle(report)
         for request_id, offset, token in report.emitted:
@@ -971,6 +972,119 @@ def test_run_ahead(tiny_model, monkeypatch, case):
     """The plain decode step runs one ahead of its readback, and every
     request's tokens stay the closed batch's to the bit."""
     RUN_AHEAD_CASES[case](*tiny_model, monkeypatch)
+
+
+# -- a token leaves when the host holds it -------------------------------------
+
+def _self_draft(params, config):
+    return dict(draft_params=params, draft_config=config, spec_k=3)
+
+
+EMIT_CASES = {
+    "plain": lambda params, config: {},
+    "carried": lambda params, config: {},
+    "chunked": lambda params, config: {"prefill_chunk_size": 8},
+    "prefix": lambda params, config: {
+        "prefix_policy": "prefix_cache=on;min_prefix_blocks=1"},
+    "speculative": _self_draft,
+}
+
+
+@pytest.mark.parametrize("case", sorted(EMIT_CASES))
+def test_emit_moves_when_a_token_leaves_and_nothing_else(tiny_model, case):
+    """An engine stepped with an `emit` hands out the tokens the reports
+    would have held, a request's in their order, and gives the device
+    the same programs in the same order: only the moment of the
+    hand-over moves.  Two requests admitted in one tick: the first's token 0 is
+    out before the second's prefill is dispatched."""
+    params, config = tiny_model
+    options = dict(decode_slots=3, kv_block_size=8,
+                   **EMIT_CASES[case](params, config))
+    rng = np.random.default_rng(17)
+    shared = rng.integers(1, 64, size=16).astype(np.int32)
+    requests = [(index, np.concatenate([shared, rng.integers(
+        1, 64, size=int(rng.integers(1, 9))).astype(np.int32)]),
+        int(rng.integers(2, 10))) for index in range(6)]
+    arrivals = {0: requests[:2], 3: requests[2:5], 9: requests[5:]}
+    settle_at = (2, 5) if case == "carried" else ()
+
+    order, twin_order = _Order(), _Order()
+    engine = DecodeEngine(params, config, spans=order, **options)
+    twin = DecodeEngine(params, config, spans=twin_order, **options)
+    carried = {engine: 0, twin: 0}
+
+    def settling(engine):
+        # the step in flight read between two steps: its tokens ride
+        # the carry into the next step
+        def each_tick(tick):
+            if tick in settle_at:
+                engine.settle()
+                carried[engine] += len(engine._carry.emitted)
+        return each_tick
+
+    done, in_reports = _serve(
+        engine, arrivals, each_tick=settling(engine),
+        emit=lambda triple: order.events.append(("emit", triple)))
+    twin_done, twin_emitted = _serve(twin, arrivals,
+                                     each_tick=settling(twin))
+    assert in_reports == {}
+    emitted: dict = {}
+    for _, (request_id, offset, token) in order.named("emit"):
+        emitted.setdefault(request_id, []).append((offset, token))
+    assert emitted == twin_emitted
+    assert carried[engine] == carried[twin]
+    assert bool(carried[engine]) == bool(settle_at)
+    for request_id, prompt, max_new in requests:
+        expected = reference(params, config, prompt, max_new)
+        np.testing.assert_array_equal(done[request_id].tokens, expected)
+        np.testing.assert_array_equal(twin_done[request_id].tokens,
+                                      expected)
+        # gapless from 0, whatever surfaced them (a speculative round
+        # several at once, a settle between steps through the carry)
+        assert emitted[request_id] == [
+            (offset, int(expected[offset])) for offset in range(max_new)]
+    # what the device was given, and in what order
+    device = ("engine.prefill", "engine.decode", "engine.readback")
+    assert ([(name, fields.get("bucket"), fields.get("decoding"))
+             for name, fields in order.named(*device)]
+            == [(name, fields.get("bucket"), fields.get("decoding"))
+                for name, fields in twin_order.named(*device)])
+    assert engine.stats()["decode_steps"] == twin.stats()["decode_steps"]
+    # the first tick admits requests 0 and 1: 0's first token is out
+    # before 1's prefill opens (a chunked prefill runs a chunk a tick,
+    # the oldest slot's first: 0's three chunks, then its token)
+    opened = [name if name != "emit" else fields[:2]
+              for name, fields in order.events
+              if name in ("emit", "engine.prefill")]
+    assert opened[:opened.index((1, 0))].count("engine.prefill") == (
+        6 if case == "chunked" else 2)
+    assert opened[:opened.index((0, 0))].count("engine.prefill") == (
+        3 if case == "chunked" else 1)
+
+
+def test_step_without_emit_fills_the_report(tiny_model):
+    """No `emit`: a tick's tokens leave in `report.emitted` when it
+    ends, two admissions' first tokens side by side, as before."""
+    params, config = tiny_model
+    engine = DecodeEngine(params, config, decode_slots=2, kv_block_size=8)
+    prompts = [np.arange(1, 6, dtype=np.int32),
+               np.arange(3, 12, dtype=np.int32)]
+    for index, prompt in enumerate(prompts):
+        engine.submit(index, prompt, 4)
+    report = engine.step()
+    assert report.emit is None and report.admitted == 2
+    assert report.emitted == [
+        (index, 0, int(reference(params, config, prompt, 4)[0]))
+        for index, prompt in enumerate(prompts)]
+    # and a later step handed an `emit` leaves the list alone
+    seen = []
+    while engine.has_work():
+        assert engine.step(seen.append).emitted == []
+    assert sorted(seen) == sorted(
+        (index, offset, int(token))
+        for index, prompt in enumerate(prompts)
+        for offset, token in enumerate(
+            reference(params, config, prompt, 4)) if offset)
 
 
 # -- chunked prefill (paged_prefill_chunk) ----------------------------------
@@ -1357,22 +1471,124 @@ def test_continuous_pipeline_zero_recompiles_after_warmup():
     process.terminate()
 
 
-def test_continuous_token_streaming_chunks():
+def _row_chunks(streamed):
+    """{(frame_id, row): [(offset, [tokens])]} of the `(token_chunk
+    stream_id frame_id row offset payload)` publishes, in the order
+    they came."""
+    from aiko_services_tpu.utils import parse
+    rows: dict = {}
+    for payload in streamed:
+        command, parameters = parse(payload)
+        if command == "token_chunk":
+            rows.setdefault(
+                (int(parameters[1]), int(parameters[2])), []).append(
+                (int(parameters[3]),
+                 [int(token) for token in parameters[4][0]]))
+    return rows
+
+
+# stream_chunk, the element's other parameters, the count of
+# engine_stats() that says the case ran what it names
+STREAMING_CASES = {
+    "chunk1": (1, {}, "decode_steps"), "chunk2": (2, {}, "decode_steps"),
+    "chunk8": (8, {}, "decode_steps"),
+    "chunk3_chunked_prefill": (3, {"prefill_chunk_size": 8},
+                               "prefill_chunks"),
+    "chunk3_prefix_hit": (3, {"prefix_policy":
+                              "prefix_cache=on;min_prefix_blocks=1"},
+                          "prefix_hits"),
+    "chunk3_speculative": (3, {"speculative": "draft=self;k=3;layers=1"},
+                           "spec_windows"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STREAMING_CASES))
+def test_continuous_token_streaming_chunks(case):
     """`stream_tokens` under the engine publishes per-ROW chunks
-    `(token_chunk stream_id frame_id row offset payload)` with gapless
-    offsets as slots decode -- a DISTINCT command from the closed-batch
-    `(tokens stream_id offset payload)` schema."""
+    `(token_chunk stream_id frame_id row offset payload)` -- a DISTINCT
+    command from the closed-batch `(tokens stream_id offset payload)`
+    schema.  A row's first token is a chunk of its own at offset 0,
+    out the moment the host holds it; chunks of `stream_chunk` follow,
+    the last what is left; offsets are gapless and the chunks joined
+    are the answer, whoever made the first token (a whole prefill, a
+    chunked one's last chunk, a prefix hit's tail) and however many a
+    round surfaces (a speculative one several)."""
+    stream_chunk, extra, ran = STREAMING_CASES[case]
     rng = np.random.default_rng(2)
-    frames = [rng.integers(1, 300, size=(2, 5)).astype(np.int32)]
-    # 2 rows x 6 tokens in chunks of 2 -> 6 publishes
-    results, streamed, _ = run_lm_frames(
+    shared = rng.integers(1, 300, size=(1, 16)).astype(np.int32)
+    frames = [np.concatenate([np.repeat(shared, 2, axis=0), rng.integers(
+        1, 300, size=(2, 5)).astype(np.int32)], axis=1) for _ in range(2)]
+    new_tokens = 12
+    publishes = 2 * 2 * (1 + -(-(new_tokens - 1) // stream_chunk))
+    results, streamed, lm_element = run_lm_frames(
         {"continuous": True, "decode_slots": 2, "kv_block_size": 8,
-         "stream_tokens": True, "stream_chunk": 2},
-        frames, wait_out=6)
-    assert len([s for s in streamed
-                if s.startswith("(token_chunk")]) >= 6
-    [(_, _, outputs)] = results
+         "max_new_tokens": new_tokens, "stream_tokens": True,
+         "stream_chunk": stream_chunk, **extra},
+        frames, wait_out=publishes)
+    assert len(streamed) == publishes
+    assert lm_element.engine_stats()[ran] > 0
+    chunks_of = _row_chunks(streamed)
+    assert len(chunks_of) == 2 * 2
+    for (_, frame, outputs) in results:
+        generated = np.asarray(outputs["generated"])
+        assert generated.shape == (2, new_tokens)
+        for row in range(2):
+            chunks = chunks_of[frame.frame_id, row]
+            assert chunks[0][0] == 0 and len(chunks[0][1]) == 1
+            assert [len(tokens) for _, tokens in chunks[1:-1]] == [
+                stream_chunk] * (len(chunks) - 2)
+            assert 1 <= len(chunks[-1][1]) <= stream_chunk
+            # gapless: a chunk starts where the one before ended
+            assert [offset for offset, _ in chunks] == [
+                sum(len(tokens) for _, tokens in chunks[:index])
+                for index in range(len(chunks))]
+            np.testing.assert_array_equal(
+                np.concatenate([tokens for _, tokens in chunks]),
+                generated[row])
+
+
+def test_first_token_is_published_before_the_next_prefill_is_dispatched(
+        monkeypatch):
+    """Two rows of one frame are admitted in one tick: row 0's chunk at
+    offset 0 is on /out before row 1's prefill is handed to the device,
+    and the device is still given prefill, prefill, then the steps."""
+    from aiko_services_tpu.decode import engine as engine_module
+    events = []
+    for name in ("paged_prefill", "paged_decode_step"):
+        program = getattr(engine_module, name)
+
+        def recording(*args, _name=name, _program=program, **kwargs):
+            events.append(_name)
+            return _program(*args, **kwargs)
+
+        monkeypatch.setattr(engine_module, name, recording)
+    process = Process(transport_kind="loopback")
+    pipeline = create_pipeline(process, lm_definition(
+        {"continuous": True, "decode_slots": 2, "kv_block_size": 8,
+         "stream_tokens": True, "stream_chunk": 8}))
+    lm_element = pipeline.elements["lm"]
+    publish = lm_element.publish_out
+
+    def recording_publish(command, parameters):
+        events.append((command, parameters[2], parameters[3]))
+        return publish(command, parameters)
+
+    lm_element.publish_out = recording_publish
+    process.run(in_thread=True)
+    responses = queue.Queue()
+    stream = pipeline.create_stream("s", queue_response=responses,
+                                    grace_time=300)
+    pipeline.create_frame(stream, {"tokens": np.arange(
+        1, 11, dtype=np.int32).reshape(2, 5)})
+    _, _, outputs = responses.get(timeout=120)
+    process.terminate()
     assert np.asarray(outputs["generated"]).shape == (2, 6)
+    assert events[:5] == [
+        "paged_prefill", ("token_chunk", 0, 0),
+        "paged_prefill", ("token_chunk", 1, 0), "paged_decode_step"]
+    # the rest of each answer leaves when it ends: five tokens, under 8
+    assert [event for event in events[5:] if event != "paged_decode_step"
+            ] == [("token_chunk", 0, 1), ("token_chunk", 1, 1)]
 
 
 def test_continuous_stop_stream_cancels_inflight():
@@ -1476,7 +1692,7 @@ def test_engine_failure_releases_pending_frames():
     pipeline.create_frame(stream, {"tokens": tokens})
     expected = np.asarray(responses.get(timeout=120)[2]["generated"])
 
-    def explode():
+    def explode(emit=None):
         raise RuntimeError("injected device failure")
 
     lm_element._engine.step = explode

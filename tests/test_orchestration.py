@@ -341,6 +341,12 @@ class TestLifeCycle:
         wait_for(lambda: manager.clients.get(
             client_id, {}).get("state") == "running", timeout=10)
         assert client_id in manager.process_manager  # sleeper alive
+        # the reap goes by the registrar's "remove" of a service the
+        # manager's mirror HOLDS; the handshake reaches the manager
+        # directly and can arrive before the mirror has the service
+        wait_for(lambda: list(
+            manager._services_cache.services.filter_services(
+                ServiceFilter(name="worker3"))), timeout=10)
 
         client_process.transport.sever()  # crash WITH LWT
         wait_for(lambda: client_id not in manager.clients, timeout=15)

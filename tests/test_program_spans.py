@@ -473,11 +473,14 @@ class TestGraphSpans:
 
 CHUNK = 2
 NEW_TOKENS = 6
+# a request's first token is a chunk of its own; chunks of CHUNK follow,
+# the last what is left: 1 + 2 + 2 + 1
+CHUNKS = 1 + -(-(NEW_TOKENS - 1) // CHUNK)
 
 
 def _run_served(telemetry=True, requests=3):
     """A gateway in front of one `continuous: true` LMGenerate replica
-    that streams chunks of CHUNK tokens."""
+    that streams a request's first token, then chunks of CHUNK."""
     replica_process = Process(transport_kind="loopback")
     definition = lm_definition(
         {"continuous": True, "decode_slots": 2, "kv_block_size": 8,
@@ -518,8 +521,7 @@ def _run_served(telemetry=True, requests=3):
             {"tokens": rng.integers(1, 300, size=(1, 5 + index)).astype(
                 np.int32)}, frame_id=0)
     results = [responses.get(timeout=180) for _ in range(requests)]
-    wait_for(lambda: len(chunks) >= requests * NEW_TOKENS // CHUNK,
-             timeout=30)
+    wait_for(lambda: len(chunks) >= requests * CHUNKS, timeout=30)
     return dict(gateway=gateway, replica=replica, processes=processes,
                 results=results, chunks=chunks, dispatched=dispatched)
 
@@ -527,6 +529,10 @@ def _run_served(telemetry=True, requests=3):
 @pytest.fixture(scope="module")
 def served_run(tmp_path_factory):
     reset_brokers()
+    # the paged programs are jitted by config and shape for the process:
+    # whatever this worker served before, this run compiles its own and
+    # closes each with an `aiko:compile` mark
+    jax.clear_caches()
     recorded, run = _profiled(
         tmp_path_factory.mktemp("served_profile"), _run_served)
     for process in run["processes"]:
@@ -570,8 +576,11 @@ class TestServedSpans:
         kinds = set()
         for step in recorded.named("engine.step"):
             inner = [event[0] for event in recorded.inside(step)]
+            # (and the marks of the chunks it published: a token goes
+            # out inside the tick that surfaced it)
             assert set(inner) <= {"engine.prefill", "engine.decode",
-                                  "engine.readback", "compile"}, inner
+                                  "engine.readback", "engine.chunk",
+                                  "compile"}, inner
             # a tick that decodes dispatches one step, and no tick reads
             # more than one: the step before, or none after an admission
             # (the prefill's first token settled what was in flight)
@@ -678,10 +687,12 @@ class TestServedSpans:
             # the first token is stamped right after the prefill's
             # readback, inside the same tick
             assert 0 <= began - (prefill[2] + prefill[3]) < 5e6
-            assert chunk[4]["tokens"] == CHUNK
+            # and it goes out alone, however long a chunk is
+            assert chunk[4]["tokens"] == 1
         later = [event for event in recorded.named("engine.chunk")
                  if event[4]["offset"] > 0]
-        assert {event[4]["offset"] for event in later} == {2, 4}
+        assert {(event[4]["offset"], event[4]["tokens"])
+                for event in later} == {(1, CHUNK), (3, CHUNK), (5, 1)}
         by_stream: dict = {}
         for event in recorded.named("engine.chunk"):
             by_stream.setdefault(event[4]["stream"], []).append(event)
@@ -690,6 +701,30 @@ class TestServedSpans:
                 # a later chunk counts from the chunk before it
                 began = following[2] - following[4]["waited_us"] * 1e3
                 assert abs(began - previous[2]) < 2e6
+
+    def test_first_chunk_leaves_inside_the_tick_of_its_prefill(
+            self, served_run):
+        """The offset-0 mark lies inside the `engine.step` that holds its
+        request's prefill, after that prefill and before the tick's next
+        prefill or step is dispatched: the token is published the moment
+        the host holds it, not when the tick ends."""
+        recorded, _ = served_run
+        firsts = {event[4]["stream"]: event
+                  for event in recorded.named("engine.chunk")
+                  if event[4]["offset"] == 0}
+        assert len(firsts) == 3
+        seen = 0
+        for step in recorded.named("engine.step"):
+            inner = sorted(recorded.inside(step), key=lambda event: event[2])
+            for index, event in enumerate(inner):
+                if event[0] != "engine.prefill":
+                    continue
+                following = [other for other in inner[index + 1:]
+                             if other[0] != "compile"]
+                assert following and following[0] is firsts[
+                    event[4]["stream"]], (event, following[:1])
+                seen += 1
+        assert seen == 3
 
     def test_every_chunk_says_how_its_request_began(self, served_run):
         """A short profile holds requests in flight whose beginning lies
@@ -706,7 +741,7 @@ class TestServedSpans:
             by_stream.setdefault(event[4]["stream"], []).append(event[4])
         assert len(by_stream) == 3
         for stream, chunks in by_stream.items():
-            assert len(chunks) == NEW_TOKENS // CHUNK
+            assert len(chunks) == CHUNKS
             assert chunks[0]["offset"] == 0
             assert chunks[0]["first_us"] == chunks[0]["waited_us"]
             assert {args["first_us"] for args in chunks} == {
@@ -725,7 +760,7 @@ class TestServedSpans:
             names = [event[1] for event in program]
             assert names[:3] == ["aiko:ingress", "aiko:engine.submit",
                                  "aiko:engine.prefill"]
-            assert names.count("aiko:engine.chunk") == NEW_TOKENS // CHUNK
+            assert names.count("aiko:engine.chunk") == CHUNKS
             assert frame_trace.ingress_wait_s is not None
             for kind, _, _, start, duration, _ in program:
                 assert kind == "X" and duration >= 0

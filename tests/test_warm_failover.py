@@ -610,12 +610,17 @@ class TestGatewayWarmFailover:
             for process in processes:
                 process.terminate()
 
-    def test_element_resume_from_publishes_floor_offsets(self):
+    @pytest.mark.parametrize("stream_chunk", [1, 4])
+    def test_element_resume_from_publishes_floor_offsets(
+            self, stream_chunk):
         """A replaying client that already holds offsets [0, crash)
         passes resume_from through the restore hint: the restored
         element's `(token_chunk …)` offsets must START at the floor --
         publishing them from 0 would make an offset-keyed consumer
-        overwrite its held prefix with later tokens."""
+        overwrite its held prefix with later tokens.  A row restored
+        past offset 0 has no first token to hurry: its chunks are
+        `stream_chunk` long from the floor on (only a row's offset 0
+        is a chunk of its own)."""
         rng = np.random.default_rng(21)
         frame = rng.integers(1, 300, size=(1, 6)).astype(np.int32)
         max_new = 24
@@ -623,7 +628,7 @@ class TestGatewayWarmFailover:
         keeper = CheckpointKeeper("ek")
         extra = {"continuous": True, "decode_slots": 2,
                  "kv_block_size": 8, "max_new_tokens": max_new,
-                 "stream_tokens": True, "stream_chunk": 1,
+                 "stream_tokens": True, "stream_chunk": stream_chunk,
                  "checkpoint": ("checkpoint_every=1;"
                                 "max_checkpoint_lag=4;keeper=ek")}
         chunks_a, chunks_b = [], []
@@ -654,8 +659,13 @@ class TestGatewayWarmFailover:
         replica_a.create_frame(stream_a, {"tokens": frame})
         wait_for(lambda: keeper.flush(timeout=0.1)
                  and keeper.kept_count() >= 1
-                 and len(chunks_a) >= 4, timeout=60)
+                 and sum(len(t) for _s, _r, _o, t in chunks_a) >= 4,
+                 timeout=60)
         process_a.terminate()   # the crash: mid-decode, chunks held
+        # before the crash: the first token alone, then whole chunks
+        assert [(offset, len(tokens))
+                for _s, _r, offset, tokens in chunks_a[:2]] == [
+            (0, 1), (1, stream_chunk)]
         held = {}
         for _sid, _row, offset, tokens in chunks_a:
             for j, token in enumerate(tokens):
@@ -695,6 +705,11 @@ class TestGatewayWarmFailover:
                 f"restored chunks start at {offsets[0]}, the client "
                 f"already holds [0, {crash})")
             assert offsets == list(range(crash, max_new))
+            # gapless from the floor, in whole chunks but the last
+            assert [(offset, len(tokens))
+                    for _s, _r, offset, tokens in chunks_b] == [
+                (start, min(stream_chunk, max_new - start))
+                for start in range(crash, max_new, stream_chunk)]
             resumed = dict(held)
             for _sid, _row, offset, tokens in chunks_b:
                 for j, token in enumerate(tokens):
